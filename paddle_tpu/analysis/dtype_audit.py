@@ -41,7 +41,9 @@ def _allowed(eqn, allowlist):
     if fr is None:
         return False
     fname = str(getattr(fr, "file_name", "") or "")
-    func = str(getattr(fr, "function_name", "") or "")
+    # frames carry the qualified name ("Outer.<locals>.fn"); the allowlist
+    # names the function itself
+    func = str(getattr(fr, "function_name", "") or "").rsplit(".", 1)[-1]
     for entry in allowlist:
         efile, _, efunc = entry.partition("::")
         if fname.endswith(efile) and (not efunc or efunc == func):

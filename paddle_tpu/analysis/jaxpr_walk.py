@@ -2,12 +2,12 @@
 the nested ones", so audit rules (and tests) stop hand-rolling partial
 traversals.
 
-Handles every place jax 0.4.x hides a subjaxpr:
+Handles every place jax hides a subjaxpr:
   - pjit / closed_call / custom_jvp_call / custom_vjp_call_jaxpr carry a
     ClosedJaxpr under params["jaxpr"] / ["call_jaxpr"] / ["fun_jaxpr"];
   - scan / while carry ClosedJaxprs ("jaxpr", "cond_jaxpr", "body_jaxpr");
   - cond carries a TUPLE of ClosedJaxprs under "branches";
-  - legacy shard_map carries an OPEN Jaxpr under "jaxpr".
+  - shard_map carries an OPEN Jaxpr under "jaxpr".
 
 The walker doesn't enumerate those keys — it scans every param value for
 anything jaxpr-shaped (has `.eqns`, or wraps something that does), so new
@@ -88,23 +88,19 @@ def user_frame(eqn):
     """Best-effort first user (non-jax-internal) frame of an equation's
     source_info. Returns an object with file_name / start_line /
     function_name, or None."""
-    si = getattr(eqn, "source_info", None)
-    if si is None:
-        return None
-    try:
-        from jax._src import source_info_util as siu
+    from jax._src import source_info_util as siu
 
-        fr = siu.user_frame(si)
-        if fr is not None:
-            return fr
-        # fall back to the raw traceback's innermost frame (user_frame
-        # filters to non-jax code and can come up empty for ops built by
-        # jax-internal helpers)
-        tb = getattr(si, "traceback", None)
-        frames = list(tb.frames) if tb is not None else []
-        return frames[0] if frames else None
-    except Exception:
+    tb = eqn.source_info.traceback
+    if tb is None:
         return None
+    fr = siu.user_frame(tb)
+    if fr is not None:
+        return fr
+    # fall back to the raw traceback's innermost frame (user_frame filters
+    # to non-jax code and can come up empty for ops built by jax-internal
+    # helpers)
+    frames = list(tb.frames)
+    return frames[0] if frames else None
 
 
 def provenance(eqn):

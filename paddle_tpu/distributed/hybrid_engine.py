@@ -54,7 +54,6 @@ __all__ = ["HybridParallelEngine"]
 
 from paddle_tpu.core.numerics import \
     stochastic_round_bf16 as _stochastic_round_bf16
-from paddle_tpu.distributed.mesh_utils import pcast_compat as _pcast
 
 
 def _factored_leaf(shape):
@@ -317,10 +316,7 @@ class HybridParallelEngine:
             # single-chip peak would inflate MFU by the device count
             from paddle_tpu.observability.hardware import detect_peak_flops
 
-            try:
-                per_chip = detect_peak_flops()
-            except Exception:
-                per_chip = None
+            per_chip = detect_peak_flops()
             monitor.peak_flops = (per_chip * self.mesh.devices.size
                                   if per_chip else None)
         # auto-fill MFU flops only when the monitor didn't come with a
@@ -609,7 +605,7 @@ class HybridParallelEngine:
         # stage instead of deadlocking inside a divergent branch.
         lp = dict(lp)
         for k in ("embedding", "lm_head", "final_norm"):
-            lp[k] = _pcast(lp[k], ("pp",), to="varying")
+            lp[k] = jax.lax.pcast(lp[k], ("pp",), to="varying")
 
         embed_mb, head_loss, zero_loss = self._mk_stage_helpers(
             ids, labels, s_len)
@@ -658,7 +654,7 @@ class HybridParallelEngine:
         # SP); pvary the zero carry up-front so the vma type is stable
         vary_axes = (("dp", "pp") + self._cp_vary
                      + (("mp",) if (sp and mp_axis) else ()))
-        h0 = _pcast(h0, vary_axes, to="varying")
+        h0 = jax.lax.pcast(h0, vary_axes, to="varying")
         _, losses = jax.lax.scan(step, h0, jnp.arange(M + S - 1))
         # Scale by 1/dp so this is each rank's *contribution to the global
         # mean* loss. Params arrive dp-invariant, so their implicit pvary at
@@ -697,7 +693,7 @@ class HybridParallelEngine:
 
         lp = dict(lp)
         for k in ("embedding", "lm_head", "final_norm"):
-            lp[k] = _pcast(lp[k], ("pp",), to="varying")
+            lp[k] = jax.lax.pcast(lp[k], ("pp",), to="varying")
 
         za = self._zero_axis
 
@@ -741,7 +737,7 @@ class HybridParallelEngine:
         h0 = jnp.zeros((mb_local, seq_local, args.hidden_size), self.dtype)
         vary_axes = (("dp", "pp") + self._cp_vary
                      + (("mp",) if (sp and mp_axis) else ()))
-        h0 = _pcast(h0, vary_axes, to="varying")
+        h0 = jax.lax.pcast(h0, vary_axes, to="varying")
         G = -(-M // S)  # groups of S micro-batches
         a_max = (G - 1) * S * V + (V - 1) * S + (M - 1) % S
         T = a_max + S  # last unit finishes at stage S-1, tick a_max + S - 1
@@ -797,7 +793,7 @@ class HybridParallelEngine:
         # micro-batch that AD's transpose would otherwise insert.
         spec_tree = self._spec_tree(lp)
         lp = jax.tree.map(
-            lambda x, sp_: _pcast(x, self._missing_axes(sp_),
+            lambda x, sp_: jax.lax.pcast(x, self._missing_axes(sp_),
                                          to="varying"),
             lp, spec_tree, is_leaf=lambda x: isinstance(x, P))
 
@@ -821,7 +817,7 @@ class HybridParallelEngine:
                      + (("mp",) if (sp and mp_axis) else ()))
 
         def vary(x):
-            return _pcast(x, vary_axes, to="varying")
+            return jax.lax.pcast(x, vary_axes, to="varying")
 
         def step(carry, t):
             h_prev, g_prev, slots, gacc, lacc = carry
@@ -887,7 +883,7 @@ class HybridParallelEngine:
         g0 = vary(jnp.zeros(h_shape, self.dtype))
         slots0 = vary(jnp.zeros((B + 1,) + h_shape, self.dtype))
         gacc0 = jax.tree.map(jnp.zeros_like, lp)
-        lacc0 = _pcast(jnp.zeros((), jnp.float32),
+        lacc0 = jax.lax.pcast(jnp.zeros((), jnp.float32),
                               ("dp", "pp") + self._cp_vary,
                               to="varying")
         T = M + 2 * S - 1
@@ -940,7 +936,7 @@ class HybridParallelEngine:
 
         spec_tree = self._spec_tree(lp)
         lp = jax.tree.map(
-            lambda x, sp_: _pcast(x, self._missing_axes(sp_),
+            lambda x, sp_: jax.lax.pcast(x, self._missing_axes(sp_),
                                          to="varying"),
             lp, spec_tree, is_leaf=lambda x: isinstance(x, P))
 
@@ -963,7 +959,7 @@ class HybridParallelEngine:
                      + (("mp",) if (sp and mp_axis) else ()))
 
         def vary(x):
-            return _pcast(x, vary_axes, to="varying")
+            return jax.lax.pcast(x, vary_axes, to="varying")
 
         role = jnp.where(stage == 0, 0, jnp.where(stage == S - 1, 2, 1))
 
@@ -1028,7 +1024,7 @@ class HybridParallelEngine:
         g0 = vary(jnp.zeros(h_shape, self.dtype))
         h_store0 = vary(jnp.zeros((M + 1,) + h_shape, self.dtype))
         g_store0 = vary(jnp.zeros((M + 1,) + h_shape, self.dtype))
-        lacc0 = _pcast(jnp.zeros((), jnp.float32),
+        lacc0 = jax.lax.pcast(jnp.zeros((), jnp.float32),
                               ("dp", "pp") + self._cp_vary,
                               to="varying")
         T = M + 2 * S - 1
@@ -1157,9 +1153,7 @@ class HybridParallelEngine:
             local = functools.partial(
                 {"1f1b": self._grads_1f1b, "zb": self._grads_zb}.get(
                     self.schedule, self._local_grads))
-            from paddle_tpu.distributed.mesh_utils import shard_map_compat
-
-            shard_mapped = shard_map_compat(
+            shard_mapped = jax.shard_map(
                 local, mesh=mesh,
                 in_specs=(flat_specs_tree, data_spec, data_spec),
                 out_specs=(P(), flat_specs_tree),

@@ -24,102 +24,7 @@ import jax
 import numpy as np
 from jax.sharding import Mesh
 
-__all__ = ["create_hybrid_mesh", "single_axis_mesh", "slice_count",
-           "shard_map_compat"]
-
-
-_legacy_rules_registered = False
-
-
-def _register_legacy_rep_rules():
-    """Teach the legacy replication checker the identity primitives our
-    programs use (checkpoint_name lacks a rule there). Best-effort: private
-    registry, so failures just leave the checker stricter."""
-    global _legacy_rules_registered
-    if _legacy_rules_registered:
-        return
-    _legacy_rules_registered = True
-    try:
-        from jax._src.ad_checkpoint import name_p
-        from jax.experimental import shard_map as smod
-
-        smod.register_standard_check(name_p)
-        smod.register_standard_rewrite(name_p)
-    except Exception:
-        pass
-
-
-def shard_map_compat(f, mesh, in_specs, out_specs, check_vma=True):
-    """`jax.shard_map` across jax versions: the top-level alias (and its
-    `check_vma` spelling) only exist on newer jax; older versions carry
-    `jax.experimental.shard_map.shard_map` with the pre-vma `check_rep`
-    checker. The checker stays ON there: where legacy cannot analyze a
-    program (e.g. lax.cond branches) it fails LOUDLY with a clear message
-    — strictly better than check_rep=False, under which AD'd paths that
-    rely on vma-typed transposes produce silently wrong gradients."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as legacy_sm
-
-    _register_legacy_rep_rules()
-    return legacy_sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=check_vma)
-
-
-def axis_size_compat(axis_name):
-    """Static mesh-axis size inside shard_map across jax versions:
-    `jax.lax.axis_size` on newer jax; the axis-env frame (which already
-    carries the static size) on legacy."""
-    ax = getattr(jax.lax, "axis_size", None)
-    if ax is not None:
-        return ax(axis_name)
-    from jax.core import axis_frame
-
-    frame = axis_frame(axis_name)
-    if isinstance(frame, int):  # 0.4.x returns the size directly
-        return frame
-    return frame.size  # raise HERE if neither shape fits, not at range(P)
-
-
-import functools as _functools
-
-
-@_functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
-def _legacy_pvary(x, axes):
-    return x
-
-
-def _legacy_pvary_fwd(x, axes):
-    return x, None
-
-
-def _legacy_pvary_bwd(axes, _, g):
-    return (jax.lax.psum(g, axes),)
-
-
-_legacy_pvary.defvjp(_legacy_pvary_fwd, _legacy_pvary_bwd)
-
-
-def pcast_compat(x, axes, to="varying"):
-    """`jax.lax.pcast` when it exists (the vma cast newer shard_map needs).
-    On legacy jax the cast is identity in forward, but its AD transpose is
-    load-bearing: replicated->varying casts psum the cotangent over `axes`
-    (how replicated params' grads get combined across e.g. 'pp'). Emulated
-    with a custom_vjp so the AD'd schedule paths stay numerically correct
-    under the legacy shard_map."""
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is not None:
-        return pcast(x, axes, to=to)
-    if to != "varying":
-        raise NotImplementedError(
-            f"pcast_compat only emulates to='varying' on legacy jax, "
-            f"got to={to!r}")
-    axes = (axes,) if isinstance(axes, str) else tuple(axes)
-    if not axes:
-        return x
-    return _legacy_pvary(x, axes)
+__all__ = ["create_hybrid_mesh", "single_axis_mesh", "slice_count"]
 
 
 def slice_count(devices=None):

@@ -38,17 +38,6 @@ __all__ = ["init_parallel_env", "get_rank", "get_world_size", "ParallelEnv",
 _env = None
 
 
-def _jax_distributed_initialized():
-    """jax.distributed.is_initialized() only exists from jax 0.4.39; on
-    older jax, the coordination-service client on global_state is the
-    initialized-ness signal."""
-    probe = getattr(jax.distributed, "is_initialized", None)
-    if probe is not None:
-        return bool(probe())
-    state = getattr(jax.distributed, "global_state", None)
-    return getattr(state, "client", None) is not None
-
-
 class ParallelEnv:
     """Reference: parallel.py ParallelEnv reading PADDLE_TRAINER_* env."""
 
@@ -83,7 +72,7 @@ def init_parallel_env():
     master = os.environ.get("PADDLE_MASTER", "")
     nprocs = int(os.environ.get("PADDLE_TRAINERS_NUM", "1"))
     proc_id = int(os.environ.get("PADDLE_TRAINER_ID", "0"))
-    if master and nprocs > 1 and not _jax_distributed_initialized():
+    if master and nprocs > 1 and not jax.distributed.is_initialized():
         # native TCPStore rendezvous (reference parallel.py:1134): rank 0
         # hosts the store; everyone barriers so jax.distributed.initialize
         # only starts once all hosts are up (clearer failures than a

@@ -34,9 +34,6 @@ planned refinement.
 from __future__ import annotations
 
 import jax
-
-from paddle_tpu.distributed.mesh_utils import \
-    axis_size_compat as _axis_size
 import jax.numpy as jnp
 
 __all__ = ["ring_attention", "ulysses_attention"]
@@ -99,7 +96,7 @@ def ring_attention(q, k, v, axis_name, causal=True, sm_scale=None,
 
     b, s_local, h, d = q.shape
     sm_scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
-    P = _axis_size(axis_name)
+    P = jax.lax.axis_size(axis_name)
     rank = jax.lax.axis_index(axis_name)
     perm = [(i, (i + 1) % P) for i in range(P)]
 
@@ -178,7 +175,7 @@ def ulysses_attention(q, k, v, axis_name, causal=True, sm_scale=None,
     from paddle_tpu.kernels import flash_attention as fa
     from paddle_tpu.nn.functional.flash_attention import _sdpa_reference
 
-    P = _axis_size(axis_name)
+    P = jax.lax.axis_size(axis_name)
     h, hk = q.shape[2], k.shape[2]
     if h % P != 0 or hk % P != 0:
         raise ValueError(

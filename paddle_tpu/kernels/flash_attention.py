@@ -96,13 +96,8 @@ def _pick_block(seq, preferred, floor=128, fallback=None):
 def _sds(shape, dtype, like):
     """ShapeDtypeStruct carrying `like`'s varying-mesh-axes type, so the
     kernels compose with shard_map(check_vma=True) (e.g. under the hybrid
-    engine's mp axis or ring attention's cp axis). `jax.typeof` only exists
-    on newer jax; older versions have no vma tracking to propagate."""
-    typeof = getattr(jax, "typeof", None)
-    vma = getattr(typeof(like), "vma", None) if typeof is not None else None
-    if vma:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    return jax.ShapeDtypeStruct(shape, dtype)
+    engine's mp axis or ring attention's cp axis)."""
+    return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
 
 
 # ---------------------------------------------------------------------------
@@ -504,15 +499,6 @@ def flash_attention_fwd(q, k, v, causal=False, scale=None, interpret=False):
     if not supports(q.shape, k.shape, q.dtype.itemsize):
         # unpadded tails: fall back to the fused XLA path
         return _sdpa_xla(q, k, v, causal, sm_scale)
-    try:
-        return _flash_attention(q, k, v, causal, sm_scale, interpret)
-    except Exception as e:  # lowering constraints supports() doesn't model
-        # loud fallback: real kernel bugs must surface, not vanish silently
-        # (backward-only lowering failures are not caught here — they raise
-        # at vjp time)
-        import warnings
-
-        warnings.warn(
-            f"Pallas flash attention failed ({type(e).__name__}: {e}); "
-            f"falling back to the XLA path for shapes q={q.shape} k={k.shape}")
-        return _sdpa_xla(q, k, v, causal, sm_scale)
+    # a shape supports() accepts and Mosaic refuses is a bug in supports()
+    # or in the kernel: it raises, it does not become the XLA path
+    return _flash_attention(q, k, v, causal, sm_scale, interpret)

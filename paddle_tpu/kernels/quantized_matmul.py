@@ -10,7 +10,7 @@ after the MACs) and
 Why a kernel and not XLA: through plain StableHLO the weight-only dequant
 (`convert(int8) * scale`) is materialized as a full-width bf16 weight in HBM
 before every matmul, so small-batch decode pays the int8 read AND a bf16
-round trip — measured 0.892x bf16 (BENCH_r05 `int8_weight_only_infer`).
+round trip — measured 0.892x bf16 (v5e, 2026-08-01, before PR 1).
 Small-batch decode is weight-stream bound, so the only lever is bytes moved:
 
 - `fused_dequant_matmul`: int8 weight tiles DMA from HBM into VMEM at 1-byte
@@ -161,12 +161,17 @@ def fused_dequant_matmul(x, w, scale, out_dtype=None, block_m=None,
     bn = min(block_n, _round_up(n_total, 128))
     bk = min(block_k, _round_up(k_total, 128))
     n_kb = pl.cdiv(k_total, bk)
-    grid = (pl.cdiv(m_total, bm), pl.cdiv(n_total, bn), n_kb)
+    if m_total < 8 and k_total % bk:
+        # Mosaic cannot mask the K tail of an activation with fewer rows
+        # than one sublane tile ("Not implemented: Sublane broadcast" on
+        # the v5e, libtpu 0.0.34): batch-1..7 decode pads up to 8 rows
+        x2 = jnp.pad(x2, ((0, 8 - m_total), (0, 0)))
+    grid = (pl.cdiv(x2.shape[0], bm), pl.cdiv(n_total, bn), n_kb)
 
     out = pl.pallas_call(
         functools.partial(_dqmm_kernel, block_k=bk, n_kb=n_kb,
                           k_total=k_total),
-        out_shape=jax.ShapeDtypeStruct((m_total, n_total), out_dtype),
+        out_shape=jax.ShapeDtypeStruct((x2.shape[0], n_total), out_dtype),
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, ki: (i, ki)),
@@ -177,7 +182,7 @@ def fused_dequant_matmul(x, w, scale, out_dtype=None, block_m=None,
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
     )(x2, w, scale.reshape(1, n_total).astype(jnp.float32))
-    return out.reshape(*lead, n_total)
+    return out[:m_total].reshape(*lead, n_total)
 
 
 def matmul_supported(x_shape, w_shape, itemsize=2, block_n=512, block_k=512):
@@ -230,18 +235,8 @@ def weight_only_matmul(x, w, scale, out_dtype=None):
     use_pallas, interpret = _mode()
     if use_pallas and w.dtype == jnp.int8 and \
             matmul_supported(x.shape, w.shape, x.dtype.itemsize):
-        try:
-            return fused_dequant_matmul(x, w, scale, out_dtype,
-                                        interpret=interpret)
-        except Exception as e:  # lowering constraints supports() can't model
-            # loud fallback, as kernels/flash_attention: real kernel bugs
-            # must surface, not vanish silently
-            import warnings
-
-            warnings.warn(
-                f"Pallas fused dequant-matmul failed ({type(e).__name__}: "
-                f"{e}); falling back to the XLA composition for "
-                f"x={x.shape} w={w.shape}")
+        return fused_dequant_matmul(x, w, scale, out_dtype,
+                                    interpret=interpret)
     return _dequant_matmul_xla(x, w, scale, out_dtype)
 
 
@@ -508,16 +503,8 @@ def window_decode_attention(q, cache_k, cache_v, pos, scale=None,
     use_pallas, interpret = _mode()
     if use_pallas and window_supported(q.shape, cache_k.shape,
                                        q.dtype.itemsize):
-        try:
-            return _window_attention_pallas(q, cache_k, cache_v, pos,
-                                            sm_scale, block_k, interpret)
-        except Exception as e:  # lowering constraints supports() can't model
-            import warnings
-
-            warnings.warn(
-                f"Pallas window attention failed ({type(e).__name__}: "
-                f"{e}); falling back to the XLA path for q={q.shape} "
-                f"cache={cache_k.shape}")
+        return _window_attention_pallas(q, cache_k, cache_v, pos,
+                                        sm_scale, block_k, interpret)
     return _window_attention_xla(q, cache_k, cache_v, pos, sm_scale)
 
 
@@ -592,17 +579,19 @@ def paged_decode_supported(q_shape, pool_shape, bt_shape, itemsize=2):
     return per_step <= _VMEM_BUDGET_BYTES
 
 
-def _paged_decode_kernel_q8(pos_ref, bt_ref, q_ref, k_ref, v_ref, sk_ref,
-                            sv_ref, o_ref, acc_ref, m_ref, l_ref, *,
-                            page_size, sm_scale):
+def _paged_decode_kernel_q8(pos_ref, bt_ref, sk_ref, sv_ref, q_ref, k_ref,
+                            v_ref, o_ref, acc_ref, m_ref, l_ref, *,
+                            page_size, sm_scale, nkv):
     # int8-pool variant of `_paged_decode_kernel`: k/v blocks arrive as
-    # int8 PAGES with this page's per-(page, kv-head) absmax in sk/sv
-    # (1, 1) blocks routed through the same block-table index map. The
+    # int8 PAGES; the per-(page, kv-head) absmax scales ride in SMEM
+    # (scalar-prefetched, flattened [num_pages * nkv]) and are read as
+    # scalars at the page the block table names — a (1, 1) VMEM block of
+    # the [num_pages, nkv] array is not a tile Mosaic can window. The
     # dequant is the PR-1 in-registers pattern — int8 upcasts between the
     # DMA and the MXU (exact in bf16), and the page's scale folds into
     # the score scale (k) and the accumulator contribution (v), so a
     # full-width page never exists outside registers.
-    bi, j = pl.program_id(0), pl.program_id(2)
+    bi, hi, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     pos = pos_ref[bi]
 
     @pl.when(j == 0)
@@ -619,7 +608,8 @@ def _paged_decode_kernel_q8(pos_ref, bt_ref, q_ref, k_ref, v_ref, sk_ref,
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
-        s = s * (sk_ref[0, 0] * (sm_scale / 127.0))          # [g, ps]
+        sidx = bt_ref[bi, j] * nkv + hi
+        s = s * (sk_ref[sidx] * (sm_scale / 127.0))          # [g, ps]
         cols = j * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(cols <= pos, s, _NEG_INF)
         m_prev, l_prev = m_ref[...], l_ref[...]
@@ -630,7 +620,7 @@ def _paged_decode_kernel_q8(pos_ref, bt_ref, q_ref, k_ref, v_ref, sk_ref,
         l_ref[...] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * (sv_ref[0, 0] / 127.0)
+            preferred_element_type=jnp.float32) * (sv_ref[sidx] / 127.0)
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _finish():
@@ -648,47 +638,43 @@ def _paged_decode_attention_pallas(q, pool_k, pool_v, block_tables, pos,
     pos_arr = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
     bt_arr = jnp.asarray(block_tables, jnp.int32)
 
-    def kv_map(bi, hi, j, pos_ref, bt_ref):
+    def kv_map(bi, hi, j, pos_ref, bt_ref, *scale_refs):
         # clamp to the watermark page: steps past the row's valid prefix
         # keep mapping the same block, so Pallas elides the re-fetch
         jj = jnp.minimum(j, pos_ref[bi] // ps)
         return (bt_ref[bi, jj], hi, 0, 0)
 
-    def sc_map(bi, hi, j, pos_ref, bt_ref):
-        jj = jnp.minimum(j, pos_ref[bi] // ps)
-        return (bt_ref[bi, jj], hi)
+    if k_scale is None:
+        kernel = functools.partial(_paged_decode_kernel, page_size=ps,
+                                   sm_scale=sm_scale)
+        prefetch = [pos_arr, bt_arr]
+    else:
+        kernel = functools.partial(_paged_decode_kernel_q8, page_size=ps,
+                                   sm_scale=sm_scale, nkv=nkv)
+        prefetch = [pos_arr, bt_arr,
+                    k_scale.astype(jnp.float32).reshape(-1),
+                    v_scale.astype(jnp.float32).reshape(-1)]
 
-    quantized = k_scale is not None
-    in_specs = [
-        pl.BlockSpec((1, 1, g, hd),
-                     lambda bi, hi, j, pos_ref, bt_ref: (bi, hi, 0, 0)),
-        pl.BlockSpec((1, 1, ps, hd), kv_map),
-        pl.BlockSpec((1, 1, ps, hd), kv_map),
-    ]
-    operands = [q4, pool_k, pool_v]
-    if quantized:
-        in_specs += [pl.BlockSpec((1, 1), sc_map), pl.BlockSpec((1, 1),
-                                                                sc_map)]
-        operands += [k_scale.astype(jnp.float32),
-                     v_scale.astype(jnp.float32)]
+    def q_map(bi, hi, j, *prefetch_refs):
+        return (bi, hi, 0, 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=len(prefetch),
         grid=(b, nkv, P),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, g, hd),
-                               lambda bi, hi, j, pos_ref, bt_ref:
-                               (bi, hi, 0, 0)),
+        in_specs=[pl.BlockSpec((1, 1, g, hd), q_map),
+                  pl.BlockSpec((1, 1, ps, hd), kv_map),
+                  pl.BlockSpec((1, 1, ps, hd), kv_map)],
+        out_specs=pl.BlockSpec((1, 1, g, hd), q_map),
         scratch_shapes=[pltpu.VMEM((g, hd), jnp.float32),
                         pltpu.VMEM((g, 1), jnp.float32),
                         pltpu.VMEM((g, 1), jnp.float32)],
     )
-    kernel = _paged_decode_kernel_q8 if quantized else _paged_decode_kernel
     out = pl.pallas_call(
-        functools.partial(kernel, page_size=ps, sm_scale=sm_scale),
+        kernel,
         out_shape=jax.ShapeDtypeStruct((b, nkv, g, hd), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
-    )(pos_arr, bt_arr, *operands)
+    )(*prefetch, q4, pool_k, pool_v)
     return out.reshape(b, nh, hd)[:, None]
 
 
@@ -741,17 +727,9 @@ def paged_decode_attention(q, pool_k, pool_v, block_tables, pos, scale=None,
     if use_pallas and paged_decode_supported(q.shape, pool_k.shape,
                                              jnp.shape(block_tables),
                                              pool_k.dtype.itemsize):
-        try:
-            return _paged_decode_attention_pallas(
-                q, pool_k, pool_v, block_tables, pos, sm_scale, interpret,
-                k_scale=k_scale, v_scale=v_scale)
-        except Exception as e:  # lowering constraints supports() can't model
-            import warnings
-
-            warnings.warn(
-                f"Pallas paged decode attention failed ({type(e).__name__}: "
-                f"{e}); falling back to the XLA gather for q={q.shape} "
-                f"pool={pool_k.shape}")
+        return _paged_decode_attention_pallas(
+            q, pool_k, pool_v, block_tables, pos, sm_scale, interpret,
+            k_scale=k_scale, v_scale=v_scale)
     return _paged_decode_attention_xla(q, pool_k, pool_v, block_tables, pos,
                                        sm_scale, k_scale, v_scale)
 
@@ -773,14 +751,6 @@ def decode_attention(q, cache_k, cache_v, pos, scale=None, block_k=None):
     use_pallas, interpret = _mode()
     if use_pallas and decode_supported(q.shape, cache_k.shape,
                                        q.dtype.itemsize):
-        try:
-            return _decode_attention_pallas(q, cache_k, cache_v, pos,
-                                            sm_scale, block_k, interpret)
-        except Exception as e:  # lowering constraints supports() can't model
-            import warnings
-
-            warnings.warn(
-                f"Pallas decode attention failed ({type(e).__name__}: {e}); "
-                f"falling back to the XLA path for q={q.shape} "
-                f"cache={cache_k.shape}")
+        return _decode_attention_pallas(q, cache_k, cache_v, pos,
+                                        sm_scale, block_k, interpret)
     return _decode_attention_xla(q, cache_k, cache_v, pos, sm_scale)

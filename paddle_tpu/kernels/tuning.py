@@ -104,13 +104,17 @@ def _backend():
         return "cpu"
 
 
+# the in-checkout cache directory `_platform_setup.CACHE_DIR` names (that
+# module sits outside the package, so the path is rebuilt here; a test pins
+# the two together). Never $HOME: what runs must follow from the checkout.
+_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
 def cache_path():
-    p = os.environ.get(_CACHE_ENV)
-    if p:
-        return p
-    base = os.environ.get("XDG_CACHE_HOME",
-                          os.path.join(os.path.expanduser("~"), ".cache"))
-    return os.path.join(base, "paddle_tpu", "kernel_tuning.json")
+    return (os.environ.get(_CACHE_ENV)
+            or os.path.join(_CACHE_DIR, "kernel_tuning.json"))
 
 
 def _load_cache():

@@ -419,11 +419,33 @@ def _ce_chunk_stats(h_c, head, labels_c, inv_n, args: LlamaArgs, mp_axis,
     loss_sum = jnp.sum(lse - true_logit)
     dl = d_logits.astype(h_c.dtype)
     d_h = dl @ head.T  # [b, c, hidden]; partial over the local vocab shard
-    if mp_axis is not None:
+    # the cotangent carries its primal's type: an h that is replicated over
+    # mp wants the shards' partials summed; an h typed VARYING over mp (the
+    # sequence-parallel all_gather's output) wants this rank's partial —
+    # its producer's transpose (psum_scatter) does the sum
+    if mp_axis is not None and mp_axis not in jax.typeof(h_c).vma:
         d_h = jax.lax.psum(d_h, mp_axis)
     d_head = jnp.einsum("bch,bcv->hv", h_c, dl,
                         preferred_element_type=jnp.float32)
     return loss_sum, d_h.astype(h_c.dtype), d_head
+
+
+def _zeros_of(fn, *operands):
+    """Zeros shaped AND typed like `fn(*operands)`: under
+    shard_map(check_vma=True) a scan's initial carry must carry exactly the
+    varying-mesh-axes type its body produces, so plain `jnp.zeros` only
+    works off the mesh."""
+    def zero(t):
+        z = jnp.zeros(t.shape, t.dtype)
+        return jax.lax.pcast(z, tuple(t.vma), to="varying") if t.vma else z
+
+    return jax.tree.map(zero, jax.eval_shape(fn, *operands))
+
+
+@jax.tree_util.register_static
+class _MeshAxes(frozenset):
+    """A primal's varying-mesh-axes set, carried through the residuals as
+    static data."""
 
 
 def _fused_ce_loss_only(h, head, labels, args: LlamaArgs, mp_axis, mp_degree,
@@ -437,13 +459,15 @@ def _fused_ce_loss_only(h, head, labels, args: LlamaArgs, mp_axis, mp_degree,
     lc = jnp.swapaxes(
         labels[:, :nfull * chunk].reshape(b, nfull, chunk), 0, 1)
 
-    def body(loss_sum, xs):
-        h_c, l_c = xs
-        per_tok = parallel_cross_entropy(h_c @ head, l_c, args, mp_axis,
-                                         mp_degree)
-        return loss_sum + per_tok * (b * chunk), None
+    def chunk_loss(h_c, l_c):
+        return parallel_cross_entropy(h_c @ head, l_c, args, mp_axis,
+                                      mp_degree) * (b * chunk)
 
-    loss_sum, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32), (hc, lc))
+    def body(loss_sum, xs):
+        return loss_sum + chunk_loss(*xs), None
+
+    loss_sum, _ = jax.lax.scan(body, _zeros_of(chunk_loss, hc[0], lc[0]),
+                               (hc, lc))
     if rem:
         per_tok = parallel_cross_entropy(
             h[:, nfull * chunk:] @ head, labels[:, nfull * chunk:], args,
@@ -482,16 +506,18 @@ def _fused_ce_fwd(h, head, labels, args: LlamaArgs, mp_axis, mp_degree,
     lc = jnp.swapaxes(
         labels[:, :nfull * chunk].reshape(b, nfull, chunk), 0, 1)
 
+    def stats(h_c, l_c):
+        return _ce_chunk_stats(h_c, head, l_c, inv_n, args, mp_axis,
+                               mp_degree)
+
     def body(carry, xs):
         loss_sum, d_head = carry
-        h_c, l_c = xs
-        ls, d_h_c, d_hd = _ce_chunk_stats(h_c, head, l_c, inv_n, args,
-                                          mp_axis, mp_degree)
+        ls, d_h_c, d_hd = stats(*xs)
         return (loss_sum + ls, d_head + d_hd), d_h_c
 
-    carry0 = (jnp.zeros((), jnp.float32),
-              jnp.zeros((hidden, head.shape[-1]), jnp.float32))
-    (loss_sum, d_head), d_h_chunks = jax.lax.scan(body, carry0, (hc, lc))
+    ls0, _, d_head0 = _zeros_of(stats, hc[0], lc[0])
+    (loss_sum, d_head), d_h_chunks = jax.lax.scan(body, (ls0, d_head0),
+                                                  (hc, lc))
     d_h = jnp.swapaxes(d_h_chunks, 0, 1).reshape(b, nfull * chunk, hidden)
     if rem:
         ls, d_h_r, d_hd = _ce_chunk_stats(
@@ -500,13 +526,24 @@ def _fused_ce_fwd(h, head, labels, args: LlamaArgs, mp_axis, mp_degree,
         loss_sum = loss_sum + ls
         d_head = d_head + d_hd
         d_h = jnp.concatenate([d_h, d_h_r], axis=1)
-    res = (d_h, d_head.astype(head.dtype), labels)
+    res = (d_h, d_head.astype(head.dtype), labels,
+           _MeshAxes(jax.typeof(h).vma), _MeshAxes(jax.typeof(head).vma))
     return loss_sum * jnp.float32(inv_n), res
 
 
 def _fused_ce_bwd(args, mp_axis, mp_degree, chunk, res, g):
-    d_h, d_head, labels = res
-    return (d_h * g.astype(d_h.dtype), d_head * g.astype(d_head.dtype),
+    d_h, d_head, labels, h_vma, head_vma = res
+
+    def cotangent(ct, primal_vma):
+        # under shard_map(check_vma=True) a cotangent carries its primal's
+        # type: sum over the mesh axes it varies over and the primal does
+        # not — what AD's own transpose of the implicit replicated->varying
+        # cast does (e.g. the dp sum of a dp-replicated head's grads)
+        ct = ct * g.astype(ct.dtype)
+        extra = tuple(jax.typeof(ct).vma - primal_vma)
+        return jax.lax.psum(ct, extra) if extra else ct
+
+    return (cotangent(d_h, h_vma), cotangent(d_head, head_vma),
             np.zeros(labels.shape, dtype=jax.dtypes.float0))
 
 

@@ -11,10 +11,11 @@ __all__ = ["PEAK_FLOPS", "PEAK_HBM_BW", "peak_flops_for", "peak_hbm_bw_for",
            "detect_device_kind", "detect_peak_flops",
            "llama_param_count", "llama_flops_per_token"]
 
-# peak dense bf16 FLOP/s per chip by device kind substring
+# peak dense bf16 FLOP/s per chip by device kind substring (vendor
+# datasheets; the v5e row is Google Cloud's "TPU v5e" page)
 PEAK_FLOPS = [
     ("v5 lite", 197e12), ("v5e", 197e12),
-    ("v5p", 459e12), ("v5", 459e12),
+    ("v5p", 459e12),
     ("v6", 918e12), ("trillium", 918e12),
     ("v4", 275e12), ("v3", 123e12),
 ]
@@ -22,7 +23,7 @@ PEAK_FLOPS = [
 # peak HBM bandwidth (bytes/s) per chip — the decode roofline
 PEAK_HBM_BW = [
     ("v5 lite", 819e9), ("v5e", 819e9),
-    ("v5p", 2765e9), ("v5", 2765e9),
+    ("v5p", 2765e9),
     ("v6", 1640e9), ("trillium", 1640e9),
     ("v4", 1228e9), ("v3", 900e9),
 ]
@@ -33,27 +34,37 @@ def _lookup(kind, table):
     for sub, peak in table:
         if sub in k:
             return peak
-    return None
+    raise KeyError(
+        f"device_kind {kind!r} is not in the peak table "
+        "(paddle_tpu/observability/hardware.py); add its datasheet row — a "
+        "utilization against a guessed peak is worse than none")
 
 
 def peak_flops_for(kind):
+    """Peak bf16 FLOP/s for a device kind; an unknown kind raises."""
     return _lookup(kind, PEAK_FLOPS)
 
 
 def peak_hbm_bw_for(kind):
+    """Peak HBM bytes/s for a device kind; an unknown kind raises."""
     return _lookup(kind, PEAK_HBM_BW)
 
 
 def detect_device_kind():
     import jax
 
-    devs = jax.devices()
-    return devs[0].device_kind if devs else "cpu"
+    return jax.devices()[0].device_kind
 
 
 def detect_peak_flops():
-    """Peak bf16 FLOP/s of the local chip, or None when unknown (CPU)."""
-    return peak_flops_for(detect_device_kind())
+    """Peak bf16 FLOP/s of the local chip. None off the TPU (a CPU run has
+    no MFU); a TPU whose kind is not in the table raises."""
+    import jax
+
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return None
+    return peak_flops_for(dev.device_kind)
 
 
 def llama_param_count(args):

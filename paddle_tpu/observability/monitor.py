@@ -67,10 +67,7 @@ class TrainingMonitor:
         if self.peak_flops == "auto":
             from paddle_tpu.observability.hardware import detect_peak_flops
 
-            try:
-                self.peak_flops = detect_peak_flops()
-            except Exception:
-                self.peak_flops = None
+            self.peak_flops = detect_peak_flops()
         return self.peak_flops
 
     # -- compile counting (call at TRACE time inside the jitted step) -------

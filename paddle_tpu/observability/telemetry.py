@@ -5,8 +5,7 @@
 `write_run_telemetry` so every run leaves a diffable JSON record: the
 bench/record payload plus a full registry snapshot (step-time histograms,
 MFU, compile counters, heartbeat gauges). Perf regressions become a JSON
-diff instead of a scrollback hunt, and future BENCH_r0*.json roofline-%
-fields source from the same snapshot.
+diff instead of a scrollback hunt.
 """
 
 from __future__ import annotations

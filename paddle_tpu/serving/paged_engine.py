@@ -30,8 +30,7 @@ TENSOR PARALLELISM (`mesh=`): pass a Mesh with an `mp` axis and every
 step program runs as one shard_map SPMD program over it — weights in
 the Megatron split, the page pool sharded on its nkv axis, block tables
 and the host-side allocator untouched (`serving/tp.py` has the
-placement; `mesh_utils.shard_map_compat` keeps legacy jax working).
-Model size now scales with the mesh, not one chip's HBM.
+placement). Model size now scales with the mesh, not one chip's HBM.
 
 CHUNKED PREFILL (`prefill_chunk=`): a long prompt no longer runs as one
 monolithic program that stalls every decoding slot for its whole
@@ -124,7 +123,10 @@ def _paged_prefill_traced(params, ids, h, last_idx, bt_row, new_pages,
             L, 1, nkv, Pn * ps, hd)
         g_v = jnp.swapaxes(pv[:, bt_row], 1, 2).reshape(
             L, 1, nkv, Pn * ps, hd)
-    pad = jnp.zeros((L, 1, nkv, sb, hd), dtype)
+    # (the pad itself rounds up to the 128-position tile: the Pallas window
+    # kernel only takes a 128-aligned stripe, and with a bare `sb` pad the
+    # smallest bucket's stripe never was)
+    pad = jnp.zeros((L, 1, nkv, -(-sb // 128) * 128, hd), dtype)
     temp_k = jnp.concatenate([g_k, pad], axis=3)
     temp_v = jnp.concatenate([g_v, pad], axis=3)
 
@@ -295,16 +297,13 @@ class PagedEngine(Engine):
     # -- program construction ----------------------------------------------
     def _sharded(self, body, in_specs, out_specs, donate):
         """jit a traced step body, shard_map-wrapped when a mesh is set.
-        check_vma stays off for these forward-only programs: the legacy
+        check_vma stays off for these forward-only programs: the
         checker's value is guarding AD transposes, and serving has no
-        gradients — while its missing rules for scatter/sort/PRNG
-        primitives would reject valid inference bodies."""
+        gradients."""
         if self.mesh is None:
             return jax.jit(body, donate_argnums=donate)
-        from paddle_tpu.distributed.mesh_utils import shard_map_compat
-
-        sm = shard_map_compat(body, self.mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_vma=False)
+        sm = jax.shard_map(body, mesh=self.mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
         return jax.jit(sm, donate_argnums=donate)
 
     def _setup_device_state(self):

@@ -25,8 +25,8 @@ follows its weight and the per-out-channel `scale` follows the out dim
 
 Params are placed EAGERLY (`shard_params` -> jax.device_put with
 NamedSharding) at engine construction, and the engine's traced step
-bodies run under `mesh_utils.shard_map_compat` — the jax-0.4.37-safe
-spelling — with these specs as in_specs/out_specs. Everything here is
+bodies run under `jax.shard_map` with these specs as
+in_specs/out_specs. Everything here is
 data (PartitionSpec trees); the collectives live in models/generation.
 """
 

@@ -164,8 +164,8 @@ class TestNoLogitsBuffer:
 class TestVocabParallel:
     """The mp_axis regression: chunked loss used to ignore vocab sharding."""
 
-    def _sharded(self, chunk, s=24):
-        from jax.experimental.shard_map import shard_map
+    def _sharded(self, chunk, s=24, check_vma=False):
+        from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
 
         mp = 2
@@ -184,12 +184,51 @@ class TestVocabParallel:
             local, mesh=mesh,
             in_specs=(P(), P(None, "mp"), P()),
             out_specs=(P(), (P(), P(None, "mp"))),
-            check_rep=False)(h, head, labels)
+            check_vma=check_vma)(h, head, labels)
         return (h, head, labels), loss, dh, dw
 
-    @pytest.mark.parametrize("chunk", [8, 13])
-    def test_matches_unsharded_reference(self, chunk):
-        (h, head, labels), loss, dh, dw = self._sharded(chunk)
+    @pytest.mark.parametrize("chunk,check_vma", [(8, False), (13, False),
+                                                 (8, True)])
+    def test_matches_unsharded_reference(self, chunk, check_vma):
+        (h, head, labels), loss, dh, dw = self._sharded(
+            chunk, check_vma=check_vma)
+        ref_loss, (ref_dh, ref_dw) = jax.value_and_grad(
+            lambda a, w: _ref_loss(a, w, labels), argnums=(0, 1))(h, head)
+        np.testing.assert_allclose(float(loss), float(ref_loss),
+                                   rtol=1e-6, atol=1e-6)
+        np.testing.assert_allclose(np.asarray(dh), np.asarray(ref_dh),
+                                   rtol=1e-5, atol=1e-6)
+        np.testing.assert_allclose(np.asarray(dw), np.asarray(ref_dw),
+                                   rtol=1e-5, atol=1e-6)
+
+    def test_typed_mesh_cotangents(self):
+        """Under shard_map(check_vma=True) — how the hybrid engine runs —
+        the custom vjp's cotangents must carry their primals' types: a
+        hidden state typed VARYING over mp (the sequence-parallel
+        all_gather's output) gets this rank's partial, not the psum; a
+        head replicated over dp gets the dp-sum of the ranks' grads. The
+        scan carries must be typed too, or tracing fails outright."""
+        from jax import shard_map
+        from jax.sharding import Mesh, PartitionSpec as P
+
+        dp = mp = 2
+        h, head, labels = _inputs()
+        mesh = Mesh(np.array(jax.devices()[:dp * mp]).reshape(dp, mp),
+                    ("dp", "mp"))
+
+        def local(h_, head_, labels_):
+            h_ = jax.lax.pcast(h_, "mp", to="varying")
+            loss, (dh, dw) = jax.value_and_grad(
+                lambda a, w: lf.fused_linear_cross_entropy(
+                    a, w, labels_, ARGS, "mp", mp, 8),
+                argnums=(0, 1))(h_, head_)
+            return (jax.lax.pmean(loss, "dp"), jax.lax.psum(dh, "mp") / dp,
+                    dw / dp)
+
+        loss, dh, dw = shard_map(
+            local, mesh=mesh,
+            in_specs=(P("dp"), P(None, "mp"), P("dp")),
+            out_specs=(P(), P("dp"), P(None, "mp")))(h, head, labels)
         ref_loss, (ref_dh, ref_dw) = jax.value_and_grad(
             lambda a, w: _ref_loss(a, w, labels), argnums=(0, 1))(h, head)
         np.testing.assert_allclose(float(loss), float(ref_loss),
@@ -204,7 +243,7 @@ class TestVocabParallel:
         into the fused CE — the silent-ignore bug put the OLD remat trick
         on the local vocab shard only. Detect by sharding the head and
         checking the chunked loss equals the unchunked mp-aware loss."""
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
 
         mp = 2
@@ -221,7 +260,7 @@ class TestVocabParallel:
 
         run = lambda f: shard_map(  # noqa: E731
             f, mesh=mesh, in_specs=(P(), P(None, "mp")), out_specs=P(),
-            check_rep=False)(h, head)
+            check_vma=False)(h, head)
         np.testing.assert_allclose(float(run(chunked)),
                                    float(run(unchunked)),
                                    rtol=1e-6, atol=1e-6)

@@ -8,8 +8,7 @@ the parallel loss must match the single-device loss on the same params/batch.
 
 import jax
 
-from paddle_tpu.distributed.mesh_utils import \
-    shard_map_compat as _shard_map
+from jax import shard_map as _shard_map
 import jax.numpy as jnp
 import numpy as np
 import pytest

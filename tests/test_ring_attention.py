@@ -4,8 +4,7 @@ grads), causal and non-causal."""
 
 import jax
 
-from paddle_tpu.distributed.mesh_utils import \
-    shard_map_compat as _shard_map
+from jax import shard_map as _shard_map
 import jax.numpy as jnp
 import numpy as np
 import pytest

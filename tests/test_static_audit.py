@@ -192,7 +192,7 @@ class TestDonationAudit:
         assert v and v[0].rule == "donation.arg-mismatch"
 
     def test_spmd_alias_lives_in_compiled_hlo(self):
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         mesh = Mesh(np.array(jax.devices()[:2]), ("mp",))
         sm = shard_map(lambda a: a * 2, mesh=mesh, in_specs=(P("mp"),),
@@ -312,16 +312,16 @@ def _tp_body(x, w):
 
 class TestCollectiveAudit:
     def _sharded_jaxpr(self, body):
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         # genuine row-parallel: contraction dim sharded, so the partial
-        # products NEED the psum epilogue. check_rep=False matches the
+        # products NEED the psum epilogue. check_vma=False matches the
         # engine's shard_map mode (and keeps lax.psum staged as `psum`
-        # rather than the rep-checker's rewritten psum2)
+        # rather than the vma checker's psum_invariant)
         mesh = Mesh(np.array(jax.devices()[:2]), ("mp",))
         f = shard_map(body, mesh=mesh,
                       in_specs=(P(None, "mp"), P("mp", None)),
-                      out_specs=P(None), check_rep=False)
+                      out_specs=P(None), check_vma=False)
         return jax.make_jaxpr(f)(jnp.ones((4, 8)), jnp.ones((8, 4)))
 
     def test_census_and_fingerprint(self):

@@ -36,7 +36,7 @@ H2048 = dict(vocab_size=32000, hidden_size=2048, intermediate_size=5504,
 # ~17.5k. r5: moments='bf16' (stochastic-rounded) frees 3.8GB and
 # 'factored' ~7.3GB — sweep 'half' and no-remat at the freed budget.
 #
-# r5 RESULT (2026-08-01, driver-verifiable in BENCH_r05.json): the decisive
+# r5 RESULT (2026-08-01, v5e, before PR 1; not re-measured): the decisive
 # lever was none of the above — xprof showed ~17% of the step in the layer
 # scan's dynamic-update-slice residual stacking. With the layer loop
 # UNROLLED (engine `unroll`, default on a 1x1x1 mesh) no-remat fits at M=2

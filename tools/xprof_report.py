@@ -19,8 +19,7 @@ fills the Profiler's Operator DevTotal column, so the numbers agree.
 Usage:
   python tools/xprof_report.py LOGDIR_OR_TRACE [--top K] [--json OUT]
 
-The --json payload carries the per-class device-time shares (the
-roofline-% fields future BENCH_r0*.json records source from).
+The --json payload carries the per-class device-time shares.
 """
 
 from __future__ import annotations
