@@ -1,0 +1,353 @@
+"""The plain reference: a dense decoder with grouped-query attention, rotary
+positions, RMS norms and a SwiGLU feed-forward, written from the published
+equations in `jax.numpy`, float32, matmul precision `highest`.
+
+No kernel, no cache, no batching tricks, and nothing of the program: its
+weights are made here from the seed (`harness.weights`), one layer upcast
+at a time. `mm` is the one matrix multiplication every weight goes
+through; the control swaps it for a lower precision (`fp8_mm`).
+
+Served model:  `served_logits` runs each prompt with its served tokens once
+and returns the logits at the served positions.
+Training:      `TrainReference` follows the first steps of AdamW training
+layer by layer (forward keeps the layer inputs, backward recomputes one
+layer at a time and updates it at once), so it fits where the program did.
+"""
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from benchmarks.harness import weights
+from benchmarks.harness.counts import head_dim
+
+HIGHEST = jax.lax.Precision.HIGHEST
+Q_BLOCK = 1024     # queries attended at once: bounds the score matrix
+
+
+def f32_mm(x, w):
+    return jnp.matmul(x, w, precision=HIGHEST)
+
+
+def _fake_fp8(x):
+    """Round to float8 e4m3 (3 mantissa bits) under one scale for the whole
+    tensor that puts its largest magnitude at the format's top, back in f32."""
+    top = float(jnp.finfo(jnp.float8_e4m3fn).max)
+    s = jnp.max(jnp.abs(x)) / top
+    s = jnp.where(s > 0, s, 1.0)
+    return (x / s).astype(jnp.float8_e4m3fn).astype(jnp.float32) * s
+
+
+@jax.custom_vjp
+def fp8_mm(x, w):
+    """x [..., k] @ w [k, n] with both operands rounded to fp8, forward and
+    backward: the precision below bfloat16, the control's arithmetic."""
+    return f32_mm(_fake_fp8(x), _fake_fp8(w))
+
+
+def _fp8_mm_fwd(x, w):
+    return fp8_mm(x, w), (x, w)
+
+
+def _fp8_mm_bwd(res, dy):
+    x, w = res
+    x2, dy2 = x.reshape(-1, x.shape[-1]), dy.reshape(-1, dy.shape[-1])
+    dyq = _fake_fp8(dy2)
+    dx = f32_mm(dyq, _fake_fp8(w).T).reshape(x.shape)
+    return dx, f32_mm(_fake_fp8(x2).T, dyq)
+
+
+fp8_mm.defvjp(_fp8_mm_fwd, _fp8_mm_bwd)
+
+
+# ---------------------------------------------------------------------------
+# the decoder layer
+# ---------------------------------------------------------------------------
+
+def rms_norm(x, w, eps):
+    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * w
+
+
+def rotary(x, theta):
+    """x [s, heads, hd]; pairs (i, i + hd/2) rotate by pos * theta^(-2i/hd)."""
+    s, _, hd = x.shape
+    inv = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
+    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv[None, :]
+    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
+    x1, x2 = x[..., :hd // 2], x[..., hd // 2:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+
+
+def causal_attention(q, k, v):
+    """One sequence. q [s, nh, hd], k/v [s, nkv, hd]; query head j reads
+    key/value head j // (nh/nkv). Queries go in blocks of Q_BLOCK."""
+    s, nh, hd = q.shape
+    nkv = k.shape[1]
+    qg = q.reshape(s, nkv, nh // nkv, hd)
+    blk = min(Q_BLOCK, s)
+    if s % blk:
+        raise ValueError(f"sequence {s} is not a multiple of {blk}")
+
+    @jax.checkpoint
+    def block(i):
+        qb = jax.lax.dynamic_slice_in_dim(qg, i * blk, blk, 0)
+        sc = jnp.einsum("qkgd,skd->kgqs", qb, k, precision=HIGHEST)
+        sc = sc / math.sqrt(hd)
+        qpos = i * blk + jnp.arange(blk)[:, None]
+        sc = jnp.where(jnp.arange(s)[None, :] <= qpos, sc, -jnp.inf)
+        p = jax.nn.softmax(sc, axis=-1)
+        return jnp.einsum("kgqs,skd->qkgd", p, v, precision=HIGHEST)
+
+    out = jax.lax.map(block, jnp.arange(s // blk))
+    return out.reshape(s, nh * hd)
+
+
+def decoder_layer(x, w, arch, mm):
+    """x [b, s, h] float32; w one layer's weights (any float type)."""
+    w = jax.tree.map(lambda a: a.astype(jnp.float32), w)
+    return _decoder_layer32(x, w, arch, mm)
+
+
+def _decoder_layer32(x, w, arch, mm):
+    hd, eps = head_dim(arch), arch["rms_norm_eps"]
+    nh, nkv = arch["num_attention_heads"], arch["num_key_value_heads"]
+    theta = arch["rope_theta"]
+    b, s, _ = x.shape
+    hin = rms_norm(x, w["ln1"], eps)
+    q = mm(hin, w["wq"]).reshape(b, s, nh, hd)
+    k = mm(hin, w["wk"]).reshape(b, s, nkv, hd)
+    v = mm(hin, w["wv"]).reshape(b, s, nkv, hd)
+
+    def one(qkv):
+        q1, k1, v1 = qkv
+        return causal_attention(rotary(q1, theta), rotary(k1, theta), v1)
+
+    attn = jax.lax.map(one, (q, k, v))
+    x = x + mm(attn, w["wo"])
+    hin = rms_norm(x, w["ln2"], eps)
+    act = jax.nn.silu(mm(hin, w["w_gate"])) * mm(hin, w["w_up"])
+    return x + mm(act, w["w_down"])
+
+
+def _frozen(arch):
+    return tuple(sorted((k, v) for k, v in arch.items()
+                        if isinstance(v, (int, float)) and v is not None))
+
+
+@functools.lru_cache(maxsize=None)
+def _layer_fwd(frozen, mm):
+    arch = dict(frozen)
+    return jax.jit(lambda x, w: decoder_layer(x, w, arch, mm))
+
+
+@functools.lru_cache(maxsize=None)
+def _layer_bwd(frozen, mm):
+    arch = dict(frozen)
+
+    def bwd(x, w, dy):
+        w32 = jax.tree.map(lambda a: a.astype(jnp.float32), w)
+        _, vjp = jax.vjp(lambda x_, w_: _decoder_layer32(x_, w_, arch, mm),
+                         x, w32)
+        return vjp(dy)
+
+    return jax.jit(bwd, donate_argnums=(2,))
+
+
+# ---------------------------------------------------------------------------
+# a served model: logits at the served positions
+# ---------------------------------------------------------------------------
+
+@functools.lru_cache(maxsize=None)
+def _head_fn(eps, mm):
+    def head(x, norm_w, head_w):
+        x = rms_norm(x, norm_w.astype(jnp.float32), eps)
+        return mm(x, head_w.astype(jnp.float32))
+
+    return jax.jit(head)
+
+
+HEAD_ROWS = 128    # served positions go through the head in multiples of it
+
+
+def served_logits(arch, seed, requests, mm=f32_mm):
+    """For each (prompt, tokens) of `requests`, the reference logits
+    [len(tokens), vocab] at the positions where the server chose `tokens`
+    after `prompt`: one full forward over the prompt with its served
+    tokens, padded up to a multiple of Q_BLOCK (padding sits after
+    everything it could influence). Layer by layer over all the requests,
+    so each layer's weights are made once."""
+    outer = weights.outer_params(arch, seed)
+    xs = []
+    for prompt, tokens in requests:
+        seq = np.concatenate([np.asarray(prompt), np.asarray(tokens)[:-1]])
+        ids = np.zeros(-(-len(seq) // Q_BLOCK) * Q_BLOCK, np.int32)
+        ids[:len(seq)] = seq
+        xs.append(outer["embedding"][jnp.asarray(ids)]
+                  .astype(jnp.float32)[None])
+    fwd = _layer_fwd(_frozen(arch), mm)
+    for i in range(arch["num_hidden_layers"]):
+        w = weights.layer_params(arch, seed, i)
+        for j, x in enumerate(xs):
+            xs[j] = fwd(x, w)
+    head = _head_fn(arch["rms_norm_eps"], mm)
+    out = []
+    for (prompt, tokens), x in zip(requests, xs):
+        n, m = len(prompt), len(tokens)
+        rows = n - 1 + np.arange(-(-m // HEAD_ROWS) * HEAD_ROWS)
+        rows = np.minimum(rows, x.shape[1] - 1)
+        out.append(np.asarray(head(x[0][jnp.asarray(rows)],
+                                   outer["final_norm"],
+                                   outer["lm_head"]))[:m])
+    return out
+
+
+def served_gap(logits, tokens):
+    """How far the chosen token's logit lies below the reference's best, at
+    each position: 0 where the server chose the reference's first token."""
+    logits = np.asarray(logits, np.float32)
+    chosen = logits[np.arange(len(tokens)), np.asarray(tokens)]
+    return logits.max(-1) - chosen
+
+
+# ---------------------------------------------------------------------------
+# training: the first AdamW steps, layer by layer
+# ---------------------------------------------------------------------------
+
+HEAD_CHUNK = 2048      # tokens whose logits exist at once
+
+
+@functools.lru_cache(maxsize=None)
+def _head_loss_grad(eps, mm):
+    def loss_sum(x, norm_w, head_w, labels):
+        logits = mm(rms_norm(x, norm_w, eps), head_w)
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        true = jnp.take_along_axis(logits, labels[:, None], axis=-1)[:, 0]
+        return jnp.sum(lse - true)
+
+    def fn(x, norm_w, head_w, labels):
+        return jax.value_and_grad(loss_sum, argnums=(0, 1, 2))(
+            x, norm_w.astype(jnp.float32), head_w.astype(jnp.float32),
+            labels)
+
+    return jax.jit(fn)
+
+
+@functools.partial(jax.jit, static_argnames=("hp",), donate_argnums=(0, 2, 3))
+def _adamw(p, g, m, v, step, hp):
+    """The update of Loshchilov & Hutter's AdamW in float32; the weight is
+    stored back in its own (bfloat16) type, as the configuration states.
+    Returns the squared norm of the gradient as well."""
+    lr, b1, b2, eps, wd = hp
+    g = g.astype(jnp.float32)
+    m = b1 * m + (1 - b1) * g
+    v = b2 * v + (1 - b2) * g * g
+    mhat = m / (1 - b1 ** step)
+    vhat = v / (1 - b2 ** step)
+    p32 = p.astype(jnp.float32)
+    p32 = p32 - lr * (mhat / (jnp.sqrt(vhat) + eps) + wd * p32)
+    return p32.astype(p.dtype), m, v, jnp.sum(g * g)
+
+
+@jax.jit
+def _delta_sq(p, p0):
+    d = p.astype(jnp.float32) - p0.astype(jnp.float32)
+    return jnp.sum(d * d)
+
+
+class TrainReference:
+    """Follows the program's first training steps on the same batches.
+
+    `hp` = (lr, beta1, beta2, eps, weight_decay). Weights are kept in the
+    type the configuration trains in (bfloat16) and the moments in float32;
+    all arithmetic is float32 at `highest`.
+    """
+
+    def __init__(self, arch, seed, hp, mm=f32_mm, dtype=jnp.bfloat16):
+        self.arch, self.seed, self.hp, self.mm = arch, seed, tuple(hp), mm
+        self.dtype = dtype
+        self.n_layers = arch["num_hidden_layers"]
+        self.layers = [weights.layer_params(arch, seed, i, dtype)
+                       for i in range(self.n_layers)]
+        self.outer = weights.outer_params(arch, seed, dtype)
+        zeros = functools.partial(jax.tree.map,
+                                  lambda a: jnp.zeros(a.shape, jnp.float32))
+        self.m = {"layers": [zeros(w) for w in self.layers],
+                  "outer": zeros(self.outer)}
+        self.v = {"layers": [zeros(w) for w in self.layers],
+                  "outer": zeros(self.outer)}
+        self.step = 0
+        self.grad_sq = None      # leaf -> squared gradient norm, last step
+
+    def _update(self, group, idx, name, g, sq):
+        store = self.layers[idx] if group == "layers" else self.outer
+        m = self.m[group][idx] if group == "layers" else self.m[group]
+        v = self.v[group][idx] if group == "layers" else self.v[group]
+        store[name], m[name], v[name], gsq = _adamw(
+            store[name], g, m[name], v[name], jnp.float32(self.step),
+            self.hp)
+        key = f"layers/{name}" if group == "layers" else name
+        sq[key] = sq.get(key, 0.0) + gsq
+
+    def train_step(self, ids, labels):
+        """ids, labels [B, s] int32. Returns the mean loss (a float)."""
+        arch, mm, frozen = self.arch, self.mm, _frozen(self.arch)
+        self.step += 1
+        ids, labels = jnp.asarray(ids), jnp.asarray(labels)
+        B, s = ids.shape
+        fwd, bwd = _layer_fwd(frozen, mm), _layer_bwd(frozen, mm)
+        xs = [self.outer["embedding"][ids].astype(jnp.float32)]
+        for w in self.layers:
+            xs.append(fwd(xs[-1], w))
+
+        # head and loss in chunks of rows; d(final_norm), d(lm_head) add up
+        h = arch["hidden_size"]
+        x_last = xs.pop().reshape(B * s, h)
+        flat_labels = labels.reshape(B * s)
+        head = _head_loss_grad(arch["rms_norm_eps"], mm)
+        inv_n = 1.0 / (B * s)
+        loss, dxs, dnorm, dhead = 0.0, [], 0.0, 0.0
+        for a in range(0, B * s, HEAD_CHUNK):
+            ls, (dx, dn, dh) = head(
+                x_last[a:a + HEAD_CHUNK], self.outer["final_norm"],
+                self.outer["lm_head"], flat_labels[a:a + HEAD_CHUNK])
+            loss, dnorm, dhead = loss + ls, dnorm + dn, dhead + dh
+            dxs.append(dx)
+        del x_last
+        dy = (jnp.concatenate(dxs) * inv_n).reshape(B, s, h)
+        del dxs
+        sq = {}
+        self._update("outer", None, "final_norm", dnorm * inv_n, sq)
+        self._update("outer", None, "lm_head", dhead * inv_n, sq)
+        del dnorm, dhead
+
+        for i in reversed(range(self.n_layers)):
+            dy, dw = bwd(xs.pop(), self.layers[i], dy)
+            for name in sorted(dw):
+                self._update("layers", i, name, dw.pop(name), sq)
+        demb = jnp.zeros(self.outer["embedding"].shape, jnp.float32)
+        demb = demb.at[ids].add(dy)
+        self._update("outer", None, "embedding", demb, sq)
+        self.grad_sq = {k: float(x) for k, x in sq.items()}
+        return float(loss) * inv_n
+
+    def grad_norms(self):
+        """Leaf -> norm of the last step's gradient (a stacked leaf of the
+        program, `layers/<name>`, is all its layers together)."""
+        return {k: math.sqrt(x) for k, x in self.grad_sq.items()}
+
+    def delta_norms(self):
+        """Leaf -> norm of (weights now - weights at the seed)."""
+        out = {}
+        for i, w in enumerate(self.layers):
+            w0 = weights.layer_params(self.arch, self.seed, i, self.dtype)
+            for name in w:
+                key = f"layers/{name}"
+                out[key] = out.get(key, 0.0) + float(_delta_sq(w[name],
+                                                               w0[name]))
+        o0 = weights.outer_params(self.arch, self.seed, self.dtype)
+        for name in self.outer:
+            out[name] = float(_delta_sq(self.outer[name], o0[name]))
+        return {k: math.sqrt(x) for k, x in out.items()}
