@@ -206,7 +206,7 @@ def kernel_cases(args):
              *up(q, k, v), b, p, scale),
          (qd, normal(num_pages, nkv, PAGE, hd),
           normal(num_pages, nkv, PAGE, hd), bt, pos)),
-        ("paged_decode_int8", ("_paged_decode_kernel_q8",),
+        ("paged_decode_int8", ("_paged_decode_kernel",),
          lambda q, k, v, b, p, ks, vs: qm.paged_decode_attention(
              q, k, v, b, p, k_scale=ks, v_scale=vs),
          lambda q, k, v, b, p, ks, vs: qm._paged_decode_attention_xla(
@@ -546,7 +546,7 @@ def main():
     with clock.phase("serve int8 pool"):
         out8 = serve("int8", params, args, requests, refs, forward,
                      gap_bar=GAP_BAR["int8"], kv_dtype="int8",
-                     expect_kernels=("_paged_decode_kernel_q8",))
+                     expect_kernels=("_paged_decode_kernel",))
     agree = {n: round(float(np.mean(np.asarray(out8[n])
                                     == np.asarray(out[n]))), 3) for n in out}
     print(f"serve int8: top-1 agreement with the model-dtype pool = {agree}",

@@ -524,48 +524,156 @@ def window_decode_attention(q, cache_k, cache_v, pos, scale=None,
 # ---------------------------------------------------------------------------
 
 
-def _paged_decode_kernel(pos_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
-                         acc_ref, m_ref, l_ref, *, page_size, sm_scale):
-    # grid (b, nkv, P): the innermost dim walks the row's block table; the
-    # k/v BlockSpec index maps read bt_ref (scalar-prefetched) so each step
-    # DMAs the PAGE the table points at — the gather never materializes a
-    # contiguous cache. Online max/sum state lives in VMEM scratch because
-    # it must survive across grid steps (the non-paged kernel keeps it in
-    # registers inside one fori_loop).
-    bi, j = pl.program_id(0), pl.program_id(2)
-    pos = pos_ref[bi]
+def _paged_block_scales(scale_ref, pages, nkv, page_size):
+    """[nkv, 1, len(pages) * page_size] f32: for each column of a compute
+    block the absmax scale of (its page, the kv head), from the flattened
+    SMEM table. A (1, 1) VMEM block of the [num_pages, nkv] array is not a
+    tile Mosaic can window, so the scalars are spread by selects."""
+    bk = len(pages) * page_size
+    col_page = jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1) // page_size
+    rows = []
+    for h in range(nkv):
+        r = jnp.zeros((1, bk), jnp.float32)
+        for i, page in enumerate(pages):
+            r = jnp.where(col_page == i, scale_ref[page * nkv + h], r)
+        rows.append(r)
+    return jnp.stack(rows)
 
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
 
-    # pages past the row's position watermark are skipped entirely (their
-    # index map re-points at the watermark page, so no fresh DMA either)
-    @pl.when(j * page_size <= pos)
-    def _page():
-        q = q_ref[0, 0]                       # [g, d]
-        k = k_ref[0, 0]                       # [page_size, d]
-        v = v_ref[0, 0]
+def _paged_decode_kernel(*refs, page_size, pages_per_block, sm_scale,
+                         quantized):
+    # grid (b,): one step a ROW, all kv heads at once. The pools stay in
+    # HBM (memory_space ANY) and are fetched by hand: a compute block is
+    # `pages_per_block` pages, each ONE async copy of pool[bt[row, j]] —
+    # every kv head of the page, contiguous in the page-major pool — into
+    # its columns of a [nkv, block, hd] VMEM buffer. The page loop's trip
+    # count follows the row's position, so the copies issued and the
+    # blocks computed are the pages the row holds, not the table's width.
+    # Two buffers: block i + 1 streams while block i computes, and a row's
+    # last block starts the next row's first, so only row 0 waits for an
+    # exposed copy. Scalars in SMEM: pos [b], block tables [b, P], and for
+    # an int8 pool the K and V scales flattened [num_pages * nkv] (the
+    # dequant stays in registers: int8 upcasts between the copy and the
+    # MXU, the scales fold into the scores and the probabilities).
+    if quantized:
+        (pos_ref, bt_ref, sk_ref, sv_ref, q_ref, k_hbm, v_hbm, o_ref,
+         k_buf, v_buf, sems, slot_ref) = refs
+    else:
+        (pos_ref, bt_ref, q_ref, k_hbm, v_hbm, o_ref,
+         k_buf, v_buf, sems, slot_ref) = refs
+    ps, ppb = page_size, pages_per_block
+    bk = ps * ppb
+    nkv = k_buf.shape[1]
+    last_table = bt_ref.shape[1] - 1
+    row, rows = pl.program_id(0), pl.num_programs(0)
+
+    def last_page(r):
+        return jnp.minimum(pos_ref[r] // ps, last_table)
+
+    def block_pages(r, blk):
+        # (page index in the table is live, pool page) for the block's
+        # pages; a table entry past the row's last live page is never
+        # read (it may lie past the table where ppb does not divide P)
+        lp = last_page(r)
+        js = [blk * ppb + i for i in range(ppb)]
+        return [(j <= lp, bt_ref[r, jnp.minimum(j, lp)]) for j in js]
+
+    def copies(page, i, slot):
+        dst = (slot, slice(None), pl.ds(i * ps, ps), slice(None))
+        return (pltpu.make_async_copy(k_hbm.at[page], k_buf.at[dst],
+                                      sems.at[0, slot]),
+                pltpu.make_async_copy(v_hbm.at[page], v_buf.at[dst],
+                                      sems.at[1, slot]))
+
+    def start_block(r, blk, slot):
+        for i, (live, page) in enumerate(block_pages(r, blk)):
+            @pl.when(live)
+            def _start():
+                for c in copies(page, i, slot):
+                    c.start()
+
+    def wait_block(pages, slot):
+        for i, (live, page) in enumerate(pages):
+            @pl.when(live)
+            def _wait():
+                for c in copies(page, i, slot):
+                    c.wait()
+
+            # a page nobody fetched holds whatever the buffer held: its
+            # scores are masked below, and its V must be zero as well
+            # (a probability of 0 times garbage is not 0)
+            @pl.when(jnp.logical_not(live))
+            def _zero():
+                v_buf[slot, :, pl.ds(i * ps, ps), :] = jnp.zeros(
+                    (nkv, ps, v_buf.shape[-1]), v_buf.dtype)
+
+    @pl.when(row == 0)
+    def _first():
+        slot_ref[0] = 0
+        start_block(0, 0, 0)
+
+    pos = pos_ref[row]
+    n_blocks = last_page(row) // ppb + 1    # cdiv(pos + 1, bk), pos >= 0
+    slot0 = slot_ref[0]
+    q = q_ref[0]                             # [nkv, g, hd]
+    g, hd = q.shape[1], q.shape[2]
+
+    def body(i, carry):
+        acc, m, l = carry
+        slot = (slot0 + i) % 2
+
+        @pl.when(i + 1 < n_blocks)
+        def _next_block():
+            start_block(row, i + 1, 1 - slot)
+
+        @pl.when(jnp.logical_and(i + 1 == n_blocks, row + 1 < rows))
+        def _next_row():
+            start_block(row + 1, 0, 1 - slot)
+
+        pages = block_pages(row, i)
+        wait_block(pages, slot)
+        k, v = k_buf[slot], v_buf[slot]      # [nkv, bk, hd]
+        if quantized:
+            k, v = k.astype(q.dtype), v.astype(q.dtype)   # exact in bf16
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale   # [g, ps]
-        cols = j * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            q, k, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)           # [nkv, g, bk]
+        if quantized:
+            ids = [page for _, page in pages]
+            s = s * (_paged_block_scales(sk_ref, ids, nkv, ps)
+                     * (sm_scale / 127.0))
+        else:
+            s = s * sm_scale
+        cols = i * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
         s = jnp.where(cols <= pos, s, _NEG_INF)
-        m_prev, l_prev = m_ref[...], l_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        m_ref[...] = m_new
-        l_ref[...] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        alpha = jnp.exp(m - m_new)
+        l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        if quantized:
+            p = p * (_paged_block_scales(sv_ref, ids, nkv, ps) / 127.0)
+        acc = acc * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)           # [nkv, g, hd]
+        return acc, m_new, l
 
-    @pl.when(j == pl.num_programs(2) - 1)
-    def _finish():
-        o_ref[0, 0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+    acc, _, l = jax.lax.fori_loop(
+        0, n_blocks, body,
+        (jnp.zeros((nkv, g, hd), jnp.float32),
+         jnp.full((nkv, g, 1), _NEG_INF, jnp.float32),
+         jnp.zeros((nkv, g, 1), jnp.float32)))
+    slot_ref[0] = (slot0 + n_blocks) % 2
+    o_ref[0] = (acc / l).astype(o_ref.dtype)
+
+
+def _paged_pages_per_block(pool_shape, pages_per_slot, itemsize,
+                           block_k=512):
+    """Pages of one compute block: `block_k` positions' worth, as many as
+    the row's table holds at most, within the VMEM budget for K and V
+    double-buffered with every kv head of a page."""
+    nkv, ps, hd = pool_shape[1:]
+    fit = _VMEM_BUDGET_BYTES // (2 * 2 * nkv * ps * hd * itemsize)
+    return max(1, min(block_k // ps, pages_per_slot, fit))
 
 
 def paged_decode_supported(q_shape, pool_shape, bt_shape, itemsize=2):
@@ -586,104 +694,63 @@ def paged_decode_supported(q_shape, pool_shape, bt_shape, itemsize=2):
     min_sublane = 32 // max(int(itemsize), 1)   # f32: 8, bf16: 16
     if ps % min_sublane != 0 or hd % 128 != 0:
         return False
-    per_step = 2 * 2 * ps * hd * itemsize      # k + v page, double-buffered
-    return per_step <= _VMEM_BUDGET_BYTES
-
-
-def _paged_decode_kernel_q8(pos_ref, bt_ref, sk_ref, sv_ref, q_ref, k_ref,
-                            v_ref, o_ref, acc_ref, m_ref, l_ref, *,
-                            page_size, sm_scale, nkv):
-    # int8-pool variant of `_paged_decode_kernel`: k/v blocks arrive as
-    # int8 PAGES; the per-(page, kv-head) absmax scales ride in SMEM
-    # (scalar-prefetched, flattened [num_pages * nkv]) and are read as
-    # scalars at the page the block table names — a (1, 1) VMEM block of
-    # the [num_pages, nkv] array is not a tile Mosaic can window. The
-    # dequant is the PR-1 in-registers pattern — int8 upcasts between the
-    # DMA and the MXU (exact in bf16), and the page's scale folds into
-    # the score scale (k) and the accumulator contribution (v), so a
-    # full-width page never exists outside registers.
-    bi, hi, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    pos = pos_ref[bi]
-
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    @pl.when(j * page_size <= pos)
-    def _page():
-        q = q_ref[0, 0]                       # [g, d]
-        k = k_ref[0, 0].astype(q.dtype)       # int8 -> compute dtype, exact
-        v = v_ref[0, 0].astype(q.dtype)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        sidx = bt_ref[bi, j] * nkv + hi
-        s = s * (sk_ref[sidx] * (sm_scale / 127.0))          # [g, ps]
-        cols = j * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(cols <= pos, s, _NEG_INF)
-        m_prev, l_prev = m_ref[...], l_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        m_ref[...] = m_new
-        l_ref[...] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * (sv_ref[sidx] / 127.0)
-
-    @pl.when(j == pl.num_programs(2) - 1)
-    def _finish():
-        o_ref[0, 0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+    # one block at the least: a page of K and of V with every kv head,
+    # double-buffered, beside the block's f32 scores and probabilities
+    # and the row's q, accumulator and output (a group pads to 8 sublanes)
+    rows = nkv * _round_up(nh // nkv, 8)
+    per_row = (2 * 2 * nkv * ps * hd * itemsize + 2 * rows * ps * 4
+               + 3 * rows * hd * 4)
+    return per_row <= _VMEM_BUDGET_BYTES
 
 
 def _paged_decode_attention_pallas(q, pool_k, pool_v, block_tables, pos,
                                    sm_scale, interpret, k_scale=None,
-                                   v_scale=None):
+                                   v_scale=None, pages_per_block=None):
     b, _, nh, hd = q.shape
     nkv, ps = pool_k.shape[1], pool_k.shape[2]
     P = block_tables.shape[1]
     g = nh // nkv
+    if pages_per_block is None:
+        from paddle_tpu.kernels import tuning
+
+        block_k = tuning.get_blocks(
+            "paged_decode_attention",
+            {"page_size": ps, "kv_heads": nkv, "head_dim": hd},
+            pool_k.dtype, {"block_k": 512})["block_k"]
+        pages_per_block = _paged_pages_per_block(
+            pool_k.shape, P, pool_k.dtype.itemsize, block_k)
+    ppb = pages_per_block
     q4 = q[:, 0].reshape(b, nkv, g, hd)
     pos_arr = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
-    bt_arr = jnp.asarray(block_tables, jnp.int32)
+    prefetch = [pos_arr, jnp.asarray(block_tables, jnp.int32)]
+    if k_scale is not None:
+        prefetch += [k_scale.astype(jnp.float32).reshape(-1),
+                     v_scale.astype(jnp.float32).reshape(-1)]
 
-    def kv_map(bi, hi, j, pos_ref, bt_ref, *scale_refs):
-        # clamp to the watermark page: steps past the row's valid prefix
-        # keep mapping the same block, so Pallas elides the re-fetch
-        jj = jnp.minimum(j, pos_ref[bi] // ps)
-        return (bt_ref[bi, jj], hi, 0, 0)
-
-    if k_scale is None:
-        kernel = functools.partial(_paged_decode_kernel, page_size=ps,
-                                   sm_scale=sm_scale)
-        prefetch = [pos_arr, bt_arr]
-    else:
-        kernel = functools.partial(_paged_decode_kernel_q8, page_size=ps,
-                                   sm_scale=sm_scale, nkv=nkv)
-        prefetch = [pos_arr, bt_arr,
-                    k_scale.astype(jnp.float32).reshape(-1),
-                    v_scale.astype(jnp.float32).reshape(-1)]
-
-    def q_map(bi, hi, j, *prefetch_refs):
-        return (bi, hi, 0, 0)
+    def row_map(bi, *prefetch_refs):
+        return (bi, 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
-        grid=(b, nkv, P),
-        in_specs=[pl.BlockSpec((1, 1, g, hd), q_map),
-                  pl.BlockSpec((1, 1, ps, hd), kv_map),
-                  pl.BlockSpec((1, 1, ps, hd), kv_map)],
-        out_specs=pl.BlockSpec((1, 1, g, hd), q_map),
-        scratch_shapes=[pltpu.VMEM((g, hd), jnp.float32),
-                        pltpu.VMEM((g, 1), jnp.float32),
-                        pltpu.VMEM((g, 1), jnp.float32)],
+        grid=(b,),
+        in_specs=[pl.BlockSpec((1, nkv, g, hd), row_map),
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, nkv, g, hd), row_map),
+        scratch_shapes=[pltpu.VMEM((2, nkv, ppb * ps, hd), pool_k.dtype),
+                        pltpu.VMEM((2, nkv, ppb * ps, hd), pool_v.dtype),
+                        pltpu.SemaphoreType.DMA((2, 2)),
+                        pltpu.SMEM((1,), jnp.int32)],
     )
     out = pl.pallas_call(
-        kernel,
+        functools.partial(_paged_decode_kernel, page_size=ps,
+                          pages_per_block=ppb, sm_scale=sm_scale,
+                          quantized=k_scale is not None),
         out_shape=jax.ShapeDtypeStruct((b, nkv, g, hd), q.dtype),
         grid_spec=grid_spec,
+        # rows in order on one core: a row starts its successor's copies
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(*prefetch, q4, pool_k, pool_v)
     return out.reshape(b, nh, hd)[:, None]
@@ -726,10 +793,12 @@ def paged_decode_attention(q, pool_k, pool_v, block_tables, pos, scale=None,
     pool_k/pool_v [num_pages, nkv, page_size, hd] indexed through per-row
     block tables [b, P] (page i of row r holds that row's positions
     [i*ps, (i+1)*ps)), valid prefix [0, pos[r]]. Unused table entries may
-    point anywhere valid (the null page); the position mask keeps them
-    unread. Pallas on TPU (per-row page-index prefetch: the block-table
-    lookup happens in the BlockSpec index map, so K/V stream page-by-page
-    straight from HBM with no contiguous copy), jnp gather elsewhere.
+    point anywhere valid (the null page): entries past a row's last live
+    page are never read. Pallas on TPU (a grid step a row; the kernel
+    walks the row's table as far as its position reaches and copies each
+    live page, every kv head at once, straight from the pool in HBM: no
+    contiguous copy, and no work for a page nobody holds), jnp gather
+    elsewhere.
 
     k_scale/v_scale [num_pages, nkv]: the pools are int8 pages with
     per-(page, kv-head) absmax scales — the kernel dequantizes

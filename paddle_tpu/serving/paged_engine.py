@@ -719,6 +719,11 @@ class PagedEngine(Engine):
             bt = np.full((self.max_slots, Pn), NULL_PAGE, np.int32)
             for slot in active:
                 bt[slot, :len(self._bt[slot])] = self._bt[slot]
+            # the share of the table's width the step's rows hold: the
+            # pages the paged kernel fetches, over slots x pages a slot
+            live = int(np.sum(self._npos[active] // self.page_size + 1))
+            self.metrics.observe("decode_live_page_share",
+                                 live / (self.max_slots * Pn))
             self._pk, self._pv, nxt = self._decode_v[
                 self._sampling_active()](
                 self.params, jnp.asarray(self._last_tok), self._pk,

@@ -319,74 +319,213 @@ class TestRadixTree:
             a.alloc()
 
 
+# one row of the table: name -> (shape, pos per row, how the table is built,
+# pages a compute block; None = what the wrapper works out from the shapes).
+# ps = 16; with 2 pages a block a block boundary falls at position 32.
+_KERNEL_CASES = {
+    # rows at different depths, a page shared by two rows, null-page tails
+    "shared_pages_null_tails": dict(
+        b=3, pos=[49, 127, 33], ppb=None,
+        bt=[[3, 7, 2, 11], [5, 6, 8, 9, 10, 12, 13, 14], [3, 15, 16]]),
+    # an identity table: pages in table order ARE the contiguous cache
+    "identity_table": dict(b=2, P=4, pos=[17, 63], bt="identity", ppb=None),
+    "public_dispatch_hd128": dict(b=2, nh=2, nkv=1, hd=128, P=4,
+                                  pos=[10, 60], bt="identity", ppb=None,
+                                  via="dispatch"),
+    "int8_pool": dict(b=3, hd=128, ps=32, P=4, NP=9, pos=[5, 37, 120],
+                      bt="random", int8=True, via="dispatch", ppb=None,
+                      atol=2e-5),
+    "int8_pool_two_page_blocks": dict(b=3, hd=128, ps=32, P=4, NP=9,
+                                      pos=[0, 64, 127], bt="random",
+                                      int8=True, ppb=2, atol=2e-5),
+    "pos_0": dict(pos=[0, 127], ppb=2),
+    "pos_last_of_first_page": dict(pos=[15, 127], ppb=2),
+    "pos_first_of_second_page": dict(pos=[16, 127], ppb=2),
+    "pos_block_boundary_minus_1": dict(pos=[31, 127], ppb=2),
+    "pos_block_boundary": dict(pos=[32, 127], ppb=2),
+    "pos_block_boundary_plus_1": dict(pos=[33, 127], ppb=2),
+    "full_table_every_row": dict(b=3, pos=[127, 127, 127], ppb=2),
+    # 5 live pages in blocks of 3, which divide neither them nor P = 8
+    "block_divides_neither_pages_nor_table": dict(b=3, pos=[70, 127, 40],
+                                                  ppb=3),
+    "one_page_blocks": dict(b=3, pos=[70, 5, 127], ppb=1),
+    "whole_table_in_one_block": dict(b=3, pos=[70, 5, 127], ppb=8),
+    # a free slot (position 0, every entry the null page) between full rows
+    "free_row_beside_full_ones": dict(b=3, pos=[127, 0, 127],
+                                      bt="free_middle", ppb=2),
+    "free_row_first_and_last": dict(b=4, pos=[0, 90, 127, 0],
+                                    bt="free_ends", ppb=2),
+    # one kv head: what a tensor-parallel shard of the pool holds
+    "one_local_kv_head": dict(b=3, nh=4, nkv=1, pos=[49, 127, 3], ppb=2),
+    "bf16_pool": dict(b=3, pos=[49, 127, 33], ppb=2, dtype="bfloat16",
+                      atol=2e-2),
+    "bf16_pool_derived_block": dict(b=3, hd=128, pos=[49, 127, 0],
+                                    ppb=None, dtype="bfloat16", atol=2e-2,
+                                    via="dispatch"),
+}
+
+
 class TestPagedDecodeKernel:
     def _pool(self, rng, num_pages, nkv, ps, hd, dtype=jnp.float32):
         pk = jnp.asarray(rng.normal(size=(num_pages, nkv, ps, hd)), dtype)
         pv = jnp.asarray(rng.normal(size=(num_pages, nkv, ps, hd)), dtype)
         return pk, pv
 
-    def test_block_table_gather_matches_reference(self):
-        """The Pallas paged kernel (per-row page-index prefetch, per-row
-        watermark) must match the contiguous-gather XLA reference across
-        rows at different depths, shared pages, and null-page tails."""
-        rng = np.random.default_rng(0)
-        b, nh, nkv, hd, ps, P = 3, 4, 2, 32, 16, 8
-        pk, pv = self._pool(rng, 20, nkv, ps, hd)
-        q = jnp.asarray(rng.normal(size=(b, 1, nh, hd)), jnp.float32)
-        bt = np.zeros((b, P), np.int32)
-        bt[0, :4] = [3, 7, 2, 11]       # 50 tokens deep
-        bt[1, :8] = [5, 6, 8, 9, 10, 12, 13, 14]   # full table
-        bt[2, :3] = [3, 15, 16]         # shares row 0's first page
-        pos = jnp.asarray([49, 127, 33], jnp.int32)
-        out = qm._paged_decode_attention_pallas(
-            q, pk, pv, jnp.asarray(bt), pos, 1.0 / np.sqrt(hd),
-            interpret=_INTERPRET)
+    def _case(self, seed, b=2, nh=4, nkv=2, hd=32, ps=16, P=8, NP=None,
+              pos=(), bt="permuted", dtype="float32", int8=False, **_):
+        """Operands of one case: q, the pools (and their scales), the
+        tables and the positions. Pages of a row are drawn without
+        replacement from a shuffled pool unless the case says otherwise;
+        entries past a row's last live page stay the null page (0)."""
+        rng = np.random.default_rng(seed)
+        NP = NP or b * P + 1
+        dtype = jnp.dtype(dtype)
+        q = jnp.asarray(rng.normal(size=(b, 1, nh, hd)),
+                        jnp.float32 if int8 else dtype)
+        if int8:
+            pk, pv = (jnp.asarray(rng.integers(-127, 128,
+                                               size=(NP, nkv, ps, hd)),
+                                  jnp.int8) for _ in range(2))
+            scales = tuple(jnp.asarray(rng.uniform(0.5, 2.0, size=(NP, nkv)),
+                                       jnp.float32) for _ in range(2))
+        else:
+            pk, pv = self._pool(rng, NP, nkv, ps, hd, dtype)
+            scales = (None, None)
+        table = np.zeros((b, P), np.int32)
+        if bt == "identity":
+            table[:] = np.arange(1, 1 + b * P).reshape(b, P)
+        elif bt == "random":
+            table[:] = rng.integers(1, NP, size=(b, P))
+        elif isinstance(bt, list):
+            for r, pages in enumerate(bt):
+                table[r, :len(pages)] = pages
+        else:
+            free = bt in ("free_middle", "free_ends")
+            pages = rng.permutation(np.arange(1, NP))
+            for r in range(b):
+                live = 0 if free and pos[r] == 0 else pos[r] // ps + 1
+                table[r, :live], pages = pages[:live], pages[live:]
+        return (q, pk, pv, jnp.asarray(table),
+                jnp.asarray(pos, jnp.int32)) + scales
+
+    def _run(self, ops, ppb=None, via="kernel", interpret=_INTERPRET):
+        q, pk, pv, bt, pos, ks, vs = ops
+        sm = 1.0 / np.sqrt(q.shape[-1])
+        if via == "dispatch":
+            assert qm.paged_decode_supported(q.shape, pk.shape, bt.shape,
+                                             pk.dtype.itemsize)
+            with qm.fused_dispatch(enabled=True, interpret=_INTERPRET):
+                return qm.paged_decode_attention(q, pk, pv, bt, pos,
+                                                 k_scale=ks, v_scale=vs)
+        return qm._paged_decode_attention_pallas(
+            q, pk, pv, bt, pos, sm, interpret, ks, vs, pages_per_block=ppb)
+
+    @pytest.mark.parametrize("name", list(_KERNEL_CASES))
+    def test_kernel_matches_gather_reference(self, name):
+        """The Pallas paged kernel (a grid step a row, a page loop bounded
+        by the row's position, one copy a live page for all kv heads) must
+        match the contiguous-gather XLA reference."""
+        case = _KERNEL_CASES[name]
+        ops = self._case(sorted(_KERNEL_CASES).index(name), **case)
+        q, pk, pv, bt, pos, ks, vs = ops
+        out = self._run(ops, case.get("ppb"), case.get("via", "kernel"))
         ref = qm._paged_decode_attention_xla(
-            q, pk, pv, jnp.asarray(bt), pos, 1.0 / np.sqrt(hd))
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=1e-4)
+            q.astype(jnp.float32),
+            *((pk, pv) if ks is not None else
+              (pk.astype(jnp.float32), pv.astype(jnp.float32))),
+            bt, pos, 1.0 / np.sqrt(q.shape[-1]), ks, vs)
+        assert out.dtype == q.dtype
+        np.testing.assert_allclose(np.asarray(out, np.float32),
+                                   np.asarray(ref), atol=case.get("atol",
+                                                                  1e-4))
 
-    def test_matches_contiguous_decode_kernel(self):
-        """An identity block table over a paged pool must reproduce the
-        contiguous decode-attention path bit-for... well, to tolerance:
-        pages in table order ARE the sequence."""
-        rng = np.random.default_rng(1)
-        b, nh, nkv, hd, ps, P = 2, 4, 2, 32, 16, 4
-        pk, pv = self._pool(rng, P * b + 1, nkv, ps, hd)
-        q = jnp.asarray(rng.normal(size=(b, 1, nh, hd)), jnp.float32)
-        bt = np.arange(1, 1 + b * P, dtype=np.int32).reshape(b, P)
-        pos = jnp.asarray([17, 63], jnp.int32)
-        ck = qm.paged_gather(pk, jnp.asarray(bt))
-        cv = qm.paged_gather(pv, jnp.asarray(bt))
-        paged = qm._paged_decode_attention_pallas(
-            q, pk, pv, jnp.asarray(bt), pos, 1.0 / np.sqrt(hd),
-            interpret=_INTERPRET)
-        contig = qm._decode_attention_xla(q, ck, cv, pos, 1.0 / np.sqrt(hd))
-        np.testing.assert_allclose(np.asarray(paged), np.asarray(contig),
-                                   atol=1e-4)
+    @pytest.mark.parametrize("kind", ["float32", "bfloat16", "int8"])
+    def test_pages_past_the_last_live_one_are_never_read(self, kind):
+        """Every table entry past a row's last live page points at a page
+        of NaN (for an int8 pool: a page whose scales are NaN): the output
+        is finite and equal to the reference over clean tables. Neither
+        the copies nor the sums may touch a page the row does not hold."""
+        int8 = kind == "int8"
+        case = dict(b=3, hd=128 if int8 else 32, ps=32 if int8 else 16,
+                    pos=[0, 70 if not int8 else 140, 33], int8=int8,
+                    dtype="float32" if int8 else kind)
+        q, pk, pv, bt, pos, ks, vs = self._case(77, **case)
+        poison = max(set(range(1, pk.shape[0])) - set(np.asarray(bt).ravel()))
+        if int8:
+            ks, vs = ks.at[poison].set(jnp.nan), vs.at[poison].set(jnp.nan)
+        else:
+            pk, pv = pk.at[poison].set(jnp.nan), pv.at[poison].set(jnp.nan)
+        ps = pk.shape[2]
+        dead = (np.arange(bt.shape[1])[None, :]
+                > (np.asarray(pos) // ps)[:, None])
+        poisoned = jnp.asarray(np.where(dead, poison, np.asarray(bt)))
+        # the TPU interpreter starts every buffer as NaN, as a page that
+        # was not copied must be taken to be, and raises on a read past
+        # an array's end; it also looks for a copy racing the sums
+        from jax.experimental.pallas import tpu as pltpu
 
-    def test_dispatch_and_supports(self):
-        rng = np.random.default_rng(2)
-        b, nh, nkv, hd, ps, P = 2, 2, 1, 128, 16, 4
-        pk, pv = self._pool(rng, 9, nkv, ps, hd)
-        q = jnp.asarray(rng.normal(size=(b, 1, nh, hd)), jnp.float32)
-        bt = jnp.asarray(np.arange(1, 9, dtype=np.int32).reshape(b, P))
-        pos = jnp.asarray([10, 60], jnp.int32)
-        assert qm.paged_decode_supported(q.shape, pk.shape, bt.shape,
-                                         q.dtype.itemsize)
-        with qm.fused_dispatch(enabled=True, interpret=_INTERPRET):
-            out = qm.paged_decode_attention(q, pk, pv, bt, pos)
-        ref = qm._paged_decode_attention_xla(q, pk, pv, bt, pos,
-                                             1.0 / np.sqrt(hd))
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=1e-4)
+        tpu_like = (pltpu.InterpretParams(detect_races=True)
+                    if _INTERPRET else False)
+        for ppb in (1, 2, 3):
+            out = self._run((q, pk, pv, poisoned, pos, ks, vs), ppb,
+                            interpret=tpu_like)
+            assert np.isfinite(np.asarray(out, np.float32)).all()
+            ref = self._run((q, pk, pv, bt, pos, ks, vs), ppb)
+            np.testing.assert_array_equal(np.asarray(out, np.float32),
+                                          np.asarray(ref, np.float32))
+        want = qm._paged_decode_attention_xla(
+            q.astype(jnp.float32),
+            *((pk, pv) if int8 else (pk.astype(jnp.float32),
+                                     pv.astype(jnp.float32))),
+            bt, pos, 1.0 / np.sqrt(q.shape[-1]),
+            None if ks is None else ks.at[poison].set(1.0),
+            None if vs is None else vs.at[poison].set(1.0))
+        np.testing.assert_allclose(np.asarray(out, np.float32),
+                                   np.asarray(want),
+                                   atol=2e-2 if kind == "bfloat16" else 1e-4)
+
+    def test_pages_per_block_follows_the_shapes(self):
+        # Mistral's widths: 512 positions a block = 8 pages of 64; a table
+        # narrower than that is one block; a wide page is held to the VMEM
+        # budget (K and V, two buffers, every kv head of a page)
+        assert qm._paged_pages_per_block((896, 8, 64, 128), 128, 2) == 8
+        assert qm._paged_pages_per_block((20, 2, 16, 32), 8, 4) == 8
+        assert qm._paged_pages_per_block((64, 8, 16, 128), 128, 2) == 32
+        assert qm._paged_pages_per_block((64, 32, 256, 128), 64, 2) == 1
+        assert qm._paged_pages_per_block((64, 1, 64, 128), 128, 1) == 8
+
+    def test_supports(self):
+        pool, bt = (9, 1, 16, 128), (2, 4)
+        assert qm.paged_decode_supported((2, 1, 2, 128), pool, bt, 4)
         # unsupported shapes: multi-query, lane-misaligned hd, odd page
-        assert not qm.paged_decode_supported((2, 2, 2, 128), pk.shape,
-                                             bt.shape)
+        assert not qm.paged_decode_supported((2, 2, 2, 128), pool, bt)
         assert not qm.paged_decode_supported((2, 1, 2, 64),
-                                             (9, 1, 16, 64), bt.shape, 4)
+                                             (9, 1, 16, 64), bt, 4)
         assert not qm.paged_decode_supported((2, 1, 2, 128),
-                                             (9, 1, 12, 128), bt.shape, 4)
+                                             (9, 1, 12, 128), bt, 4)
+        # int8 pools are eligible at ps % 32 == 0 (the int8 sublane
+        # minimum); the engine's ps=8 fixtures take the gather fallback
+        assert qm.paged_decode_supported((3, 1, 4, 128), (9, 2, 32, 128),
+                                         (3, 4), 1)
+        assert not qm.paged_decode_supported((3, 1, 4, 128),
+                                             (9, 2, 16, 128), (3, 4), 1)
+        # one page with every kv head, K and V, twice, must fit in VMEM
+        assert not qm.paged_decode_supported((2, 1, 64, 128),
+                                             (9, 64, 256, 128), bt, 2)
+
+    def test_int8_gather_dequantizes_exactly(self):
+        # the oracle's own dequantizing gather against a manual dequant
+        rng = np.random.default_rng(11)
+        b, nkv, hd, ps, NP, P = 3, 2, 128, 32, 9, 4
+        kq = jnp.asarray(rng.integers(-127, 128, size=(NP, nkv, ps, hd)),
+                         jnp.int8)
+        ks = jnp.asarray(rng.uniform(0.5, 2.0, size=(NP, nkv)), jnp.float32)
+        bt = jnp.asarray(rng.integers(1, NP, size=(b, P)), jnp.int32)
+        man = (np.asarray(kq)[np.asarray(bt)].astype(np.float32)
+               * (np.asarray(ks)[np.asarray(bt)] / 127.0)[..., None, None])
+        man = np.swapaxes(man, 1, 2).reshape(b, nkv, P * ps, hd)
+        np.testing.assert_allclose(
+            np.asarray(qm.paged_gather(kq, bt, scale=ks)), man, atol=1e-6)
 
     def test_cow_device_copy(self):
         from paddle_tpu.serving.paged_engine import _copy_page_traced
@@ -1068,3 +1207,28 @@ def test_pallas_paged_kernel_keeps_the_scan_body_as_innermost_scope(params):
         text = _lowered_text(eng, "decode")
     assert re.search(r"pt\.attention/pt\.paged_attention/closed_call/"
                      r"pallas_call", text)
+
+
+def test_decode_live_page_share_counts_the_pages_the_rows_hold(params,
+                                                               monkeypatch):
+    """One observation a decode step: the pages its rows hold (position //
+    page size + 1 each) over slots x pages a slot: what the paged kernel
+    fetches, as a share of what the table could name."""
+    eng = PagedEngine(params, ARGS, max_slots=2, max_len=64, page_size=8,
+                      min_bucket=8)
+    seen, observe = [], eng.metrics.observe
+
+    def record(name, value, **kw):
+        if name == "decode_live_page_share":
+            seen.append(value)
+        return observe(name, value, **kw)
+
+    monkeypatch.setattr(eng.metrics, "observe", record)
+    a, b = _prompts([5, 19], seed=41)
+    # one token comes from the prefill: a decodes once (at position 5), b
+    # three times (at positions 19, 20, 21), the first beside a
+    eng.serve([Request(a, 2), Request(b, 4)])
+    assert seen == [(1 + 3) / 16, 3 / 16, 3 / 16]
+    got = eng.metrics.observation("decode_live_page_share")
+    assert got["count"] == 3 and abs(got["mean"] - 10 / 48) < 1e-9
+    assert eng.metrics.counter("decode_steps") == 3
