@@ -54,22 +54,16 @@ class ServeRun:
     def __init__(self, cell, seed, devices, log):
         import jax.numpy as jnp
 
-        from paddle_tpu.models import llama_functional as lf
         from paddle_tpu.serving import PagedEngine, Request
 
         self.Request = Request
         self.cell, self.seed, self.log = cell, seed, log
-        arch = self.arch = cell.config
-        args = lf.LlamaArgs(
-            vocab_size=arch["vocab_size"], hidden_size=arch["hidden_size"],
-            intermediate_size=arch["intermediate_size"],
-            num_layers=arch["num_hidden_layers"],
-            num_heads=arch["num_attention_heads"],
-            num_kv_heads=arch["num_key_value_heads"],
-            rope_theta=arch["rope_theta"], rms_eps=arch["rms_norm_eps"])
-        params = make_params(arch, seed, jnp.bfloat16)
+        arch, family = self.arch, self.family = cell.config, cell.family
+        params = make_params(family, arch, seed, jnp.bfloat16)
         self.engine_kw = dict(cell.spec["engine"])
-        self.eng = PagedEngine(params, args, **self.engine_kw)
+        # the engine is the harness's choice, never the family's
+        self.eng = PagedEngine(params, family.serve_args(arch),
+                               **self.engine_kw)
         del params
         self.traffic = ServeTraffic(cell.traffic, arch["vocab_size"], seed)
         self.spans = []      # (type, start, end, tokens emitted) per step()
@@ -111,8 +105,10 @@ class ServeRun:
         self.compiles_before = self._compiles()
 
     def _compiles(self):
+        """Every program the engine counts the compilations of, whatever
+        step programs a family's path adds to prefill and decode."""
         c = self.eng.metrics.summary()["counters"]
-        return c.get("prefill_compiles", 0) + c.get("decode_compiles", 0)
+        return sum(n for name, n in c.items() if name.endswith("_compiles"))
 
     # -- the loop ----------------------------------------------------------------
     def _submit(self, session, due, now):
@@ -252,8 +248,10 @@ class ServeRun:
     def sample(self):
         """The finished requests to compare: every one, or past COMPARE_MAX
         the longest (prompt + served tokens) and a draw from the seed of the
-        others. Returns (prompt, tokens) pairs and checks every finished
-        request has its asked length."""
+        others. Returns (prompt, tokens, record) and checks every finished
+        request has its asked length. `record` is the attribute of the
+        finished `Request` that the family's `REQUEST_RECORD` names, for the
+        family's own `served_logits` to read (None where it names none)."""
         done = [r for r in self.recs.values()
                 if r.req is not None and r.req.finished]
         short = [r.rid for r in done if len(r.req.token_ids) != r.want]
@@ -268,8 +266,9 @@ class ServeRun:
             rng = np.random.default_rng([int(self.seed), 7])
             picks = rng.choice(len(rest), COMPARE_MAX - 1, replace=False)
             done = [longest] + [rest[i] for i in sorted(picks)]
-        return [(r.prompt, np.asarray(r.req.token_ids, np.int32))
-                for r in done]
+        record = getattr(self.family, "REQUEST_RECORD", None)
+        return [(r.prompt, np.asarray(r.req.token_ids, np.int32),
+                 getattr(r.req, record) if record else None) for r in done]
 
     def free(self):
         del self.eng
@@ -279,7 +278,12 @@ class ServeRun:
 
     def reference_logits(self, sample, mm=reference.f32_mm):
         t = time.perf_counter()
-        out = reference.served_logits(self.arch, self.seed, sample, mm)
+        # tokens chosen left to right are judged by one causal pass; a
+        # family that chooses them otherwise brings its own rule
+        own = getattr(self.family, "served_logits", None)
+        out = (own(self.arch, self.seed, sample, mm) if own else
+               reference.served_logits(self.family, self.arch, self.seed,
+                                       sample, mm))
         self.log(f"correct: the reference ran {len(sample)} requests in "
                  f"{time.perf_counter() - t:.1f} s")
         return out
@@ -297,7 +301,7 @@ class ServeRun:
         if logits is None:
             logits = self.reference_logits(sample)
         gaps = []
-        for i, (prompt, served) in enumerate(sample):
+        for i, (prompt, served, _) in enumerate(sample):
             gap = reference.served_gap(
                 logits[i], served if tokens is None else tokens[i])
             self.log(f"correct: request of {len(prompt)} + {len(served)} "

@@ -39,31 +39,25 @@ class TrainRun:
         import jax.numpy as jnp
 
         from paddle_tpu.distributed.hybrid_engine import HybridParallelEngine
-        from paddle_tpu.models.llama import LlamaConfig
 
         self.jax, self.jnp = jax, jnp
         self.cell, self.seed, self.log = cell, seed, log
         arch, eng_kw = cell.config, dict(cell.spec["engine"])
-        self.arch = arch
+        self.arch, self.family = arch, cell.family
         self.traffic = TrainTraffic(cell.traffic, arch["vocab_size"], seed)
         dtype = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}[
             eng_kw.pop("dtype")]
         self.hp = cell.spec["optimizer"]
-        cfg = LlamaConfig(
-            vocab_size=arch["vocab_size"], hidden_size=arch["hidden_size"],
-            intermediate_size=arch["intermediate_size"],
-            num_hidden_layers=arch["num_hidden_layers"],
-            num_attention_heads=arch["num_attention_heads"],
-            num_key_value_heads=arch["num_key_value_heads"],
-            rms_norm_eps=arch["rms_norm_eps"], rope_theta=arch["rope_theta"])
+        # the engine is the harness's choice, never the family's
         self.eng = HybridParallelEngine(
-            cfg, micro_batches=self.traffic.micro_batches, dtype=dtype,
+            self.family.train_config(arch),
+            micro_batches=self.traffic.micro_batches, dtype=dtype,
             lr=self.hp["lr"], devices=devices, **eng_kw)
         # the program's own (zero) optimizer state; its seeded weights are
         # dropped for the benchmark's, which the reference can make too
         p0, self.opt = self.eng.init_state(0)
         del p0
-        self.params = make_params(arch, seed, dtype,
+        self.params = make_params(self.family, arch, seed, dtype,
                                   out_shardings=self.eng.param_shardings())
         self.dtype = dtype
         self.spans = []          # (name, start, end) on the host clock
@@ -94,7 +88,8 @@ class TrainRun:
         now = _leaf_names(self.params)
         self.readings["delta_norms"] = {
             k: math.sqrt(float(dsq(now[k], p0)))
-            for k, p0 in leaves(self.arch, self.seed, self.dtype)}
+            for k, p0 in leaves(self.family, self.arch, self.seed,
+                                self.dtype)}
         self.next_step = CHECK_STEPS
 
     # -- the measured window ---------------------------------------------------
@@ -150,7 +145,7 @@ class TrainRun:
         first gradient's norms and the parameters' change, leaf by leaf."""
         hp = self.hp
         ref = reference.TrainReference(
-            self.arch, self.seed,
+            self.family, self.arch, self.seed,
             (hp["lr"], hp["beta1"], hp["beta2"], hp["eps"],
              hp["weight_decay"]), mm=mm, dtype=self.dtype)
         losses, grads = [], None

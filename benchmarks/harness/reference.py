@@ -1,14 +1,17 @@
-"""The plain reference: a dense decoder with grouped-query attention, rotary
-positions, RMS norms and a SwiGLU feed-forward, written from the published
-equations in `jax.numpy`, float32, matmul precision `highest`.
+"""The plain reference's machinery, for any decoder-only language model on
+the two engines: embedding, a stack of layers, a final RMS norm and a head,
+in `jax.numpy`, float32, matmul precision `highest`.
 
-No kernel, no cache, no batching tricks, and nothing of the program: its
-weights are made here from the seed (`harness.weights`), one layer upcast
-at a time. `mm` is the one matrix multiplication every weight goes
-through; the control swaps it for a lower precision (`fp8_mm`).
+The layer itself is the family's (`family.decoder_layer(x, w, arch, mm)`,
+see `harness/spec.py`), written from the published equations. No kernel, no
+cache, no batching tricks, and nothing of the program: the weights are made
+here from the seed (`harness.weights`), one layer upcast at a time. `mm` is
+the one matrix multiplication every weight goes through; the control swaps
+it for a lower precision (`fp8_mm`).
 
 Served model:  `served_logits` runs each prompt with its served tokens once
-and returns the logits at the served positions.
+and returns the logits at the served positions (right where tokens are
+chosen left to right; a family that chooses them otherwise brings its own).
 Training:      `TrainReference` follows the first steps of AdamW training
 layer by layer (forward keeps the layer inputs, backward recomputes one
 layer at a time and updates it at once), so it fits where the program did.
@@ -22,10 +25,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.harness import weights
-from benchmarks.harness.counts import head_dim
 
 HIGHEST = jax.lax.Precision.HIGHEST
-Q_BLOCK = 1024     # queries attended at once: bounds the score matrix
+Q_BLOCK = 1024     # queries a layer attends at once, to bound its score
+                   # matrix: sequences come padded to a multiple of it
 
 
 def f32_mm(x, w):
@@ -64,72 +67,15 @@ fp8_mm.defvjp(_fp8_mm_fwd, _fp8_mm_bwd)
 
 
 # ---------------------------------------------------------------------------
-# the decoder layer
+# one layer of the family, jitted
 # ---------------------------------------------------------------------------
 
 def rms_norm(x, w, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * w
 
 
-def rotary(x, theta):
-    """x [s, heads, hd]; pairs (i, i + hd/2) rotate by pos * theta^(-2i/hd)."""
-    s, _, hd = x.shape
-    inv = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
-    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv[None, :]
-    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
-    x1, x2 = x[..., :hd // 2], x[..., hd // 2:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-def causal_attention(q, k, v):
-    """One sequence. q [s, nh, hd], k/v [s, nkv, hd]; query head j reads
-    key/value head j // (nh/nkv). Queries go in blocks of Q_BLOCK."""
-    s, nh, hd = q.shape
-    nkv = k.shape[1]
-    qg = q.reshape(s, nkv, nh // nkv, hd)
-    blk = min(Q_BLOCK, s)
-    if s % blk:
-        raise ValueError(f"sequence {s} is not a multiple of {blk}")
-
-    @jax.checkpoint
-    def block(i):
-        qb = jax.lax.dynamic_slice_in_dim(qg, i * blk, blk, 0)
-        sc = jnp.einsum("qkgd,skd->kgqs", qb, k, precision=HIGHEST)
-        sc = sc / math.sqrt(hd)
-        qpos = i * blk + jnp.arange(blk)[:, None]
-        sc = jnp.where(jnp.arange(s)[None, :] <= qpos, sc, -jnp.inf)
-        p = jax.nn.softmax(sc, axis=-1)
-        return jnp.einsum("kgqs,skd->qkgd", p, v, precision=HIGHEST)
-
-    out = jax.lax.map(block, jnp.arange(s // blk))
-    return out.reshape(s, nh * hd)
-
-
-def decoder_layer(x, w, arch, mm):
-    """x [b, s, h] float32; w one layer's weights (any float type)."""
-    w = jax.tree.map(lambda a: a.astype(jnp.float32), w)
-    return _decoder_layer32(x, w, arch, mm)
-
-
-def _decoder_layer32(x, w, arch, mm):
-    hd, eps = head_dim(arch), arch["rms_norm_eps"]
-    nh, nkv = arch["num_attention_heads"], arch["num_key_value_heads"]
-    theta = arch["rope_theta"]
-    b, s, _ = x.shape
-    hin = rms_norm(x, w["ln1"], eps)
-    q = mm(hin, w["wq"]).reshape(b, s, nh, hd)
-    k = mm(hin, w["wk"]).reshape(b, s, nkv, hd)
-    v = mm(hin, w["wv"]).reshape(b, s, nkv, hd)
-
-    def one(qkv):
-        q1, k1, v1 = qkv
-        return causal_attention(rotary(q1, theta), rotary(k1, theta), v1)
-
-    attn = jax.lax.map(one, (q, k, v))
-    x = x + mm(attn, w["wo"])
-    hin = rms_norm(x, w["ln2"], eps)
-    act = jax.nn.silu(mm(hin, w["w_gate"])) * mm(hin, w["w_up"])
-    return x + mm(act, w["w_down"])
+def _f32(w):
+    return jax.tree.map(lambda a: a.astype(jnp.float32), w)
 
 
 def _frozen(arch):
@@ -138,19 +84,19 @@ def _frozen(arch):
 
 
 @functools.lru_cache(maxsize=None)
-def _layer_fwd(frozen, mm):
+def _layer_fwd(layer, frozen, mm):
+    """`layer` is a family's `decoder_layer`; the jitted function takes x
+    [b, s, h] float32 and w, one layer's weights in any float type."""
     arch = dict(frozen)
-    return jax.jit(lambda x, w: decoder_layer(x, w, arch, mm))
+    return jax.jit(lambda x, w: layer(x, _f32(w), arch, mm))
 
 
 @functools.lru_cache(maxsize=None)
-def _layer_bwd(frozen, mm):
+def _layer_bwd(layer, frozen, mm):
     arch = dict(frozen)
 
     def bwd(x, w, dy):
-        w32 = jax.tree.map(lambda a: a.astype(jnp.float32), w)
-        _, vjp = jax.vjp(lambda x_, w_: _decoder_layer32(x_, w_, arch, mm),
-                         x, w32)
+        _, vjp = jax.vjp(lambda x_, w_: layer(x_, w_, arch, mm), x, _f32(w))
         return vjp(dy)
 
     return jax.jit(bwd, donate_argnums=(2,))
@@ -172,8 +118,8 @@ def _head_fn(eps, mm):
 HEAD_ROWS = 128    # served positions go through the head in multiples of it
 
 
-def served_logits(arch, seed, requests, mm=f32_mm):
-    """For each (prompt, tokens) of `requests`, the reference logits
+def served_logits(family, arch, seed, requests, mm=f32_mm):
+    """For each (prompt, tokens, ...) of `requests`, the reference logits
     [len(tokens), vocab] at the positions where the server chose `tokens`
     after `prompt`: one full forward over the prompt with its served
     tokens, padded up to a multiple of Q_BLOCK (padding sits after
@@ -181,20 +127,20 @@ def served_logits(arch, seed, requests, mm=f32_mm):
     so each layer's weights are made once."""
     outer = weights.outer_params(arch, seed)
     xs = []
-    for prompt, tokens in requests:
+    for prompt, tokens, *_ in requests:
         seq = np.concatenate([np.asarray(prompt), np.asarray(tokens)[:-1]])
         ids = np.zeros(-(-len(seq) // Q_BLOCK) * Q_BLOCK, np.int32)
         ids[:len(seq)] = seq
         xs.append(outer["embedding"][jnp.asarray(ids)]
                   .astype(jnp.float32)[None])
-    fwd = _layer_fwd(_frozen(arch), mm)
+    fwd = _layer_fwd(family.decoder_layer, _frozen(arch), mm)
     for i in range(arch["num_hidden_layers"]):
-        w = weights.layer_params(arch, seed, i)
+        w = weights.layer_params(family, arch, seed, i)
         for j, x in enumerate(xs):
             xs[j] = fwd(x, w)
     head = _head_fn(arch["rms_norm_eps"], mm)
     out = []
-    for (prompt, tokens), x in zip(requests, xs):
+    for (prompt, tokens, *_), x in zip(requests, xs):
         n, m = len(prompt), len(tokens)
         rows = n - 1 + np.arange(-(-m // HEAD_ROWS) * HEAD_ROWS)
         rows = np.minimum(rows, x.shape[1] - 1)
@@ -265,11 +211,12 @@ class TrainReference:
     all arithmetic is float32 at `highest`.
     """
 
-    def __init__(self, arch, seed, hp, mm=f32_mm, dtype=jnp.bfloat16):
-        self.arch, self.seed, self.hp, self.mm = arch, seed, tuple(hp), mm
-        self.dtype = dtype
+    def __init__(self, family, arch, seed, hp, mm=f32_mm,
+                 dtype=jnp.bfloat16):
+        self.family, self.arch, self.seed = family, arch, seed
+        self.hp, self.mm, self.dtype = tuple(hp), mm, dtype
         self.n_layers = arch["num_hidden_layers"]
-        self.layers = [weights.layer_params(arch, seed, i, dtype)
+        self.layers = [weights.layer_params(family, arch, seed, i, dtype)
                        for i in range(self.n_layers)]
         self.outer = weights.outer_params(arch, seed, dtype)
         zeros = functools.partial(jax.tree.map,
@@ -297,7 +244,8 @@ class TrainReference:
         self.step += 1
         ids, labels = jnp.asarray(ids), jnp.asarray(labels)
         B, s = ids.shape
-        fwd, bwd = _layer_fwd(frozen, mm), _layer_bwd(frozen, mm)
+        fwd = _layer_fwd(self.family.decoder_layer, frozen, mm)
+        bwd = _layer_bwd(self.family.decoder_layer, frozen, mm)
         xs = [self.outer["embedding"][ids].astype(jnp.float32)]
         for w in self.layers:
             xs.append(fwd(xs[-1], w))
@@ -342,7 +290,8 @@ class TrainReference:
         """Leaf -> norm of (weights now - weights at the seed)."""
         out = {}
         for i, w in enumerate(self.layers):
-            w0 = weights.layer_params(self.arch, self.seed, i, self.dtype)
+            w0 = weights.layer_params(self.family, self.arch, self.seed, i,
+                                      self.dtype)
             for name in w:
                 key = f"layers/{name}"
                 out[key] = out.get(key, 0.0) + float(_delta_sq(w[name],

@@ -2,7 +2,17 @@
 
 A cell is one entry of `workloads`. Its files:
 
-  benchmarks/configs/<config>.json     the model configuration as it is run
+  benchmarks/configs/<config>.json     the model configuration as it is run;
+                                       its key `family` names the next file
+  benchmarks/families/<family>.py      what the harness knows of the model's
+                                       block: the program's static arguments
+                                       (`serve_args`, `train_config`), one
+                                       layer's leaves (`layer_shapes`), the
+                                       plain float32 layer (`decoder_layer`)
+                                       and, where the generic ones do not
+                                       serve, `leaf_init`, `served_logits`
+                                       with `REQUEST_RECORD`, and the counts
+                                       of its own readers
   benchmarks/traffic/<traffic>.json    the traffic mix: parameters only
   benchmarks/workloads/<cell>.json     kind, engine arguments, limits
   benchmarks/metrics/<metric>.py       one per-layer metric's reader; a
@@ -10,10 +20,11 @@ A cell is one entry of `workloads`. Its files:
                                        metric it moves (`<quantity>.<split>`)
                                        has one reader, `<quantity>.py`
 
-A later PR adds a cell, a configuration, a mix or a metric by adding files
-and entries; nothing here is edited for it.
+A later PR adds a cell, a configuration, a family, a mix or a metric by
+adding files and entries; nothing here is edited for it.
 """
 
+import functools
 import importlib.util
 import json
 import os
@@ -25,6 +36,14 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 def _read_json(path):
     with open(path) as f:
         return json.load(f)
+
+
+def _load(name, path):
+    spec = importlib.util.spec_from_file_location(
+        name.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 class Cell:
@@ -46,6 +65,14 @@ class Cell:
         conf = next(c for c in bench["configs"]
                     if c["name"] == self.config_name)
         self.config = _read_json(os.path.join(root, conf["file"]))
+        family, fdir = self.config.get("family"), os.path.join(
+            self.bench_dir, "families")
+        self._family_path = os.path.join(fdir, f"{family}.py")
+        if not os.path.isfile(self._family_path):
+            there = sorted(f[:-3] for f in os.listdir(fdir)
+                           if f.endswith(".py"))
+            raise SystemExit(f"{conf['file']} names the family {family!r}; "
+                             f"{fdir} has {there}")
         self.traffic = _read_json(os.path.join(
             self.bench_dir, "traffic", self.traffic_name + ".json"))
         self.spec = _read_json(os.path.join(
@@ -63,6 +90,14 @@ class Cell:
             if (name in m["workloads"] if "workloads" in m
                 else m["moves"] in e2e_names)]
 
+    @functools.cached_property
+    def family(self):
+        """The module benchmarks/families/<the configuration's `family`>.py,
+        loaded at its first use: it imports jax, which `run.py` configures
+        after it has read the cell."""
+        return _load("bench_family_" + self.config["family"],
+                     self._family_path)
+
     def reader(self, metric_name):
         """The `read(ctx)` function of benchmarks/metrics/<metric>.py or,
         where that file is not there, of <name before the first dot>.py."""
@@ -70,9 +105,4 @@ class Cell:
         path = os.path.join(mdir, metric_name + ".py")
         if not os.path.isfile(path):
             path = os.path.join(mdir, metric_name.split(".")[0] + ".py")
-        spec = importlib.util.spec_from_file_location(
-            "bench_metric_" + metric_name.replace(".", "_").replace("-", "_"),
-            path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod.read
+        return _load("bench_metric_" + metric_name, path).read

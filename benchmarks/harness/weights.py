@@ -2,17 +2,18 @@
 
 The layout is the one the program's entry points take: `embedding`
 [vocab, h], `layers/*` stacked on a leading layer axis with every matrix
-stored [in, out], `final_norm`, `lm_head` [h, vocab]. Each layer's values
-depend only on (seed, layer index), so the reference can make one layer at
-a time and get the same numbers.
+stored [in, out], `final_norm`, `lm_head` [h, vocab]. One layer's leaves,
+by name and shape, are the family's (`family.layer_shapes(arch)`, see
+`harness/spec.py`); a leaf whose published init differs from the rules here
+is in the family's `leaf_init(arch)` as name -> (mean, std). Each layer's
+values depend only on (seed, layer index), so the reference can make one
+layer at a time and get the same numbers.
 """
 
 import functools
 
 import jax
 import jax.numpy as jnp
-
-from benchmarks.harness.counts import head_dim
 
 INIT_STD = 0.02      # the published initializer_range of both models
 NORM_JITTER = 0.05   # norm weights 1 + 0.05 N(0,1): a dropped norm weight shows
@@ -25,30 +26,27 @@ def _key(seed):
     return jax.random.fold_in(k, (seed >> 16) & 0xFFFFFFFF)
 
 
-def layer_shapes(arch):
-    h, i, hd = arch["hidden_size"], arch["intermediate_size"], head_dim(arch)
-    nh, nkv = arch["num_attention_heads"], arch["num_key_value_heads"]
-    return {"wq": (h, nh * hd), "wk": (h, nkv * hd), "wv": (h, nkv * hd),
-            "wo": (nh * hd, h), "w_gate": (h, i), "w_up": (h, i),
-            "w_down": (i, h), "ln1": (h,), "ln2": (h,)}
-
-
-def _leaf(key, shape, dtype):
+def _leaf(key, shape, dtype, init=None):
     x = jax.random.normal(key, shape, jnp.float32)
+    if init is not None:
+        return (init[0] + init[1] * x).astype(dtype)
     if len(shape) == 1:
         return (1.0 + NORM_JITTER * x).astype(dtype)
     return (INIT_STD * x).astype(dtype)
 
 
 def make_layer(arch_key, seed_key, index, dtype):
-    """One layer's weights; `arch_key` is a hashable tuple of the shapes."""
+    """One layer's weights; `arch_key` is a hashable tuple of (name, shape,
+    stated init or None), sorted by name: a leaf's fold-in is its place."""
     k = jax.random.fold_in(seed_key, index)
-    return {name: _leaf(jax.random.fold_in(k, j), shape, dtype)
-            for j, (name, shape) in enumerate(arch_key)}
+    return {name: _leaf(jax.random.fold_in(k, j), shape, dtype, init)
+            for j, (name, shape, init) in enumerate(arch_key)}
 
 
-def _arch_key(arch):
-    return tuple(sorted(layer_shapes(arch).items()))
+def _arch_key(family, arch):
+    inits = getattr(family, "leaf_init", lambda arch: {})(arch)
+    return tuple((name, tuple(shape), inits.get(name)) for name, shape
+                 in sorted(family.layer_shapes(arch).items()))
 
 
 def make_outer(arch, seed_key, dtype):
@@ -60,9 +58,9 @@ def make_outer(arch, seed_key, dtype):
             "lm_head": _leaf(jax.random.fold_in(k, 2), (h, v), dtype)}
 
 
-def make_params(arch, seed, dtype=jnp.bfloat16, out_shardings=None):
+def make_params(family, arch, seed, dtype=jnp.bfloat16, out_shardings=None):
     """The whole tree in one jitted call on the device."""
-    akey, n_layers = _arch_key(arch), arch["num_hidden_layers"]
+    akey, n_layers = _arch_key(family, arch), arch["num_hidden_layers"]
 
     def build(seed_key):
         layers = jax.vmap(lambda i: make_layer(akey, seed_key, i, dtype))(
@@ -79,9 +77,10 @@ def _layer_fn(akey, dtype):
     return jax.jit(lambda seed_key, i: make_layer(akey, seed_key, i, dtype))
 
 
-def layer_params(arch, seed, index, dtype=jnp.bfloat16):
+def layer_params(family, arch, seed, index, dtype=jnp.bfloat16):
     """Layer `index` alone: equal to make_params(...)['layers'][*][index]."""
-    return _layer_fn(_arch_key(arch), dtype)(_key(seed), jnp.int32(index))
+    return _layer_fn(_arch_key(family, arch), dtype)(_key(seed),
+                                                     jnp.int32(index))
 
 
 @functools.lru_cache(maxsize=None)
@@ -91,12 +90,12 @@ def _stacked_leaf_fn(akey, name, n_layers, dtype):
             jnp.arange(n_layers)))
 
 
-def leaves(arch, seed, dtype=jnp.bfloat16):
+def leaves(family, arch, seed, dtype=jnp.bfloat16):
     """(`layers/<name>` or outer name, array) one leaf at a time, each equal
     to make_params's and each materialized in `dtype` by a call of its own
     (inside one fused program XLA may skip the rounding to `dtype`)."""
-    akey, n = _arch_key(arch), arch["num_hidden_layers"]
-    for name, _ in akey:
+    akey, n = _arch_key(family, arch), arch["num_hidden_layers"]
+    for name, _, _ in akey:
         yield f"layers/{name}", _stacked_leaf_fn(akey, name, n, dtype)(
             _key(seed))
     yield from outer_params(arch, seed, dtype).items()

@@ -165,7 +165,8 @@ def main(argv=None, require_chip=True, root=ROOT):
             device["window_s"] = summary["window_s"]
             result["breakdown"] = reduce_trace.breakdown(summary)
         ctx.__dict__.update(
-            cell=cell, arch=cell.config, e2e=e2e, trace=summary,
+            cell=cell, arch=cell.config, family=cell.family, e2e=e2e,
+            trace=summary,
             device=device, chips=len(devices), seconds=opts.seconds,
             trace_host_window=tracer.host_window,
             peaks=(peaks.peaks_for(device["kind"])

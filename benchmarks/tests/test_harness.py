@@ -4,11 +4,15 @@ check the yardstick's arithmetic by hand, and keep the two proofs that
 `correct` can fail: the lower-precision control and a broken timed path.
 """
 
+import filecmp
+import functools
+import hashlib
 import json
 import os
 import re
 import subprocess
 import sys
+import types
 
 import numpy as np
 import pytest
@@ -25,6 +29,7 @@ force_cpu_platform(1)
 from benchmarks import run  # noqa: E402
 from benchmarks.harness import (counts, peaks, reduce_trace,  # noqa: E402
                                 reference, traffic, weights)
+from benchmarks.harness.spec import Cell  # noqa: E402
 from benchmarks.tests import tiny  # noqa: E402
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -53,6 +58,8 @@ def test_benchmark_json_names_files_that_exist():
         conf = _config(os.path.basename(c["file"]))
         assert conf["reduced"] == c["reduced"]
         assert conf["source"] == c["source"]
+        assert NAME.match(conf["family"]) and os.path.isfile(os.path.join(
+            bdir, "families", conf["family"] + ".py"))
     e2e = {m["name"] for m in b["end_to_end"]}
     assert "setup_s" in e2e
     cells = {w["name"] for w in b["workloads"]}
@@ -74,6 +81,35 @@ def test_benchmark_json_names_files_that_exist():
         assert set(m.get("workloads", [])) <= cells
     assert sum(w["chips"] == 4 for w in b["workloads"]) <= max(
         1, len(b["workloads"]) // 4)
+
+
+def test_every_cell_resolves_to_a_family_with_the_names_its_kind_calls():
+    """No jit and no engine: what a tier-1 test of the seam would check."""
+    for w in _bench()["workloads"]:
+        cell = Cell(w["name"])
+        fam = cell.family
+        assert fam.__file__ == os.path.join(
+            ROOT, "benchmarks", "families", cell.config["family"] + ".py")
+        entry = {"serve": "serve_args", "train": "train_config"}[cell.kind]
+        for name in (entry, "layer_shapes", "decoder_layer"):
+            assert callable(getattr(fam, name)), (w["name"], name)
+        shapes = fam.layer_shapes(cell.config)
+        assert shapes and all(isinstance(n, int) for shape in shapes.values()
+                              for n in shape)
+        assert set(getattr(fam, "leaf_init", lambda a: {})(cell.config)) \
+            <= set(shapes)
+
+
+def test_a_family_that_is_not_there_stops_the_run_with_those_that_are(
+        tmp_path):
+    root = tiny.tiny_root(tmp_path)
+    path = os.path.join(root, "benchmarks", "configs", "tiny.json")
+    with open(path, "w") as f:
+        json.dump(dict(tiny.TINY_ARCH, family="no_such_block"), f)
+    with pytest.raises(SystemExit) as e:
+        Cell("tiny_train", root)
+    assert "no_such_block" in str(e.value)
+    assert "'dense_gqa_swiglu', 'tiny_alt'" in str(e.value)
 
 
 def test_run_refuses_without_a_chip():
@@ -223,22 +259,30 @@ def test_reduce_trace_on_the_recorded_extract():
 
 # -- the reference against the program, toy size ---------------------------------------
 
+@functools.cache
+def _dense():
+    """The committed dense family, found as a run finds it (once: a module
+    loaded anew is a new `decoder_layer`, which the reference jits anew)."""
+    return Cell("train_internlm2_s4096").family
+
+
 def test_reference_forward_matches_llama_functional():
     import jax
     import jax.numpy as jnp
 
     from paddle_tpu.models import llama_functional as lf
 
-    arch = tiny.TINY_ARCH
-    args = lf.LlamaArgs(8192, 128, 256, 2, 4, 2, 10000.0, 1e-05)
-    params = weights.make_params(arch, 7, jnp.float32)
+    arch, fam = tiny.TINY_ARCH, _dense()
+    args = fam.serve_args(arch)
+    assert args == lf.LlamaArgs(8192, 128, 256, 2, 4, 2, 10000.0, 1e-05)
+    params = weights.make_params(fam, arch, 7, jnp.float32)
     rng = np.random.default_rng(0)
     prompt, toks = rng.integers(1, 8192, 37), rng.integers(1, 8192, 9)
     seq = np.concatenate([prompt, toks[:-1]])[None]
     want = lf.forward(params, jnp.asarray(seq), args, remat=False)[0, 36:]
-    got = reference.served_logits(arch, 7, [(prompt, toks)])[0]
+    got = reference.served_logits(fam, arch, 7, [(prompt, toks)])[0]
     # the reference makes bf16 weights; the program got the same seed in f32
-    params16 = weights.make_params(arch, 7, jnp.bfloat16)
+    params16 = weights.make_params(fam, arch, 7, jnp.bfloat16)
     want16 = lf.forward(jax.tree.map(lambda a: a.astype(jnp.float32),
                                      params16),
                         jnp.asarray(seq), args, remat=False)[0, 36:]
@@ -246,7 +290,122 @@ def test_reference_forward_matches_llama_functional():
     assert np.abs(np.asarray(want) - np.asarray(want16)).max() > 1e-4
 
 
-# -- the drivers end to end, and adding a cell needs no edit ---------------------------
+# -- the seeded weights did not move ---------------------------------------------------
+
+# sha256, first 16 hex digits, of each leaf's bytes for TINY_ARCH at seed
+# 2**31 + 17 on the CPU, taken on PR 25's commit (before the family seam):
+# the dense cells' weights, and with them every limit of `correct` that PR 23
+# measured, keep their meaning
+PINNED = {
+    "make_params/bfloat16": {
+        "embedding": "3b87218fe64b5424", "final_norm": "e8f2deae18dc7d31",
+        "layers/ln1": "712abf736882fb9e", "layers/ln2": "50812d5544ea735d",
+        "layers/w_down": "bcca87ff7df45c1a",
+        "layers/w_gate": "966a4b84f21a0f20",
+        "layers/w_up": "cfa3053c535fa496", "layers/wk": "c6ab4be5686c4aad",
+        "layers/wo": "7b35370a19244ef1", "layers/wq": "720ce1fa22b23b9d",
+        "layers/wv": "f4e3efb5b0fead87", "lm_head": "09768780812b4b7b"},
+    "make_params/float32": {
+        "embedding": "6097995b0a284c5f", "final_norm": "ab80832d8b53aa77",
+        "layers/ln1": "caacae945ec63668", "layers/ln2": "a0da556b22337508",
+        "layers/w_down": "45618fd35cba1ebe",
+        "layers/w_gate": "f98dd1752fcda0eb",
+        "layers/w_up": "f17fa5385bf7f230", "layers/wk": "058e791bcd380298",
+        "layers/wo": "d779d707b37c80ae", "layers/wq": "c728f724e29d173f",
+        "layers/wv": "0ccbdfee3f1a23ff", "lm_head": "ba777248acc65fdb"},
+    "layer_params/1/bfloat16": {
+        "ln1": "71683180dbbb4f9f", "ln2": "9c47c55ecc2e0361",
+        "w_down": "58eb39d03e85901d", "w_gate": "dbdc9e98434934a1",
+        "w_up": "bfa2beb7bcd05d6d", "wk": "7c53fe105e916fa6",
+        "wo": "e1b638d8bb952ba5", "wq": "7569f1f094914cb0",
+        "wv": "30a7b7015ce116e4"}}
+
+
+def _digests(tree):
+    from benchmarks.harness.driver_train import _leaf_names
+
+    out = {}
+    for name, leaf in _leaf_names(tree).items():
+        a = np.asarray(leaf)
+        a = a.view(np.uint16) if a.dtype.itemsize == 2 else a
+        out[name] = hashlib.sha256(a.tobytes()).hexdigest()[:16]
+    return out
+
+
+@pytest.mark.parametrize("which", sorted(PINNED))
+def test_seeded_weights_are_bit_identical_to_pr25(which):
+    import jax.numpy as jnp
+
+    fam, arch, seed = _dense(), tiny.TINY_ARCH, 2**31 + 17
+    if which.startswith("make_params"):
+        dtype = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}[
+            which.split("/")[1]]
+        tree = weights.make_params(fam, arch, seed, dtype)
+        # a leaf alone, as the train driver reads it back, is the same leaf
+        for name, leaf in weights.leaves(fam, arch, seed, dtype):
+            assert _digests({name: leaf})[name] == PINNED[which][name]
+    else:
+        tree = weights.layer_params(fam, arch, seed, 1)
+    assert _digests(tree) == PINNED[which]
+
+
+# -- the harness holds no leaf name and no head size ------------------------------------
+
+def test_weights_and_reference_take_any_leaves_and_any_head_size(root):
+    """The rehearsal family under leaf names of its own and a head size that
+    is NOT hidden // heads (4 heads of 48 at hidden 128), through the
+    weights and the reference alone: the program cannot run either today."""
+    import jax.numpy as jnp
+
+    alt = _cell(root, "alt_train").family
+    arch = dict(tiny.ALT_ARCH, head_size=48)
+    with pytest.raises(ValueError):
+        alt.serve_args(arch)
+    mine = {n: "blk_" + n for n in alt.layer_shapes(arch)}
+    back = {v: k for k, v in mine.items()}
+    fam = types.SimpleNamespace(
+        layer_shapes=lambda a: {mine[n]: s
+                                for n, s in alt.layer_shapes(a).items()},
+        leaf_init=lambda a: {mine[n]: i for n, i in alt.leaf_init(a).items()},
+        decoder_layer=lambda x, w, a, mm: alt.decoder_layer(
+            x, {back[n]: v for n, v in w.items()}, a, mm))
+    params = weights.make_params(fam, arch, 5, jnp.float32)
+    assert set(params["layers"]) == set(back)
+    assert params["layers"]["blk_wq"].shape == (2, 128, 4 * 48)
+    assert params["layers"]["blk_wo"].shape == (2, 4 * 48, 128)
+    # the stated init of one leaf (std 0.01) beside the rule's (0.02)
+    assert float(params["layers"]["blk_wo"].std()) == pytest.approx(
+        0.01, rel=0.02)
+    assert float(params["layers"]["blk_wq"].std()) == pytest.approx(
+        0.02, rel=0.02)
+    rng = np.random.default_rng(0)
+    prompt, toks = rng.integers(1, 8192, 30), rng.integers(1, 8192, 5)
+    logits, = reference.served_logits(fam, arch, 5, [(prompt, toks)])
+    assert logits.shape == (5, 8192) and np.isfinite(logits).all()
+    ref = reference.TrainReference(fam, arch, 5, (3e-4, 0.9, 0.999, 1e-8,
+                                                  0.01), dtype=jnp.float32)
+    ids = rng.integers(1, 8192, (2, 1025)).astype(np.int32)
+    loss = ref.train_step(ids[:, :-1], ids[:, 1:])
+    assert np.isfinite(loss)
+    grads = ref.grad_norms()
+    assert {k for k in grads if k.startswith("layers/")} == {
+        "layers/" + n for n in back}
+    assert all(g > 0 for g in grads.values())
+    assert all(d > 0 for d in ref.delta_norms().values())
+
+
+def test_compiles_counts_every_program_the_engine_counts():
+    from benchmarks.harness.driver_serve import ServeRun
+
+    run = ServeRun.__new__(ServeRun)
+    counters = {"prefill_compiles": 2, "decode_compiles": 1,
+                "block_step_compiles": 4, "cow_copies": 9}
+    run.eng = types.SimpleNamespace(metrics=types.SimpleNamespace(
+        summary=lambda: {"counters": counters}))
+    assert run._compiles() == 7
+
+
+# -- the drivers end to end, and adding a cell or a family needs no edit ---------------
 
 @pytest.fixture(scope="module")
 def root(tmp_path_factory):
@@ -276,6 +435,38 @@ def test_added_cell_runs_end_to_end(root, cell, metric, capsys):
     assert any(line.startswith("compare:") for line in out)
 
 
+@pytest.mark.parametrize("cell,correct", [
+    ("alt_train", True), ("alt_backlog", True),
+    ("altwrong_train", False), ("altwrong_backlog", False)])
+def test_added_family_runs_end_to_end_and_its_reference_decides(
+        root, cell, correct, capsys):
+    """A family that came as files: its cells are correct by ITS reference,
+    and not correct where that reference's first norm lost its weight, so
+    the run read the added file."""
+    rc, res, out = _run(root, cell, 0, capsys)
+    assert rc == 0 and res["failed"] == 0 and res["attempted"] > 0
+    assert res["correct"] is correct
+    rows = [line for line in out if line.startswith("compare:")]
+    assert rows and any("NOT OK" in r for r in rows) is not correct
+
+
+def test_the_added_family_and_cells_needed_no_edit(root):
+    theirs, mine = (os.path.join(r, "benchmarks") for r in (root, ROOT))
+    same = ["run.py", "control.py",
+            os.path.join("families", "dense_gqa_swiglu.py")]
+    same += [os.path.join("harness", f)
+             for f in os.listdir(os.path.join(mine, "harness"))
+             if f.endswith(".py")]
+    same += [os.path.join(d, f) for d in ("configs", "traffic", "workloads",
+                                          "metrics")
+             for f in os.listdir(os.path.join(mine, d))
+             if not f.startswith("__")]
+    assert len(same) > 30
+    match, mismatch, errors = filecmp.cmpfiles(mine, theirs, same,
+                                               shallow=False)
+    assert (mismatch, errors) == ([], [])
+
+
 def test_traced_run_reports_per_layer_and_the_added_metric(root, capsys):
     rc, res, _ = _run(root, "tiny_backlog", 1, capsys)
     assert rc == 0 and res["correct"] is True
@@ -290,9 +481,8 @@ def test_traced_run_reports_per_layer_and_the_added_metric(root, capsys):
 
 # -- `correct` can fail: the control, and a broken timed path ---------------------------
 
+@functools.cache
 def _cell(root, name):
-    from benchmarks.harness.spec import Cell
-
     return Cell(name, root)
 
 
@@ -303,9 +493,10 @@ def test_control_fp8_fails_the_served_limit(root, seed):
     cell = _cell(root, "tiny_sessions")
     rng = np.random.default_rng(seed)
     prompt, toks = rng.integers(1, 8192, 512), rng.integers(1, 8192, 513)
-    ref, = reference.served_logits(cell.config, seed, [(prompt, toks)])
-    low, = reference.served_logits(cell.config, seed, [(prompt, toks)],
-                                   reference.fp8_mm)
+    ref, = reference.served_logits(cell.family, cell.config, seed,
+                                   [(prompt, toks)])
+    low, = reference.served_logits(cell.family, cell.config, seed,
+                                   [(prompt, toks)], reference.fp8_mm)
     gap = reference.served_gap(ref, np.asarray(low).argmax(-1))
     assert gap.mean() > cell.spec["limits"]["served_gap_mean"]
 
@@ -322,7 +513,8 @@ def test_control_fp8_fails_a_training_limit(root, seed):
     for name, mm in (("ref", reference.f32_mm), ("low", reference.fp8_mm)):
         import jax.numpy as jnp
 
-        r = reference.TrainReference(cell.config, seed, hp, mm, jnp.float32)
+        r = reference.TrainReference(cell.family, cell.config, seed, hp, mm,
+                                     jnp.float32)
         losses = [r.train_step(*data.batch(0))]
         out[name] = {"losses": losses, "grad_norms": r.grad_norms(),
                      "delta_norms": r.delta_norms()}
@@ -389,16 +581,14 @@ def test_third_turn_of_a_session_agrees_with_the_reference():
     import jax
     import jax.numpy as jnp
 
-    from paddle_tpu.models import llama_functional as lf
     from paddle_tpu.serving import PagedEngine, Request
 
-    arch = tiny.TINY_ARCH
-    args = lf.LlamaArgs(8192, 128, 256, 2, 4, 2, 10000.0, 1e-05)
+    arch, fam = tiny.TINY_ARCH, _dense()
     params = jax.tree.map(lambda a: a.astype(jnp.float32),
-                          weights.make_params(arch, 11, jnp.bfloat16))
-    eng = PagedEngine(params, args, max_slots=3, max_len=512, page_size=16,
-                      num_pages=120, min_bucket=16, prefill_chunk=32,
-                      prefix_policy="radix")
+                          weights.make_params(fam, arch, 11, jnp.bfloat16))
+    eng = PagedEngine(params, fam.serve_args(arch), max_slots=3, max_len=512,
+                      page_size=16, num_pages=120, min_bucket=16,
+                      prefill_chunk=32, prefix_policy="radix")
     rng = np.random.default_rng(0)
     hist = rng.integers(1, 8192, 70).astype(np.int32)
     worst = 0.0
@@ -409,7 +599,8 @@ def test_third_turn_of_a_session_agrees_with_the_reference():
         eng.run_until_idle()
         toks = np.asarray(req.token_ids, np.int32)
         gap = reference.served_gap(
-            reference.served_logits(arch, 11, [(prompt, toks)])[0], toks)
+            reference.served_logits(fam, arch, 11, [(prompt, toks)])[0],
+            toks)
         worst = max(worst, float(gap.max()))
         hist = np.concatenate([prompt, toks])
     assert worst < 1e-3      # float32 program against the float32 reference

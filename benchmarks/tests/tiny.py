@@ -1,16 +1,19 @@
 """A tiny benchmark in a temporary root: the committed harness, readers
-and BENCHMARK.json's metrics, with a toy configuration, toy mixes and toy
-cells ADDED as new files and entries. It is how the tests rehearse the
-drivers on the CPU, and it shows that a new cell needs no edit."""
+and BENCHMARK.json's metrics, with toy configurations, toy mixes, toy
+cells and a second model family ADDED as new files and entries. It is how
+the tests rehearse the drivers on the CPU, and it shows that a new cell or
+a new family needs no edit."""
 
 import json
 import os
+import re
 import shutil
 
 from benchmarks.harness.spec import ROOT
 
 TINY_ARCH = {
-    "source": "none: a toy for the CPU tests", "hidden_size": 128,
+    "source": "none: a toy for the CPU tests", "family": "dense_gqa_swiglu",
+    "hidden_size": 128,
     "intermediate_size": 256, "num_hidden_layers": 2,
     "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 32,
     "vocab_size": 8192, "rms_norm_eps": 1e-05, "rope_theta": 10000.0,
@@ -45,14 +48,50 @@ TINY_SERVE_CELL = {
     "limits": {"served_gap_widest": 0.02, "served_gap_mean": 0.0003}}
 
 
+# the second family (`data/tiny_alt.py`), and the same with the norms'
+# weights dropped from its `decoder_layer`: a run over that one has to come
+# out not correct, which shows that a run reads the added file
+ALT_FAMILY = os.path.join(os.path.dirname(__file__), "data", "tiny_alt.py")
+ALT_WRONG = re.compile(r'_norm\(x, w\["ln[12]"\], eps\)'), "_norm(x, 1.0, eps)"
+ALT_ARCH = {
+    "source": "none: a toy for the CPU tests", "family": "tiny_alt",
+    "hidden_size": 128, "ffn_size": 256, "num_hidden_layers": 2, "heads": 4,
+    "kv_heads": 2, "head_size": 32, "vocab_size": 8192,
+    "rms_norm_eps": 1e-05, "rope_theta": 10000.0, "reduced": [],
+    "assumed": {}}
+# the mean gap over 4 seeds on the CPU (PR 26): sound 0 .. 1.7e-5, with the
+# norms' weights dropped 4.7e-4 .. 9.6e-4
+ALT_SERVE_CELL = dict(TINY_SERVE_CELL, limits={"served_gap_widest": 0.02,
+                                               "served_gap_mean": 1e-4})
+
+# (cell, configuration, traffic, the cell's file, its end-to-end metric)
+CELLS = [
+    ("tiny_train", "tiny", "tiny_train", TINY_TRAIN_CELL,
+     "train_tokens_per_s"),
+    ("tiny_sessions", "tiny", "tiny_sessions", TINY_SERVE_CELL,
+     "itl_mean_ms"),
+    ("tiny_backlog", "tiny", "tiny_backlog", TINY_SERVE_CELL,
+     "serve_out_tokens_per_s"),
+    ("alt_train", "tiny_alt", "tiny_train", TINY_TRAIN_CELL,
+     "train_tokens_per_s"),
+    ("alt_backlog", "tiny_alt", "tiny_backlog", ALT_SERVE_CELL,
+     "serve_out_tokens_per_s"),
+    ("altwrong_train", "tiny_alt_wrong", "tiny_train", TINY_TRAIN_CELL,
+     "train_tokens_per_s"),
+    ("altwrong_backlog", "tiny_alt_wrong", "tiny_backlog", ALT_SERVE_CELL,
+     "serve_out_tokens_per_s")]
+E2E = {"train_tokens_per_s": ("tokens/s/chip", "higher"),
+       "itl_mean_ms": ("ms", "lower"),
+       "serve_out_tokens_per_s": ("tokens/s", "higher")}
+
+
 def _dump(path, obj):
-    os.makedirs(os.path.dirname(path), exist_ok=True)
     with open(path, "w") as f:
         json.dump(obj, f)
 
 
 def tiny_root(tmp):
-    """Copy the committed benchmark into `tmp` and add the toy cells."""
+    """Copy the committed benchmark into `tmp` and add the toy files."""
     tmp = str(tmp)
     shutil.copytree(os.path.join(ROOT, "benchmarks"),
                     os.path.join(tmp, "benchmarks"),
@@ -60,41 +99,45 @@ def tiny_root(tmp):
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     b = os.path.join(tmp, "benchmarks")
-    _dump(os.path.join(b, "configs", "tiny.json"), TINY_ARCH)
+    with open(ALT_FAMILY) as f:
+        alt = f.read()
+    wrong, n = ALT_WRONG[0].subn(ALT_WRONG[1], alt)
+    if n != 2:
+        raise RuntimeError("data/tiny_alt.py lost the two lines to break")
+    for name, text in (("tiny_alt", alt), ("tiny_alt_wrong", wrong)):
+        with open(os.path.join(b, "families", name + ".py"), "w") as f:
+            f.write(text)
+    configs = {"tiny": TINY_ARCH, "tiny_alt": ALT_ARCH,
+               "tiny_alt_wrong": dict(ALT_ARCH, family="tiny_alt_wrong")}
+    for name, arch in configs.items():
+        _dump(os.path.join(b, "configs", name + ".json"), arch)
+        bench["configs"].append({"name": name, "source": "none",
+                                 "file": f"benchmarks/configs/{name}.json",
+                                 "reduced": [], "why": "toy"})
     _dump(os.path.join(b, "traffic", "tiny_train.json"), TINY_TRAIN_MIX)
     _dump(os.path.join(b, "traffic", "tiny_sessions.json"), TINY_SERVE_MIX)
     _dump(os.path.join(b, "traffic", "tiny_backlog.json"), TINY_BACKLOG_MIX)
-    _dump(os.path.join(b, "workloads", "tiny_train.json"), TINY_TRAIN_CELL)
-    _dump(os.path.join(b, "workloads", "tiny_sessions.json"), TINY_SERVE_CELL)
-    _dump(os.path.join(b, "workloads", "tiny_backlog.json"), TINY_SERVE_CELL)
     with open(os.path.join(b, "metrics", "tiny_steps.py"), "w") as f:
         f.write("def read(ctx):\n    return float(len(ctx.spans))\n")
-    bench["configs"].append({"name": "tiny", "source": "none",
-                             "file": "benchmarks/configs/tiny.json",
-                             "reduced": [], "why": "toy"})
-    for name, traffic in (("tiny_train", "tiny_train"),
-                          ("tiny_sessions", "tiny_sessions"),
-                          ("tiny_backlog", "tiny_backlog")):
-        bench["workloads"].append({"name": name, "config": "tiny",
+    # the toy cells report the end-to-end metric of their kind (an entry
+    # the committed file lacks is added, as a later PR would add it), and a
+    # cell that wants an existing per-layer metric joins its `workloads`
+    have = {m["name"]: m for m in bench["end_to_end"]}
+    for cell, config, traffic, spec, metric in CELLS:
+        _dump(os.path.join(b, "workloads", cell + ".json"), spec)
+        bench["workloads"].append({"name": cell, "config": config,
                                    "traffic": traffic, "chips": 1,
                                    "why": "toy"})
-    # the toy cells report the end-to-end metrics of their kind (an entry
-    # the committed file lacks is added, as a later PR would add it)
-    kinds = {"train_tokens_per_s": ("tiny_train", "tokens/s/chip", "higher"),
-             "itl_mean_ms": ("tiny_sessions", "ms", "lower"),
-             "serve_out_tokens_per_s": ("tiny_backlog", "tokens/s", "higher")}
-    have = {m["name"]: m for m in bench["end_to_end"]}
-    for name, (cell, unit, better) in kinds.items():
-        if name in have:
-            have[name]["workloads"].append(cell)
-        else:
-            bench["end_to_end"].append({
-                "name": name, "unit": unit, "better": better, "bound": 0.05,
-                "source": "host_clock", "workloads": [cell]})
-    # a cell that wants an existing per-layer metric joins its `workloads`
-    for m in bench["per_layer"]:
-        if "workloads" in m and m["moves"] in kinds:
-            m["workloads"].append(kinds[m["moves"]][0])
+        if metric not in have:
+            unit, better = E2E[metric]
+            have[metric] = {"name": metric, "unit": unit, "better": better,
+                            "bound": 0.05, "source": "host_clock",
+                            "workloads": []}
+            bench["end_to_end"].append(have[metric])
+        have[metric]["workloads"].append(cell)
+        for m in bench["per_layer"]:
+            if "workloads" in m and m["moves"] == metric:
+                m["workloads"].append(cell)
     bench["per_layer"].append({
         "name": "tiny_steps", "unit": "steps", "better": "higher",
         "source": "program_span", "layer": "server entry",
