@@ -42,6 +42,8 @@ from __future__ import annotations
 from collections import OrderedDict
 from typing import List, NamedTuple, Optional
 
+import numpy as np
+
 __all__ = ["BlockAllocator", "PrefixMatch", "NULL_PAGE"]
 
 NULL_PAGE = 0
@@ -59,15 +61,27 @@ class PrefixMatch(NamedTuple):
                   must gather from it and write into its own copy.
     partial_len:  valid leading tokens on partial_page (0 when None).
     matched:      total cached tokens = len(pages)*page_size+partial_len.
+    state:        id of the recurrent-state snapshot taken at `matched`
+                  tokens, where the allocator keeps snapshots (a model
+                  with recurrent layers can resume only from one); else
+                  None.
     """
 
     pages: List[int]
     partial_page: Optional[int]
     partial_len: int
     matched: int
+    state: Optional[int] = None
 
 
 _EMPTY_MATCH = PrefixMatch([], None, 0, 0)
+
+
+def _ints(tokens):
+    """Python ints of a token sequence: `tolist` of an array is ten times
+    faster than a loop, which matters at prompts of tens of thousands of
+    tokens walked for every queued request."""
+    return np.asarray(tokens).tolist()
 
 
 class _RadixNode:
@@ -82,7 +96,8 @@ class _RadixNode:
     child extends it. Every owned page id appears exactly once in the
     whole tree."""
 
-    __slots__ = ("edge", "start", "pages", "children", "parent", "stamp")
+    __slots__ = ("edge", "start", "pages", "children", "parent", "stamp",
+                 "snaps")
 
     def __init__(self, edge, start, pages, parent):
         self.edge = edge          # tuple of ints
@@ -91,6 +106,9 @@ class _RadixNode:
         self.children = {}        # first edge token -> _RadixNode
         self.parent = parent
         self.stamp = 0            # LRU clock of the last committed hit
+        # absolute token count (start < count <= end) -> id of the recurrent
+        # state snapshot taken after that many tokens of this path
+        self.snaps = {}
 
     @property
     def end(self):
@@ -114,20 +132,23 @@ class _RadixIndex:
         return page in self._owner
 
     # -- longest-prefix match ----------------------------------------------
-    def match(self, tokens, touch=False):
+    def match(self, tokens, touch=False, need_state=False):
         """Longest cached prefix of `tokens`, capped at len-1 so the
         final token is always recomputed (its next-token logits are the
         point of the prefill). Pure tree walk — refcounts are the
-        allocator's business."""
+        allocator's business. With `need_state` the match is cut back to
+        the deepest recurrent-state snapshot on the matched path (a
+        model with recurrent layers resumes only from one): no snapshot,
+        no hit, whatever pages matched."""
         ps = self._a.page_size
         limit = len(tokens) - 1
-        if limit <= 0:
-            return _EMPTY_MATCH
-        toks = [int(t) for t in tokens[:limit]]
+        if limit <= 0 or int(tokens[0]) not in self.root.children:
+            return _EMPTY_MATCH   # (before a long prompt is converted)
+        toks = _ints(tokens[:limit])
         acc = []                  # pages in path order: acc[i] covers page i
         node = self.root
         path = [node]
-        m = 0
+        m, snap = 0, None         # snap: deepest (token count, snapshot id)
         while m < limit:
             child = node.children.get(toks[m])
             if child is None:
@@ -138,6 +159,9 @@ class _RadixIndex:
             acc.extend(child.pages)
             path.append(child)
             m += k
+            for count, sid in child.snaps.items():
+                if count <= m and (snap is None or count > snap[0]):
+                    snap = (count, sid)
             if k < len(edge):
                 break
             node = child
@@ -145,13 +169,24 @@ class _RadixIndex:
             t = self._tick()
             for nd in path:
                 nd.stamp = t
+        state = None
+        if need_state:
+            if snap is None:
+                if m and touch and self._a._metrics is not None:
+                    self._a._metrics.inc("prefix_hits_without_state")
+                return _EMPTY_MATCH
+            m, state = snap
+            if touch:
+                self._a._touch_snapshot(state)
         full, plen = m // ps, m % ps
         partial = None
         if plen:
             partial = self._page_covering(path[-1], acc, full)
             if partial is None:     # defensive: degrade to page-aligned
+                if need_state:      # a snapshot is of one token count
+                    return _EMPTY_MATCH
                 plen, m = 0, full * ps
-        return PrefixMatch(acc[:full], partial, plen, m)
+        return PrefixMatch(acc[:full], partial, plen, m, state)
 
     def _page_covering(self, last, acc, idx):
         """Physical page holding page-index `idx` of the matched path.
@@ -178,7 +213,7 @@ class _RadixIndex:
         owned elsewhere are never re-claimed (the walk passes through
         them); registration never touches refcounts."""
         ps = self._a.page_size
-        toks = [int(t) for t in tokens]
+        toks = _ints(tokens)
         n = len(toks)
         if not n or not pages:
             return
@@ -205,6 +240,51 @@ class _RadixIndex:
             self._insert_leaf(mid, toks, i + k, pages)
             return
         # walked the whole sequence along existing edges: already cached
+
+    def attach_state(self, tokens, sid):
+        """Hang snapshot `sid`, taken after ALL of `tokens`, on the node
+        whose edge holds that token count. False where the tree does not
+        hold the whole sequence (or already has a snapshot there)."""
+        toks = _ints(tokens)
+        n, node, i = len(toks), self.root, 0
+        while i < n:
+            child = node.children.get(toks[i])
+            if child is None:
+                return False
+            edge, k = child.edge, 0
+            while k < len(edge) and i + k < n and edge[k] == toks[i + k]:
+                k += 1
+            i += k
+            if i == n:
+                # the pages up to n must be there too: a leaf's edge can
+                # outrun them only by what an eviction cut, never
+                if n in child.snaps:
+                    return False
+                child.snaps[n] = sid
+                self._a.prefix_version += 1
+                return True
+            if k < len(edge):
+                return False
+            node = child
+        return False
+
+    def _drop_snaps(self, node, beyond):
+        """Give back the snapshots of `node` taken past `beyond` tokens."""
+        for count in [c for c in node.snaps if c > beyond]:
+            self._a._snapshot_gone(node.snaps.pop(count))
+
+    def detach_state(self, sid):
+        """Forget snapshot `sid` wherever it hangs (its buffer is being
+        taken for a newer one)."""
+        stack = [self.root]
+        while stack:
+            nd = stack.pop()
+            stack.extend(nd.children.values())
+            for count, s in list(nd.snaps.items()):
+                if s == sid:
+                    del nd.snaps[count]
+                    self._a.prefix_version += 1
+                    return
 
     def _insert_leaf(self, parent, toks, i, pages):
         """Hang a new leaf for tokens [i, n) under `parent`. The leaf
@@ -252,6 +332,8 @@ class _RadixIndex:
         child.parent = mid
         mid.children = {child.edge[0]: child}
         mid.stamp = child.stamp
+        mid.snaps = {c: s for c, s in child.snaps.items() if c <= d}
+        child.snaps = {c: s for c, s in child.snaps.items() if c > d}
         for p in mid.pages:
             self._owner[p] = mid
         if self._a._metrics is not None:
@@ -289,6 +371,7 @@ class _RadixIndex:
         if best.pages:
             new_end = (best.start // ps + len(best.pages)) * ps
             best.edge = best.edge[:new_end - best.start]
+            self._drop_snaps(best, new_end)
         else:
             self._remove(best)
         return p
@@ -299,6 +382,7 @@ class _RadixIndex:
         child."""
         while node is not self.root:
             parent = node.parent
+            self._drop_snaps(node, -1)
             del parent.children[node.edge[0]]
             if parent.children or parent.pages or parent is self.root:
                 return
@@ -358,7 +442,7 @@ class _HashChainIndex:
     def owns(self, page):
         return page in self._key_of
 
-    def match(self, tokens, touch=False):
+    def match(self, tokens, touch=False, need_state=False):
         ps = self._a.page_size
         max_pages = (len(tokens) - 1) // ps
         pages, parent = [], -1
@@ -434,9 +518,21 @@ class BlockAllocator:
 
     policy="radix" (default) indexes prefixes in a token-granular radix
     tree with COW page splits and leaf-LRU eviction; policy="hash"
-    keeps the PR-8 exact-match full-page chain as a baseline."""
+    keeps the PR-8 exact-match full-page chain as a baseline.
 
-    def __init__(self, num_pages, page_size, metrics=None, policy="radix"):
+    state_snapshots=N (radix only) is for a model with RECURRENT layers,
+    whose per-request state is no function of pages: a prefix is reusable
+    only from a token count at which the state was saved. The allocator
+    then owns N snapshot ids (the engine owns the N device buffers):
+    `take_snapshot` hands one out, taking the least recently hit one back
+    from the tree when none is free; `attach_state` hangs it on the radix
+    node that holds its token count; `match_prefix` returns the longest
+    match that ENDS AT a snapshot (`PrefixMatch.state`), else no hit, and
+    counts `prefix_hits_without_state`; evicting the pages under a snapshot
+    frees it."""
+
+    def __init__(self, num_pages, page_size, metrics=None, policy="radix",
+                 state_snapshots=0):
         if num_pages < 2:
             raise ValueError("need >= 2 pages (page 0 is the null page)")
         if page_size < 1:
@@ -460,6 +556,13 @@ class BlockAllocator:
         else:
             raise ValueError(f"unknown prefix policy {policy!r} "
                              "(expected 'radix' or 'hash')")
+        if state_snapshots and self.policy != "radix":
+            raise ValueError("state snapshots hang on the radix tree: "
+                             "state_snapshots needs policy='radix'")
+        self.state_snapshots = int(state_snapshots)
+        self._snap_free = list(range(self.state_snapshots - 1, -1, -1))
+        self._snap_lru = OrderedDict()    # attached snapshot ids, LRU first
+        self._bulk = False
         self._gauges()
 
     # -- introspection ------------------------------------------------------
@@ -490,9 +593,30 @@ class BlockAllocator:
         return self._index.owns(page)
 
     def _gauges(self):
-        if self._metrics is not None:
+        # `available` walks the radix tree: once an operation, so the bulk
+        # forms below (a window's pages, a retiring slot's) pay it once
+        if self._metrics is not None and not self._bulk:
             self._metrics.set_gauge("pages_in_use", len(self._ref))
             self._metrics.set_gauge("pages_free", self.available)
+
+    def alloc_many(self, n):
+        """`alloc()` n times, the gauges set once."""
+        self._bulk = True
+        try:
+            return [self.alloc() for _ in range(n)]
+        finally:
+            self._bulk = False
+            self._gauges()
+
+    def release_many(self, pages):
+        """`release()` of every page, the gauges set once."""
+        self._bulk = True
+        try:
+            for p in pages:
+                self.release(p)
+        finally:
+            self._bulk = False
+            self._gauges()
 
     # -- alloc / ref / release ---------------------------------------------
     def alloc(self):
@@ -565,6 +689,42 @@ class BlockAllocator:
         self._gauges()
         return new, True
 
+    # -- recurrent-state snapshots -------------------------------------------
+    def take_snapshot(self):
+        """An id to save a slot's recurrent state under, or None when every
+        id is held by a request that has not retired yet. The id is the
+        caller's until `attach_state` or `release_snapshot`."""
+        if self._snap_free:
+            return self._snap_free.pop()
+        if not self._snap_lru:
+            return None
+        sid, _ = self._snap_lru.popitem(last=False)
+        self._index.detach_state(sid)
+        return sid
+
+    def attach_state(self, tokens, sid):
+        """Make snapshot `sid`, the state after all of `tokens`, hittable:
+        it hangs on the radix node that holds that token count. Where the
+        tree does not hold the sequence the id goes back. Returns whether
+        it hangs."""
+        if self._index.attach_state(tokens, sid):
+            self._snap_lru[sid] = True
+            return True
+        self._snap_free.append(sid)
+        return False
+
+    def release_snapshot(self, sid):
+        """Give back an id that was taken and never attached."""
+        self._snap_free.append(sid)
+
+    def _touch_snapshot(self, sid):
+        self._snap_lru.move_to_end(sid)
+
+    def _snapshot_gone(self, sid):
+        """The index dropped an attached snapshot (its pages were evicted)."""
+        del self._snap_lru[sid]
+        self._snap_free.append(sid)
+
     # -- prefix cache -------------------------------------------------------
     def match_prefix(self, tokens, commit=True):
         """Longest cached prefix of `tokens` as a PrefixMatch — full
@@ -574,7 +734,8 @@ class BlockAllocator:
         for the caller (reviving cached pages) and the path's LRU stamp
         is bumped; commit=False is a side-effect-free peek for admission
         checks."""
-        m = self._index.match(tokens, touch=commit)
+        m = self._index.match(tokens, touch=commit,
+                              need_state=bool(self.state_snapshots))
         if commit:
             for p in m.pages:
                 self.ref(p)

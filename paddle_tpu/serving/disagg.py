@@ -248,6 +248,14 @@ class StoreTransport:
         return int(self.store.add(f"{self.channel}/n", 0)) - self._seen
 
 
+def _refuse_hybrid(args):
+    if hasattr(args, "layer_kinds"):
+        raise ValueError(
+            "disaggregated workers do not serve a hybrid model: a "
+            "`KVHandoff` ships pages, and the lightning layers' recurrent "
+            "state is in none of them")
+
+
 class PrefillWorker(PagedEngine):
     """A `PagedEngine` restricted to the PREFILL role via the scheduler
     hooks: `_decodable_slots` is empty so `_step_action` only ever
@@ -257,6 +265,7 @@ class PrefillWorker(PagedEngine):
     accounting are all the base engine's."""
 
     def __init__(self, params, args, *, transport, **kw):
+        _refuse_hybrid(args)
         if kw.get("draft_params") is not None:
             raise ValueError("disaggregated workers do not run "
                              "speculative decoding (the draft mirror "
@@ -320,6 +329,7 @@ class DecodeWorker(PagedEngine):
 
     def __init__(self, params, args, *, transport, completion_cb=None,
                  **kw):
+        _refuse_hybrid(args)
         if kw.get("draft_params") is not None:
             raise ValueError("disaggregated workers do not run "
                              "speculative decoding (the draft has no "

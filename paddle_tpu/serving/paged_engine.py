@@ -55,6 +55,17 @@ past a row's page reservation are redirected to the null page.
 Greedy parity with sequential `generate` stays exact under every
 combination of the three (and int8 `quantize_params` trees stream
 through the same fused dequant-matmul dispatch).
+
+A HYBRID STACK (`args` a `models/hybrid_functional.HybridArgs`: lightning
+linear-attention layers beside block-sparse attention layers) runs through
+the same `submit` / `step`, scheduler and allocator with a second kind of
+per-request state: pages for the sparse layers only, and a fixed-size
+recurrent state a slot for the lightning layers (`serving/hybrid.py` holds
+both and the two step programs). A prefix hit is then usable only where a
+SNAPSHOT of the recurrent state was taken: at a finished prompt's end, hung
+on the radix tree when the request retires (`BlockAllocator(...,
+state_snapshots=)`). `preempt` / `resume` carry the state; a mesh, an int8
+pool and a draft model are refused at construction.
 """
 
 from __future__ import annotations
@@ -288,6 +299,19 @@ class PagedEngine(Engine):
                     f"prefill_chunk={prefill_chunk} must be a positive "
                     f"multiple of page_size={page_size}")
         self.prefill_chunk = prefill_chunk
+        # a model description that lists its layers' kinds is a hybrid stack
+        self._hybrid = hasattr(args, "layer_kinds")
+        if self._hybrid:
+            for given, what, why in (
+                    (mesh, "mesh=", "the recurrent state and the selection "
+                     "have no tensor-parallel placement yet"),
+                    (kv_dtype, "kv_dtype='int8'", "the selector's "
+                     "compressed keys are means of unquantized keys"),
+                    (draft_params, "draft_params=", "a rejected draft "
+                     "token cannot be taken back out of a recurrent state")):
+                if given is not None:
+                    raise ValueError(f"{what} is not supported for a "
+                                     f"hybrid model: {why}")
         if draft_params is not None and draft_args is None:
             raise ValueError("draft_params requires draft_args "
                              "(see generation.draft_from_params)")
@@ -319,9 +343,31 @@ class PagedEngine(Engine):
                            out_specs=out_specs, check_vma=False)
         return jax.jit(sm, donate_argnums=donate)
 
+    def _reset_host_state(self):
+        """The allocator, block tables and reservations of an empty
+        engine (construction and `reset`)."""
+        self._alloc = BlockAllocator(
+            self.num_pages, self.page_size, metrics=self.metrics,
+            policy=self.prefix_policy,
+            state_snapshots=self._hy.snapshots if self._hybrid else 0)
+        self._bt = [[] for _ in range(self.max_slots)]   # host block tables
+        self._resv = {}            # slot -> pages still reserved for decode
+        self._reserved_total = 0
+        self._chunk_streams = {}   # slot -> {req, n, done} mid-chunked-prefill
+        self._chunk_turn = False
+        self._admit_idx = None     # _can_prefill's cached admission scan
+
     def _setup_device_state(self):
         args = self.args
         axis = self.tp_axis
+        if self._hybrid:
+            # pools, recurrent state and step programs of the hybrid path
+            from paddle_tpu.serving.hybrid import HybridPath
+
+            self.tp_degree, self._spec = 1, None
+            self._hy = HybridPath(self)
+            self._reset_host_state()
+            return
         if self.mesh is not None:
             from paddle_tpu.serving import tp as tp_lib
 
@@ -371,15 +417,7 @@ class PagedEngine(Engine):
         self._cos, self._sin = lf.rope_tables(2 * self.max_len, hd,
                                               args.rope_theta)
 
-        self._alloc = BlockAllocator(self.num_pages, self.page_size,
-                                     metrics=self.metrics,
-                                     policy=self.prefix_policy)
-        self._bt = [[] for _ in range(self.max_slots)]   # host block tables
-        self._resv = {}            # slot -> pages still reserved for decode
-        self._reserved_total = 0
-        self._chunk_streams = {}   # slot -> {req, n, done} mid-chunked-prefill
-        self._chunk_turn = False
-        self._admit_idx = None     # _can_prefill's cached admission scan
+        self._reset_host_state()
 
         donate = self._donate_enabled()
         rep = P()
@@ -519,6 +557,14 @@ class PagedEngine(Engine):
         return [s for s in active if s not in self._chunk_streams]
 
     # -- prefill ------------------------------------------------------------
+    def _cow_device(self, src, dst):
+        """Device half of copy-on-write: clone page `src` into `dst`."""
+        if self._hybrid:
+            self._hy.copy_page(src, dst)
+        else:
+            self._pk, self._pv = self._copy_page(
+                self._pk, self._pv, jnp.int32(src), jnp.int32(dst))
+
     def _begin_paged_prefill(self, req, slot, n):
         """Match prefix hits, seat the block table, and reserve the
         request's remaining worst-case pages (prompt pages still to be
@@ -538,10 +584,13 @@ class PagedEngine(Engine):
             src = hit.partial_page
             copy, _ = self._alloc.ensure_writable(src)
             with self._phase("stage", request_id=req.request_id, slot=slot):
-                self._pk, self._pv = self._copy_page(
-                    self._pk, self._pv, jnp.int32(src), jnp.int32(copy))
+                self._cow_device(src, copy)
             self._bt[slot].append(copy)
             held += 1
+        if hit.state is not None:
+            # a hybrid model resumes from the snapshot the match ends at
+            with self._phase("stage", request_id=req.request_id, slot=slot):
+                self._hy.load_snapshot(slot, hit.state)
         resv = pages_for(n, req.max_new_tokens, ps) - held
         self._resv[slot] = resv
         self._reserved_total += resv
@@ -563,7 +612,7 @@ class PagedEngine(Engine):
         # partial-hit COW copy, earlier chunks); token-granular `start`
         # makes this ceil(end/ps) minus the seated count
         n_now = -(-end // ps) - len(self._bt[slot])
-        new_pages = [self._alloc.alloc() for _ in range(n_now)]
+        new_pages = self._alloc.alloc_many(n_now)
         self._resv[slot] -= n_now
         self._reserved_total -= n_now
         self._bt[slot].extend(new_pages)
@@ -587,13 +636,20 @@ class PagedEngine(Engine):
                 padded = np.full((1, sb), self.pad_id, np.int32)
                 padded[0, :end - start] = req.prompt_ids[start:end]
                 sample = final and req.temperature > 0
-                self._pk, self._pv, first = self._prefill_v[sample](
-                    self.params, jnp.asarray(padded), jnp.int32(start),
-                    jnp.int32(end - 1 - start), jnp.asarray(bt_row),
-                    jnp.asarray(new_vec), self._pk, self._pv,
-                    self._cos, self._sin, jnp.float32(req.temperature),
-                    jnp.float32(req.top_p), jnp.int32(req.top_k),
-                    jnp.asarray([req.seed], jnp.int32))
+                if self._hybrid:
+                    first = self._hy.prefill(padded, start, end - 1 - start,
+                                             bt_row, new_vec, slot, req,
+                                             sample)
+                    if final:
+                        self._hy.save_snapshot(slot)
+                else:
+                    self._pk, self._pv, first = self._prefill_v[sample](
+                        self.params, jnp.asarray(padded), jnp.int32(start),
+                        jnp.int32(end - 1 - start), jnp.asarray(bt_row),
+                        jnp.asarray(new_vec), self._pk, self._pv,
+                        self._cos, self._sin, jnp.float32(req.temperature),
+                        jnp.float32(req.top_p), jnp.int32(req.top_k),
+                        jnp.asarray([req.seed], jnp.int32))
             with self._phase("wait", **ids):
                 first = int(first)
         if final:
@@ -703,8 +759,7 @@ class PagedEngine(Engine):
             page, copied = self._alloc.ensure_writable(old)
             if copied:
                 with self._phase("stage", slot=slot):
-                    self._pk, self._pv = self._copy_page(
-                        self._pk, self._pv, jnp.int32(old), jnp.int32(page))
+                    self._cow_device(old, page)
                 pages[pi] = page
         while len(pages) * ps <= top:
             pages.append(self._alloc.alloc())
@@ -724,11 +779,15 @@ class PagedEngine(Engine):
             live = int(np.sum(self._npos[active] // self.page_size + 1))
             self.metrics.observe("decode_live_page_share",
                                  live / (self.max_slots * Pn))
-            self._pk, self._pv, nxt = self._decode_v[
-                self._sampling_active()](
-                self.params, jnp.asarray(self._last_tok), self._pk,
-                self._pv, jnp.asarray(bt), jnp.asarray(self._npos),
-                self._cos, self._sin, *self._sampling_args())
+            if self._hybrid:
+                nxt = self._hy.decode(bt, active, self._sampling_active(),
+                                      self._sampling_args())
+            else:
+                self._pk, self._pv, nxt = self._decode_v[
+                    self._sampling_active()](
+                    self.params, jnp.asarray(self._last_tok), self._pk,
+                    self._pv, jnp.asarray(bt), jnp.asarray(self._npos),
+                    self._cos, self._sin, *self._sampling_args())
         with self._phase("wait"):
             return np.asarray(nxt)
 
@@ -742,13 +801,18 @@ class PagedEngine(Engine):
         # cached: their bytes came from prefill programs, so later hits
         # replay the exact values a fresh prefill would compute.
         req = self.slots.owner(slot)
-        if req is not None and int(self._npos[slot]) >= req.prompt_ids.size:
+        whole = req is not None and \
+            int(self._npos[slot]) >= req.prompt_ids.size
+        if whole:
             n = int(req.prompt_ids.size)
             n_pages = -(-n // self.page_size)
             self._alloc.register_prefix(req.prompt_ids,
                                         self._bt[slot][:n_pages])
-        for p in self._bt[slot]:
-            self._alloc.release(p)
+        if self._hybrid and req is not None:
+            # the snapshot of the state at the prompt's end joins the tree
+            # with the prompt's last page
+            self._hy.attach(slot, req.prompt_ids, whole)
+        self._alloc.release_many(self._bt[slot])
         self._bt[slot] = []
         self._reserved_total -= self._resv.pop(slot, 0)
         if self.spec_enabled:
@@ -780,6 +844,11 @@ class PagedEngine(Engine):
                  "npos": int(self._npos[slot]),
                  "last_tok": int(self._last_tok[slot]),
                  "resv": self._resv.get(slot, 0)}
+        if self._hybrid:
+            # the recurrent state leaves the slot with the request, and
+            # with it the snapshot waiting for the request's retirement
+            state["recurrent"] = self._hy.take_state(slot)
+            state["snapshot"] = self._hy.pending.pop(slot, None)
         self._bt[slot] = []
         self._reserved_total -= self._resv.pop(slot, 0)
         self.slots.retire(slot)
@@ -803,6 +872,10 @@ class PagedEngine(Engine):
         self._reserved_total += state["resv"]
         self._npos[slot] = state["npos"]
         self._last_tok[slot] = state["last_tok"]
+        if self._hybrid:
+            self._hy.put_state(slot, state["recurrent"])
+            if state["snapshot"] is not None:
+                self._hy.pending[slot] = state["snapshot"]
         self.metrics.inc("resumes")
         return slot
 
@@ -812,16 +885,12 @@ class PagedEngine(Engine):
         compiled programs and compile counters survive."""
         super().reset()
         # the page pool survives a reset, so its byte gauge must too
-        self.metrics.set_gauge("kv_pool_bytes", 2 * sum(
-            x.size * x.dtype.itemsize
-            for x in jax.tree_util.tree_leaves(self._pk)))
-        self._alloc = BlockAllocator(self.num_pages, self.page_size,
-                                     metrics=self.metrics,
-                                     policy=self.prefix_policy)
-        self._bt = [[] for _ in range(self.max_slots)]
-        self._resv = {}
-        self._reserved_total = 0
-        self._chunk_streams = {}
-        self._chunk_turn = False
+        if self._hybrid:
+            self._hy.reset()
+        else:
+            self.metrics.set_gauge("kv_pool_bytes", 2 * sum(
+                x.size * x.dtype.itemsize
+                for x in jax.tree_util.tree_leaves(self._pk)))
+        self._reset_host_state()
         if self.spec_enabled:
             self._spec.reset()

@@ -58,6 +58,11 @@ _SHAPES = {
                                         "bfloat16", None),
     "chip_smoke_llama_1b": (8, 16, 16, 128, 64, 16, 129, "bfloat16", None),
     "float32_pool_one_page_blocks": (4, 8, 2, 128, 16, 8, 33, "float32", 1),
+    # a hybrid model's sparse layers: 32 slots x 2 KV heads as rows of 16
+    # query heads over the pool viewed as one-head pages, through a
+    # compacted table of a selection (64) or a dense context (128 pages)
+    "minicpm_sala_compacted_table": (64, 16, 1, 128, 64, 128, 16384,
+                                     "bfloat16", None),
 }
 
 
