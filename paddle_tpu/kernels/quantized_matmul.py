@@ -541,7 +541,7 @@ def _paged_block_scales(scale_ref, pages, nkv, page_size):
 
 
 def _paged_decode_kernel(*refs, page_size, pages_per_block, sm_scale,
-                         quantized):
+                         quantized, based):
     # grid (b,): one step a ROW, all kv heads at once. The pools stay in
     # HBM (memory_space ANY) and are fetched by hand: a compute block is
     # `pages_per_block` pages, each ONE async copy of pool[bt[row, j]] —
@@ -551,16 +551,17 @@ def _paged_decode_kernel(*refs, page_size, pages_per_block, sm_scale,
     # blocks computed are the pages the row holds, not the table's width.
     # Two buffers: block i + 1 streams while block i computes, and a row's
     # last block starts the next row's first, so only row 0 waits for an
-    # exposed copy. Scalars in SMEM: pos [b], block tables [b, P], and for
-    # an int8 pool the K and V scales flattened [num_pages * nkv] (the
-    # dequant stays in registers: int8 upcasts between the copy and the
-    # MXU, the scales fold into the scores and the probabilities).
-    if quantized:
-        (pos_ref, bt_ref, sk_ref, sv_ref, q_ref, k_hbm, v_hbm, o_ref,
-         k_buf, v_buf, sems, slot_ref) = refs
-    else:
-        (pos_ref, bt_ref, q_ref, k_hbm, v_hbm, o_ref,
-         k_buf, v_buf, sems, slot_ref) = refs
+    # exposed copy. Scalars in SMEM: pos [b], block tables [b, P], where a
+    # caller gives one the page base [1] (where the tables' page 0 lies in
+    # the pools: a layer's pages inside a stack of layers; it moves the
+    # copies and nothing else), and for an int8 pool the K and V scales of
+    # the tables' own pages flattened [num_pages * nkv] (the dequant stays
+    # in registers: int8 upcasts between the copy and the MXU, the scales
+    # fold into the scores and the probabilities).
+    pos_ref, bt_ref, *refs = refs
+    base_ref = refs.pop(0) if based else None
+    sk_ref, sv_ref = (refs.pop(0), refs.pop(0)) if quantized else (None, None)
+    q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, slot_ref = refs
     ps, ppb = page_size, pages_per_block
     bk = ps * ppb
     nkv = k_buf.shape[1]
@@ -579,10 +580,11 @@ def _paged_decode_kernel(*refs, page_size, pages_per_block, sm_scale,
         return [(j <= lp, bt_ref[r, jnp.minimum(j, lp)]) for j in js]
 
     def copies(page, i, slot):
+        src = base_ref[0] + page if based else page
         dst = (slot, slice(None), pl.ds(i * ps, ps), slice(None))
-        return (pltpu.make_async_copy(k_hbm.at[page], k_buf.at[dst],
+        return (pltpu.make_async_copy(k_hbm.at[src], k_buf.at[dst],
                                       sems.at[0, slot]),
-                pltpu.make_async_copy(v_hbm.at[page], v_buf.at[dst],
+                pltpu.make_async_copy(v_hbm.at[src], v_buf.at[dst],
                                       sems.at[1, slot]))
 
     def start_block(r, blk, slot):
@@ -705,7 +707,8 @@ def paged_decode_supported(q_shape, pool_shape, bt_shape, itemsize=2):
 
 def _paged_decode_attention_pallas(q, pool_k, pool_v, block_tables, pos,
                                    sm_scale, interpret, k_scale=None,
-                                   v_scale=None, pages_per_block=None):
+                                   v_scale=None, pages_per_block=None,
+                                   page_base=None):
     b, _, nh, hd = q.shape
     nkv, ps = pool_k.shape[1], pool_k.shape[2]
     P = block_tables.shape[1]
@@ -723,6 +726,8 @@ def _paged_decode_attention_pallas(q, pool_k, pool_v, block_tables, pos,
     q4 = q[:, 0].reshape(b, nkv, g, hd)
     pos_arr = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
     prefetch = [pos_arr, jnp.asarray(block_tables, jnp.int32)]
+    if page_base is not None:
+        prefetch.append(jnp.asarray(page_base, jnp.int32).reshape(1))
     if k_scale is not None:
         prefetch += [k_scale.astype(jnp.float32).reshape(-1),
                      v_scale.astype(jnp.float32).reshape(-1)]
@@ -745,7 +750,8 @@ def _paged_decode_attention_pallas(q, pool_k, pool_v, block_tables, pos,
     out = pl.pallas_call(
         functools.partial(_paged_decode_kernel, page_size=ps,
                           pages_per_block=ppb, sm_scale=sm_scale,
-                          quantized=k_scale is not None),
+                          quantized=k_scale is not None,
+                          based=page_base is not None),
         out_shape=jax.ShapeDtypeStruct((b, nkv, g, hd), q.dtype),
         grid_spec=grid_spec,
         # rows in order on one core: a row starts its successor's copies
@@ -757,17 +763,21 @@ def _paged_decode_attention_pallas(q, pool_k, pool_v, block_tables, pos,
 
 
 @jax.named_scope("pt.kv_gather")
-def paged_gather(pool, block_tables, scale=None, out_dtype=None):
+def paged_gather(pool, block_tables, scale=None, out_dtype=None,
+                 page_base=None):
     """Gather a pool [num_pages, nkv, page_size, hd] through block tables
     [b, P] into the contiguous per-row cache layout [b, nkv, P*ps, hd] —
     the jnp fallback path and the parity oracle for the paged kernel
     (pages laid out in table order ARE the row's sequence). With `scale`
     [num_pages, nkv] the pool is int8 and the gather dequantizes
     (q * scale / 127) into `out_dtype` (default f32) — the oracle for the
-    int8 kernel's in-registers dequant."""
+    int8 kernel's in-registers dequant. `page_base`: where the tables'
+    page 0 lies in `pool` (`paged_decode_attention`); `scale` is the
+    tables' own pages'."""
     b, P = block_tables.shape
     nkv, ps, hd = pool.shape[1], pool.shape[2], pool.shape[3]
-    g = jnp.swapaxes(pool[block_tables], 1, 2)   # [b, nkv, P, ps, hd]
+    pages = block_tables if page_base is None else page_base + block_tables
+    g = jnp.swapaxes(pool[pages], 1, 2)          # [b, nkv, P, ps, hd]
     if scale is not None:
         sc = jnp.swapaxes(scale[block_tables], 1, 2)   # [b, nkv, P]
         g = (g.astype(jnp.float32)
@@ -779,16 +789,17 @@ def paged_gather(pool, block_tables, scale=None, out_dtype=None):
 
 
 def _paged_decode_attention_xla(q, pool_k, pool_v, block_tables, pos,
-                                sm_scale, k_scale=None, v_scale=None):
+                                sm_scale, k_scale=None, v_scale=None,
+                                page_base=None):
     return _decode_attention_xla(
-        q, paged_gather(pool_k, block_tables, k_scale, q.dtype),
-        paged_gather(pool_v, block_tables, v_scale, q.dtype),
+        q, paged_gather(pool_k, block_tables, k_scale, q.dtype, page_base),
+        paged_gather(pool_v, block_tables, v_scale, q.dtype, page_base),
         pos, sm_scale)
 
 
 @jax.named_scope("pt.paged_attention")
 def paged_decode_attention(q, pool_k, pool_v, block_tables, pos, scale=None,
-                           k_scale=None, v_scale=None):
+                           k_scale=None, v_scale=None, page_base=None):
     """Single-query attention of q [b, 1, nh, hd] over a PAGED KV cache:
     pool_k/pool_v [num_pages, nkv, page_size, hd] indexed through per-row
     block tables [b, P] (page i of row r holds that row's positions
@@ -803,7 +814,14 @@ def paged_decode_attention(q, pool_k, pool_v, block_tables, pos, scale=None,
     k_scale/v_scale [num_pages, nkv]: the pools are int8 pages with
     per-(page, kv-head) absmax scales — the kernel dequantizes
     in-registers (q * scale / 127) so the HBM stream stays 1 byte/elem;
-    the fallback dequantizes in the gather."""
+    the fallback dequantizes in the gather.
+
+    page_base (a traced scalar; None: the pools are the tables' own, and
+    the kernel takes no such operand): the tables index a run of pages that
+    starts at `page_base` in the pools — one layer's pages in a stack of
+    layers viewed [L * num_pages, nkv, page_size, hd], read where it lies.
+    The scales are those of the run alone, [its pages, nkv]: they go to
+    SMEM, where a whole stack's do not belong."""
     sm_scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
     use_pallas, interpret = _mode()
     if use_pallas and paged_decode_supported(q.shape, pool_k.shape,
@@ -812,9 +830,9 @@ def paged_decode_attention(q, pool_k, pool_v, block_tables, pos, scale=None,
         with jax.named_scope(_SCAN_BODY):
             return _paged_decode_attention_pallas(
                 q, pool_k, pool_v, block_tables, pos, sm_scale, interpret,
-                k_scale=k_scale, v_scale=v_scale)
+                k_scale=k_scale, v_scale=v_scale, page_base=page_base)
     return _paged_decode_attention_xla(q, pool_k, pool_v, block_tables, pos,
-                                       sm_scale, k_scale, v_scale)
+                                       sm_scale, k_scale, v_scale, page_base)
 
 
 @jax.named_scope("pt.paged_attention")

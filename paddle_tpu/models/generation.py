@@ -58,14 +58,28 @@ class QuantizedKVPage(NamedTuple):
     """int8 KV page-pool half: `q` int8 [..., num_pages, nkv, page_size,
     hd] with per-(page, kv-head) absmax `scale` [..., num_pages, nkv] f32
     (dequant = q * scale / 127 — the QuantizedWeight convention). A
-    pytree node: the stacked [L, ...] pool slices per layer under
-    lax.scan exactly like the bf16 pool arrays, and jit donation /
+    pytree node: the stacked [L, ...] pool is the decode scan's carry
+    (the verify scan's xs) like the bf16 pool arrays, and jit donation /
     shard_map specs treat (q, scale) as ONE pool operand — both leaves
     shard on the nkv axis, so the bf16 `P(None, None, mp)` pool spec
     applies to the pair as a pytree prefix unchanged."""
 
     q: jax.Array
     scale: jax.Array
+
+
+@jax.named_scope("pt.kv_write")
+def _write_rows(pool, new, page, at):
+    """pool[page[r], :, at[r]] = new[r] for every row r, as a read-modify-
+    write of WHOLE pages: a page is the unit the pool's layout tiles, and
+    a write of one row of a tile (a scatter over two axes, or an update of
+    a [1, nkv, 1, d] slice) has XLA re-lay the whole pool around it, every
+    step. Rows own their pages; the null page takes the others' garbage."""
+    old = pool[page]                                  # [b, nkv, n, d]
+    here = jnp.arange(pool.shape[2], dtype=jnp.int32)[None, :] == at[:, None]
+    new = jnp.where(here[:, None, :, None],
+                    new[:, :, None, :].astype(pool.dtype), old)
+    return pool.at[page].set(new)
 
 
 @jax.named_scope("pt.kv_write")
@@ -343,17 +357,24 @@ def _forward_cached(params, ids, caches_k, caches_v, pos, cos, sin, args,
     return logits.astype(jnp.float32), new_k, new_v
 
 
-def _layer_step_paged(lp, h, pool_k_l, pool_v_l, bt, pos, cos, sin, args,
-                      page_size, tp_axis=None, tp_degree=1):
+def _layer_step_paged(lp, h, pool_k, pool_v, bt, pos, cos, sin, args,
+                      page_size, tp_axis=None, tp_degree=1, layer=0,
+                      num_layers=1):
     """One decoder layer's decode step (s == 1) over a PAGED KV cache.
 
-    pool_k_l/pool_v_l: this layer's page pool [num_pages, nkv, ps, hd];
-    bt: int32 block tables [b, P] (page i of row r holds positions
-    [i*ps, (i+1)*ps) of that row — unused entries point at the null page);
-    pos: int32 [b] per-row write positions. Each row's new k/v is
-    SCATTERED to (bt[r, pos[r]//ps], pos[r] % ps) — write-before-attend,
-    like the stripe path — then attention gathers K/V through the block
-    table (Pallas paged kernel on TPU, jnp gather elsewhere).
+    pool_k/pool_v: the page pools of `num_layers` layers, layer-major
+    [num_layers * num_pages, nkv, ps, hd], of which this is layer `layer`
+    (a traced index): its pages are the run that starts at `layer *
+    num_pages`, written and read where they lie. The defaults say that the
+    pool is this layer's own. bt: int32 block tables [b, P] of a layer's
+    page numbers (page i of row r holds positions [i*ps, (i+1)*ps) of that
+    row — unused entries point at the null page); pos: int32 [b] per-row
+    write positions. Each row's new k/v goes to (bt[r, pos[r]//ps], pos[r]
+    % ps) as a read-modify-write of that page (`_write_rows`) —
+    write-before-attend, like the stripe path — then attention gathers K/V
+    through the block table (Pallas paged kernel on TPU, jnp gather
+    elsewhere). Nothing here has a pool's or a layer's size but the pools
+    themselves, returned updated in place.
 
     tp_axis/tp_degree: shard_map tensor parallelism — weight shards as in
     `_layer_step`, the page pool sharded on nkv (block tables replicated,
@@ -365,6 +386,9 @@ def _layer_step_paged(lp, h, pool_k_l, pool_v_l, bt, pos, cos, sin, args,
     nkv = args.num_kv_heads // tp_degree
     hd = args.hidden_size // args.num_heads
     ps = page_size
+    quantized = isinstance(pool_k, QuantizedKVPage)
+    num_pages = (pool_k.q if quantized else pool_k).shape[0] // num_layers
+    base = layer * num_pages
 
     from paddle_tpu.kernels import quantized_matmul as qm
 
@@ -376,38 +400,40 @@ def _layer_step_paged(lp, h, pool_k_l, pool_v_l, bt, pos, cos, sin, args,
         q, k = _rope_rows(q, k, jnp.take(cos, pos, axis=0),
                           jnp.take(sin, pos, axis=0))
 
-        # per-row scatter into the pool: rows own their tail page
-        # exclusively (the host-side COW gate guarantees it), so writes
-        # never collide on a live page
-        page = jnp.take_along_axis(bt, (pos // ps)[:, None], axis=1)[:, 0]
+        # rows own their tail page exclusively (the host-side COW gate
+        # guarantees it), so writes never collide on a live page; rows
+        # that do not decode name the null page, the garbage sink
+        page = base + jnp.take_along_axis(bt, (pos // ps)[:, None],
+                                          axis=1)[:, 0]
         off = pos % ps
-        if isinstance(pool_k_l, QuantizedKVPage):
-            pool_k_l = _kv_quant_write(pool_k_l, page, off, k[:, 0])
-            pool_v_l = _kv_quant_write(pool_v_l, page, off, v[:, 0])
-            kq, ks = pool_k_l
-            vq, vs = pool_v_l
+        if quantized:
+            pool_k = _kv_quant_write(pool_k, page, off, k[:, 0])
+            pool_v = _kv_quant_write(pool_v, page, off, v[:, 0])
+            kq, vq = pool_k.q, pool_v.q
+            # the layer's own scales: what the kernel holds in SMEM
+            ks = jax.lax.dynamic_slice_in_dim(pool_k.scale, base, num_pages)
+            vs = jax.lax.dynamic_slice_in_dim(pool_v.scale, base, num_pages)
         else:
-            with jax.named_scope("pt.kv_write"):
-                pool_k_l = pool_k_l.at[page, :, off].set(k[:, 0])
-                pool_v_l = pool_v_l.at[page, :, off].set(v[:, 0])
-            kq, ks, vq, vs = pool_k_l, None, pool_v_l, None
+            pool_k = _write_rows(pool_k, k[:, 0], page, off)
+            pool_v = _write_rows(pool_v, v[:, 0], page, off)
+            kq, ks, vq, vs = pool_k, None, pool_v, None
 
         if qm.fused_enabled() and qm.paged_decode_supported(
                 q.shape, kq.shape, bt.shape, kq.dtype.itemsize):
-            attn = qm.paged_decode_attention(q, kq, vq, bt, pos,
-                                             k_scale=ks, v_scale=vs)
+            attn = qm.paged_decode_attention(q, kq, vq, bt, pos, k_scale=ks,
+                                             v_scale=vs, page_base=base)
         else:
             # gather pages into the contiguous per-row layout (dequantized
             # under an int8 pool) and reuse the stripe attention (jnp mask
             # fallback; contiguous Pallas kernel if eligible) — table order
             # IS sequence order, so positions line up
             attn = _cached_attention(
-                q, qm.paged_gather(kq, bt, scale=ks, out_dtype=q.dtype),
-                qm.paged_gather(vq, bt, scale=vs, out_dtype=q.dtype), pos)
+                q, qm.paged_gather(kq, bt, ks, q.dtype, base),
+                qm.paged_gather(vq, bt, vs, q.dtype, base), pos)
         h = h + _tp_reduce(_wmm(attn.reshape(b, 1, nh * hd), lp["wo"]),
                            tp_axis)
 
-    return _mlp_block(lp, h, args, tp_axis), pool_k_l, pool_v_l
+    return _mlp_block(lp, h, args, tp_axis), pool_k, pool_v
 
 
 def _layer_step_paged_verify(lp, h, pool_k_l, pool_v_l, bt, pos, limit,
@@ -484,19 +510,30 @@ def _layer_step_paged_verify(lp, h, pool_k_l, pool_v_l, bt, pos, limit,
 def _paged_forward_decode(params, ids, pool_k, pool_v, bt, pos, cos, sin,
                           args, page_size, tp_axis=None, tp_degree=1):
     """ids [b, 1] -> (next-token logits [b, vocab], new pools). The paged
-    analogue of `_forward_cached`'s decode step: pools are [L, num_pages,
-    nkv, ps, hd] and slice per layer under the same lax.scan."""
+    analogue of `_forward_cached`'s decode step, except that the pools
+    [L, num_pages, nkv, ps, hd] are the layer scan's CARRY, viewed
+    layer-major [L * num_pages, nkv, ps, hd] (a bitcast): a layer writes
+    and reads its own run of pages in the carried pool
+    (`_layer_step_paged`), so the program never slices a layer out, copies
+    a pool or writes one back. What a step touches is the pages its rows
+    write and read."""
     h = jnp.take(params["embedding"], ids, axis=0)
+    L, num_pages = jax.tree_util.tree_leaves(pool_k)[0].shape[:2]
+    tree_map = jax.tree_util.tree_map
 
     def step(carry, xs):
-        h = carry
-        lp, pk, pv = xs
-        h, pk, pv = _layer_step_paged(lp, h, pk, pv, bt, pos, cos, sin,
-                                      args, page_size, tp_axis, tp_degree)
-        return h, (pk, pv)
+        h, pk, pv = carry
+        lp, layer = xs
+        return _layer_step_paged(lp, h, pk, pv, bt, pos, cos, sin, args,
+                                 page_size, tp_axis, tp_degree, layer, L), None
 
-    h, (new_k, new_v) = jax.lax.scan(step, h,
-                                     (params["layers"], pool_k, pool_v))
+    (h, *pools), _ = jax.lax.scan(
+        step,
+        (h, *tree_map(lambda a: a.reshape((L * num_pages,) + a.shape[2:]),
+                      (pool_k, pool_v))),
+        (params["layers"], jnp.arange(L, dtype=jnp.int32)))
+    new_k, new_v = tree_map(
+        lambda a: a.reshape((L, num_pages) + a.shape[1:]), pools)
     h = lf.rms_norm(h, params["final_norm"], args.rms_eps)
     logits = _wmm(h[:, -1, :], params["lm_head"])
     return logits.astype(jnp.float32), new_k, new_v

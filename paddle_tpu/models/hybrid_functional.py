@@ -37,7 +37,7 @@ import jax.numpy as jnp
 from paddle_tpu.kernels import lightning_attention as la
 from paddle_tpu.kernels import sparse_attention as sa
 from paddle_tpu.models import llama_functional as lf
-from paddle_tpu.models.generation import _wmm
+from paddle_tpu.models.generation import _wmm, _write_rows
 
 __all__ = ["HybridArgs", "SPARSE", "LIGHTNING", "prefill_window",
            "decode_step"]
@@ -243,20 +243,6 @@ def _lightning_decode(lp, x, S, pos, live, cos, sin, args):
                         args.rms_eps)
         x = _gated_out(lp, x, hin, o, args)
     return _mlp(lp, x, args), S
-
-
-@jax.named_scope("pt.kv_write")
-def _write_rows(pool, new, page, at):
-    """pool[page[r], :, at[r]] = new[r] for every row r, as a read-modify-
-    write of WHOLE pages: a page is the unit the pool's layout tiles, and
-    a write of one row of a tile (a scatter over two axes, or an update of
-    a [1, nkv, 1, d] slice) has XLA re-lay the whole pool around it, every
-    step. Rows own their pages; the null page takes the others' garbage."""
-    old = pool[page]                                  # [b, nkv, n, d]
-    here = jnp.arange(pool.shape[2], dtype=jnp.int32)[None, :] == at[:, None]
-    new = jnp.where(here[:, None, :, None],
-                    new[:, :, None, :].astype(pool.dtype), old)
-    return pool.at[page].set(new)
 
 
 def _sparse_decode(lp, x, pk, pv, kc, bt, pos, args):

@@ -439,6 +439,34 @@ class TestPagedDecodeKernel:
                                    np.asarray(ref), atol=case.get("atol",
                                                                   1e-4))
 
+    @pytest.mark.parametrize("kind", ["bfloat16", "int8"])
+    def test_page_base_reads_one_layers_run_of_a_stack(self, kind):
+        """`page_base` moves the copies and nothing else: over three
+        layers' pools laid end to end, with the middle layer's base and
+        its own scales, the kernel gives what it gives over that layer's
+        pool alone, bit for bit (the other layers hold NaN, or codes under
+        NaN scales it is not handed)."""
+        int8 = kind == "int8"
+        ops = self._case(11, b=3, hd=128, ps=32, P=4, NP=9,
+                         pos=[5, 37, 120], bt="random", int8=int8,
+                         dtype="float32" if int8 else kind)
+        q, pk, pv, bt, pos, ks, vs = ops
+        fill = 127 if int8 else np.nan
+        stack = [jnp.concatenate([jnp.full_like(x, fill), x,
+                                  jnp.full_like(x, fill)]) for x in (pk, pv)]
+        sm = 1.0 / np.sqrt(q.shape[-1])
+        want = self._run(ops, ppb=2)
+        got = qm._paged_decode_attention_pallas(
+            q, *stack, bt, pos, sm, _INTERPRET, ks, vs, pages_per_block=2,
+            page_base=jnp.int32(pk.shape[0]))
+        assert np.isfinite(np.asarray(got, np.float32)).all()
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(want, np.float32))
+        ref = qm._paged_decode_attention_xla(q, *stack, bt, pos, sm, ks, vs,
+                                             page_base=pk.shape[0])
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(ref, np.float32), atol=2e-2)
+
     @pytest.mark.parametrize("kind", ["float32", "bfloat16", "int8"])
     def test_pages_past_the_last_live_one_are_never_read(self, kind):
         """Every table entry past a row's last live page points at a page
@@ -623,6 +651,183 @@ class TestPagedDecodeKernel:
         np.testing.assert_allclose(np.asarray(out2.scale[2]), 0.5)
         np.testing.assert_array_equal(np.asarray(out2.q[2, :, 0]),
                                       np.full((nkv, hd), 64, np.int8))
+
+
+# name -> (page, offset) of each row's write into a pool of 7 pages of 8.
+# Page 0 is the null page: rows that do not decode all name it.
+_WRITE_CASES = {
+    "first_offset_of_a_page": ([3, 5, 1], [0, 0, 0]),
+    "last_offset_of_a_page": ([3, 5, 1], [7, 7, 7]),
+    "a_row_on_the_last_page_of_the_pool": ([6, 2, 4], [3, 0, 7]),
+    "rows_that_do_not_decode_share_the_null_page": ([0, 4, 0, 0],
+                                                    [2, 5, 2, 6]),
+}
+
+
+class TestPageWrite:
+    """A token's K/V goes into the pool as a read-modify-write of the
+    row's own page (`generation._write_rows`, the dense and the hybrid
+    path's one write): the pool afterwards is what the per-token scatter
+    `pool.at[page, :, off].set(new)` left, bit for bit."""
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("name", list(_WRITE_CASES))
+    def test_page_write_equals_the_per_token_scatter(self, name, dtype):
+        from paddle_tpu.models.generation import _write_rows
+
+        page, off = (jnp.asarray(x, jnp.int32) for x in _WRITE_CASES[name])
+        rng = np.random.default_rng(len(name))
+        pool = jnp.asarray(rng.normal(size=(7, 2, 8, 16)), dtype)
+        new = jnp.asarray(rng.normal(size=(page.shape[0], 2, 16)),
+                          jnp.float32)
+        got = np.asarray(jax.jit(_write_rows)(pool, new, page, off),
+                         np.float32)
+        want = np.asarray(pool.at[page, :, off].set(new.astype(pool.dtype)),
+                          np.float32)
+        # the null page is the garbage sink: of the rows that share it one
+        # page's worth survives, which one is nobody's business
+        np.testing.assert_array_equal(got[1:], want[1:])
+        if int(np.sum(np.asarray(page) == 0)) <= 1:
+            np.testing.assert_array_equal(got[0], want[0])
+        assert np.isfinite(got[0]).all()
+
+    def test_int8_write_into_a_stack_keeps_each_pages_running_scale(self):
+        """`_kv_quant_write` addresses the carried pool: a layer's write
+        at `base + page` of the layers' pools laid end to end leaves that
+        layer's run as a write into its own pool leaves it (codes
+        re-scaled under a louder token, the scale restarted at offset 0)
+        and every other layer's run as it was."""
+        from paddle_tpu.models.generation import (QuantizedKVPage,
+                                                  _kv_quant_write)
+
+        rng = np.random.default_rng(5)
+        L, NP, nkv, ps, hd = 3, 5, 2, 4, 8
+        q = jnp.asarray(rng.integers(-127, 128, (L, NP, nkv, ps, hd)),
+                        jnp.int8)
+        sc = jnp.asarray(rng.uniform(0.1, 1.0, (L, NP, nkv)), jnp.float32)
+        page = jnp.asarray([2, 4, 0, 0], jnp.int32)
+        off = jnp.asarray([0, 3, 1, 2], jnp.int32)   # a restart, a running
+        tok = jnp.asarray(rng.normal(size=(4, nkv, hd)) * 2.0, jnp.float32)
+        flat = QuantizedKVPage(q.reshape(L * NP, nkv, ps, hd),
+                               sc.reshape(L * NP, nkv))
+        got = jax.jit(_kv_quant_write)(flat, 1 * NP + page, off, tok)
+        want = _kv_quant_write(QuantizedKVPage(q[1], sc[1]), page, off, tok)
+        got_q = np.asarray(got.q).reshape(L, NP, nkv, ps, hd)
+        got_s = np.asarray(got.scale).reshape(L, NP, nkv)
+        np.testing.assert_array_equal(got_q[1, 1:], np.asarray(want.q)[1:])
+        np.testing.assert_array_equal(got_s[1, 1:],
+                                      np.asarray(want.scale)[1:])
+        for other in (0, 2):
+            np.testing.assert_array_equal(got_q[other], np.asarray(q[other]))
+            np.testing.assert_array_equal(got_s[other],
+                                          np.asarray(sc[other]))
+
+
+class TestDecodeScanCarriesThePools:
+    """`_paged_forward_decode` carries the stacked pools through the
+    layer scan and each layer writes and reads its own run of pages
+    there: the logits AND the pools it returns are those of a plain loop
+    that hands `_layer_step_paged` one layer's own pool at a time."""
+
+    ARGS = ARGS._replace(num_layers=3)
+    PS, NP, P, B = 8, 13, 4, 3
+
+    @staticmethod
+    def _loop(params, ids, pk, pv, bt, pos, cos, sin, args, ps, tp_axis=None,
+              tp_degree=1):
+        from paddle_tpu.models import generation as gen
+
+        def layer(tree, l):
+            return jax.tree_util.tree_map(lambda a: a[l], tree)
+
+        h = jnp.take(params["embedding"], ids, axis=0)
+        ks, vs = [], []
+        for l in range(args.num_layers):
+            h, k_l, v_l = gen._layer_step_paged(
+                layer(params["layers"], l), h, layer(pk, l), layer(pv, l),
+                bt, pos, cos, sin, args, ps, tp_axis, tp_degree)
+            ks.append(k_l)
+            vs.append(v_l)
+        stack = lambda xs: jax.tree_util.tree_map(
+            lambda *a: jnp.stack(a), *xs)
+        h = lf.rms_norm(h, params["final_norm"], args.rms_eps)
+        logits = gen._wmm(h[:, -1, :], params["lm_head"])
+        return logits.astype(jnp.float32), stack(ks), stack(vs)
+
+    def _programs(self, mesh):
+        from paddle_tpu.models import generation as gen
+
+        args, ps = self.ARGS, self.PS
+        if mesh is None:
+            return [jax.jit(lambda *a, f=f: f(*a, args, ps))
+                    for f in (gen._paged_forward_decode, self._loop)]
+        from jax.sharding import PartitionSpec
+        from paddle_tpu.serving import tp
+
+        params = jax.eval_shape(
+            lambda: lf.init_params(args, jax.random.key(0)))
+        rep, pool = PartitionSpec(), tp.pool_spec()
+        specs = (tp.llama_tp_specs(params), rep, pool, pool, rep, rep, rep,
+                 rep)
+        return [jax.jit(jax.shard_map(
+            lambda *a, f=f: f(*a, args, ps, "mp", 2), mesh=mesh,
+            in_specs=specs, out_specs=(rep, pool, pool), check_vma=False))
+            for f in (gen._paged_forward_decode, self._loop)]
+
+    @pytest.mark.parametrize("sharded", [False, True],
+                             ids=["one_device", "mp2_mesh"])
+    @pytest.mark.parametrize("kind", ["bfloat16", "int8"])
+    def test_scan_equals_a_loop_over_the_layers(self, kind, sharded):
+        from paddle_tpu.models import generation as gen
+
+        mesh = None
+        if sharded:
+            from paddle_tpu.distributed.mesh_utils import single_axis_mesh
+
+            mesh = single_axis_mesh("mp", 2)
+        args, ps, NP, P, B = self.ARGS, self.PS, self.NP, self.P, self.B
+        L, nkv = args.num_layers, args.num_kv_heads
+        hd = args.hidden_size // args.num_heads
+        rng = np.random.default_rng(17)
+        # float32 arithmetic over a bf16 (or int8) pool: what the two
+        # programs round, they round at the same places
+        params = lf.init_params(args, jax.random.key(3))
+        shape = (L, NP, nkv, ps, hd)
+        if kind == "int8":
+            pk, pv = (gen.QuantizedKVPage(
+                jnp.asarray(rng.integers(-127, 128, shape), jnp.int8),
+                jnp.asarray(rng.uniform(0.5, 2.0, shape[:3]), jnp.float32))
+                for _ in range(2))
+        else:
+            pk, pv = (jnp.asarray(rng.normal(size=shape), jnp.bfloat16)
+                      for _ in range(2))
+        # row 0 crosses onto a fresh page at the second step, row 1 ends
+        # on the last offset of the last page of its table, row 2 does not
+        # decode (a table of null pages, as the engine stages it)
+        bt = jnp.asarray([[3, 7, 0, 0], [5, 6, 8, 9], [0, 0, 0, 0]],
+                         jnp.int32)
+        pos0 = np.asarray([7, P * ps - 3, 0], np.int32)
+        cos, sin = lf.rope_tables(P * ps, hd, args.rope_theta)
+        scan, loop = self._programs(mesh)
+        a = b = (pk, pv)
+        for step in range(3):
+            ids = jnp.asarray(rng.integers(1, args.vocab_size, (B, 1)),
+                              jnp.int32)
+            pos = jnp.asarray(pos0 + [step, step, 0], jnp.int32)
+            la, *a = scan(params, ids, *a, bt, pos, cos, sin)
+            lb, *b = loop(params, ids, *b, bt, pos, cos, sin)
+            np.testing.assert_array_equal(np.asarray(la), np.asarray(lb))
+            for x, y in zip(jax.tree_util.tree_leaves(a),
+                            jax.tree_util.tree_leaves(b)):
+                assert x.shape == y.shape and x.dtype == y.dtype
+                # all but the null page of every layer
+                np.testing.assert_array_equal(
+                    np.asarray(x[:, 1:], np.float32),
+                    np.asarray(y[:, 1:], np.float32))
+        # and the three steps did write
+        assert not np.array_equal(
+            np.asarray(jax.tree_util.tree_leaves(a)[0], np.float32),
+            np.asarray(jax.tree_util.tree_leaves(pk)[0], np.float32))
 
 
 class TestPagedEngineParity:

@@ -10,6 +10,7 @@ described inside a fixture of this ONE file (never at import), and the tests
 skip where it cannot be described.
 """
 
+import math
 import os
 
 import pytest
@@ -89,3 +90,62 @@ def test_paged_decode_kernel_compiles_for_v5e(one_chip, name):
 
     text = jax.jit(call).lower(*args).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
+
+
+@pytest.mark.parametrize("kind", ["bfloat16", "int8"])
+def test_decode_program_touches_pages_and_never_a_whole_pool(one_chip, kind):
+    """The dense decode program at the serving cell's widths (32 rows, 32 / 8
+    heads x 128, FFN 14,336, 896 pages x 64, 128 pages a slot; depth 3), pools
+    donated: the stacked pools ride the layer scan as its carry, so nothing
+    of a layer's slice of a pool (117 MB in bf16) or more is computed but
+    the in-place scatters of the rows' own pages, and the program plans
+    no temporary of that size. (A scan over the pools as `xs` / `ys` sliced
+    a layer out, re-laid it around the token's write and wrote the stack
+    back: five pool-sized operations a layer, 4.46 GB of temporaries.)"""
+    import re
+
+    from paddle_tpu.models import generation as gen
+    from paddle_tpu.models import llama_functional as lf
+
+    L, b, nkv, hd, ps, P, NP = 3, 32, 8, 128, 64, 128, 896
+    args = lf.LlamaArgs(32768, 4096, 14336, L, 32, nkv, 1e6, 1e-5)
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda: lf.init_params(args, jax.random.key(0),
+                                              jnp.bfloat16)))
+    pool = sds((L, NP, nkv, ps, hd), jnp.dtype(kind))
+    if kind == "int8":
+        pool = gen.QuantizedKVPage(pool, sds((L, NP, nkv), jnp.float32))
+    table = sds((P * ps, hd), jnp.float32)
+
+    def decode(params, ids, pk, pv, bt, pos, cos, sin):
+        with qm.fused_dispatch(True):
+            return gen._paged_forward_decode(params, ids, pk, pv, bt, pos,
+                                             cos, sin, args, ps)
+
+    compiled = jax.jit(decode, donate_argnums=(2, 3)).lower(
+        params, sds((b, 1), jnp.int32), pool, pool, sds((b, P), jnp.int32),
+        sds((b,), jnp.int32), table, table).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+
+    moved = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (.*?) ([\w\-]+)\(", line)
+        if not m or m.group(2) in ("parameter", "tuple", "get-tuple-element",
+                                   "while", "bitcast"):
+            continue
+        # results shaped like pages: [.., nkv, ps, hd] (a feed-forward
+        # weight of this model happens to have a layer slice's elements)
+        pages = [math.prod(map(int, dims.split(","))) for dims in re.findall(
+            rf"\[([\d,]+),{nkv},{ps},{hd}\]", m.group(1))]
+        if pages and max(pages) >= NP:
+            if not re.search(r'op_name="[^"]*pt\.kv_write/scatter"', line):
+                moved.append(line.strip()[:200])
+    assert not moved, "\n".join(moved)
+    layer_bytes = NP * nkv * ps * hd * jnp.dtype(kind).itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
