@@ -396,26 +396,26 @@ def serve(label, params, args, requests, refs, forward, *, gap_bar,
         _spread(f"serve {label}", mesh.devices.ravel(), before)
     # the step programs must carry their Mosaic calls (lowering only: the
     # donated pool buffers are not consumed)
-    P = eng.pages_per_slot
+    P, path = eng.pages_per_slot, eng.path
     zeros = jnp.zeros((P,), jnp.int32)
     prefill_names = _mosaic_kernels(
-        ("_window_kernel",), eng._prefill_v[False], eng.params,
+        ("_window_kernel",), path._prefill[False], eng.params,
         jnp.zeros((1, MIN_BUCKET), jnp.int32), jnp.int32(0), jnp.int32(0),
-        zeros, zeros, eng._pk, eng._pv, eng._cos, eng._sin, jnp.float32(0),
+        zeros, zeros, path.pk, path.pv, path.cos, path.sin, jnp.float32(0),
         jnp.float32(1), jnp.int32(0), jnp.zeros((1,), jnp.int32))
     decode_names = _mosaic_kernels(
-        expect_kernels, eng._decode_v[False], eng.params,
+        expect_kernels, path._decode[False], eng.params,
         jnp.asarray(eng._last_tok),
-        eng._pk, eng._pv, jnp.zeros((SLOTS, P), jnp.int32),
-        jnp.asarray(eng._npos), eng._cos, eng._sin, *eng._sampling_args())
+        path.pk, path.pv, jnp.zeros((SLOTS, P), jnp.int32),
+        jnp.asarray(eng._npos), path.cos, path.sin, *eng._sampling_args())
     print(f"serve {label}: prefill[{MIN_BUCKET}] carries {prefill_names}, "
           f"decode carries {decode_names}", flush=True)
     if eng.spec_enabled:
         verify_names = _mosaic_kernels(
             ("_window_kernel",), eng._spec._verify, eng.params,
-            jnp.zeros((SLOTS, SPEC_TOKENS + 1), jnp.int32), eng._pk, eng._pv,
+            jnp.zeros((SLOTS, SPEC_TOKENS + 1), jnp.int32), path.pk, path.pv,
             jnp.zeros((SLOTS, P), jnp.int32), jnp.asarray(eng._npos),
-            jnp.asarray(eng._npos), eng._cos, eng._sin)
+            jnp.asarray(eng._npos), path.cos, path.sin)
         print(f"serve {label}: verify carries {verify_names}", flush=True)
 
     picked = [r for r in requests if only is None or r[0] in only]

@@ -258,47 +258,30 @@ def serving_programs(tp=2, num_heads=None):
     meta = {"tp": tp if mesh is not None else 0,
             "num_layers": args.num_layers}
 
-    # plain engine: prefill + decode captured by serving; the COW
-    # page-copy program never fires on the natural flow (the allocator
-    # only COWs shared/registered tail pages), so it is traced directly
-    # from the engine's own jitted object with the live pool shapes
-    eng = PagedEngine(params, args, **kw)
-    recs = {
-        "paged_prefill": _Recorder(eng._prefill_v[False]),
-        "paged_decode": _Recorder(eng._decode_v[False]),
-    }
-    eng._prefill_v[False] = recs["paged_prefill"]
-    eng._decode_v[False] = recs["paged_decode"]
-    eng.serve([Request(prompt(16), max_new_tokens=4),
-               Request(prompt(10), max_new_tokens=3)])
+    # plain engine, then the same step family over QuantizedKVPage pools
+    # (int8 codes + per-(page, kv-head) scales): prefill + decode captured
+    # by serving; the COW page-copy program never fires on the natural flow
+    # (the allocator only COWs shared/registered tail pages), so it is
+    # traced directly from the path's own jitted object with the live pool
+    # shapes. Quantize-at-scatter and dequant-at-gather must not change the
+    # collective structure (still the 2 row-parallel psums per scanned layer
+    # body), and the int8 page copy must stay pure data movement over BOTH
+    # leaves.
+    recs, donated = {}, {}
     i32 = jax.ShapeDtypeStruct((), jnp.int32)
-    copy_args = (_sds_tree(eng._pk), _sds_tree(eng._pv), i32, i32)
-    out["page_copy"] = _from_traced(
-        "page_copy", eng._copy_page.trace(*copy_args), copy_args,
-        donated=(0, 1), meta=meta)
-    donated = {"paged_prefill": (6, 7), "paged_decode": (2, 3)}
-
-    # int8-pool engine: the same step family over QuantizedKVPage pools
-    # (int8 codes + per-(page, kv-head) scales). Quantize-at-scatter and
-    # dequant-at-gather must not change the collective structure (still
-    # the 2 row-parallel psums per scanned layer body), and the int8
-    # page copy must stay pure data movement over BOTH leaves.
-    eng8 = PagedEngine(params, args, kv_dtype="int8", **kw)
-    recs8 = {
-        "paged_prefill_int8": _Recorder(eng8._prefill_v[False]),
-        "paged_decode_int8": _Recorder(eng8._decode_v[False]),
-    }
-    eng8._prefill_v[False] = recs8["paged_prefill_int8"]
-    eng8._decode_v[False] = recs8["paged_decode_int8"]
-    eng8.serve([Request(prompt(16), max_new_tokens=4),
-                Request(prompt(10), max_new_tokens=3)])
-    copy8 = (_sds_tree(eng8._pk), _sds_tree(eng8._pv), i32, i32)
-    out["page_copy_int8"] = _from_traced(
-        "page_copy_int8", eng8._copy_page.trace(*copy8), copy8,
-        donated=(0, 1), meta=meta)
-    recs.update(recs8)
-    donated["paged_prefill_int8"] = (6, 7)
-    donated["paged_decode_int8"] = (2, 3)
+    for suffix, kv_dtype in (("", None), ("_int8", "int8")):
+        eng = PagedEngine(params, args, kv_dtype=kv_dtype, **kw)
+        path = eng.path
+        for name, table, pools in (("paged_prefill", path._prefill, (6, 7)),
+                                   ("paged_decode", path._decode, (2, 3))):
+            recs[name + suffix] = table[False] = _Recorder(table[False])
+            donated[name + suffix] = pools
+        eng.serve([Request(prompt(16), max_new_tokens=4),
+                   Request(prompt(10), max_new_tokens=3)])
+        copy_args = (_sds_tree(path.pk), _sds_tree(path.pv), i32, i32)
+        out["page_copy" + suffix] = _from_traced(
+            "page_copy" + suffix, path._copy.trace(*copy_args), copy_args,
+            donated=(0, 1), meta=meta)
 
     # draft engine: the speculative verify program (plain decode is
     # replaced by propose/verify rounds when a draft is loaded)
@@ -365,10 +348,10 @@ def disagg_programs():
         done = []
         dw = DecodeWorker(params, args, transport=lt, kv_dtype=kv_dtype,
                           completion_cb=done.append, **kw)
-        recs[f"page_extract{suffix}"] = pw._page_extract = _Recorder(
-            pw._page_extract)
-        recs[f"page_scatter{suffix}"] = dw._page_scatter = _Recorder(
-            dw._page_scatter)
+        recs[f"page_extract{suffix}"] = pw.path._extract = _Recorder(
+            pw.path._extract)
+        recs[f"page_scatter{suffix}"] = dw.path._scatter = _Recorder(
+            dw.path._scatter)
         donated[f"page_extract{suffix}"] = ()
         donated[f"page_scatter{suffix}"] = (0, 1)
         pw.submit(Request(prompt(12), max_new_tokens=3))

@@ -263,9 +263,41 @@ def _mlp_block(lp, h, args, tp_axis):
         return h + _tp_reduce(_wmm(act, lp["w_down"]), tp_axis)
 
 
+def _serving_layer(lp, h, args, attend, tp_axis=None, tp_degree=1):
+    """One Llama decoder layer over `h` [b, s, hid] as the serving steps run
+    it: what the stripe, the paged decode and the verify step share. Norm,
+    the three projections split into heads, the output projection with its
+    tensor-parallel reduce and the SwiGLU half are written here; `attend(q,
+    k, v) -> (attn [b, s, nh, hd], *caches)` is the caller's: RoPE at the
+    rows its positions name, the write of k / v into its cache, and
+    attention over that cache (write-before-attend). Returns (h, *caches).
+
+    tp_axis/tp_degree: when set, this body runs inside shard_map over a
+    tensor-parallel mesh axis — lp holds the Megatron shards (wq/wk/wv/
+    w_gate/w_up split on the out dim, wo/w_down on the in dim), a cache
+    holds this device's nkv/tp_degree heads, and the row-parallel outputs
+    are psum-reduced so `h` stays replicated."""
+    b, s = h.shape[0], h.shape[1]
+    nh = args.num_heads // tp_degree
+    nkv = args.num_kv_heads // tp_degree
+    hd = lf.head_dim(args)
+
+    hin = lf.rms_norm(h, lp["ln1"], args.rms_eps)
+    with jax.named_scope("pt.attention"):
+        q = _wmm(hin, lp["wq"]).reshape(b, s, nh, hd)
+        k = _wmm(hin, lp["wk"]).reshape(b, s, nkv, hd)
+        v = _wmm(hin, lp["wv"]).reshape(b, s, nkv, hd)
+        attn, *caches = attend(q, k, v)
+        h = h + _tp_reduce(_wmm(attn.reshape(b, s, nh * hd), lp["wo"]),
+                           tp_axis)
+
+    return (_mlp_block(lp, h, args, tp_axis), *caches)
+
+
 def _layer_step(lp, h, cache_k, cache_v, pos, cos, sin, args,
                 tp_axis=None, tp_degree=1):
-    """One decoder layer over `h` [b, s, hid] with a fixed-size cache.
+    """One decoder layer over `h` [b, s, hid] with a fixed-size cache
+    [b, nkv, max_len, hd] (heads-major).
 
     prefill (pos == 0, s == prompt len): causal attention within the
     block, cache slots [0, s) written. decode (s == 1): attend over
@@ -274,23 +306,9 @@ def _layer_step(lp, h, cache_k, cache_v, pos, cos, sin, args,
 
     pos may be an int32 [b] vector (requires s == 1): every row sits at its
     own position — per-row RoPE, per-row cache-slot writes, per-row
-    attention masking. This is the continuous-batching decode step.
-
-    tp_axis/tp_degree: when set, this body runs inside shard_map over a
-    tensor-parallel mesh axis — lp holds the Megatron shards (wq/wk/wv/
-    w_gate/w_up split on the out dim, wo/w_down on the in dim), the cache
-    holds this device's nkv/tp_degree heads, and the row-parallel outputs
-    are psum-reduced so `h` stays replicated."""
-    b, s = h.shape[0], h.shape[1]
-    nh = args.num_heads // tp_degree
-    nkv = args.num_kv_heads // tp_degree
-    hd = args.hidden_size // args.num_heads
-
-    hin = lf.rms_norm(h, lp["ln1"], args.rms_eps)
-    with jax.named_scope("pt.attention"):
-        q = _wmm(hin, lp["wq"]).reshape(b, s, nh, hd)
-        k = _wmm(hin, lp["wk"]).reshape(b, s, nkv, hd)
-        v = _wmm(hin, lp["wv"]).reshape(b, s, nkv, hd)
+    attention masking. This is the continuous-batching decode step."""
+    def attend(q, k, v):
+        s = q.shape[1]
         if jnp.ndim(pos) == 1:
             if s != 1:
                 raise ValueError("per-row pos vector requires s == 1 "
@@ -298,43 +316,43 @@ def _layer_step(lp, h, cache_k, cache_v, pos, cos, sin, args,
             q, k = _rope_rows(q, k, jnp.take(cos, pos, axis=0),
                               jnp.take(sin, pos, axis=0))
 
-            # cache [b, nkv, max_len, hd]: each row's new kv lands at that
-            # row's own position
+            # each row's new kv lands at that row's own position
             def write_row(c, new, p):
                 return jax.lax.dynamic_update_slice_in_dim(c, new, p, axis=1)
 
             with jax.named_scope("pt.kv_write"):
-                cache_k = jax.vmap(write_row)(cache_k, jnp.swapaxes(k, 1, 2),
-                                              pos)
-                cache_v = jax.vmap(write_row)(cache_v, jnp.swapaxes(v, 1, 2),
-                                              pos)
+                ck = jax.vmap(write_row)(cache_k, jnp.swapaxes(k, 1, 2), pos)
+                cv = jax.vmap(write_row)(cache_v, jnp.swapaxes(v, 1, 2), pos)
         else:
             q, k = lf.apply_rope(
                 q, k, jax.lax.dynamic_slice_in_dim(cos, pos, s, 0),
                 jax.lax.dynamic_slice_in_dim(sin, pos, s, 0))
-            # cache is heads-major [b, nkv, max_len, hd]; write new slots
-            # at pos
             with jax.named_scope("pt.kv_write"):
-                cache_k = jax.lax.dynamic_update_slice_in_dim(
+                ck = jax.lax.dynamic_update_slice_in_dim(
                     cache_k, jnp.swapaxes(k, 1, 2), pos, axis=2)
-                cache_v = jax.lax.dynamic_update_slice_in_dim(
+                cv = jax.lax.dynamic_update_slice_in_dim(
                     cache_v, jnp.swapaxes(v, 1, 2), pos, axis=2)
+        return _cached_attention(q, ck, cv, pos), ck, cv
 
-        attn = _cached_attention(q, cache_k, cache_v, pos)
-        attn = attn.reshape(b, s, nh * hd)
-        h = h + _tp_reduce(_wmm(attn, lp["wo"]), tp_axis)
+    return _serving_layer(lp, h, args, attend, tp_axis, tp_degree)
 
-    return _mlp_block(lp, h, args, tp_axis), cache_k, cache_v
+
+def _last_hidden(h, last_idx):
+    """h [b, s, hid] at each row's LAST REAL token. last_idx: optional traced
+    per-row (or scalar) index of it — serving prefills pad prompts up to a
+    length bucket, so the next-token logits live at true_len-1, not at s-1.
+    None keeps the plain h[:, -1] gather."""
+    if last_idx is None:
+        return h[:, -1, :]
+    idx = jnp.broadcast_to(jnp.asarray(last_idx, jnp.int32).reshape(-1),
+                           (h.shape[0],))
+    return jnp.take_along_axis(h, idx[:, None, None], axis=1)[:, 0, :]
 
 
 def _forward_cached(params, ids, caches_k, caches_v, pos, cos, sin, args,
                     last_idx=None, tp_axis=None, tp_degree=1):
-    """ids [b, s] -> (next-token logits [b, vocab], new caches).
-
-    last_idx: optional traced per-row (or scalar) index of the LAST REAL
-    token in each row — serving prefills pad prompts up to a length bucket,
-    so the next-token logits live at true_len-1, not at s-1. None keeps the
-    plain h[:, -1] gather."""
+    """ids [b, s] -> (next-token logits [b, vocab], new caches); `last_idx`
+    as in `_last_hidden`."""
     h = jnp.take(params["embedding"], ids, axis=0)
 
     def step(carry, xs):
@@ -347,13 +365,7 @@ def _forward_cached(params, ids, caches_k, caches_v, pos, cos, sin, args,
     h, (new_k, new_v) = jax.lax.scan(step, h,
                                      (params["layers"], caches_k, caches_v))
     h = lf.rms_norm(h, params["final_norm"], args.rms_eps)
-    if last_idx is None:
-        hl = h[:, -1, :]
-    else:
-        idx = jnp.broadcast_to(jnp.asarray(last_idx, jnp.int32).reshape(-1),
-                               (h.shape[0],))
-        hl = jnp.take_along_axis(h, idx[:, None, None], axis=1)[:, 0, :]
-    logits = _wmm(hl, params["lm_head"])
+    logits = _wmm(_last_hidden(h, last_idx), params["lm_head"])
     return logits.astype(jnp.float32), new_k, new_v
 
 
@@ -374,17 +386,10 @@ def _layer_step_paged(lp, h, pool_k, pool_v, bt, pos, cos, sin, args,
     write-before-attend, like the stripe path — then attention gathers K/V
     through the block table (Pallas paged kernel on TPU, jnp gather
     elsewhere). Nothing here has a pool's or a layer's size but the pools
-    themselves, returned updated in place.
-
-    tp_axis/tp_degree: shard_map tensor parallelism — weight shards as in
-    `_layer_step`, the page pool sharded on nkv (block tables replicated,
-    every device walks the same tables over its own kv-head slice)."""
-    b, s = h.shape[0], h.shape[1]
-    if s != 1:
-        raise ValueError(f"paged decode requires s == 1 (got s={s})")
-    nh = args.num_heads // tp_degree
-    nkv = args.num_kv_heads // tp_degree
-    hd = args.hidden_size // args.num_heads
+    themselves, returned updated in place. Under tensor parallelism
+    (`_serving_layer`) the pool is sharded on nkv and the tables replicated."""
+    if h.shape[1] != 1:
+        raise ValueError(f"paged decode requires s == 1 (got s={h.shape[1]})")
     ps = page_size
     quantized = isinstance(pool_k, QuantizedKVPage)
     num_pages = (pool_k.q if quantized else pool_k).shape[0] // num_layers
@@ -392,11 +397,7 @@ def _layer_step_paged(lp, h, pool_k, pool_v, bt, pos, cos, sin, args,
 
     from paddle_tpu.kernels import quantized_matmul as qm
 
-    hin = lf.rms_norm(h, lp["ln1"], args.rms_eps)
-    with jax.named_scope("pt.attention"):
-        q = _wmm(hin, lp["wq"]).reshape(b, 1, nh, hd)
-        k = _wmm(hin, lp["wk"]).reshape(b, 1, nkv, hd)
-        v = _wmm(hin, lp["wv"]).reshape(b, 1, nkv, hd)
+    def attend(q, k, v):
         q, k = _rope_rows(q, k, jnp.take(cos, pos, axis=0),
                           jnp.take(sin, pos, axis=0))
 
@@ -407,16 +408,16 @@ def _layer_step_paged(lp, h, pool_k, pool_v, bt, pos, cos, sin, args,
                                           axis=1)[:, 0]
         off = pos % ps
         if quantized:
-            pool_k = _kv_quant_write(pool_k, page, off, k[:, 0])
-            pool_v = _kv_quant_write(pool_v, page, off, v[:, 0])
-            kq, vq = pool_k.q, pool_v.q
+            pk = _kv_quant_write(pool_k, page, off, k[:, 0])
+            pv = _kv_quant_write(pool_v, page, off, v[:, 0])
+            kq, vq = pk.q, pv.q
             # the layer's own scales: what the kernel holds in SMEM
-            ks = jax.lax.dynamic_slice_in_dim(pool_k.scale, base, num_pages)
-            vs = jax.lax.dynamic_slice_in_dim(pool_v.scale, base, num_pages)
+            ks = jax.lax.dynamic_slice_in_dim(pk.scale, base, num_pages)
+            vs = jax.lax.dynamic_slice_in_dim(pv.scale, base, num_pages)
         else:
-            pool_k = _write_rows(pool_k, k[:, 0], page, off)
-            pool_v = _write_rows(pool_v, v[:, 0], page, off)
-            kq, ks, vq, vs = pool_k, None, pool_v, None
+            pk = _write_rows(pool_k, k[:, 0], page, off)
+            pv = _write_rows(pool_v, v[:, 0], page, off)
+            kq, ks, vq, vs = pk, None, pv, None
 
         if qm.fused_enabled() and qm.paged_decode_supported(
                 q.shape, kq.shape, bt.shape, kq.dtype.itemsize):
@@ -430,10 +431,9 @@ def _layer_step_paged(lp, h, pool_k, pool_v, bt, pos, cos, sin, args,
             attn = _cached_attention(
                 q, qm.paged_gather(kq, bt, ks, q.dtype, base),
                 qm.paged_gather(vq, bt, vs, q.dtype, base), pos)
-        h = h + _tp_reduce(_wmm(attn.reshape(b, 1, nh * hd), lp["wo"]),
-                           tp_axis)
+        return attn, pk, pv
 
-    return _mlp_block(lp, h, args, tp_axis), pool_k, pool_v
+    return _serving_layer(lp, h, args, attend, tp_axis, tp_degree)
 
 
 def _layer_step_paged_verify(lp, h, pool_k_l, pool_v_l, bt, pos, limit,
@@ -450,19 +450,12 @@ def _layer_step_paged_verify(lp, h, pool_k_l, pool_v_l, bt, pos, limit,
     never touches pages it does not own, and the position mask keeps the
     skipped slots unread. Attention gathers the row's whole table and
     masks per row per query (`_cached_attention`'s vector-pos branch)."""
-    b, s = h.shape[0], h.shape[1]
-    nh = args.num_heads // tp_degree
-    nkv = args.num_kv_heads // tp_degree
-    hd = args.hidden_size // args.num_heads
     ps = page_size
 
     from paddle_tpu.kernels import quantized_matmul as qm
 
-    hin = lf.rms_norm(h, lp["ln1"], args.rms_eps)
-    with jax.named_scope("pt.attention"):
-        q = _wmm(hin, lp["wq"]).reshape(b, s, nh, hd)
-        k = _wmm(hin, lp["wk"]).reshape(b, s, nkv, hd)
-        v = _wmm(hin, lp["wv"]).reshape(b, s, nkv, hd)
+    def attend(q, k, v):
+        b, s, nkv, hd = k.shape
         prow = pos[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
         cos_r = jnp.take(cos, prow, axis=0)                  # [b, s, hd]
         sin_r = jnp.take(sin, prow, axis=0)
@@ -472,26 +465,23 @@ def _layer_step_paged_verify(lp, h, pool_k_l, pool_v_l, bt, pos, limit,
         page = jnp.take_along_axis(bt, prow // ps, axis=1)   # [b, s]
         page = jnp.where(prow <= limit[:, None], page, 0)    # null-page sink
         off = prow % ps
-        if isinstance(pool_k_l, QuantizedKVPage):
+        pk, pv = pool_k_l, pool_v_l
+        if isinstance(pk, QuantizedKVPage):
             # token-at-a-time running-absmax writes (s is tiny — the draft
             # window) so a window straddling a page boundary re-scales each
             # touched page exactly once per token that exceeds its scale
             for i in range(s):
-                pool_k_l = _kv_quant_write(pool_k_l, page[:, i], off[:, i],
-                                           k[:, i])
-                pool_v_l = _kv_quant_write(pool_v_l, page[:, i], off[:, i],
-                                           v[:, i])
-            kq, ks = pool_k_l
-            vq, vs = pool_v_l
+                pk = _kv_quant_write(pk, page[:, i], off[:, i], k[:, i])
+                pv = _kv_quant_write(pv, page[:, i], off[:, i], v[:, i])
+            kq, ks = pk
+            vq, vs = pv
         else:
             with jax.named_scope("pt.kv_write"):
-                pool_k_l = pool_k_l.at[
-                    page.reshape(-1), :, off.reshape(-1)].set(
-                        k.reshape(b * s, nkv, hd))
-                pool_v_l = pool_v_l.at[
-                    page.reshape(-1), :, off.reshape(-1)].set(
-                        v.reshape(b * s, nkv, hd))
-            kq, ks, vq, vs = pool_k_l, None, pool_v_l, None
+                pk = pk.at[page.reshape(-1), :, off.reshape(-1)].set(
+                    k.reshape(b * s, nkv, hd))
+                pv = pv.at[page.reshape(-1), :, off.reshape(-1)].set(
+                    v.reshape(b * s, nkv, hd))
+            kq, ks, vq, vs = pk, None, pv, None
 
         # gather the row's table and run the window through the shared
         # masked attention (its vector-pos s>1 branch: query i of row r at
@@ -501,10 +491,9 @@ def _layer_step_paged_verify(lp, h, pool_k_l, pool_v_l, bt, pos, limit,
         attn = _cached_attention(
             q, qm.paged_gather(kq, bt, scale=ks, out_dtype=q.dtype),
             qm.paged_gather(vq, bt, scale=vs, out_dtype=q.dtype), pos)
-        h = h + _tp_reduce(_wmm(attn.reshape(b, s, nh * hd), lp["wo"]),
-                           tp_axis)
+        return attn, pk, pv
 
-    return _mlp_block(lp, h, args, tp_axis), pool_k_l, pool_v_l
+    return _serving_layer(lp, h, args, attend, tp_axis, tp_degree)
 
 
 def _paged_forward_decode(params, ids, pool_k, pool_v, bt, pos, cos, sin,
@@ -577,7 +566,7 @@ def paged_decode_step(params, args, token, pool_k, pool_v, block_tables,
     may be `QuantizedKVPage` pairs (int8 pages + per-(page, kv-head)
     scales): writes then quantize in place and attention dequantizes
     in-registers."""
-    hd = args.hidden_size // args.num_heads
+    hd = lf.head_dim(args)
     P = block_tables.shape[1]
     cos, sin = lf.rope_tables(P * int(page_size), hd, args.rope_theta)
     return _paged_forward_decode(
@@ -720,7 +709,7 @@ def _init_cache(params, args, b, max_len):
     shared by the public prefill/decode_step incremental API and the
     compiled generate."""
     L = lf.stack_leading_dim(params["layers"])
-    hd = args.hidden_size // args.num_heads
+    hd = lf.head_dim(args)
     ck = jnp.zeros((L, b, args.num_kv_heads, max_len, hd),
                    params["embedding"].dtype)
     cv = jnp.zeros_like(ck)
@@ -747,7 +736,7 @@ def decode_step(params, args, token, caches_k, caches_v, pos, max_len):
     different times sit at different sequence depths inside one batched
     program. Rows are independent — an inactive/garbage slot cannot perturb
     the others."""
-    hd = args.hidden_size // args.num_heads
+    hd = lf.head_dim(args)
     cos, sin = lf.rope_tables(max_len, hd, args.rope_theta)
     if jnp.ndim(pos) == 1:
         pos = jnp.asarray(pos, jnp.int32)
@@ -877,7 +866,7 @@ def _layer_norm(x, w, b, eps):
 def _gpt_layer_step(lp, h, cache_k, cache_v, pos, args: GPTGenArgs):
     b, s = h.shape[0], h.shape[1]
     nh = args.num_heads
-    hd = args.hidden_size // nh
+    hd = lf.head_dim(args)
 
     hin = _layer_norm(h, lp["ln1_w"], lp["ln1_b"], args.ln_eps)
     q = (hin @ lp["wq"] + lp["bq"]).reshape(b, s, nh, hd)
@@ -906,9 +895,8 @@ def _gpt_layer_step(lp, h, cache_k, cache_v, pos, args: GPTGenArgs):
 
 def _gpt_forward_cached(params, ids, caches_k, caches_v, pos,
                         args: GPTGenArgs, last_idx=None):
-    """pos: scalar, or int32 [b] per-row positions (serving decode, s=1).
-    last_idx: optional per-row index of the last REAL token (serving
-    prefills pad to a length bucket) — None keeps the h[:, -1] gather."""
+    """pos: scalar, or int32 [b] per-row positions (serving decode, s=1);
+    `last_idx` as in `_last_hidden`."""
     b, s = ids.shape
     if jnp.ndim(pos) == 1:
         positions = pos[:, None] + jnp.arange(s, dtype=jnp.int32)[None]
@@ -927,13 +915,7 @@ def _gpt_forward_cached(params, ids, caches_k, caches_v, pos,
     h, (new_k, new_v) = jax.lax.scan(step, h,
                                      (params["layers"], caches_k, caches_v))
     h = _layer_norm(h, params["lnf_w"], params["lnf_b"], args.ln_eps)
-    if last_idx is None:
-        hl = h[:, -1, :]
-    else:
-        idx = jnp.broadcast_to(jnp.asarray(last_idx, jnp.int32).reshape(-1),
-                               (h.shape[0],))
-        hl = jnp.take_along_axis(h, idx[:, None, None], axis=1)[:, 0, :]
-    logits = hl @ params["word_emb"].T  # tied head
+    logits = _last_hidden(h, last_idx) @ params["word_emb"].T  # tied head
     return logits.astype(jnp.float32), new_k, new_v
 
 
@@ -970,7 +952,7 @@ def _gpt_generate_jit(params, args, prompt_ids, max_new_tokens, sample,
     b, s = prompt_ids.shape
     max_len = s + max_new_tokens
     L = args.num_layers
-    hd = args.hidden_size // args.num_heads
+    hd = lf.head_dim(args)
     ck = jnp.zeros((L, b, args.num_heads, max_len, hd),
                    params["word_emb"].dtype)
     cv = jnp.zeros_like(ck)

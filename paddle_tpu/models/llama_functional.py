@@ -59,6 +59,12 @@ class LlamaArgs(NamedTuple):
         )
 
 
+def head_dim(args):
+    """Width of one attention head. THE one derivation: every cache shape,
+    RoPE table and projection reshape of the dense family asks here."""
+    return args.hidden_size // args.num_heads
+
+
 # --------------------------------------------------------------------------
 # init
 # --------------------------------------------------------------------------
@@ -67,7 +73,7 @@ class LlamaArgs(NamedTuple):
 def init_layer_params(args: LlamaArgs, key, dtype=jnp.float32):
     """One decoder layer's params (unstacked)."""
     h, i = args.hidden_size, args.intermediate_size
-    hd = h // args.num_heads
+    hd = head_dim(args)
     ks = jax.random.split(key, 7)
     init = jax.nn.initializers.normal(0.02)
     return {
@@ -169,7 +175,7 @@ def decoder_layer(p, h, cos, sin, args: LlamaArgs, mp_axis=None, mp_degree=1,
     context costs exactly one attention exchange per layer."""
     nh = args.num_heads // (mp_degree if mp_axis else 1)
     nkv = max(1, args.num_kv_heads // (mp_degree if mp_axis else 1))
-    hd = args.hidden_size // args.num_heads
+    hd = head_dim(args)
 
     def maybe_gather_seq(x):
         # SP: activations arrive seq-sharded over the mp axis; gather full seq
@@ -595,8 +601,7 @@ def forward_hidden(params, ids, args: LlamaArgs, mp_axis=None, mp_degree=1,
         s_local = ids.shape[1] // mp_degree
         rank = jax.lax.axis_index(mp_axis)
         h = jax.lax.dynamic_slice_in_dim(h, rank * s_local, s_local, axis=1)
-    cos, sin = rope_tables(ids.shape[1], args.hidden_size // args.num_heads,
-                           args.rope_theta)
+    cos, sin = rope_tables(ids.shape[1], head_dim(args), args.rope_theta)
     h = run_layers(params["layers"], h, cos, sin, args, mp_axis, mp_degree,
                    sp, remat, unroll=unroll)
     h = rms_norm(h, params["final_norm"], args.rms_eps)

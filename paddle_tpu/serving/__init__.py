@@ -1,73 +1,41 @@
 """paddle_tpu.serving — continuous-batching LLM serving.
 
-Two engines share one iteration-level scheduler (Orca-style):
+    Router / DisaggServer -> PagedEngine (host) -> path (device state and
+    programs) -> models/*_functional -> kernels/
 
-  - `Engine` (serving/engine.py): slot-based KV cache — one `max_len`
-    stripe per slot. Simple, but HBM caps concurrency at S stripes.
-  - `PagedEngine` (serving/paged_engine.py): paged KV cache — a fixed
-    page pool + per-slot block tables (`serving/block_manager.py`:
-    refcounted pages, copy-on-write, leaf-LRU eviction) with RADIX-TREE
-    PREFIX REUSE: every prefilled prompt is registered in a radix tree
-    over token sequences, so a shared system prompt is prefilled once
-    and later requests reuse it at TOKEN granularity — a mid-page
-    divergence still shares the straddled page via a COW page split
-    (`prefix_policy="hash"` keeps the PR-8 exact-match chain as the
-    baseline). Admission allocates pages on demand (worst case reserved
-    up front), so far more concurrent requests fit the same KV HBM —
-    and `kv_dtype="int8"` quantizes the page pool itself (int8 codes +
-    per-(page, kv-head) absmax scales, dequantized inside the paged
-    kernel) for ~2x the pages again at the same byte budget, with a
-    top-1 agreement parity bar vs the model-dtype pool.
+  - `Engine` (engine.py): the iteration-level scheduler (Orca-style) over a
+    slot-based KV cache, one `max_len` stripe per slot. The simple baseline;
+    `Request`, the step phases and the streaming callbacks live here.
+  - `PagedEngine` (paged_engine.py): the same scheduler over a PAGED cache,
+    the host half only: block tables, `BlockAllocator` (block_manager.py:
+    refcounted pages, copy-on-write, radix-tree prefix reuse at token
+    granularity, leaf-LRU eviction), admission by worst-case page count,
+    CHUNKED PREFILL (`prefill_chunk=`) interleaved with decode steps,
+    `preempt` / `resume`. Greedy output is token-for-token sequential
+    `generate`'s.
+  - the PATH (paths.py: the table and the interface): everything on the
+    device is one object a model family, chosen by the type of `args` —
+    dense.py for a `LlamaArgs` (the page pools in the model dtype or
+    `kv_dtype="int8"`, TENSOR PARALLELISM over `mesh=` with tp.py's
+    placement, the disaggregated page mover), hybrid.py for a `HybridArgs`
+    (pages for the sparse layers, a recurrent state a slot, snapshots).
+  - SPECULATIVE DECODING (spec_decode.py): `draft_params=` / `draft_args=`
+    propose `spec_tokens` tokens in one traced scan and verify the window in
+    one batched paged forward; the block table rolls back to what was
+    accepted.
+  - per-request sampling (sampler.py): `Request(temperature=, top_p=, top_k=,
+    seed=)` as traced per-row vectors; greedy rows stay bit-exact argmax in
+    mixed batches and seeds make tokens batch-independent.
+  - DISAGGREGATED PREFILL / DECODE (disagg.py): `PrefillWorker` and
+    `DecodeWorker` are role-restricted `PagedEngine`s; a finished prefill
+    ships as a `KVHandoff` (page contents verbatim) over a `LocalTransport`
+    or a `StoreTransport`; `DisaggServer` wires one of each.
+  - SLO-AWARE MULTI-MODEL ROUTER (router.py): `Router` fronts named backends
+    (`PagedEngine`, `GptEngine`, `BertBackend`) with `slo="interactive" |
+    "batch"` classes and preemption of batch slots.
 
-The paged engine stacks the three serving-throughput levers (ISSUE 14),
-all preserving exact greedy parity with sequential `generate`:
-
-  - TENSOR PARALLELISM: `PagedEngine(mesh=...)` runs every step as a
-    shard_map SPMD program over a mesh `mp` axis — Megatron weight
-    shards, page pool sharded on nkv, block tables replicated
-    (`serving/tp.py` placement);
-  - CHUNKED PREFILL: `prefill_chunk=` streams long prompts in
-    page-aligned chunks interleaved with decode steps (+ anti-convoy
-    short-prompt bypass), keeping TTFT flat under long-prompt bursts;
-  - SPECULATIVE DECODING (`serving/spec_decode.py`): `draft_params=`/
-    `draft_args=` (see `generation.draft_from_params`) propose
-    `spec_tokens` draft tokens in one traced scan and verify the window
-    in one batched paged forward — greedy exact-match acceptance, then
-    the block table rolls back to the committed watermark (rejected
-    window pages return to the pool);
-  - per-request sampling (`serving/sampler.py`): `Request(temperature=,
-    top_p=, top_k=, seed=)` as traced per-row vectors (greedy rows stay
-    bit-exact argmax in mixed batches; seeds make tokens
-    batch-independent).
-
-Above the single-engine layer sit two ISSUE-20 subsystems:
-
-  - DISAGGREGATED PREFILL/DECODE (`serving/disagg.py`): `PrefillWorker`
-    and `DecodeWorker` are role-restricted `PagedEngine`s — prefill
-    never decodes, decode never admits locally. A finished prefill
-    becomes a `KVHandoff` (request identity + sampling state + the
-    slot's KV page contents, bf16 or int8 `QuantizedKVPage`s verbatim)
-    shipped over a transport (`LocalTransport` in-process,
-    `StoreTransport` over the TCPStore in the 2-process rig); the
-    decode side re-scatters the pages into fresh pool pages and seats
-    the request mid-flight — greedy output stays token-for-token equal
-    to a monolithic engine, and the steady decode stream keeps its
-    per-step rate while the other role absorbs long-prompt bursts.
-    `DisaggServer` wires one prefill + one decode worker behind a
-    single submit/step surface.
-  - SLO-AWARE MULTI-MODEL ROUTER (`serving/router.py`): a `Router`
-    fronts named backends — llama (`PagedEngine`), GPT-2 (`GptEngine`,
-    the stripe scheduler re-pointed at `_gpt_forward_cached`), BERT
-    embeddings (`BertBackend`, batched non-autoregressive forwards) —
-    with `slo="interactive"|"batch"` classes, preemption of batch
-    slots (block-table checkpoint, bit-identical `resume`), and
-    per-model/per-tenant labeled counters on its registry.
-
-`serving/scheduler.py` holds the admission queue / length buckets /
-slot table / page math; `serving/metrics.py` the counters (queue depth,
-TTFT, tokens/sec, occupancy, compile counts, prefix-cache hit rate,
-pages in use/free, COW copies, prefill chunks, draft proposed/accepted,
-hand-off counts/bytes/latency, preemptions/resumes) that also back
+scheduler.py holds the admission queue, length buckets, slot table and page
+math; metrics.py the counters, gauges and observations that also back
 `inference.Config.enable_profile()`.
 
     from paddle_tpu.serving import PagedEngine, Request
@@ -78,12 +46,6 @@ hand-off counts/bytes/latency, preemptions/resumes) that also back
                              eos_token_id=2, stream_cb=on_token))
     eng.run_until_idle()          # req.token_ids, req.ttft_s, ...
     print(eng.metrics.summary())
-
-`bench.py --serving` replays deterministic arrival traces
-(`tools/serving_trace.py`, incl. shared-prefix and mixed long/short
-traces) and reports throughput + TTFT vs sequential `generate`, plus a
-stripe-vs-paged comparison at equal KV-cache HBM, a chunked-vs-
-monolithic TTFT leg, and a speculative-vs-greedy tokens/sec leg.
 """
 
 from paddle_tpu.serving.block_manager import (NULL_PAGE, BlockAllocator,

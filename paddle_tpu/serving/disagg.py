@@ -41,9 +41,11 @@ re-computing anything.
   single-process case and mirrors completions back onto the submitted
   Request objects.
 
-Extraction and re-scatter are two tiny jitted programs (`page_extract` /
-`page_scatter`) that must stay COLLECTIVE-FREE — pure page-axis data
-movement, pinned by the `analysis/presets.py` disagg goldens.
+Extraction and re-scatter are two tiny jitted programs of the family's path
+(`serving/dense.py`: `extract_pages` / `scatter_pages`, which know the
+pool's layout) that must stay COLLECTIVE-FREE — pure page-axis data
+movement, pinned by the `analysis/presets.py` disagg goldens. A family whose
+sequence is more than its pages says so (`path.check_handoff`).
 """
 
 from __future__ import annotations
@@ -54,7 +56,6 @@ import time
 from collections import deque
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from paddle_tpu.models import generation as gen
@@ -64,34 +65,6 @@ from paddle_tpu.serving.scheduler import pages_for
 
 __all__ = ["KVHandoff", "LocalTransport", "StoreTransport",
            "PrefillWorker", "DecodeWorker", "DisaggServer"]
-
-
-@jax.named_scope("pt.kv_gather")
-def _extract_pages_traced(pk, pv, pages):
-    """Gather the K/V contents of `pages` (int32 [P]) out of the pool:
-    every pool leaf — the bf16/f32 arrays, or an int8 `QuantizedKVPage`'s
-    codes [L, num_pages, nkv, ps, hd] AND scales [L, num_pages, nkv] —
-    has the page axis at axis 1, so one tree_map covers both layouts.
-    Pure data movement: the disagg transfer programs are pinned
-    collective-free."""
-    def take(a):
-        return jnp.take(a, pages, axis=1)
-
-    return (jax.tree_util.tree_map(take, pk),
-            jax.tree_util.tree_map(take, pv))
-
-
-@jax.named_scope("pt.kv_write")
-def _scatter_pages_traced(pk, pv, pages, data_k, data_v):
-    """Write extracted page contents back into a (different) pool at
-    fresh page ids `pages` [P] — the inverse of `_extract_pages_traced`,
-    leaf-wise over the same axis-1 layout (int8 codes and scales land
-    verbatim: no quantization round-trip on migration)."""
-    def put(a, d):
-        return a.at[:, pages].set(d)
-
-    return (jax.tree_util.tree_map(put, pk, data_k),
-            jax.tree_util.tree_map(put, pv, data_v))
 
 
 def _leaf_dtype(name):
@@ -248,14 +221,6 @@ class StoreTransport:
         return int(self.store.add(f"{self.channel}/n", 0)) - self._seen
 
 
-def _refuse_hybrid(args):
-    if hasattr(args, "layer_kinds"):
-        raise ValueError(
-            "disaggregated workers do not serve a hybrid model: a "
-            "`KVHandoff` ships pages, and the lightning layers' recurrent "
-            "state is in none of them")
-
-
 class PrefillWorker(PagedEngine):
     """A `PagedEngine` restricted to the PREFILL role via the scheduler
     hooks: `_decodable_slots` is empty so `_step_action` only ever
@@ -265,23 +230,13 @@ class PrefillWorker(PagedEngine):
     accounting are all the base engine's."""
 
     def __init__(self, params, args, *, transport, **kw):
-        _refuse_hybrid(args)
         if kw.get("draft_params") is not None:
             raise ValueError("disaggregated workers do not run "
                              "speculative decoding (the draft mirror "
                              "belongs to the decode role)")
         self.transport = transport
         super().__init__(params, args, **kw)
-
-    def _setup_device_state(self):
-        super()._setup_device_state()
-        # extraction never donates: the pool must survive the gather
-        # (the slot retires on the HOST side after the ship)
-        self._page_extract = self._sharded(
-            _extract_pages_traced,
-            in_specs=(self._poolspec, self._poolspec, None),
-            out_specs=(self._poolspec, self._poolspec),
-            donate=())
+        self.path.check_handoff()
 
     def _decodable_slots(self):
         return []
@@ -289,8 +244,7 @@ class PrefillWorker(PagedEngine):
     def _build_handoff(self, req, slot, first):
         pages = np.asarray(self._bt[slot], np.int32)
         with self.metrics.timer("page_extract_s"), self._phase("stage"):
-            pk, pv = self._page_extract(self._pk, self._pv,
-                                        jnp.asarray(pages))
+            pk, pv = self.path.extract_pages(pages)
         with self._phase("wait"):
             pk = jax.tree_util.tree_map(np.asarray, pk)
             pv = jax.tree_util.tree_map(np.asarray, pv)
@@ -329,7 +283,6 @@ class DecodeWorker(PagedEngine):
 
     def __init__(self, params, args, *, transport, completion_cb=None,
                  **kw):
-        _refuse_hybrid(args)
         if kw.get("draft_params") is not None:
             raise ValueError("disaggregated workers do not run "
                              "speculative decoding (the draft has no "
@@ -338,16 +291,7 @@ class DecodeWorker(PagedEngine):
         self.completion_cb = completion_cb
         self._inbox = deque()
         super().__init__(params, args, **kw)
-
-    def _setup_device_state(self):
-        super()._setup_device_state()
-        donate = self._donate_enabled()
-        self._page_scatter = self._sharded(
-            _scatter_pages_traced,
-            in_specs=(self._poolspec, self._poolspec, None,
-                      self._poolspec, self._poolspec),
-            out_specs=(self._poolspec, self._poolspec),
-            donate=(0, 1) if donate else ())
+        self.path.check_handoff()
 
     def _can_prefill(self):
         return False
@@ -380,10 +324,7 @@ class DecodeWorker(PagedEngine):
         n_pages = pkg.num_pages
         pages = [self._alloc.alloc() for _ in range(n_pages)]
         with self.metrics.timer("page_scatter_s"), self._phase("stage"):
-            self._pk, self._pv = self._page_scatter(
-                self._pk, self._pv, jnp.asarray(pages, jnp.int32),
-                jax.tree_util.tree_map(jnp.asarray, pkg.pages_k),
-                jax.tree_util.tree_map(jnp.asarray, pkg.pages_v))
+            self.path.scatter_pages(pages, pkg.pages_k, pkg.pages_v)
         self._bt[slot] = pages
         resv = pages_for(n, pkg.max_new_tokens, self.page_size) - n_pages
         self._resv[slot] = resv
@@ -481,8 +422,6 @@ class DisaggServer:
     def run_until_idle(self):
         stalled = 0
         while self.busy:
-            before = (self.prefill.step_count + self.decode.step_count,
-                      len(self.decode._inbox))
             self.step()
             progressed = (self.prefill.queue
                           or self.prefill.slots.active_slots
@@ -498,7 +437,6 @@ class DisaggServer:
                     f"{pages_for(pkg.prompt_ids.size, pkg.max_new_tokens, self.decode.page_size)} "
                     f"pages, pool has {self.decode._alloc.available} "
                     f"available")
-            _ = before
 
     def serve(self, requests):
         reqs = [self.submit(r) for r in requests]

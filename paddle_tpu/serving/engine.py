@@ -59,7 +59,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from paddle_tpu.models import generation as gen
-from paddle_tpu.models import llama_functional as lf
 from paddle_tpu.observability.spans import span
 from paddle_tpu.serving.metrics import Metrics
 from paddle_tpu.serving.sampler import SlotSampler, pick as _pick
@@ -250,15 +249,8 @@ class Engine:
         hook: the paged engine replaces the per-slot stripes with a page
         pool here)."""
         args = self.args
-        L = lf.stack_leading_dim(self.params["layers"])
-        hd = args.hidden_size // args.num_heads
-        cache_dtype = self.params["embedding"].dtype
-        self._ck = jnp.zeros(
-            (L, self.max_slots, args.num_kv_heads, self.max_len, hd),
-            cache_dtype)
-        self._cv = jnp.zeros_like(self._ck)
-        self._cos, self._sin = lf.rope_tables(self.max_len, hd,
-                                              args.rope_theta)
+        self._ck, self._cv, self._cos, self._sin = gen._init_cache(
+            self.params, args, self.max_slots, self.max_len)
 
         # donate the KV cache buffers: the engine threads ck/cv through
         # every step and immediately drops the old arrays, so XLA aliases

@@ -85,6 +85,16 @@ class HybridPath:
 
     def __init__(self, eng):
         args, self.eng = eng.args, eng
+        for given, what, why in (
+                (eng.mesh, "mesh=", "the recurrent state and the selection "
+                 "have no tensor-parallel placement yet"),
+                (eng.kv_dtype, "kv_dtype='int8'", "the selector's "
+                 "compressed keys are means of unquantized keys"),
+                (eng.draft_params, "draft_params=", "a rejected draft "
+                 "token cannot be taken back out of a recurrent state")):
+            if given is not None:
+                raise ValueError(f"{what} is not supported for a "
+                                 f"hybrid model: {why}")
         args.validate()
         cfg = args.sparse
         if eng.page_size != cfg.block_size:
@@ -107,11 +117,10 @@ class HybridPath:
                            for _ in range(n_light))
         self.snaps = tuple(jnp.zeros((self.snapshots, H, d, d), jnp.float32)
                            for _ in range(n_light))
-        self.pending = {}     # slot -> snapshot id taken at its prompt's end
         self.layer_ids = jnp.arange(args.num_layers, dtype=jnp.int32)
         self.cos, self.sin = lf.rope_tables(2 * eng.max_len, d,
                                             args.rope_theta)
-        self._gauges()
+        self.reset()
 
         donate = eng._donate_enabled()
         kw = dict(args=args, metrics=eng.metrics)
@@ -134,10 +143,8 @@ class HybridPath:
         """An empty engine: no snapshot is waiting (the allocator's ids
         start over with it); the arrays stay, a slot's state restarts at
         position 0 anyway."""
-        self.pending = {}
-        self._gauges()
+        self.pending = {}     # slot -> snapshot id taken at its prompt's end
 
-    def _gauges(self):
         def nbytes(tree):
             return sum(x.size * x.dtype.itemsize
                        for x in jax.tree_util.tree_leaves(tree))
@@ -151,15 +158,21 @@ class HybridPath:
         self.pk, self.pv, self.kc = self._copy(
             self.pk, self.pv, self.kc, jnp.int32(src), jnp.int32(dst))
 
+    def check_handoff(self):
+        raise ValueError(
+            "disaggregated workers do not serve a hybrid model: a "
+            "`KVHandoff` ships pages, and the lightning layers' recurrent "
+            "state is in none of them")
+
     # -- recurrent state --------------------------------------------------------
     def load_snapshot(self, slot, sid):
         self.state = self._move(self.state, self.snaps, jnp.int32(slot),
                                 jnp.int32(sid))
 
-    def save_snapshot(self, slot):
-        """Keep the slot's state, now the state after its whole prompt,
-        where a snapshot id is to be had; the request's retirement hangs
-        it on the radix tree (`attach`)."""
+    def prompt_done(self, slot):
+        """The slot's last prefill window ran: keep its state, now the state
+        after its whole prompt, where a snapshot id is to be had; the
+        request's retirement hangs it on the radix tree (`attach`)."""
         sid = self.eng._alloc.take_snapshot()
         if sid is None:
             # every id waits for a request that is still decoding: the
@@ -182,14 +195,19 @@ class HybridPath:
             self.eng._alloc.release_snapshot(sid)
 
     def take_state(self, slot):
-        """A preempted slot's state, out of the slot's row."""
+        """What a preempted slot leaves with: its state, out of the slot's
+        row, and the snapshot waiting for the request's retirement."""
         one = tuple(jnp.zeros((1,) + s.shape[1:], s.dtype)
                     for s in self.state)
-        return self._move(one, self.state, jnp.int32(0), jnp.int32(slot))
+        return (self._move(one, self.state, jnp.int32(0), jnp.int32(slot)),
+                self.pending.pop(slot, None))
 
     def put_state(self, slot, saved):
-        self.state = self._move(self.state, saved, jnp.int32(slot),
+        recurrent, sid = saved
+        self.state = self._move(self.state, recurrent, jnp.int32(slot),
                                 jnp.int32(0))
+        if sid is not None:
+            self.pending[slot] = sid
 
     # -- the two step programs ----------------------------------------------------
     def prefill(self, ids, start, last_idx, bt_row, new_vec, slot, req,

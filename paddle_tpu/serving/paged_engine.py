@@ -1,5 +1,6 @@
-"""Paged-KV serving engine: block tables, prefix reuse, tensor-parallel
-decode, chunked prefill, and speculative decoding.
+"""Paged-KV serving engine, the HOST half: block tables, prefix reuse,
+chunked prefill, the step loop, preemption, and the speculative round's
+scheduling. What is on the device belongs to the model family's PATH.
 
 `Engine` (serving/engine.py) reserves a full `max_len` KV stripe per
 slot, so HBM — not compute — caps concurrency, and identical system
@@ -8,29 +9,29 @@ stripes with the vLLM PagedAttention memory model (Kwon et al.,
 SOSP'23) plus SGLang-style prefix sharing, on the same iteration-level
 scheduler:
 
-  - ONE fixed page pool `[L, num_pages, nkv, page_size, hd]` (heads-major
-    pages — the layout the Pallas paged decode kernel consumes) and a
-    per-slot BLOCK TABLE mapping sequence positions to pages;
+  - ONE fixed pool of pages on the device and a per-slot BLOCK TABLE here
+    mapping sequence positions to pages. The pools, their layout, whatever
+    else a request keeps on the device and the step programs over them are
+    `self.path`, one object a family (`serving/paths.py` has the table and
+    the interface: `serving/dense.py` for a `LlamaArgs`, `serving/hybrid.py`
+    for a `HybridArgs`). This file holds no device array and names no family;
   - PREFIX CACHE: every prefilled prompt is registered in
     `BlockAllocator`'s radix tree and REF'd by later requests sharing
     the prefix at TOKEN granularity (refcounted, COW-protected; a
     mid-page divergence shares the straddled page through a
     copy-on-write split — the PR-8 exact-match hash chain survives as
     `prefix_policy="hash"`, the bench baseline);
-  - PREFILL = gather the hit pages, run the suffix forward at traced
-    position h (one program per suffix-length bucket), scatter the new
-    pages; DECODE = one batched paged step through the block tables;
+  - PREFILL = one window of the suffix past the hit at a time through
+    `path.prefill` (one program per window-length bucket); DECODE = one
+    batched step through the block tables, `path.decode`;
   - ADMISSION reserves the worst-case page count minus hits and defers
     the FIFO head under page pressure.
 
-On top of that scheduler this engine adds the three serving-throughput
-levers (ROADMAP item 1):
-
-TENSOR PARALLELISM (`mesh=`): pass a Mesh with an `mp` axis and every
-step program runs as one shard_map SPMD program over it — weights in
-the Megatron split, the page pool sharded on its nkv axis, block tables
-and the host-side allocator untouched (`serving/tp.py` has the
-placement). Model size now scales with the mesh, not one chip's HBM.
+On top of that scheduler sit the three serving-throughput levers (ROADMAP
+item 1). Two are not this file's: TENSOR PARALLELISM (`mesh=`) is the dense
+path's placement (`serving/dense.py`, `serving/tp.py`; block tables and the
+allocator are untouched by it), SPECULATIVE DECODING (`draft_params=`) is a
+round of its own in this engine's decode turn (`serving/spec_decode.py`).
 
 CHUNKED PREFILL (`prefill_chunk=`): a long prompt no longer runs as one
 monolithic program that stalls every decoding slot for its whole
@@ -41,188 +42,31 @@ long-prompt bursts. Chunks reuse the suffix-bucket prefill program
 (each chunk is "a suffix at a deeper h"), composing with prefix hits
 unchanged.
 
-SPECULATIVE DECODING (`draft_params=`): a cheap draft model (e.g.
-`generation.draft_from_params` truncation) proposes `spec_tokens`
-greedy tokens in ONE traced scan over its own stripe cache; the target
-model scores the whole window in ONE batched paged verify forward; the
-host commits the longest exactly-matching prefix plus the target's own
-next token (Leviathan-style greedy acceptance — output is token-for-
-token THE target's greedy sequence, just cheaper). Accepted tokens'
-K/V land in the paged tail pages during verify; rejected positions are
-garbage that the write-before-attend order overwrites, and positions
-past a row's page reservation are redirected to the null page.
-
 Greedy parity with sequential `generate` stays exact under every
 combination of the three (and int8 `quantize_params` trees stream
 through the same fused dequant-matmul dispatch).
 
-A HYBRID STACK (`args` a `models/hybrid_functional.HybridArgs`: lightning
-linear-attention layers beside block-sparse attention layers) runs through
-the same `submit` / `step`, scheduler and allocator with a second kind of
-per-request state: pages for the sparse layers only, and a fixed-size
-recurrent state a slot for the lightning layers (`serving/hybrid.py` holds
-both and the two step programs). A prefix hit is then usable only where a
-SNAPSHOT of the recurrent state was taken: at a finished prompt's end, hung
-on the radix tree when the request retires (`BlockAllocator(...,
-state_snapshots=)`). `preempt` / `resume` carry the state; a mesh, an int8
-pool and a draft model are refused at construction.
+A family may keep a second kind of per-request state beside the pages (a
+hybrid stack's recurrent state): a prefix hit is then usable only where a
+SNAPSHOT of it was taken, which the path saves at a finished prompt's end
+(`path.prompt_done`) and the request's retirement hangs on the radix tree
+(`path.attach`, `BlockAllocator(..., state_snapshots=path.snapshots)`);
+`preempt` / `resume` carry it (`path.take_state` / `put_state`). What a
+family cannot do (a mesh, an int8 pool, a draft model) its path refuses at
+construction.
 """
 
 from __future__ import annotations
 
-import functools
-
-import jax
-import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
 
-from paddle_tpu.models import generation as gen
-from paddle_tpu.models import llama_functional as lf
 from paddle_tpu.serving.block_manager import NULL_PAGE, BlockAllocator
 from paddle_tpu.serving.engine import Engine, Request
-from paddle_tpu.serving.sampler import pick as _pick
+from paddle_tpu.serving.paths import path_for
 from paddle_tpu.serving.scheduler import bucket_for, pages_for
 from paddle_tpu.serving.spec_decode import SpecDecoder
 
 __all__ = ["PagedEngine"]
-
-
-def _paged_prefill_traced(params, ids, h, last_idx, bt_row, new_pages,
-                          pk, pv, cos, sin, temp, top_p, top_k, seeds, *,
-                          args, metrics, page_size, pages_per_slot,
-                          sample=False, tp_axis=None, tp_degree=1):
-    """Prefill a suffix window whose first `h` positions are already
-    cached: gather the slot's pages into a contiguous scratch stripe,
-    forward the window tokens at position h, scatter the freshly written
-    pages back.
-
-    ids: [1, sb] window right-padded to a length bucket; h: traced token
-    count already cached (prefix hits AND previously prefilled chunks —
-    TOKEN-granular under the radix cache, so h may sit mid-page: the
-    straddled page is gathered from the frozen cached page and the
-    scatter rewrites the slot's COW copy of it from the page-aligned
-    base); last_idx: index of the window's last real token WITHIN the
-    block; bt_row/new_pages: [P] page indices (unused entries -> null
-    page 0). One XLA program per window bucket — h, last_idx and the
-    page vectors are traced operands, so neither hit depth nor chunk
-    position recompiles."""
-    metrics.inc("prefill_compiles")
-    quantized = isinstance(pk, gen.QuantizedKVPage)
-    arr = pk.q if quantized else pk
-    L, nkv, hd = arr.shape[0], arr.shape[2], arr.shape[4]
-    ps, Pn = page_size, pages_per_slot
-    sb = ids.shape[1]
-    dtype = params["embedding"].dtype if quantized else pk.dtype
-
-    # gather the block-table row into contiguous [L, 1, nkv, P*ps, hd]
-    # (hit pages carry real prefix K/V; later entries are garbage that the
-    # suffix writes + position mask keep unread), then pad by the suffix
-    # bucket so the write at [h, h+sb) can never clamp. An int8 pool
-    # dequantizes in the gather — the scratch stripe the forward runs
-    # over is always the compute dtype
-    with jax.named_scope("pt.kv_gather"):
-        if quantized:
-            def dq(pool):
-                raw = pool.q[:, bt_row].astype(jnp.float32)  # [L,P,nkv,ps,hd]
-                sc = (pool.scale[:, bt_row] / 127.0)[..., None, None]
-                return (raw * sc).astype(dtype)
-
-            g_k = jnp.swapaxes(dq(pk), 1, 2).reshape(L, 1, nkv, Pn * ps, hd)
-            g_v = jnp.swapaxes(dq(pv), 1, 2).reshape(L, 1, nkv, Pn * ps, hd)
-        else:
-            g_k = jnp.swapaxes(pk[:, bt_row], 1, 2).reshape(
-                L, 1, nkv, Pn * ps, hd)
-            g_v = jnp.swapaxes(pv[:, bt_row], 1, 2).reshape(
-                L, 1, nkv, Pn * ps, hd)
-        # (the pad itself rounds up to the 128-position tile: the Pallas
-        # window kernel only takes a 128-aligned stripe, and with a bare
-        # `sb` pad the smallest bucket's stripe never was)
-        pad = jnp.zeros((L, 1, nkv, -(-sb // 128) * 128, hd), dtype)
-        temp_k = jnp.concatenate([g_k, pad], axis=3)
-        temp_v = jnp.concatenate([g_v, pad], axis=3)
-
-    logits, temp_k, temp_v = gen._forward_cached(
-        params, ids, temp_k, temp_v, h, cos, sin, args, last_idx=last_idx,
-        tp_axis=tp_axis, tp_degree=tp_degree)
-    # the emitted token sits at sequence index h + last_idx + 1 — the
-    # (seed, position) the offline generate(seeds=...) would use
-    first = _pick(logits, sample, temp, top_p, top_k, seeds,
-                  h + last_idx + 1)[0]
-
-    # scatter the freshly written pages back from the page-aligned base
-    # below h: when h is mid-page the first chunk carries the gathered
-    # cached half [base, h) plus the new tokens — exactly the COW-copy
-    # content. Unused entries land on the null page.
-    base = h - h % ps
-    pk, pv = _scatter_window(pk, pv, temp_k, temp_v, new_pages, base,
-                             h + last_idx + 1, ps, Pn)
-    return pk, pv, first
-
-
-@jax.named_scope("pt.kv_write")
-def _scatter_window(pk, pv, temp_k, temp_v, new_pages, base, end, ps, Pn):
-    """Cut the scratch stripe into pages from `base` on and write them to
-    `new_pages` of the pool (quantizing them for an int8 pool; `end` is the
-    first position past the window's last real token)."""
-    quantized = isinstance(pk, gen.QuantizedKVPage)
-
-    def chunk(t, i):
-        return jax.lax.dynamic_slice_in_dim(t, base + i * ps, ps, axis=3)
-
-    new_k = jnp.concatenate([chunk(temp_k, i) for i in range(Pn)], axis=1)
-    new_v = jnp.concatenate([chunk(temp_v, i) for i in range(Pn)], axis=1)
-    if quantized:
-        # scatter-time quantization: per-(page, kv-head) absmax over the
-        # VALID positions only — the scratch stripe beyond the window's
-        # last real token [end = h + last_idx + 1] is garbage (pad +
-        # forward junk) that would otherwise inflate the scale and crush
-        # the real values' precision. Masked positions store 0.
-        pos_abs = (base + (jnp.arange(Pn, dtype=jnp.int32) * ps)[:, None]
-                   + jnp.arange(ps, dtype=jnp.int32)[None, :])   # [Pn, ps]
-        valid = (pos_abs < end)[None, :, None, :, None]
-
-        def quant(newx):
-            x = jnp.where(valid, newx.astype(jnp.float32), 0.0)
-            s = jnp.max(jnp.abs(x), axis=(3, 4))                 # [L, Pn, nkv]
-            qx = jnp.clip(jnp.round(
-                x / jnp.maximum(s, 1e-9)[..., None, None] * 127.0),
-                -127, 127).astype(jnp.int8)
-            return qx, s
-
-        qk, sk = quant(new_k)
-        qv, sv = quant(new_v)
-        pk = gen.QuantizedKVPage(pk.q.at[:, new_pages].set(qk),
-                                 pk.scale.at[:, new_pages].set(sk))
-        pv = gen.QuantizedKVPage(pv.q.at[:, new_pages].set(qv),
-                                 pv.scale.at[:, new_pages].set(sv))
-    else:
-        pk = pk.at[:, new_pages].set(new_k)   # [L, P, nkv, ps, hd]
-        pv = pv.at[:, new_pages].set(new_v)
-    return pk, pv
-
-
-def _paged_decode_traced(params, tokens, pk, pv, bt, pos, cos, sin, temp,
-                         top_p, top_k, seeds, *, args, metrics, page_size,
-                         sample=False, tp_axis=None, tp_degree=1):
-    metrics.inc("decode_compiles")
-    logits, pk, pv = gen._paged_forward_decode(
-        params, tokens[:, None], pk, pv, bt, pos, cos, sin, args, page_size,
-        tp_axis=tp_axis, tp_degree=tp_degree)
-    return pk, pv, _pick(logits, sample, temp, top_p, top_k, seeds, pos + 1)
-
-
-@jax.named_scope("pt.kv_write")
-def _copy_page_traced(pk, pv, src, dst):
-    """Device half of copy-on-write: clone one page's K/V across layers.
-    The page axis is axis 1 of every pool leaf — the bf16 arrays AND both
-    halves of an int8 `QuantizedKVPage` (codes [L, pages, ...] and scales
-    [L, pages, nkv]) — so one tree_map covers both pool layouts."""
-    def cp(a):
-        return jax.lax.dynamic_update_slice_in_dim(
-            a, jax.lax.dynamic_slice_in_dim(a, src, 1, axis=1), dst, axis=1)
-
-    return (jax.tree_util.tree_map(cp, pk), jax.tree_util.tree_map(cp, pv))
 
 
 class PagedEngine(Engine):
@@ -255,18 +99,10 @@ class PagedEngine(Engine):
                decoding with `spec_tokens` drafts per round. Greedy
                requests only (exact-match acceptance); sampling requests
                are rejected at submit.
-    kv_dtype:  None (pool in the model dtype) or 'int8' — quantize the
-               KV page pool to int8 with per-(page, kv-head) absmax
-               scales (`generation.QuantizedKVPage`). Prefill scatters
-               quantize whole pages, decode/verify writes keep a RUNNING
-               absmax (re-scaling a page's codes in-registers when a new
-               token exceeds its scale), and attention dequantizes
-               inside the paged kernel — KV bytes halve vs bf16, so an
+    kv_dtype:  None (pool in the model dtype) or 'int8': the dense path
+               quantizes the page pool itself (`serving/dense.py`), so an
                equal-HBM pool holds ~2x the pages. Outputs track the
-               bf16 pool to a top-1 agreement bar, not bit-exactly
-               (quantization perturbs KV); on TPU the int8 paged kernel
-               needs page_size % 32 == 0 and head_dim % 128 == 0, other
-               shapes ride the dequant-gather fallback.
+               bf16 pool to a top-1 agreement bar, not bit-exactly.
     """
 
     def __init__(self, params, args, *, max_slots=4, max_len=256,
@@ -299,30 +135,12 @@ class PagedEngine(Engine):
                     f"prefill_chunk={prefill_chunk} must be a positive "
                     f"multiple of page_size={page_size}")
         self.prefill_chunk = prefill_chunk
-        # a model description that lists its layers' kinds is a hybrid stack
-        self._hybrid = hasattr(args, "layer_kinds")
-        if self._hybrid:
-            for given, what, why in (
-                    (mesh, "mesh=", "the recurrent state and the selection "
-                     "have no tensor-parallel placement yet"),
-                    (kv_dtype, "kv_dtype='int8'", "the selector's "
-                     "compressed keys are means of unquantized keys"),
-                    (draft_params, "draft_params=", "a rejected draft "
-                     "token cannot be taken back out of a recurrent state")):
-                if given is not None:
-                    raise ValueError(f"{what} is not supported for a "
-                                     f"hybrid model: {why}")
         if draft_params is not None and draft_args is None:
             raise ValueError("draft_params requires draft_args "
                              "(see generation.draft_from_params)")
         self.draft_params = draft_params
         self.draft_args = draft_args
         self.spec_tokens = int(spec_tokens)
-        if draft_params is not None:
-            if self.spec_tokens < 1:
-                raise ValueError("spec_tokens must be >= 1")
-            if draft_args.vocab_size != args.vocab_size:
-                raise ValueError("draft and target must share a vocab")
         super().__init__(params, args, max_slots=max_slots, max_len=max_len,
                          min_bucket=min_bucket, pad_id=pad_id,
                          metrics=metrics, donate_steps=donate_steps)
@@ -331,25 +149,14 @@ class PagedEngine(Engine):
     def spec_enabled(self):
         return self.draft_params is not None
 
-    # -- program construction ----------------------------------------------
-    def _sharded(self, body, in_specs, out_specs, donate):
-        """jit a traced step body, shard_map-wrapped when a mesh is set.
-        check_vma stays off for these forward-only programs: the
-        checker's value is guarding AD transposes, and serving has no
-        gradients."""
-        if self.mesh is None:
-            return jax.jit(body, donate_argnums=donate)
-        sm = jax.shard_map(body, mesh=self.mesh, in_specs=in_specs,
-                           out_specs=out_specs, check_vma=False)
-        return jax.jit(sm, donate_argnums=donate)
-
+    # -- construction ------------------------------------------------------
     def _reset_host_state(self):
         """The allocator, block tables and reservations of an empty
         engine (construction and `reset`)."""
         self._alloc = BlockAllocator(
             self.num_pages, self.page_size, metrics=self.metrics,
             policy=self.prefix_policy,
-            state_snapshots=self._hy.snapshots if self._hybrid else 0)
+            state_snapshots=self.path.snapshots)
         self._bt = [[] for _ in range(self.max_slots)]   # host block tables
         self._resv = {}            # slot -> pages still reserved for decode
         self._reserved_total = 0
@@ -358,102 +165,14 @@ class PagedEngine(Engine):
         self._admit_idx = None     # _can_prefill's cached admission scan
 
     def _setup_device_state(self):
-        args = self.args
-        axis = self.tp_axis
-        if self._hybrid:
-            # pools, recurrent state and step programs of the hybrid path
-            from paddle_tpu.serving.hybrid import HybridPath
-
-            self.tp_degree, self._spec = 1, None
-            self._hy = HybridPath(self)
-            self._reset_host_state()
-            return
-        if self.mesh is not None:
-            from paddle_tpu.serving import tp as tp_lib
-
-            self.tp_degree = int(self.mesh.shape[axis])
-            tp_lib.tp_validate(args, self.tp_degree)
-            # eager placement: weights land in their Megatron shards once,
-            # at construction — never resharded on the hot path
-            self.params = tp_lib.shard_params(self.params, self.mesh, axis)
-            self._pspecs = tp_lib.llama_tp_specs(self.params, axis)
-            self._poolspec = tp_lib.pool_spec(axis)
-        else:
-            self.tp_degree = 1
-            self._pspecs = self._poolspec = None
-        tp_kw = dict(tp_axis=axis if self.mesh is not None else None,
-                     tp_degree=self.tp_degree)
-
-        L = lf.stack_leading_dim(self.params["layers"])
-        hd = args.hidden_size // args.num_heads
-        dtype = jax.tree_util.tree_leaves(self.params["embedding"])[0].dtype
-        nkv = args.num_kv_heads
-        pool_shape = (L, self.num_pages, nkv, self.page_size, hd)
-        if self.kv_dtype == "int8":
-            # int8 pages + per-(page, kv-head) absmax scales: halves (vs
-            # bf16) the KV bytes behind a page, so the same HBM budget
-            # holds ~2x the pages -> ~2x the sustained slots. Scales
-            # start at 0: the first write into a page sets them
-            self._pk = gen.QuantizedKVPage(
-                jnp.zeros(pool_shape, jnp.int8),
-                jnp.zeros((L, self.num_pages, nkv), jnp.float32))
-            self._pv = gen.QuantizedKVPage(
-                jnp.zeros(pool_shape, jnp.int8),
-                jnp.zeros((L, self.num_pages, nkv), jnp.float32))
-        else:
-            self._pk = jnp.zeros(pool_shape, dtype)
-            self._pv = jnp.zeros_like(self._pk)
-        self.metrics.set_gauge("kv_pool_bytes", 2 * sum(
-            x.size * x.dtype.itemsize
-            for x in jax.tree_util.tree_leaves(self._pk)))
-        if self.mesh is not None:
-            # both halves of a QuantizedKVPage shard on nkv, so the bf16
-            # pool spec applies to the pair as a pytree prefix
-            sh = NamedSharding(self.mesh, self._poolspec)
-            self._pk = jax.device_put(self._pk, sh)
-            self._pv = jax.device_put(self._pv, sh)
-        # 2*max_len: suffix prefills write at [h, h+bucket), which can
-        # overshoot max_len before masking trims it
-        self._cos, self._sin = lf.rope_tables(2 * self.max_len, hd,
-                                              args.rope_theta)
-
+        # everything on the device — pools, per-request state, the step
+        # programs — is the model family's (`serving/paths.py`)
+        self.path = path_for(self)
         self._reset_host_state()
-
-        donate = self._donate_enabled()
-        rep = P()
-        prefill_specs = dict(
-            in_specs=(self._pspecs, rep, rep, rep, rep, rep,
-                      self._poolspec, self._poolspec, rep, rep, rep, rep,
-                      rep, rep),
-            out_specs=(self._poolspec, self._poolspec, rep))
-        decode_specs = dict(
-            in_specs=(self._pspecs, rep, self._poolspec, self._poolspec,
-                      rep, rep, rep, rep, rep, rep, rep, rep),
-            out_specs=(self._poolspec, self._poolspec, rep))
-        self._prefill_v, self._decode_v = {}, {}
-        for sample in (False, True):
-            self._prefill_v[sample] = self._sharded(
-                functools.partial(
-                    _paged_prefill_traced, args=args, metrics=self.metrics,
-                    page_size=self.page_size,
-                    pages_per_slot=self.pages_per_slot, sample=sample,
-                    **tp_kw),
-                donate=(6, 7) if donate else (), **prefill_specs)
-            self._decode_v[sample] = self._sharded(
-                functools.partial(
-                    _paged_decode_traced, args=args, metrics=self.metrics,
-                    page_size=self.page_size, sample=sample, **tp_kw),
-                donate=(2, 3) if donate else (), **decode_specs)
-        self._copy_page = self._sharded(
-            _copy_page_traced,
-            in_specs=(self._poolspec, self._poolspec, rep, rep),
-            out_specs=(self._poolspec, self._poolspec),
-            donate=(0, 1) if donate else ())
-
-        # the speculative half (draft cache/programs + the sharded verify
-        # program + the propose/verify/accept/roll-back round) lives in
-        # serving/spec_decode.py
-        self._spec = SpecDecoder(self, donate) if self.spec_enabled else None
+        # the speculative half (draft cache and programs, the verify program,
+        # the propose / verify / accept / roll-back round): spec_decode.py
+        self._spec = SpecDecoder(self, self._donate_enabled()) \
+            if self.spec_enabled else None
 
     # -- admission ----------------------------------------------------------
     def submit(self, req):
@@ -557,14 +276,6 @@ class PagedEngine(Engine):
         return [s for s in active if s not in self._chunk_streams]
 
     # -- prefill ------------------------------------------------------------
-    def _cow_device(self, src, dst):
-        """Device half of copy-on-write: clone page `src` into `dst`."""
-        if self._hybrid:
-            self._hy.copy_page(src, dst)
-        else:
-            self._pk, self._pv = self._copy_page(
-                self._pk, self._pv, jnp.int32(src), jnp.int32(dst))
-
     def _begin_paged_prefill(self, req, slot, n):
         """Match prefix hits, seat the block table, and reserve the
         request's remaining worst-case pages (prompt pages still to be
@@ -584,13 +295,14 @@ class PagedEngine(Engine):
             src = hit.partial_page
             copy, _ = self._alloc.ensure_writable(src)
             with self._phase("stage", request_id=req.request_id, slot=slot):
-                self._cow_device(src, copy)
+                self.path.copy_page(src, copy)
             self._bt[slot].append(copy)
             held += 1
         if hit.state is not None:
-            # a hybrid model resumes from the snapshot the match ends at
+            # the match ends at a snapshot of the per-request state kept
+            # beside the pages: the slot resumes from it
             with self._phase("stage", request_id=req.request_id, slot=slot):
-                self._hy.load_snapshot(slot, hit.state)
+                self.path.load_snapshot(slot, hit.state)
         resv = pages_for(n, req.max_new_tokens, ps) - held
         self._resv[slot] = resv
         self._reserved_total += resv
@@ -636,20 +348,10 @@ class PagedEngine(Engine):
                 padded = np.full((1, sb), self.pad_id, np.int32)
                 padded[0, :end - start] = req.prompt_ids[start:end]
                 sample = final and req.temperature > 0
-                if self._hybrid:
-                    first = self._hy.prefill(padded, start, end - 1 - start,
-                                             bt_row, new_vec, slot, req,
-                                             sample)
-                    if final:
-                        self._hy.save_snapshot(slot)
-                else:
-                    self._pk, self._pv, first = self._prefill_v[sample](
-                        self.params, jnp.asarray(padded), jnp.int32(start),
-                        jnp.int32(end - 1 - start), jnp.asarray(bt_row),
-                        jnp.asarray(new_vec), self._pk, self._pv,
-                        self._cos, self._sin, jnp.float32(req.temperature),
-                        jnp.float32(req.top_p), jnp.int32(req.top_k),
-                        jnp.asarray([req.seed], jnp.int32))
+                first = self.path.prefill(padded, start, end - 1 - start,
+                                          bt_row, new_vec, slot, req, sample)
+                if final:
+                    self.path.prompt_done(slot)
             with self._phase("wait", **ids):
                 first = int(first)
         if final:
@@ -759,7 +461,7 @@ class PagedEngine(Engine):
             page, copied = self._alloc.ensure_writable(old)
             if copied:
                 with self._phase("stage", slot=slot):
-                    self._cow_device(old, page)
+                    self.path.copy_page(old, page)
                 pages[pi] = page
         while len(pages) * ps <= top:
             pages.append(self._alloc.alloc())
@@ -779,15 +481,8 @@ class PagedEngine(Engine):
             live = int(np.sum(self._npos[active] // self.page_size + 1))
             self.metrics.observe("decode_live_page_share",
                                  live / (self.max_slots * Pn))
-            if self._hybrid:
-                nxt = self._hy.decode(bt, active, self._sampling_active(),
-                                      self._sampling_args())
-            else:
-                self._pk, self._pv, nxt = self._decode_v[
-                    self._sampling_active()](
-                    self.params, jnp.asarray(self._last_tok), self._pk,
-                    self._pv, jnp.asarray(bt), jnp.asarray(self._npos),
-                    self._cos, self._sin, *self._sampling_args())
+            nxt = self.path.decode(bt, active, self._sampling_active(),
+                                   self._sampling_args())
         with self._phase("wait"):
             return np.asarray(nxt)
 
@@ -808,10 +503,10 @@ class PagedEngine(Engine):
             n_pages = -(-n // self.page_size)
             self._alloc.register_prefix(req.prompt_ids,
                                         self._bt[slot][:n_pages])
-        if self._hybrid and req is not None:
-            # the snapshot of the state at the prompt's end joins the tree
-            # with the prompt's last page
-            self._hy.attach(slot, req.prompt_ids, whole)
+        if req is not None:
+            # what the path kept of the prompt's end joins the tree with
+            # the prompt's last page
+            self.path.attach(slot, req.prompt_ids, whole)
         self._alloc.release_many(self._bt[slot])
         self._bt[slot] = []
         self._reserved_total -= self._resv.pop(slot, 0)
@@ -843,12 +538,10 @@ class PagedEngine(Engine):
         state = {"req": req, "pages": self._bt[slot],
                  "npos": int(self._npos[slot]),
                  "last_tok": int(self._last_tok[slot]),
-                 "resv": self._resv.get(slot, 0)}
-        if self._hybrid:
-            # the recurrent state leaves the slot with the request, and
-            # with it the snapshot waiting for the request's retirement
-            state["recurrent"] = self._hy.take_state(slot)
-            state["snapshot"] = self._hy.pending.pop(slot, None)
+                 "resv": self._resv.get(slot, 0),
+                 # what the path keeps of the slot beside its pages leaves
+                 # with the request
+                 "path_state": self.path.take_state(slot)}
         self._bt[slot] = []
         self._reserved_total -= self._resv.pop(slot, 0)
         self.slots.retire(slot)
@@ -872,10 +565,7 @@ class PagedEngine(Engine):
         self._reserved_total += state["resv"]
         self._npos[slot] = state["npos"]
         self._last_tok[slot] = state["last_tok"]
-        if self._hybrid:
-            self._hy.put_state(slot, state["recurrent"])
-            if state["snapshot"] is not None:
-                self._hy.pending[slot] = state["snapshot"]
+        self.path.put_state(slot, state["path_state"])
         self.metrics.inc("resumes")
         return slot
 
@@ -884,13 +574,7 @@ class PagedEngine(Engine):
         cache — a warm timed run after reset would be all hits and lie);
         compiled programs and compile counters survive."""
         super().reset()
-        # the page pool survives a reset, so its byte gauge must too
-        if self._hybrid:
-            self._hy.reset()
-        else:
-            self.metrics.set_gauge("kv_pool_bytes", 2 * sum(
-                x.size * x.dtype.itemsize
-                for x in jax.tree_util.tree_leaves(self._pk)))
+        self.path.reset()
         self._reset_host_state()
         if self.spec_enabled:
             self._spec.reset()

@@ -1,0 +1,56 @@
+"""The seam between `PagedEngine` and a model family.
+
+`PagedEngine` is the HOST half of serving: admission, the allocator and the
+block tables, reservations, chunk streams, the step loop, copy-on-write
+bookkeeping, retire / preempt / resume, counters and spans. Everything on the
+DEVICE (the pools and their layout, what a request keeps beside its pages,
+the step programs) is one object a family, `eng.path`, built here from the
+type of the model description the engine was given. The engine calls:
+
+  prefill(ids, start, last_idx, bt_row, new_vec, slot, req, sample) -> first
+      one prefill window [start, start + last_idx] of a slot's prompt, `ids`
+      [1, bucket] padded; the block-table row and the pages to write are
+      host arrays. Returns the token after the window (a device scalar).
+  decode(bt, active, sample, sampling_args) -> next
+      one batched step over the block tables `bt` [slots, pages a slot];
+      reads `eng._last_tok` / `eng._npos`. Returns the next tokens [slots].
+  copy_page(src, dst)       device half of a copy-on-write
+  prompt_done(slot)         the slot's last prefill window ran
+  load_snapshot(slot, sid)  a prefix hit that ends at snapshot `sid`
+  attach(slot, prompt_ids, registered)   the slot retires
+  take_state(slot) -> saved / put_state(slot, saved)   preempt / resume
+  reset()                   an empty engine (arrays and programs stay)
+  snapshots                 how many snapshot ids the allocator hands out
+  check_handoff()           raises where a sequence is more than its pages;
+                            else `extract_pages(pages)` / `scatter_pages(
+                            pages, data_k, data_v)` move them (`disagg.py`)
+
+A path is built as `Path(eng)` once the engine's own arguments are stored
+(`eng.args`, `params`, `page_size`, `num_pages`, `max_slots`, `mesh`, ..)
+and refuses there what its family cannot do. Adding a family: a functional
+forward under `models/`, a path beside `dense.py`, an entry in `PATHS`.
+"""
+
+from __future__ import annotations
+
+from paddle_tpu.models.hybrid_functional import HybridArgs
+from paddle_tpu.models.llama_functional import LlamaArgs
+from paddle_tpu.serving.dense import DensePath
+from paddle_tpu.serving.hybrid import HybridPath
+
+__all__ = ["PATHS", "path_for"]
+
+# type of the model description -> the family's device half
+PATHS = {LlamaArgs: DensePath, HybridArgs: HybridPath}
+
+
+def path_for(eng):
+    """The device half of `eng` for the family `eng.args` describes."""
+    try:
+        cls = PATHS[type(eng.args)]
+    except KeyError:
+        raise TypeError(
+            f"no serving path for a {type(eng.args).__name__}: "
+            "paddle_tpu.serving.paths.PATHS holds "
+            f"{sorted(t.__name__ for t in PATHS)}") from None
+    return cls(eng)

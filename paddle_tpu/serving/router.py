@@ -50,6 +50,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from paddle_tpu.models import generation as gen
+from paddle_tpu.models import llama_functional as lf
 from paddle_tpu.serving.engine import Engine, Request
 from paddle_tpu.serving.metrics import Metrics
 from paddle_tpu.serving.sampler import pick as _pick
@@ -99,7 +100,7 @@ class GptEngine(Engine):
             raise ValueError(
                 f"max_len={self.max_len} exceeds the learned position "
                 f"table ({args.max_position_embeddings})")
-        hd = args.hidden_size // args.num_heads
+        hd = lf.head_dim(args)
         self._ck = jnp.zeros((args.num_layers, self.max_slots,
                               args.num_heads, self.max_len, hd),
                              self.params["word_emb"].dtype)

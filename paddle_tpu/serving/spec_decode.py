@@ -212,10 +212,14 @@ class SpecDecoder:
         self.eng = engine
         self.g = engine.spec_tokens
         dargs = engine.draft_args
+        if self.g < 1:
+            raise ValueError("spec_tokens must be >= 1")
+        if dargs.vocab_size != engine.args.vocab_size:
+            raise ValueError("draft and target must share a vocab")
         self.draft_params = engine.draft_params
         self.draft_args = dargs
         Ld = lf.stack_leading_dim(self.draft_params["layers"])
-        dhd = dargs.hidden_size // dargs.num_heads
+        dhd = lf.head_dim(dargs)
         ddtype = self.draft_params["embedding"].dtype
         self._dck = jnp.zeros(
             (Ld, engine.max_slots, dargs.num_kv_heads, engine.max_len,
@@ -247,27 +251,27 @@ class SpecDecoder:
                               metrics=engine.metrics, steps=self.g + 1),
             donate_argnums=(4, 5) if donate else (),
             static_argnames=("sample",))
-        rep = P()
+        # the target's half: over the dense path's pools, in its placement
+        rep, path = P(), engine.path
+        pool = path.poolspec
         tp_kw = dict(
             args=engine.args, metrics=engine.metrics,
             page_size=engine.page_size,
             tp_axis=engine.tp_axis if engine.mesh is not None else None,
-            tp_degree=engine.tp_degree)
-        self._verify = engine._sharded(
+            tp_degree=path.tp_degree)
+        self._verify = path.sharded(
             functools.partial(_paged_verify_traced, **tp_kw),
-            in_specs=(engine._pspecs, rep, engine._poolspec,
-                      engine._poolspec, rep, rep, rep, rep, rep),
-            out_specs=(engine._poolspec, engine._poolspec, rep),
+            in_specs=(path.pspecs, rep, pool, pool, rep, rep, rep, rep, rep),
+            out_specs=(pool, pool, rep),
             donate=(2, 3) if donate else ())
         # the rejection-sampling verify also returns the warped target
         # distributions; built lazily-adjacent here so greedy-only
         # engines never trace it
-        self._verify_sampled = engine._sharded(
+        self._verify_sampled = path.sharded(
             functools.partial(_paged_verify_sampled_traced, **tp_kw),
-            in_specs=(engine._pspecs, rep, engine._poolspec,
-                      engine._poolspec, rep, rep, rep, rep, rep, rep,
-                      rep, rep),
-            out_specs=(engine._poolspec, engine._poolspec, rep, rep),
+            in_specs=(path.pspecs, rep, pool, pool, rep, rep, rep, rep, rep,
+                      rep, rep, rep),
+            out_specs=(pool, pool, rep, rep),
             donate=(2, 3) if donate else ())
 
     # -- lifecycle -----------------------------------------------------------
@@ -407,20 +411,21 @@ class SpecDecoder:
             for slot in active:
                 bt[slot, :len(eng._bt[slot])] = eng._bt[slot]
         # `verify_s`: the verify program's dispatch and read-back alone
+        path = eng.path
         with eng.metrics.timer("verify_s"):
             tprobs = None
             with eng._phase("stage"):
                 if sampling:
-                    eng._pk, eng._pv, tgt, tprobs = self._verify_sampled(
-                        eng.params, jnp.asarray(ids), eng._pk, eng._pv,
+                    path.pk, path.pv, tgt, tprobs = self._verify_sampled(
+                        eng.params, jnp.asarray(ids), path.pk, path.pv,
                         jnp.asarray(bt), jnp.asarray(eng._npos),
-                        jnp.asarray(limit), eng._cos, eng._sin,
+                        jnp.asarray(limit), path.cos, path.sin,
                         *eng.sampler.device_args()[:3])
                 else:
-                    eng._pk, eng._pv, tgt = self._verify(
-                        eng.params, jnp.asarray(ids), eng._pk, eng._pv,
+                    path.pk, path.pv, tgt = self._verify(
+                        eng.params, jnp.asarray(ids), path.pk, path.pv,
                         jnp.asarray(bt), jnp.asarray(eng._npos),
-                        jnp.asarray(limit), eng._cos, eng._sin)
+                        jnp.asarray(limit), path.cos, path.sin)
             with eng._phase("wait"):
                 if sampling:
                     tprobs = np.asarray(tprobs)           # [S, g+1, V]

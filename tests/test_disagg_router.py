@@ -18,9 +18,10 @@ import jax.numpy as jnp
 
 from paddle_tpu.models import llama_functional as lf
 from paddle_tpu.models.generation import generate
-from paddle_tpu.serving.disagg import (
-    DisaggServer, KVHandoff, LocalTransport, _extract_pages_traced,
-    _scatter_pages_traced)
+from paddle_tpu.serving.dense import (_extract_pages_traced,
+                                      _scatter_pages_traced)
+from paddle_tpu.serving.disagg import (DisaggServer, KVHandoff,
+                                       LocalTransport)
 from paddle_tpu.serving.engine import Request
 from paddle_tpu.serving.paged_engine import PagedEngine
 from paddle_tpu.serving.router import (
@@ -164,7 +165,7 @@ class TestDisaggParity:
         finally:
             params = saved
         # the pool dtype followed the params: bf16 rode the wire
-        leaf = jax.tree_util.tree_leaves(srv.decode._pk)[0]
+        leaf = jax.tree_util.tree_leaves(srv.decode.path.pk)[0]
         assert leaf.dtype == jnp.bfloat16
 
     def test_chunked_prefill_parity(self):

@@ -523,7 +523,7 @@ def test_preempt_and_resume_carry_the_state(fam, params, args):
         eng.step()
     slot = eng.slots.active_slots[0]
     state = eng.preempt(slot)
-    assert "recurrent" in state and not eng.slots.active_slots
+    assert state["path_state"] is not None and not eng.slots.active_slots
     # another request takes the slot and leaves its own state behind
     eng.serve([Request(_ids(30, 4), 4)])
     assert eng.can_resume(state)

@@ -524,7 +524,7 @@ WORKER_SERVING = textwrap.dedent("""
         ref = np.asarray(generate(params, ARGS, p[None],
                                   max_new_tokens=6))[0][len(p):]
         np.testing.assert_array_equal(np.asarray(r.token_ids), ref)
-    assert len(eng._pk.sharding.device_set) == 2, eng._pk.sharding
+    assert len(eng.path.pk.sharding.device_set) == 2, eng.path.pk.sharding
     c = eng.metrics.summary()["counters"]
     assert c["spec_rounds"] > 0 and c["chunked_prefills"] >= 1, c
     print("SHARDED_SERVING_OK", flush=True)

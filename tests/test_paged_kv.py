@@ -556,7 +556,7 @@ class TestPagedDecodeKernel:
             np.asarray(qm.paged_gather(kq, bt, scale=ks)), man, atol=1e-6)
 
     def test_cow_device_copy(self):
-        from paddle_tpu.serving.paged_engine import _copy_page_traced
+        from paddle_tpu.serving.dense import _copy_page_traced
 
         rng = np.random.default_rng(3)
         pk = jnp.asarray(rng.normal(size=(2, 5, 2, 4, 8)), jnp.float32)
@@ -608,7 +608,7 @@ class TestPagedDecodeKernel:
 
     def test_int8_cow_copy_clones_codes_and_scales(self):
         from paddle_tpu.models.generation import QuantizedKVPage
-        from paddle_tpu.serving.paged_engine import _copy_page_traced
+        from paddle_tpu.serving.dense import _copy_page_traced
 
         rng = np.random.default_rng(5)
         mk = lambda: QuantizedKVPage(
@@ -1045,8 +1045,8 @@ class TestSpecDecodePaged:
                     for l in range(pool.shape[0])]
             return np.asarray(jnp.stack(rows))[:, 0, :, :npos]
 
-        for pool_s, pool_p in ((spec._pk, plain._pk),
-                               (spec._pv, plain._pv)):
+        for pool_s, pool_p in ((spec.path.pk, plain.path.pk),
+                               (spec.path.pv, plain.path.pv)):
             got, want = gathered(spec, pool_s), gathered(plain, pool_p)
             # tail positions really carry K/V (not zeros/null garbage)
             assert np.abs(got[:, :, prompt_pages * ps:]).max() > 0
@@ -1222,7 +1222,7 @@ class TestInt8KVPool:
         assert rc["prefix_tokens_hit"] > hc["prefix_tokens_hit"]
         assert rc.get("prefix_partial_hits", 0) >= 1
         assert rc.get("cow_copies", 0) >= 1
-        assert isinstance(radix._pk, QuantizedKVPage)
+        assert isinstance(radix.path.pk, QuantizedKVPage)
         # gauge = exact pytree bytes (int8 codes + f32 scales); the test
         # params are f32, so the quantized pool is ~1/4 the default here
         # (~1/2 under bf16 params)
@@ -1231,7 +1231,7 @@ class TestInt8KVPool:
         b8 = radix.metrics.summary()["gauges"]["kv_pool_bytes"]["value"]
         bb = base.metrics.summary()["gauges"]["kv_pool_bytes"]["value"]
         assert b8 == 2 * sum(x.size * x.dtype.itemsize for x in
-                             jax.tree_util.tree_leaves(radix._pk))
+                             jax.tree_util.tree_leaves(radix.path.pk))
         assert b8 <= bb // 2
 
     def test_spec_decode_int8_agreement(self, params):
@@ -1363,22 +1363,22 @@ class TestStepPhases:
 def _lowered_text(engine, program):
     """The lowered text, with locations, of one of the engine's own program
     objects at the shapes the engine calls it with."""
-    Pn, S = engine.pages_per_slot, engine.max_slots
+    Pn, S, path = engine.pages_per_slot, engine.max_slots, engine.path
     if program == "decode":
-        low = engine._decode_v[False].lower(
-            engine.params, jnp.zeros(S, jnp.int32), engine._pk, engine._pv,
+        low = path._decode[False].lower(
+            engine.params, jnp.zeros(S, jnp.int32), path.pk, path.pv,
             jnp.zeros((S, Pn), jnp.int32), jnp.zeros(S, jnp.int32),
-            engine._cos, engine._sin, *engine._sampling_args())
+            path.cos, path.sin, *engine._sampling_args())
     elif program == "prefill":
-        low = engine._prefill_v[False].lower(
+        low = path._prefill[False].lower(
             engine.params, jnp.zeros((1, 16), jnp.int32), jnp.int32(0),
             jnp.int32(3), jnp.zeros(Pn, jnp.int32), jnp.zeros(Pn, jnp.int32),
-            engine._pk, engine._pv, engine._cos, engine._sin,
+            path.pk, path.pv, path.cos, path.sin,
             jnp.float32(0), jnp.float32(1), jnp.int32(0),
             jnp.zeros(1, jnp.int32))
     else:
-        low = engine._copy_page.lower(engine._pk, engine._pv, jnp.int32(1),
-                                      jnp.int32(2))
+        low = path._copy.lower(path.pk, path.pv, jnp.int32(1),
+                               jnp.int32(2))
     return low.as_text(debug_info=True)
 
 

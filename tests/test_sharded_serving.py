@@ -105,12 +105,12 @@ class TestTensorParallelParity:
         ref = _sequential(params, prompts, max_new=8)
         eng = PagedEngine(params, ARGS, max_slots=2, max_len=64,
                           page_size=8, min_bucket=8, mesh=mesh)
-        assert eng.tp_degree == 2
+        assert eng.path.tp_degree == 2
         reqs = eng.serve([Request(p, 8) for p in prompts])
         for r, s in zip(reqs, ref):
             np.testing.assert_array_equal(np.asarray(r.token_ids), s)
         # the pool really is sharded over the mesh
-        assert len(eng._pk.sharding.device_set) == 2
+        assert len(eng.path.pk.sharding.device_set) == 2
 
     @pytest.mark.slow
     def test_tp2_int8_with_prefix_hits(self, params, mesh):
