@@ -177,7 +177,7 @@ def _run_single(spec_json):
 
 def _xprof_epilogue_check(logdir, top_k=5):
     """tools/xprof_report attribution over the traced steps: the fused-CE
-    epilogue streams [b, chunk, vocab] tiles through the lm_head matmul, so
+    epilogue works on [T, Vb] blocks of the logits (lf.ce_blocking), so
     no CE-shaped vector op may rank among the top-k non-matmul consumers.
     Detection is by HLO-name marker (softmax/one-hot/log fusions keep their
     root op in the name); a miss therefore means "no large CE-named op",

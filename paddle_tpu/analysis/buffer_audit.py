@@ -1,6 +1,6 @@
 """Buffer audit: what the compiled program materializes.
 
-Three rules over the jaxpr's intermediate values:
+Four rules over the jaxpr's intermediate values:
 
   top_intermediates    the k largest buffers any equation writes — the
                        report half (what would an HBM profile blame?).
@@ -11,6 +11,11 @@ Three rules over the jaxpr's intermediate values:
                        fused-CE work (buffer.forbidden-shape): the given
                        shape must not appear anywhere in the program,
                        forward or backward, including every subjaxpr.
+  check_forbidden_carry  no scan or while loop may carry a value of the
+                       given shape and dtype from one iteration to the
+                       next (buffer.forbidden-carry): a carry is read and
+                       written every iteration, so one the size of a weight
+                       is that weight's traffic times the trip count.
 
 `has_shape` is the predicate form (used by tests/test_fused_ce.py — the
 traversal that used to live there as a private helper now has one home).
@@ -25,7 +30,8 @@ from paddle_tpu.analysis.jaxpr_walk import (format_eqn, iter_eqns,
                                             iter_shaped_values, provenance)
 
 __all__ = ["intermediates", "top_intermediates", "has_shape",
-           "check_forbidden_shape", "check_byte_ceiling"]
+           "loop_carries", "check_forbidden_shape", "check_forbidden_carry",
+           "check_byte_ceiling"]
 
 
 def _nbytes(aval):
@@ -93,6 +99,38 @@ def check_forbidden_shape(jaxpr, shape, program, what="buffer"):
         if len(out) >= 5:  # the first few sites identify the leak
             break
     return out
+
+
+def loop_carries(jaxpr):
+    """Yield (aval, eqn, path) for every value a scan or a while loop
+    carries from one iteration to the next, in every subjaxpr."""
+    for eqn, path in iter_eqns(jaxpr):
+        if eqn.primitive.name == "scan":
+            lo = eqn.params["num_consts"]
+            carried = eqn.invars[lo:lo + eqn.params["num_carry"]]
+        elif eqn.primitive.name == "while":
+            carried = eqn.invars[eqn.params["cond_nconsts"]
+                                 + eqn.params["body_nconsts"]:]
+        else:
+            continue
+        for v in carried:
+            yield v.aval, eqn, path
+
+
+def check_forbidden_carry(jaxpr, shape, dtype, program, what="buffer"):
+    """No loop may carry a value of exactly `shape` and `dtype`. The
+    fused CE's standing form: no float32 [hidden, vocab_local] head
+    gradient accumulated across a scan (it was, once a 128-token chunk:
+    a read and a write of 758 MB 128 times a step)."""
+    shape, dtype = tuple(shape), np.dtype(dtype)
+    return [Violation(
+        rule="buffer.forbidden-carry",
+        program=program,
+        message=(f"forbidden {what} {shape} ({dtype}) carried by "
+                 f"{format_eqn(eqn, path)}"),
+        provenance=provenance(eqn))
+        for aval, eqn, path in loop_carries(jaxpr)
+        if tuple(aval.shape) == shape and aval.dtype == dtype]
 
 
 def check_byte_ceiling(jaxpr, ceiling_bytes, program):

@@ -25,9 +25,8 @@ __all__ = ["DEFAULT_F32_DOT_ALLOWLIST", "check_dtype_policy"]
 # of keeping f32 around. Everything else must justify itself here.
 DEFAULT_F32_DOT_ALLOWLIST = (
     "llama_functional.py::parallel_cross_entropy",
-    "llama_functional.py::_ce_chunk_stats",
-    "llama_functional.py::_fused_ce_fwd",
-    "llama_functional.py::_fused_ce_bwd",
+    "llama_functional.py::_ce_logits",
+    "llama_functional.py::_ce_block_grads",
     "llama_functional.py::rms_norm",
     "llama_functional.py::apply_rope_bcast",
     "llama_functional.py::apply_rope",

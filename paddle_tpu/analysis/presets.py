@@ -122,6 +122,9 @@ def audit_fused_ce():
     out += buffer_audit.check_forbidden_shape(
         fused.jaxpr, fused.meta["forbidden_shape"], fused.name,
         "full-logits")
+    out += buffer_audit.check_forbidden_carry(
+        fused.jaxpr, fused.meta["forbidden_carry"], "float32", fused.name,
+        "head-gradient accumulator")
     _common(fused, out)
     return out
 

@@ -145,7 +145,8 @@ def fused_ce_programs():
         argnums=(0, 1)))(h, head)
     bsv = (b, s, args.vocab_size)
     return (AuditProgram("fused_ce_fwd_bwd", fused,
-                         meta={"forbidden_shape": bsv}),
+                         meta={"forbidden_shape": bsv,
+                               "forbidden_carry": head.shape}),
             AuditProgram("unchunked_ce_reference", ref,
                          meta={"forbidden_shape": bsv}))
 

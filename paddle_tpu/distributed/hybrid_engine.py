@@ -229,9 +229,9 @@ class HybridParallelEngine:
                     "scan form supports")
             self.unroll = unroll
         self.lr = lr
-        # sequence-chunked CE (single-device path only): the [b, s, vocab]
-        # f32 logits never materialize at once — vocab matmul + CE run per
-        # seq chunk with rematerialization (forward_and_loss loss_chunk)
+        # fused lm_head + CE on every path (lf.fused_linear_cross_entropy, a
+        # custom_vjp): the [b, s, vocab] logits never materialize; the live
+        # logits block is bounded at b * loss_chunk * vocab_local elements
         self.loss_chunk = loss_chunk
         # f32 master copies of the params inside the opt state (see
         # adamw_init); off by default — costs 4 bytes/param of HBM
@@ -1138,6 +1138,22 @@ class HybridParallelEngine:
         return loss, grads
 
     # -- public API ----------------------------------------------------------
+    def _record_ce_blocking(self, ids_shape):
+        """Gauges that say how the step being built blocks its fused CE.
+        The blocking is static (`lf.ce_blocking` of one device's
+        micro-batch), so it is a record, not a rate."""
+        if not self.loss_chunk:
+            return
+        b, s = ids_shape[1] // self.dp, ids_shape[2] // self.cp
+        tile, block, _ = lf.ce_blocking(
+            b, s, self.args.vocab_size // self.mp, self.loss_chunk)
+        for name, value in (
+                ("train.ce_token_tile", tile),
+                ("train.ce_vocab_block", block),
+                ("train.ce_head_grad_passes_per_microbatch", b * s // tile)):
+            self.monitor.registry.set_gauge(
+                name, value, labels={"source": self.monitor.source})
+
     def build_train_step(self):
         if self._train_step is not None:
             return self._train_step
@@ -1170,6 +1186,7 @@ class HybridParallelEngine:
             # (a cached call never re-enters the traced Python), so this
             # counter is precisely "train-step programs built"
             monitor.record_compile("train_step")
+            self._record_ce_blocking(ids.shape)
             loss, grads = shard_mapped(params, ids, labels)
             new_params, new_opt = adamw_update(params, grads, opt_state,
                                                lr=lr, moments=moments)

@@ -384,73 +384,102 @@ def parallel_cross_entropy(logits, labels, args: LlamaArgs, mp_axis=None,
     return jnp.mean(lse - true_logit)
 
 
-def _ce_chunk_stats(h_c, head, labels_c, inv_n, args: LlamaArgs, mp_axis,
-                    mp_degree):
-    """One sequence chunk's CE loss-sum AND input gradients, single pass.
+def ce_blocking(b, s, vocab_local, chunk):
+    """How the fused head + CE cuts its [b * s, vocab_local] logits:
+    (token tile T, vocab block Vb, number of vocab blocks).
 
-    The Liger-kernel observation: softmax-CE's logits gradient is the
-    closed form (softmax - onehot) / n, already known in forward. Computing
-    it here means backward never re-runs the [b, c, hidden] @ [hidden,
-    vocab] matmul and the full [b, s, vocab] tensor exists in no pass.
-
-    Returns (loss_sum f32 scalar over the chunk's tokens,
-             d_h_c [b, c, hidden] in h's dtype,
-             d_head_c [hidden, vocab_local] f32 — the LOCAL head shard's
-             grad under mp; vocab-sharded like the weight, no collective).
+    `chunk` bounds the live logits block as it always has: T * Vb is at
+    most b * chunk * vocab_local elements. Inside that budget the float32
+    accumulators' traffic picks T, a divisor of b * s: the hidden gradient
+    is read and written once a vocab block (ceil(vocab_local / Vb) passes
+    over b * s rows), a block of the head's gradient once a token tile
+    (b * s / T passes over vocab_local columns), `hidden` wide both. Ties
+    go to the wider tile. Vb is whole 128-lane groups where it holds one.
     """
-    logits = (h_c @ head).astype(jnp.float32)  # [b, c, vocab_local]
-    if mp_axis is None:
-        m = jnp.max(logits, axis=-1, keepdims=True)
-        e = jnp.exp(logits - m)
-        denom = jnp.sum(e, axis=-1, keepdims=True)
-        lse = jnp.log(denom[..., 0]) + m[..., 0]
-        true_logit = jnp.take_along_axis(
-            logits, labels_c[..., None], axis=-1)[..., 0]
-        iota = jax.lax.broadcasted_iota(labels_c.dtype, logits.shape, 2)
-        onehot = (labels_c[..., None] == iota).astype(jnp.float32)
-        d_logits = (e / denom - onehot) * inv_n
-    else:
-        per = args.vocab_size // mp_degree
-        rank = jax.lax.axis_index(mp_axis)
-        start = rank * per
-        m_local = jnp.max(logits, axis=-1, keepdims=True)
-        m = jax.lax.pmax(jax.lax.stop_gradient(m_local), mp_axis)
-        e = jnp.exp(logits - m)
-        denom = jax.lax.psum(jnp.sum(e, axis=-1, keepdims=True), mp_axis)
-        lse = jnp.log(denom[..., 0]) + m[..., 0]
-        local_lab = labels_c - start
-        valid = (local_lab >= 0) & (local_lab < per)
-        ll = jnp.clip(local_lab, 0, per - 1)
-        tl = jnp.take_along_axis(logits, ll[..., None], axis=-1)[..., 0]
-        true_logit = jax.lax.psum(jnp.where(valid, tl, 0.0), mp_axis)
-        iota = jax.lax.broadcasted_iota(ll.dtype, logits.shape, 2)
-        onehot = ((ll[..., None] == iota)
-                  & valid[..., None]).astype(jnp.float32)
-        d_logits = (e / denom - onehot) * inv_n
-    loss_sum = jnp.sum(lse - true_logit)
-    dl = d_logits.astype(h_c.dtype)
-    d_h = dl @ head.T  # [b, c, hidden]; partial over the local vocab shard
-    # the cotangent carries its primal's type: an h that is replicated over
-    # mp wants the shards' partials summed; an h typed VARYING over mp (the
-    # sequence-parallel all_gather's output) wants this rank's partial —
-    # its producer's transpose (psum_scatter) does the sum
-    if mp_axis is not None and mp_axis not in jax.typeof(h_c).vma:
-        d_h = jax.lax.psum(d_h, mp_axis)
-    d_head = jnp.einsum("bch,bcv->hv", h_c, dl,
+    n = b * s
+    budget = b * max(1, min(int(chunk), s)) * vocab_local
+    best = None
+    for k in range(1, n + 1):
+        if best is not None and k * vocab_local >= best[0]:
+            break
+        if n % k:
+            continue
+        vb = max(1, min(budget // (n // k), vocab_local))
+        if 128 <= vb < vocab_local:
+            vb -= vb % 128
+        nb = -(-vocab_local // vb)
+        cost = nb * n + k * vocab_local
+        if best is None or cost < best[0]:
+            best = (cost, n // k, vb, nb)
+    return best[1:]
+
+
+def _ce_logits(tile, block):
+    return (tile @ block).astype(jnp.float32)  # [.., T, Vb]
+
+
+def _label_hits(lab, col0, width):
+    """[.., T, width] mask of each token's true-label column inside the
+    block that starts at column `col0`; a label of another block (under mp:
+    of another shard) matches none."""
+    cols = jax.lax.broadcasted_iota(lab.dtype, lab.shape + (width,), lab.ndim)
+    return (lab - col0)[..., None] == cols
+
+
+def _ce_block_stats(stats, logits, lab, col0):
+    """Fold one [.., T, Vb] logits block into the running softmax
+    statistics: (max, sum of exponentials rescaled to that max, true-label
+    logit), three [.., T] float32 vectors."""
+    m, l, tl = stats
+    m_new = jnp.maximum(m, jnp.max(logits, axis=-1))
+    l = l * jnp.exp(m - m_new) + jnp.sum(
+        jnp.exp(logits - m_new[..., None]), axis=-1)
+    hits = _label_hits(lab, col0, logits.shape[-1])
+    return m_new, l, tl + jnp.sum(jnp.where(hits, logits, 0.0), axis=-1)
+
+
+def _ce_block_grads(logits, lse, lab, col0, tile, block, inv_n):
+    """One block's share of both gradients, float32 accumulation: the
+    softmax-CE logits gradient is the closed form (softmax - onehot) / n
+    (the Liger-kernel observation), so forward knows it once the
+    normaliser is known. Returns (tile^T @ d_logits [hidden, Vb],
+    d_logits @ block^T [.., T, hidden])."""
+    onehot = _label_hits(lab, col0, logits.shape[-1]).astype(jnp.float32)
+    dl = ((jnp.exp(logits - lse[..., None]) - onehot)
+          * inv_n).astype(tile.dtype)
+    d_block = jnp.einsum("...th,...tv->hv", tile, dl,
+                         preferred_element_type=jnp.float32)
+    d_tile = jnp.einsum("...tv,hv->...th", dl, block,
                         preferred_element_type=jnp.float32)
-    return loss_sum, d_h.astype(h_c.dtype), d_head
+    return d_block, d_tile
 
 
-def _zeros_of(fn, *operands):
-    """Zeros shaped AND typed like `fn(*operands)`: under
-    shard_map(check_vma=True) a scan's initial carry must carry exactly the
-    varying-mesh-axes type its body produces, so plain `jnp.zeros` only
-    works off the mesh."""
-    def zero(t):
-        z = jnp.zeros(t.shape, t.dtype)
-        return jax.lax.pcast(z, tuple(t.vma), to="varying") if t.vma else z
+def _zeros_varying_like(shape, *operands):
+    """float32 zeros that vary over every mesh axis one of `operands`
+    does: under shard_map(check_vma=True) a scan's initial carry must
+    carry exactly the varying-mesh-axes type its body produces, so plain
+    `jnp.zeros` only works off the mesh."""
+    z = jnp.zeros(shape, jnp.float32)
+    vma = frozenset().union(*(jax.typeof(x).vma for x in operands))
+    return jax.lax.pcast(z, tuple(vma), to="varying") if vma else z
 
-    return jax.tree.map(zero, jax.eval_shape(fn, *operands))
+
+def _over_blocks(fn, carry, head, vb):
+    """Fold `fn(carry, block, col0) -> (carry, y)` over the head's column
+    blocks [hidden, vb]; a short last block runs after the scan at its own
+    static width (no padding, no mask). Returns (carry, the full blocks'
+    ys stacked, the last block's y or None)."""
+    nfull, tail = divmod(head.shape[1], vb)
+
+    def body(c, j):
+        block = jax.lax.dynamic_slice_in_dim(head, j * vb, vb, axis=1)
+        return fn(c, block, j * vb)
+
+    carry, ys = jax.lax.scan(body, carry, jnp.arange(nfull))
+    y_tail = None
+    if tail:
+        carry, y_tail = fn(carry, head[:, nfull * vb:], nfull * vb)
+    return carry, ys, y_tail
 
 
 @jax.tree_util.register_static
@@ -459,49 +488,111 @@ class _MeshAxes(frozenset):
     static data."""
 
 
+def _ce_operands(h, head, labels, mp_axis, chunk):
+    """The epilogue's operands as its passes take them: h as token tiles
+    [n_tiles, T, hidden], the labels [n_tiles, T] counted from this vocab
+    shard's first column, and the vocab block's width."""
+    b, s, hidden = h.shape
+    t, vb, _ = ce_blocking(b, s, head.shape[1], chunk)
+    if mp_axis is not None:
+        labels = labels - jax.lax.axis_index(mp_axis) * head.shape[1]
+    return h.reshape(-1, t, hidden), labels.reshape(-1, t), vb
+
+
+@jax.named_scope("pt.ce_stats")
+def _ce_stats(tiles, labs, head, vb, mp_axis):
+    """Pass 1: every token's log-sum-exp and true-label logit ([n_tiles,
+    T] float32 each) from one sweep of the head's blocks. Under `mp_axis`
+    the shards' statistics meet once, after the sweep. Where one block is
+    the whole problem its logits are returned too, for pass 2 to keep."""
+    zero = _zeros_varying_like(labs.shape, tiles, labs, head)
+    stats = (zero - jnp.inf, zero, zero)
+    kept = None
+    if tiles.shape[0] == 1 and vb == head.shape[1]:
+        kept = _ce_logits(tiles, head)
+        stats = _ce_block_stats(stats, kept, labs, 0)
+    else:
+        def block_stats(stats, block, col0):
+            def tile_stats(_, x):
+                tile, lab, *st = x
+                return None, _ce_block_stats(st, _ce_logits(tile, block),
+                                             lab, col0)
+
+            return jax.lax.scan(tile_stats, None,
+                                (tiles, labs, *stats))[1], None
+
+        stats = _over_blocks(block_stats, stats, head, vb)[0]
+    m, l, tl = stats
+    if mp_axis is not None:
+        # the max is only a numerical shift
+        m_all = jax.lax.pmax(m, mp_axis)
+        l, tl = jax.lax.psum((l * jnp.exp(m - m_all), tl), mp_axis)
+        m = m_all
+    return jnp.log(l) + m, tl, kept
+
+
+@jax.named_scope("pt.ce_grads")
+def _ce_grads(tiles, labs, lse, head, vb, inv_n, kept):
+    """Pass 2: re-form each block's logits (or take the one `kept`) and
+    form both gradients. A block of the head's gradient is complete after
+    one sweep of the token tiles and is written once, in the head's dtype;
+    what is accumulated across blocks is the hidden gradient. Returns
+    (d_tiles [n_tiles, T, hidden] float32, partial over the local vocab
+    shard; d_head [hidden, vocab_local], vocab-sharded like the weight)."""
+    if kept is not None:
+        d_head, d_tiles = _ce_block_grads(kept, lse, labs, 0, tiles, head,
+                                          inv_n)
+        return d_tiles, d_head.astype(head.dtype)
+    operands = (tiles, labs, lse, head)
+
+    def block_grads(d_tiles, block, col0):
+        def tile_grads(d_block, x):
+            tile, lab, lse_t, d_tile = x
+            db, dt = _ce_block_grads(_ce_logits(tile, block), lse_t, lab,
+                                     col0, tile, block, inv_n)
+            return d_block + db, d_tile + dt
+
+        d_block, d_tiles = jax.lax.scan(
+            tile_grads, _zeros_varying_like(block.shape, *operands),
+            (tiles, labs, lse, d_tiles))
+        return d_tiles, d_block.astype(head.dtype)
+
+    d_tiles, d_blocks, d_tail = _over_blocks(
+        block_grads, _zeros_varying_like(tiles.shape, *operands), head, vb)
+    d_head = jnp.moveaxis(d_blocks, 0, 1).reshape(head.shape[0], -1)
+    if d_tail is not None:
+        d_head = jnp.concatenate([d_head, d_tail], axis=1)
+    return d_tiles, d_head
+
+
 @jax.named_scope("pt.ce_epilogue")
 def _fused_ce_loss_only(h, head, labels, args: LlamaArgs, mp_axis, mp_degree,
                         chunk):
-    """Primal (not-being-differentiated) path: stream loss only."""
-    b, s, _ = h.shape
-    chunk = max(1, min(int(chunk), s))
-    nfull, rem = s // chunk, s % chunk
-    hc = jnp.swapaxes(
-        h[:, :nfull * chunk].reshape(b, nfull, chunk, h.shape[-1]), 0, 1)
-    lc = jnp.swapaxes(
-        labels[:, :nfull * chunk].reshape(b, nfull, chunk), 0, 1)
-
-    def chunk_loss(h_c, l_c):
-        return parallel_cross_entropy(h_c @ head, l_c, args, mp_axis,
-                                      mp_degree) * (b * chunk)
-
-    def body(loss_sum, xs):
-        return loss_sum + chunk_loss(*xs), None
-
-    loss_sum, _ = jax.lax.scan(body, _zeros_of(chunk_loss, hc[0], lc[0]),
-                               (hc, lc))
-    if rem:
-        per_tok = parallel_cross_entropy(
-            h[:, nfull * chunk:] @ head, labels[:, nfull * chunk:], args,
-            mp_axis, mp_degree)
-        loss_sum = loss_sum + per_tok * (b * rem)
-    return loss_sum / (b * s)
+    """Primal (not-being-differentiated) path: pass 1 alone."""
+    tiles, labs, vb = _ce_operands(h, head, labels, mp_axis, chunk)
+    lse, true_logit, _ = _ce_stats(tiles, labs, head, vb, mp_axis)
+    return jnp.mean(lse - true_logit)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def fused_linear_cross_entropy(h, head, labels, args: LlamaArgs,
                                mp_axis=None, mp_degree=1, chunk=128):
-    """lm_head matmul + softmax CE, streamed over sequence chunks.
+    """lm_head matmul + softmax CE, in blocks over the VOCABULARY.
 
     Mean CE over all b*s tokens, numerically matching
     `parallel_cross_entropy(h @ head, labels, ...)` — but the [b, s, vocab]
-    logits never materialize in forward OR backward: forward computes each
-    chunk's loss and d(hidden)/d(head) in one pass (peak extra memory is
-    one [b, chunk, vocab] block + the stored d_h/d_head, vs. the remat
-    trick's full re-matmul in backward). Composes with the vocab-parallel
-    (mp_axis) path: softmax statistics psum over the shards, d_head stays
-    the local shard's grad. Any s, including s % chunk != 0 (remainder
-    handled as a final short chunk).
+    logits never materialize in forward OR backward. `chunk` bounds the
+    live logits block at b * chunk * vocab_local elements; `ce_blocking`
+    turns that budget into a token tile of T rows (the whole micro-batch
+    where it can be) and vocab blocks of Vb columns. Forward sweeps the
+    head's blocks twice: pass 1 finds every token's log-sum-exp, pass 2
+    re-forms a block's logits and forms d(hidden)/d(head) from the closed
+    form (softmax - onehot) / n, each block of d(head) once, so backward
+    only scales the stored gradients and no float32 [hidden, vocab] value
+    exists. Four head matmuls of T rows in the place of three of `chunk`
+    rows. Composes with the vocab-parallel (mp_axis) path: the shards'
+    softmax statistics meet in one pmax and one psum, d_head stays the
+    local shard's grad. Any b, s and chunk.
     """
     return _fused_ce_loss_only(h, head, labels, args, mp_axis, mp_degree,
                                chunk)
@@ -510,38 +601,20 @@ def fused_linear_cross_entropy(h, head, labels, args: LlamaArgs,
 @jax.named_scope("pt.ce_epilogue")
 def _fused_ce_fwd(h, head, labels, args: LlamaArgs, mp_axis, mp_degree,
                   chunk):
-    b, s, hidden = h.shape
-    chunk = max(1, min(int(chunk), s))
-    inv_n = 1.0 / (b * s)
-    nfull, rem = s // chunk, s % chunk
-    hc = jnp.swapaxes(
-        h[:, :nfull * chunk].reshape(b, nfull, chunk, hidden), 0, 1)
-    lc = jnp.swapaxes(
-        labels[:, :nfull * chunk].reshape(b, nfull, chunk), 0, 1)
-
-    def stats(h_c, l_c):
-        return _ce_chunk_stats(h_c, head, l_c, inv_n, args, mp_axis,
-                               mp_degree)
-
-    def body(carry, xs):
-        loss_sum, d_head = carry
-        ls, d_h_c, d_hd = stats(*xs)
-        return (loss_sum + ls, d_head + d_hd), d_h_c
-
-    ls0, _, d_head0 = _zeros_of(stats, hc[0], lc[0])
-    (loss_sum, d_head), d_h_chunks = jax.lax.scan(body, (ls0, d_head0),
-                                                  (hc, lc))
-    d_h = jnp.swapaxes(d_h_chunks, 0, 1).reshape(b, nfull * chunk, hidden)
-    if rem:
-        ls, d_h_r, d_hd = _ce_chunk_stats(
-            h[:, nfull * chunk:], head, labels[:, nfull * chunk:], inv_n,
-            args, mp_axis, mp_degree)
-        loss_sum = loss_sum + ls
-        d_head = d_head + d_hd
-        d_h = jnp.concatenate([d_h, d_h_r], axis=1)
-    res = (d_h, d_head.astype(head.dtype), labels,
+    tiles, labs, vb = _ce_operands(h, head, labels, mp_axis, chunk)
+    lse, true_logit, kept = _ce_stats(tiles, labs, head, vb, mp_axis)
+    d_tiles, d_head = _ce_grads(tiles, labs, lse, head, vb,
+                                1.0 / labels.size, kept)
+    d_h = d_tiles.astype(h.dtype).reshape(h.shape)
+    # the cotangent carries its primal's type: an h that is replicated over
+    # mp wants the shards' partials summed; an h typed VARYING over mp (the
+    # sequence-parallel all_gather's output) wants this rank's partial —
+    # its producer's transpose (psum_scatter) does the sum
+    if mp_axis is not None and mp_axis not in jax.typeof(h).vma:
+        d_h = jax.lax.psum(d_h, mp_axis)
+    res = (d_h, d_head, labels,
            _MeshAxes(jax.typeof(h).vma), _MeshAxes(jax.typeof(head).vma))
-    return loss_sum * jnp.float32(inv_n), res
+    return jnp.mean(lse - true_logit), res
 
 
 @jax.named_scope("pt.ce_epilogue")
@@ -575,11 +648,12 @@ def forward(params, ids, args: LlamaArgs, mp_axis=None, mp_degree=1, sp=False,
 def forward_and_loss(params, ids, labels, args: LlamaArgs, mp_axis=None,
                      mp_degree=1, sp=False, remat=True, loss_chunk=None,
                      unroll=False):
-    """loss_chunk: fused sequence-chunked lm_head + CE
-    (`fused_linear_cross_entropy`) — the [b, s, vocab] logits never
-    materialize in forward or backward (peak memory drops by ~s/chunk) and
-    backward re-runs no vocab matmul. Works on the vocab-parallel
-    (mp_axis) path too, and for any s (remainder chunks included)."""
+    """loss_chunk: fused lm_head + CE (`fused_linear_cross_entropy`) — the
+    [b, s, vocab] logits never materialize in forward or backward: the
+    live logits block holds b * loss_chunk * vocab_local elements (peak
+    memory drops by ~s/loss_chunk) and backward re-runs no vocab matmul.
+    Works on the vocab-parallel (mp_axis) path too, for any b, s and
+    loss_chunk."""
     if loss_chunk:
         h = forward_hidden(params, ids, args, mp_axis, mp_degree, sp, remat,
                            unroll=unroll)

@@ -1,17 +1,22 @@
-"""Fused chunked linear + cross-entropy (models/llama_functional.py).
+"""Fused linear + cross-entropy (models/llama_functional.py).
 
-The `loss_chunk` path used to be a remat trick around full-vocab logits;
-it is now a custom_vjp that streams [b, chunk, vocab] tiles and stores
-d(hidden)/d(lm_head) as forward residuals, so the [b, s, vocab] logits
-tensor never exists in forward OR backward and the backward never
-re-runs the vocab matmul. These tests pin:
+`fused_linear_cross_entropy` is a custom_vjp that works on [T, Vb] blocks
+of the logits: a token tile of T rows (the whole micro-batch where the
+budget allows) against a block of Vb vocabulary columns, T * Vb bounded by
+b * chunk * vocab_local (`ce_blocking`). Forward sweeps the head's blocks
+twice (pass 1: every token's log-sum-exp; pass 2: the block's logits
+re-formed, both gradients formed) and stores d(hidden)/d(lm_head) as
+residuals, so the [b, s, vocab] logits exist in no pass, each block of the
+head's gradient is formed and written once, and backward only scales.
+These tests pin:
 
-- loss parity vs the unchunked `parallel_cross_entropy` reference
-  (f32 exact-ish, bf16 loose), any chunk size incl. s % chunk != 0;
-- gradient parity vs jax autodiff of the unchunked composite, plus the
-  OpTest-style central finite-difference probe check;
-- the memory claim itself: no [b, s, vocab]-shaped intermediate in the
-  fwd+bwd jaxpr (the CPU-verifiable form of the HLO evidence);
+- loss parity vs the unblocked `parallel_cross_entropy` reference
+  (f32 exact-ish, bf16 loose) and gradient parity vs jax autodiff of the
+  unblocked composite, over every kind of blocking the code can reach
+  (`BLOCKINGS`), plus the OpTest-style central finite-difference probe;
+- the memory claims themselves, in the fwd+bwd jaxpr (the CPU-verifiable
+  form of the HLO evidence): no [b, s, vocab] value, no float32 [hidden,
+  vocab_local] loop carry, nothing but d_head above the chunk's budget;
 - the vocab-parallel regression: mp_axis used to be silently ignored by
   the chunked path (head sharded over 'mp' gave a local-shard loss);
   fused CE under shard_map must match the unsharded reference with
@@ -46,21 +51,64 @@ def _ref_loss(h, head, labels):
     return lf.parallel_cross_entropy(logits, labels, ARGS, None, 1)
 
 
+# (s, chunk) -> what `ce_blocking(2, s, 160, chunk)` makes of it: every kind
+# of blocking the code can reach at the tests' sizes
+BLOCKINGS = {
+    (24, 24): (48, 160, 1),   # one block holds the vocabulary: one pass
+    (24, 64): (48, 160, 1),   # chunk > s
+    (24, 12): (48, 80, 2),    # even blocks
+    (24, 6): (48, 40, 4),
+    (24, 8): (48, 53, 4),     # a short last block (of ONE column)
+    (24, 13): (48, 86, 2),    # s % chunk != 0, short last block
+    (21, 8): (42, 60, 3),     # s % chunk != 0
+    (24, 2): (24, 26, 7),     # a token tile smaller than b * s
+    (24, 1): (16, 20, 8),     # three token tiles, even blocks
+}
+
+
+@pytest.mark.parametrize("case", sorted(BLOCKINGS))
+def test_blockings_under_test(case):
+    """The parity cases below are the blockings they claim to be."""
+    s, chunk = case
+    assert lf.ce_blocking(2, s, ARGS.vocab_size, chunk) == BLOCKINGS[case]
+
+
+@pytest.mark.parametrize("b,s,vocab,chunk", [
+    (1, 4096, 92544, 128), (2, 4096, 46272, 128), (8, 1024, 32000, 128),
+    (1, 32768, 32000, 128), (2, 24, 160, 8), (3, 7, 11, 2), (1, 1, 5, 128)])
+def test_blocking_keeps_the_chunks_budget(b, s, vocab, chunk):
+    """T divides b * s, the blocks cover the vocabulary, a block is whole
+    lanes where it holds one, and the live block is never above
+    b * chunk * vocab elements (`loss_chunk`'s meaning since it exists)."""
+    t, vb, nb = lf.ce_blocking(b, s, vocab, chunk)
+    assert (b * s) % t == 0 and 1 <= vb <= vocab
+    assert nb == -(-vocab // vb)
+    assert t * vb <= b * min(chunk, s) * vocab
+    assert vb % 128 == 0 or vb < 128 or vb == vocab
+
+
+def test_blocking_of_the_training_cell():
+    """internlm2 at 1 x 4,096: the micro-batch is one tile, so each block
+    of the head's gradient is formed once a micro-batch (the 128-token
+    chunks formed all of it 32 times)."""
+    assert lf.ce_blocking(1, 4096, 92544, 128) == (4096, 2816, 33)
+
+
 class TestFusedCEParity:
-    @pytest.mark.parametrize("chunk", [8, 13, 24, 64])
-    def test_loss_matches_unchunked_f32(self, chunk):
-        """Any chunk size, including odd remainders (24 % 13 = 11) and
-        chunk > s."""
-        h, head, labels = _inputs()
+    @pytest.mark.parametrize("s,chunk", sorted(BLOCKINGS))
+    def test_loss_matches_unchunked_f32(self, s, chunk):
+        """Any blocking: one block, even blocks, a short last block,
+        several token tiles, odd remainders (24 % 13 = 11), chunk > s."""
+        h, head, labels = _inputs(s=s)
         ref = _ref_loss(h, head, labels)
         got = lf.fused_linear_cross_entropy(h, head, labels, ARGS,
                                             None, 1, chunk)
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    rtol=1e-6, atol=1e-6)
 
-    @pytest.mark.parametrize("chunk", [8, 13])
-    def test_grads_match_autodiff_f32(self, chunk):
-        h, head, labels = _inputs()
+    @pytest.mark.parametrize("s,chunk", sorted(BLOCKINGS))
+    def test_grads_match_autodiff_f32(self, s, chunk):
+        h, head, labels = _inputs(s=s)
         ref_dh, ref_dw = jax.grad(_ref_loss, argnums=(0, 1))(h, head, labels)
         dh, dw = jax.grad(
             lambda a, w: lf.fused_linear_cross_entropy(
@@ -131,25 +179,31 @@ class TestFusedCEParity:
                                    rtol=1e-6, atol=1e-6)
 
 
+def _fused_jaxpr(b, s, chunk):
+    h, head, labels = _inputs(b=b, s=s)
+    return jax.make_jaxpr(jax.value_and_grad(
+        lambda a, w: lf.fused_linear_cross_entropy(
+            a, w, labels, ARGS, None, 1, chunk), argnums=(0, 1)))(h, head)
+
+
 class TestNoLogitsBuffer:
     def test_no_full_logits_intermediate_in_jaxpr(self):
         """The acceptance claim, in its CPU-checkable form: the fwd+bwd
         jaxpr of the fused loss contains NO [b, s, vocab] value anywhere
-        (the scan works on [b, chunk, vocab] tiles) — checked with the
-        shared analysis walker, which descends into custom_vjp/scan/
-        shard_map subjaxprs. The unchunked reference trips this check,
-        proving the probe has teeth."""
+        (both passes work on [T, Vb] blocks: a token tile against a block
+        of the vocabulary) — checked with the shared analysis walker, which
+        descends into custom_vjp/scan/shard_map subjaxprs. The unblocked
+        reference trips this check, proving the probe has teeth."""
         from paddle_tpu.analysis import buffer_audit
 
         b, s = 2, 64
         h, head, labels = _inputs(b=b, s=s)
 
         bsv = (b, s, ARGS.vocab_size)
-        fused = jax.make_jaxpr(jax.value_and_grad(
-            lambda a, w: lf.fused_linear_cross_entropy(
-                a, w, labels, ARGS, None, 1, 16), argnums=(0, 1)))(h, head)
+        fused = _fused_jaxpr(b, s, 16)
         assert not buffer_audit.has_shape(fused, bsv), \
             "fused CE materialized a [b, s, vocab] buffer"
+        assert not buffer_audit.has_shape(fused, (b * s, ARGS.vocab_size))
 
         ref = jax.make_jaxpr(jax.value_and_grad(
             lambda a, w: _ref_loss(a, w, labels), argnums=(0, 1)))(h, head)
@@ -159,6 +213,55 @@ class TestNoLogitsBuffer:
         v = buffer_audit.check_forbidden_shape(ref, bsv, "unchunked_ref",
                                                "full-logits")
         assert v and all(x.rule == "buffer.forbidden-shape" for x in v)
+
+    @pytest.mark.parametrize("s,chunk", [(24, 8), (24, 13), (24, 2)])
+    def test_no_f32_head_gradient_is_a_loop_carry(self, s, chunk):
+        """A loop's carry is read and written every iteration: the head's
+        gradient accumulated in float32 across a scan is [hidden,
+        vocab_local] of traffic a trip. Each block of it is formed once
+        and leaves the scan as an output. The design this replaced (a scan
+        over token chunks that adds `bch,bcv->hv` to its carry) trips the
+        probe: its teeth."""
+        from paddle_tpu.analysis import buffer_audit
+
+        hv = (ARGS.hidden_size, ARGS.vocab_size)
+        assert buffer_audit.check_forbidden_carry(
+            _fused_jaxpr(2, s, chunk), hv, "float32", "fused") == []
+
+        h, head, labels = _inputs(s=s)
+
+        def chunk_scan(h, head):
+            def body(d_head, h_c):
+                dl = jax.nn.softmax(h_c @ head)
+                return d_head + jnp.einsum("bch,bcv->hv", h_c, dl), None
+
+            return jax.lax.scan(body, jnp.zeros(hv, jnp.float32),
+                                jnp.swapaxes(h.reshape(2, -1, 8, hv[0]),
+                                             0, 1))[0]
+
+        v = buffer_audit.check_forbidden_carry(
+            jax.make_jaxpr(chunk_scan)(h, head), hv, "float32", "chunks")
+        assert v and v[0].rule == "buffer.forbidden-carry"
+
+    @pytest.mark.parametrize("s,chunk", [(24, 8), (24, 13), (21, 8)])
+    def test_nothing_but_d_head_is_above_the_chunks_budget(self, s, chunk):
+        """`chunk` bounds what is live: no value the fwd+bwd program writes
+        holds more than b * chunk * vocab elements, but the head's
+        gradient in its forms (a block, the blocks as the scan stacks them,
+        the same re-laid as [hidden, vocab]). At sizes where the hidden states
+        themselves are under that budget."""
+        from paddle_tpu.analysis import buffer_audit
+
+        b, hidden, vocab = 2, ARGS.hidden_size, ARGS.vocab_size
+        budget = b * chunk * vocab
+        _, vb, nb = lf.ce_blocking(b, s, vocab, chunk)
+        nfull = vocab // vb
+        d_head_forms = {(hidden, vb), (nfull, hidden, vb), (hidden, nfull, vb),
+                        (hidden, nfull * vb), (hidden, vocab)}
+        big = {tuple(aval.shape) for _, aval, _, _ in
+               buffer_audit.intermediates(_fused_jaxpr(b, s, chunk))
+               if int(np.prod(aval.shape)) > budget}
+        assert big and big <= d_head_forms, big - d_head_forms
 
 
 class TestVocabParallel:
@@ -187,8 +290,11 @@ class TestVocabParallel:
             check_vma=check_vma)(h, head, labels)
         return (h, head, labels), loss, dh, dw
 
-    @pytest.mark.parametrize("chunk,check_vma", [(8, False), (13, False),
-                                                 (8, True)])
+    # ce_blocking(2, 24, 80, chunk): 24 -> (48, 80, 1) one pass; 12 -> (48,
+    # 40, 2) even blocks; 13 -> (48, 43, 2) short last block; 8 -> (24, 53,
+    # 2) two token tiles; 1 -> (16, 10, 8) three
+    @pytest.mark.parametrize("check_vma", [False, True])
+    @pytest.mark.parametrize("chunk", [1, 8, 12, 13, 24])
     def test_matches_unsharded_reference(self, chunk, check_vma):
         (h, head, labels), loss, dh, dw = self._sharded(
             chunk, check_vma=check_vma)
@@ -268,8 +374,9 @@ class TestVocabParallel:
 
 class TestScopeNames:
     """`pt.ce_epilogue` names the fused epilogue's operations in the forward
-    rule, the backward rule and the chunk scan's body, and the unfused
-    path's cross entropy (PERF.md section 3)."""
+    rule, the backward rule and the block scans' bodies, and the unfused
+    path's cross entropy; `pt.ce_stats` / `pt.ce_grads` inside it split the
+    forward rule into its two passes (PERF.md section 3)."""
 
     @pytest.mark.parametrize("path", ["fused_grad", "fused_primal",
                                       "unfused"])
@@ -286,8 +393,12 @@ class TestScopeNames:
                 h @ w, labels, ARGS)
         text = jax.jit(fn).lower(h, head).as_text(debug_info=True)
         if path == "fused_grad":
-            # the scan body's matmuls and the backward rule's scaling
-            assert "jvp(pt.ce_epilogue)/while/body" in text
+            # the two passes' scan bodies and the backward rule's scaling
+            assert "jvp(pt.ce_epilogue)/pt.ce_stats/while/body" in text
+            assert "jvp(pt.ce_epilogue)/pt.ce_grads/while/body" in text
             assert "transpose(jvp(pt.ce_epilogue))/mul" in text
+        elif path == "fused_primal":
+            assert "pt.ce_epilogue/pt.ce_stats/while/body" in text
+            assert "pt.ce_grads" not in text
         else:
             assert "pt.ce_epilogue/" in text

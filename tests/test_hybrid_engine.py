@@ -613,6 +613,35 @@ def test_trivial_fast_path_loss_chunk_parity():
     np.testing.assert_allclose(float(l1), float(l2), rtol=1e-5)
 
 
+def test_step_records_its_ce_blocking_at_the_training_cells_shapes():
+    """The blocking of the fused CE is static, so the step being built
+    records it: at `train_internlm2_s4096`'s shapes (micro-batches of 1 x
+    4,096 at hidden 2,048 and 92,544 rows, loss_chunk 128) the token tile
+    is the micro-batch, so each block of the head's gradient is formed
+    once a micro-batch. Traced from shapes: nothing of that size is built."""
+    from paddle_tpu.observability import TrainingMonitor
+    from paddle_tpu.observability.registry import MetricsRegistry
+
+    cfg = LlamaConfig.tiny(
+        num_hidden_layers=1, hidden_size=2048, intermediate_size=8192,
+        num_attention_heads=16, num_key_value_heads=8, vocab_size=92544,
+        max_position_embeddings=4096, use_flash_attention=False)
+    reg = MetricsRegistry()
+    eng = HybridParallelEngine(
+        cfg, dp=1, pp=1, mp=1, micro_batches=4, dtype=jnp.bfloat16,
+        remat=False, loss_chunk=128,
+        monitor=TrainingMonitor(reg, source="cell", nan_action="none"))
+    ids = jax.ShapeDtypeStruct((4, 1, 4096), jnp.int32)
+    eng.build_train_step().trace(*jax.eval_shape(eng.init_state, 0), ids, ids)
+    tile, block, _ = lf.ce_blocking(1, 4096, 92544, 128)
+    got = {name: reg.gauge(f"train.{name}", {"source": "cell"}) for name in (
+        "ce_token_tile", "ce_vocab_block",
+        "ce_head_grad_passes_per_microbatch")}
+    assert got == {"ce_token_tile": tile, "ce_vocab_block": block,
+                   "ce_head_grad_passes_per_microbatch": 1}
+    assert (tile, block) == (4096, 2816)
+
+
 # -- memory-lean optimizer-state modes (moments='bf16'/'factored') -----------
 
 
