@@ -79,6 +79,12 @@ BYTE_CEILINGS = {
     "paged_decode": 26 * 1024,
     "spec_verify": 26 * 1024,
     "page_copy": 26 * 1024,
+    # the latent-attention expert family: the largest buffer is the pool
+    # itself (108K at the toy size, its rows padded to a lane tile), which
+    # every program carries through and none copies
+    "latent_prefill": 152 * 1024,
+    "latent_decode": 152 * 1024,
+    "latent_page_copy": 152 * 1024,
     # int8 pool: the pool buffers shrink 2-4x but the prefill gather
     # dequantizes pages to f32 before attention, so the ceilings stay at
     # the model-dtype budget rather than scaling with the pool
@@ -170,6 +176,45 @@ def audit_serving(tp=2):
     return out
 
 
+LATENT_SERVING = ("latent_prefill", "latent_decode", "latent_page_copy")
+
+
+def audit_latent_serving():
+    """The latent-attention expert family's step programs: the pool donated
+    and aliased, no collective (the family has no mesh), no host callback
+    (the routing counts ride the tokens' output), a byte ceiling,
+    and logits for the head's rows alone: the prefill window forms none for
+    its other rows, the decode step one `[slots, vocab]` and no second."""
+    progs = programs.latent_serving_programs()
+    out = []
+    from paddle_tpu.analysis.base import Violation
+    for name in sorted(set(LATENT_SERVING) - set(progs)):
+        out.append(Violation(
+            rule="audit.program-not-captured", program=name,
+            message="latent serving program was never dispatched/captured "
+                    "— scheduler or capture-harness change?"))
+    for name, p in sorted(progs.items()):
+        out += collective_audit.check_collectives(
+            p.jaxpr, name, expect_count=0, expect_fingerprint=_EMPTY_FP)
+        _donation(p, out)
+        _common(p, out)
+    if "latent_prefill" in progs:
+        p = progs["latent_prefill"]
+        out += buffer_audit.check_forbidden_shape(
+            p.jaxpr, (p.meta["bucket"], p.meta["vocab"]), p.name,
+            "logits of a whole window")
+    if "latent_decode" in progs:
+        p = progs["latent_decode"]
+        rows = [eqn for _, aval, eqn, _ in buffer_audit.intermediates(p.jaxpr)
+                if tuple(aval.shape) == (p.meta["slots"], p.meta["vocab"])]
+        if len(rows) > 2:     # the head's matmul and its cast to float32
+            out.append(Violation(
+                rule="buffer.forbidden-shape", program=p.name,
+                message=f"{len(rows)} [slots, vocab] intermediates in the "
+                        "decode step: the head makes one (and casts it)"))
+    return out
+
+
 def audit_disagg():
     """The disaggregated-serving family: KV-page migration programs
     (model-dtype + int8 pools) and the router's GPT stripe programs —
@@ -193,7 +238,7 @@ def audit_disagg():
 
 
 def run_cpu_audits(families=("fused_ce", "train_step", "opt_writeback",
-                             "serving", "disagg")):
+                             "serving", "latent_serving", "disagg")):
     """Run every audit family; returns the full list of Violations
     (empty = the repo's compiled programs uphold every invariant)."""
     runners = {
@@ -201,6 +246,7 @@ def run_cpu_audits(families=("fused_ce", "train_step", "opt_writeback",
         "train_step": audit_train_step,
         "opt_writeback": audit_opt_writeback,
         "serving": audit_serving,
+        "latent_serving": audit_latent_serving,
         "disagg": audit_disagg,
     }
     out = []

@@ -29,7 +29,8 @@ import numpy as np
 
 __all__ = ["AuditProgram", "TOY", "toy_args", "fused_ce_programs",
            "train_step_program", "opt_writeback_program",
-           "serving_programs", "disagg_programs"]
+           "serving_programs", "latent_serving_programs",
+           "disagg_programs"]
 
 # one toy geometry for every family: 2 layers, divisible by a degree-2
 # TP mesh (heads, kv heads, intermediate), tiny enough that every build
@@ -301,6 +302,71 @@ def serving_programs(tp=2, num_heads=None):
             continue  # program never dispatched (scheduler change?)
         out[name] = _from_traced(name, traced, rec.args,
                                  donated=donated[name], meta=meta)
+    return out
+
+
+@functools.lru_cache(maxsize=None)
+def latent_serving_programs():
+    """The latent-attention expert family's step programs
+    (`serving/latent.py`), captured as `serving_programs` captures the
+    dense ones: a tiny stack (one dense leading layer, two expert layers,
+    one group of eight held) serves two requests, the second a prefix hit
+    that ends mid-page (so the page copy runs), and the recorded callables
+    are re-traced. Single chip: the family has no mesh. The pool is each
+    program's one donated argument."""
+    from paddle_tpu.models import latent_moe_functional as lm
+    from paddle_tpu.serving import PagedEngine, Request
+
+    args = lm.LatentMoEArgs(
+        vocab_size=96, hidden_size=32, num_layers=3, num_heads=2, q_rank=12,
+        kv_rank=16, nope_dim=8, rope_dim=4, v_dim=8, dense_intermediate=48,
+        expert_intermediate=16, shared_experts=2, routed_experts=32,
+        first_expert=4, experts_held=4, n_group=8, topk_group=3,
+        experts_per_tok=6, routed_scaling=16.0, first_k_dense=1,
+        rope_theta=10000.0, rms_eps=1e-6,
+        yarn=lm.YarnConfig(40.0, 64, 32.0, 1.0, 0.707, 0.707))
+    h, H, E = args.hidden_size, args.num_heads, args.experts_held
+    rng = np.random.default_rng(3)
+
+    def leaf(*shape):
+        return jnp.asarray(0.1 * rng.standard_normal(shape), jnp.float32)
+
+    def attention(n):
+        return {"ln1": jnp.ones((n, h)), "ln2": jnp.ones((n, h)),
+                "w_qa": leaf(n, h, 12), "q_norm": jnp.ones((n, 12)),
+                "w_qb": leaf(n, 12, H * 12), "w_kva": leaf(n, h, 20),
+                "kv_norm": jnp.ones((n, 16)), "w_kvb": leaf(n, 16, H * 16),
+                "wo": leaf(n, H * 8, h)}
+
+    params = {
+        "embedding": leaf(96, h), "final_norm": jnp.ones(h),
+        "lm_head": leaf(h, 96),
+        "dense_layers": dict(attention(1), w_gate=leaf(1, h, 48),
+                             w_up=leaf(1, h, 48), w_down=leaf(1, 48, h)),
+        "layers": dict(attention(2), router=leaf(2, h, 32),
+                       ws_gate=leaf(2, h, 32), ws_up=leaf(2, h, 32),
+                       ws_down=leaf(2, 32, h), we_gate=leaf(2, E, h, 16),
+                       we_up=leaf(2, E, h, 16), we_down=leaf(2, E, 16, h))}
+    eng = PagedEngine(params, args, max_slots=2, max_len=32, page_size=8,
+                      min_bucket=8, donate_steps=True)
+    path, recs = eng.path, {}
+    for name, table in (("latent_prefill", path._prefill),
+                        ("latent_decode", path._decode)):
+        recs[name] = table[False] = _Recorder(table[False])
+    recs["latent_page_copy"] = path._copy = _Recorder(path._copy)
+    donated = {"latent_prefill": (6,), "latent_decode": (5,),
+               "latent_page_copy": (0,)}
+    base = rng.integers(1, 96, size=12).astype(np.int32)
+    eng.serve([Request(base, max_new_tokens=3)])
+    eng.serve([Request(np.concatenate([base, base[:5]]), max_new_tokens=3)])
+    meta = {"tp": 0, "num_layers": args.num_layers, "vocab": args.vocab_size,
+            "slots": 2, "bucket": 16}
+    out = {}
+    for name, rec in recs.items():
+        traced = rec.trace()
+        if traced is not None:
+            out[name] = _from_traced(name, traced, rec.args,
+                                     donated=donated[name], meta=meta)
     return out
 
 

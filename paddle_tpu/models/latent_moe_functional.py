@@ -1,0 +1,455 @@
+"""A decoder with LATENT attention (MLA) and routed + shared experts, served
+over `PagedEngine`'s paged cache. `LatentMoEArgs` is the static description
+that selects this path: `PagedEngine(params, LatentMoEArgs(...))`.
+
+Every layer is `x += Attn(norm(x)); x += FFN(norm(x))`.
+
+  attention  low-rank queries (`c_q = norm(h W_qa)`, `q = c_q W_qb`, a head
+             is `[q_nope; q_pe]`) against ONE cached row a token and layer,
+             `[c_kv; k_pe]` = `kv_rank + rope_dim` values: the normed latent
+             and the rotary key all heads share (YaRN frequencies on the
+             rotary slice). A head's key is `[c_kv W_uk_h; k_pe]`, its
+             value `c_kv W_uv_h` (`W_kvb` split by head).
+             DECODE takes the ABSORBED form: `q_nope` is carried through
+             `W_uk` into the latent space, every head attends the cached
+             rows themselves (the value is a row's leading `kv_rank`
+             columns), and the result goes through `W_uv`
+             (`kernels/latent_attention.latent_decode_attention`).
+             PREFILL takes the DECOMPRESSED form: the keys and values of
+             the slot's context are rebuilt from its cached rows a block at
+             a time and the window attends them in blocks over the keys
+             (`latent_prefill_attention`). Rebuilding costs 2 * kv_rank *
+             (nope + v) flops a key and head once a window; the absorbed
+             form would pay (2 * kv_rank + rope) against (nope + rope + v)
+             multiply-adds a (query, key) pair and head, 1,088 against 320
+             at the published widths.
+  FFN        `first_k_dense` leading layers: a SwiGLU. The others: shared
+             experts (one SwiGLU of their summed width) plus routed
+             experts. The router scores ALL `n_routed_experts` (softmax,
+             float32), keeps the `topk_group` best of `n_group` groups by
+             each group's best score, picks `experts_per_tok` among what
+             stays (ties to the lower index) and weighs a pick by
+             `routed_scaling_factor` times its score. This program HOLDS
+             the experts `[first_expert, first_expert + experts_held)`: a
+             pick that lands elsewhere adds nothing here (its chip's part
+             of an expert-parallel layer), and no code stands in for the
+             absent chips. Picks are sorted by expert, those not held leave
+             the dispatch, and each projection is one grouped matmul
+             (`kernels/grouped_matmul`): no capacity, no dropped token.
+
+The parameter tree is `llama_functional`'s (`embedding`, `layers/*` stacked
+on a leading layer axis, `final_norm`, `lm_head`) with the expert layer's
+leaves `ln1 ln2 w_qa q_norm w_qb w_kva kv_norm w_kvb wo router ws_gate
+ws_up ws_down we_gate we_up we_down`; where `first_k_dense` > 0 a second
+stacked group `dense_layers/*` holds the leading layers (`w_gate w_up
+w_down` in the experts' place). The page pool `[layers * num_pages, page,
+row_width]` (a row: kv_rank + rope_dim values, padded to whole lane tiles)
+is the layer scans' carry: layer l's pages are the run
+that starts at `l * num_pages`, written and read where they lie.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import NamedTuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from paddle_tpu.kernels import grouped_matmul as gm
+from paddle_tpu.kernels import latent_attention as la
+from paddle_tpu.models import llama_functional as lf
+from paddle_tpu.models.generation import _wmm, _write_rows
+from paddle_tpu.models.hybrid_functional import _write_window_pages
+
+__all__ = ["YarnConfig", "LatentMoEArgs", "rope_tables", "softmax_scale",
+           "route", "prefill_window", "decode_step"]
+
+DECOMPRESS_BLOCK = 1024     # keys rebuilt at once in a prefill window
+
+
+class YarnConfig(NamedTuple):
+    factor: float
+    original_max_position: int
+    beta_fast: float
+    beta_slow: float
+    mscale: float
+    mscale_all_dim: float
+
+
+class LatentMoEArgs(NamedTuple):
+    """Static (hashable) description of the stack."""
+
+    vocab_size: int
+    hidden_size: int
+    num_layers: int             # dense leading layers + expert layers
+    num_heads: int
+    q_rank: int
+    kv_rank: int
+    nope_dim: int               # a head's query / key width without rotary
+    rope_dim: int               # the rotary slice all heads share
+    v_dim: int
+    dense_intermediate: int     # the leading dense layers' FFN width
+    expert_intermediate: int    # one routed expert's width
+    shared_experts: int         # shared experts, each of a routed one's width
+    routed_experts: int         # the router's width: every published expert
+    first_expert: int           # the experts held here are
+    experts_held: int           # [first_expert, first_expert + experts_held)
+    n_group: int
+    topk_group: int
+    experts_per_tok: int
+    routed_scaling: float
+    first_k_dense: int
+    rope_theta: float
+    rms_eps: float
+    yarn: YarnConfig
+    # the serving path keeps the experts every token picked, for whoever
+    # judges the served tokens (`serving/latent.py`, RoutingTrace)
+    record_routing: bool = False
+
+    @property
+    def row_width(self):
+        """A cached row's width: the latent and the rotary key, padded with
+        zeros to whole lane tiles (the TPU lays a 576-wide minor axis out
+        as 640 lanes whatever the array says: the pool says it too, so that
+        a page is a whole tile and the kernel may copy it)."""
+        return -(-(self.kv_rank + self.rope_dim) // 128) * 128
+
+    def validate(self):
+        if self.routed_experts % self.n_group:
+            raise ValueError("routed_experts must be a multiple of n_group")
+        if not 0 < self.topk_group <= self.n_group:
+            raise ValueError("topk_group must lie in [1, n_group]")
+        if self.experts_per_tok > (self.topk_group * self.routed_experts
+                                   // self.n_group):
+            raise ValueError("experts_per_tok exceeds the experts of the "
+                             "groups that stay")
+        if not (0 <= self.first_expert and 0 < self.experts_held
+                and self.first_expert + self.experts_held
+                <= self.routed_experts):
+            raise ValueError("the experts held must lie inside "
+                             "[0, routed_experts)")
+        if not 0 <= self.first_k_dense < self.num_layers:
+            raise ValueError("first_k_dense must leave an expert layer")
+        if self.rope_dim % 2:
+            raise ValueError("rope_dim must be even")
+
+
+# ---------------------------------------------------------------------------
+# rotary positions (YaRN) and the attention scale
+# ---------------------------------------------------------------------------
+
+def _yarn_mscale(factor, mscale):
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(args):
+    """The rotary slice's frequencies [rope_dim / 2]: theta^(-2j/d) blended
+    with the same over `factor` by a linear ramp between the dimensions
+    that make `beta_fast` and `beta_slow` rotations over the original
+    context (the published `DeepseekV2YarnRotaryEmbedding`)."""
+    y, d, base = args.yarn, args.rope_dim, args.rope_theta
+    j = np.arange(0, d, 2, dtype=np.float64) / d
+    extra, inter = 1.0 / base ** j, 1.0 / (y.factor * base ** j)
+
+    def correction(rotations):
+        return (d * math.log(y.original_max_position
+                             / (rotations * 2 * math.pi))
+                / (2 * math.log(base)))
+
+    low = max(math.floor(correction(y.beta_fast)), 0)
+    high = min(math.ceil(correction(y.beta_slow)), d - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(d // 2) - low) / (high - low), 0.0, 1.0)
+    return inter * ramp + extra * (1.0 - ramp)
+
+
+def rope_tables(seq_len, args):
+    """cos, sin [seq_len, rope_dim] float32 (both halves alike, the
+    rotate-half convention of `lf.apply_rope_bcast`), times YaRN's
+    cos / sin multiplier mscale(factor, mscale) / mscale(factor,
+    mscale_all_dim)."""
+    freqs = np.outer(np.arange(seq_len, dtype=np.float64),
+                     yarn_inv_freq(args))
+    emb = np.concatenate([freqs, freqs], axis=-1)
+    m = (_yarn_mscale(args.yarn.factor, args.yarn.mscale)
+         / _yarn_mscale(args.yarn.factor, args.yarn.mscale_all_dim))
+    return (jnp.asarray(np.cos(emb) * m, jnp.float32),
+            jnp.asarray(np.sin(emb) * m, jnp.float32))
+
+
+def softmax_scale(args):
+    """(nope + rope)^-1/2 times YaRN's mscale(factor, mscale_all_dim)^2."""
+    m = _yarn_mscale(args.yarn.factor, args.yarn.mscale_all_dim) \
+        if args.yarn.mscale_all_dim else 1.0
+    return (args.nope_dim + args.rope_dim) ** -0.5 * m * m
+
+
+# ---------------------------------------------------------------------------
+# attention: the projections around the two cores
+# ---------------------------------------------------------------------------
+
+def _queries_and_row(lp, hin, cos, sin, args):
+    """hin [n, h] at rotary rows cos, sin [n, rope_dim] -> q_nope [n, H,
+    nope], q_pe [n, H, rope] (rotated) and the row to cache [n, row_width]:
+    the normed latent, the rotated shared key, zeros."""
+    n, H = hin.shape[0], args.num_heads
+    c_q = lf.rms_norm(_wmm(hin, lp["w_qa"]), lp["q_norm"], args.rms_eps)
+    q = _wmm(c_q, lp["w_qb"]).reshape(n, H, args.nope_dim + args.rope_dim)
+    q_nope, q_pe = q[..., :args.nope_dim], q[..., args.nope_dim:]
+    kv = _wmm(hin, lp["w_kva"])
+    c_kv = lf.rms_norm(kv[:, :args.kv_rank], lp["kv_norm"], args.rms_eps)
+    q_pe, k_pe = lf.apply_rope_bcast(
+        q_pe, kv[:, None, args.kv_rank:], cos[:, None, :], sin[:, None, :])
+    pad = jnp.zeros((n, args.row_width - args.kv_rank - args.rope_dim),
+                    c_kv.dtype)
+    return q_nope, q_pe, jnp.concatenate([c_kv, k_pe[:, 0], pad], axis=-1)
+
+
+def _w_kvb_by_head(lp, args):
+    """W_kvb [kv_rank, H, nope + v]: a head's key and value maps."""
+    return lp["w_kvb"].reshape(args.kv_rank, args.num_heads,
+                               args.nope_dim + args.v_dim)
+
+
+def _decode_attention(lp, x, pool, bt, pos, cos, sin, base, args):
+    """x [b, h], one token a row at positions pos [b] -> (x + attention,
+    pool). The absorbed form over the rows' pages."""
+    ps = pool.shape[1]
+    hin = lf.rms_norm(x, lp["ln1"], args.rms_eps)
+    with jax.named_scope("pt.attention"):
+        q_nope, q_pe, row = _queries_and_row(lp, hin, cos[pos], sin[pos],
+                                             args)
+        w = _w_kvb_by_head(lp, args)
+        q_lat = jnp.einsum("bhn,chn->bhc", q_nope, w[..., :args.nope_dim])
+        pad = jnp.zeros(q_pe.shape[:2] + (row.shape[1] - args.kv_rank
+                                          - args.rope_dim,), q_pe.dtype)
+        q = jnp.concatenate([q_lat, q_pe, pad], axis=-1)   # [b, H, row]
+    # write before attending; a row that does not decode has a table of
+    # null pages, the layer's garbage sink
+    page = base + jnp.take_along_axis(bt, (pos // ps)[:, None], axis=1)[:, 0]
+    pool = _write_rows(pool[:, None], row[:, None, :], page, pos % ps)[:, 0]
+    with jax.named_scope("pt.latent_attention"):
+        o_lat = la.latent_decode_attention(
+            q, pool, bt, pos, softmax_scale(args), args.kv_rank,
+            page_base=base)                                # [b, H, kv_rank]
+    with jax.named_scope("pt.attention"):
+        o = jnp.einsum("bhc,chv->bhv", o_lat, w[..., args.nope_dim:])
+        return x + _wmm(o.reshape(x.shape[0], -1), lp["wo"]), pool
+
+
+@jax.named_scope("pt.attention")
+def _decompress(lp, pool, bt_row, base, n_keys, args):
+    """The keys and values of the slot's first `n_keys` positions, rebuilt
+    from its cached rows a block of DECOMPRESS_BLOCK keys at a time (the
+    loop's trip count follows the traced context, not the table's width):
+    kv [T, H * (nope + v)], a head's key then its value, and the shared
+    rotary keys k_pe [T, rope_dim], T the table's positions; rows past the
+    last block rebuilt are zero."""
+    ps, P = pool.shape[1], bt_row.shape[0]
+    ppb = max(1, min(DECOMPRESS_BLOCK // ps, P))
+    n_blocks = -(-P // ppb)
+    table = jnp.zeros(n_blocks * ppb, jnp.int32).at[:P].set(bt_row)
+    width = args.num_heads * (args.nope_dim + args.v_dim)
+    kv = jnp.zeros((n_blocks * ppb * ps, width), pool.dtype)
+    k_pe = jnp.zeros((n_blocks * ppb * ps, args.rope_dim), pool.dtype)
+
+    def body(i, carry):
+        kv, k_pe = carry
+        pages = base + jax.lax.dynamic_slice_in_dim(table, i * ppb, ppb)
+        rows = pool[pages].reshape(ppb * ps, -1)
+        kv = jax.lax.dynamic_update_slice_in_dim(
+            kv, _wmm(rows[:, :args.kv_rank], lp["w_kvb"]), i * ppb * ps, 0)
+        k_pe = jax.lax.dynamic_update_slice_in_dim(
+            k_pe, rows[:, args.kv_rank:args.kv_rank + args.rope_dim],
+            i * ppb * ps, 0)
+        return kv, k_pe
+
+    live = jnp.minimum(-(-n_keys // (ppb * ps)), n_blocks)
+    return jax.lax.fori_loop(0, live, body, (kv, k_pe))
+
+
+def _window_attention(lp, x, pool, h, last_idx, bt_row, new_pages, cos, sin,
+                      base, args):
+    """x [s, h], a window of one slot at positions h .. h + s - 1, real up
+    to `last_idx` -> (x + attention, pool). The decompressed form."""
+    s, ps = x.shape[0], pool.shape[1]
+    hin = lf.rms_norm(x, lp["ln1"], args.rms_eps)
+    pos = h + jnp.arange(s, dtype=jnp.int32)
+    with jax.named_scope("pt.attention"):
+        q_nope, q_pe, row = _queries_and_row(lp, hin, cos[pos], sin[pos],
+                                             args)
+    pool = _write_window_pages(pool[:, None], row[:, None, :], h,
+                               base + bt_row, base + new_pages, ps)[:, 0]
+    kv, k_pe = _decompress(lp, pool, bt_row, base, h + last_idx + 1, args)
+    with jax.named_scope("pt.latent_attention"):
+        o = la.latent_prefill_attention(
+            q_nope, q_pe, kv, k_pe, h, last_idx, softmax_scale(args),
+            args.v_dim)                                    # [s, H, v]
+    with jax.named_scope("pt.attention"):
+        return x + _wmm(o.reshape(s, -1), lp["wo"]), pool
+
+
+# ---------------------------------------------------------------------------
+# the feed-forward half: a SwiGLU, or shared + routed experts
+# ---------------------------------------------------------------------------
+
+def _swiglu(x, w_gate, w_up, w_down):
+    return _wmm(jax.nn.silu(_wmm(x, w_gate)) * _wmm(x, w_up), w_down)
+
+
+def route(logits, args):
+    """Router logits [n, routed_experts] (float32) -> (experts [n, k] int32,
+    weights [n, k] float32): group-limited greedy routing. A group scores
+    the best of its experts; the `topk_group` best groups stay; the
+    `experts_per_tok` best experts among them are picked, each weighing
+    `routed_scaling` times its softmax score (not renormalised). Ties go to
+    the lower index (`jax.lax.top_k`)."""
+    n = logits.shape[0]
+    scores = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    per = args.routed_experts // args.n_group
+    group_best = jnp.max(scores.reshape(n, args.n_group, per), axis=-1)
+    kept = jax.lax.top_k(group_best, args.topk_group)[1]          # [n, g]
+    stays = jnp.any(jax.nn.one_hot(kept, args.n_group, dtype=bool), axis=1)
+    masked = jnp.where(jnp.repeat(stays, per, axis=1), scores, 0.0)
+    w, experts = jax.lax.top_k(masked, args.experts_per_tok)
+    return experts.astype(jnp.int32), w * args.routed_scaling
+
+
+def _routed_experts(lp, stack, first, hin, live, args):
+    """hin [n, h] -> (the held experts' part of the routed sum [n, h],
+    counts int32 [4]: tokens at the busiest held expert, picks that landed
+    on a held expert, picks in all, held experts with a token; rows where
+    `live` is False count for nothing; the experts every row picked [n, k],
+    of all the published ones). `stack`: the `we_*` leaves of the
+    WHOLE stack viewed [layers * held, ..], of which this layer's start at
+    group `first` (see `kernels/grouped_matmul`)."""
+    n, k, E = hin.shape[0], args.experts_per_tok, args.experts_held
+    with jax.named_scope("pt.moe_route"):
+        logits = jnp.matmul(hin.astype(jnp.float32),
+                            lp["router"].astype(jnp.float32))
+        experts, weights = route(logits, args)
+        local = experts - args.first_expert
+        held = (local >= 0) & (local < E) & live[:, None]
+        # sorted by expert; what is not held sorts past the held experts
+        # and into no group: it leaves the dispatch
+        key = jnp.where(held, local, E).reshape(-1)
+        order = jnp.argsort(key, stable=True)
+        token = order // k
+        sizes = jnp.sum(jax.nn.one_hot(key, E + 1, dtype=jnp.int32),
+                        axis=0)[:E]
+        xs = hin[token]                                    # [n * k, h]
+    with jax.named_scope("pt.expert_ffn"):
+        act = (jax.nn.silu(gm.grouped_matmul(xs, stack["we_gate"], sizes,
+                                             first))
+               * gm.grouped_matmul(xs, stack["we_up"], sizes, first))
+        ys = gm.grouped_matmul(act, stack["we_down"], sizes, first)
+    with jax.named_scope("pt.moe_route"):
+        w = jnp.where(held, weights, 0.0).reshape(-1)[order]
+        # a row past the last group holds whatever the grouped matmul left
+        ys = jnp.where((w > 0)[:, None], ys.astype(jnp.float32) * w[:, None],
+                       0.0)
+        # back in token order, a token's k picks side by side: a gather and
+        # a sum (a scatter-add of the sorted rows was 7% of the device's
+        # busy time at a 2,048-token window)
+        out = jnp.sum(ys[jnp.argsort(order)].reshape(n, k, -1), axis=1)
+        counts = jnp.stack([jnp.max(sizes), jnp.sum(sizes),
+                            k * jnp.sum(live.astype(jnp.int32)),
+                            jnp.sum((sizes > 0).astype(jnp.int32))])
+    return out.astype(hin.dtype), counts, experts
+
+
+def _expert_ffn(lp, stack, first, x, live, args):
+    hin = lf.rms_norm(x, lp["ln2"], args.rms_eps)
+    with jax.named_scope("pt.mlp"):
+        shared = _swiglu(hin, lp["ws_gate"], lp["ws_up"], lp["ws_down"])
+    routed, counts, picks = _routed_experts(lp, stack, first, hin, live,
+                                            args)
+    return x + shared + routed, counts, picks
+
+
+def _dense_ffn(lp, x, args):
+    hin = lf.rms_norm(x, lp["ln2"], args.rms_eps)
+    with jax.named_scope("pt.mlp"):
+        return x + _swiglu(hin, lp["w_gate"], lp["w_up"], lp["w_down"])
+
+
+# ---------------------------------------------------------------------------
+# the two step programs' bodies
+# ---------------------------------------------------------------------------
+
+def _stack(params, x, pool, attention, live, args):
+    """The dense leading layers, then the expert layers, each group one scan
+    whose carry holds the activations and the whole pool. `attention(lp, x,
+    pool, base)` is the step's own. Returns (x, pool, counts [4], picks
+    [expert layers, rows, experts a token])."""
+    num_pages = pool.shape[0] // args.num_layers
+    kd, E = args.first_k_dense, args.experts_held
+    # the experts' leaves stay out of the scan's slices (`_routed_experts`)
+    scanned = {k: v for k, v in params["layers"].items()
+               if not k.startswith("we_")}
+    experts = {k: v.reshape((-1,) + v.shape[2:])
+               for k, v in params["layers"].items() if k.startswith("we_")}
+
+    def dense(carry, xs):
+        lp, layer = xs
+        x, pool = attention(lp, *carry, layer * num_pages)
+        return (_dense_ffn(lp, x, args), pool), None
+
+    def expert(carry, xs):
+        lp, layer = xs
+        x, pool = attention(lp, *carry, layer * num_pages)
+        x, c, picks = _expert_ffn(lp, experts, (layer - kd) * E, x, live,
+                                  args)
+        return (x, pool), (c, picks)
+
+    if kd:
+        (x, pool), _ = jax.lax.scan(
+            dense, (x, pool),
+            (params["dense_layers"], jnp.arange(kd, dtype=jnp.int32)))
+    (x, pool), (per_layer, picks) = jax.lax.scan(
+        expert, (x, pool),
+        (scanned, jnp.arange(kd, args.num_layers, dtype=jnp.int32)))
+    return x, pool, jnp.sum(per_layer, axis=0), picks
+
+
+def _head(params, x, args):
+    x = lf.rms_norm(x, params["final_norm"], args.rms_eps)
+    return _wmm(x, params["lm_head"]).astype(jnp.float32)
+
+
+def prefill_window(params, ids, h, last_idx, bt_row, new_pages, pool, cos,
+                   sin, args):
+    """One prefill window of one slot: ids [s] at positions h .. h + s - 1,
+    real up to `last_idx`; bt_row [P] the slot's block table (a layer's
+    page numbers); new_pages [P] the pages the window writes, from the one
+    that holds h on (unused entries the null page). Returns (logits [vocab]
+    at last_idx, pool, picks [expert layers, s, experts a token]: the
+    experts each token picked)."""
+    s = ids.shape[0]
+    live = jnp.arange(s, dtype=jnp.int32) <= last_idx
+
+    def attention(lp, x, pool, base):
+        return _window_attention(lp, x, pool, h, last_idx, bt_row,
+                                 new_pages, cos, sin, base, args)
+
+    x = jnp.take(params["embedding"], ids, axis=0)
+    x, pool, _, picks = _stack(params, x, pool, attention, live, args)
+    return _head(params, x[last_idx][None], args)[0], pool, picks
+
+
+def decode_step(params, tokens, bt, pos, live, pool, cos, sin, args):
+    """One token a slot: tokens [b] at positions pos [b] through block
+    tables bt [b, P]; live [b] marks the rows that decode (the others write
+    to the null page and count for nothing). Returns (logits [b, vocab],
+    pool, counts int32 [4] summed over the expert layers: tokens at the
+    busiest held expert, picks on held experts, picks in all, held experts
+    with a token; picks [expert layers, b, experts a token])."""
+    def attention(lp, x, pool, base):
+        return _decode_attention(lp, x, pool, bt, pos, cos, sin, base, args)
+
+    x = jnp.take(params["embedding"], tokens, axis=0)
+    x, pool, counts, picks = _stack(params, x, pool, attention, live, args)
+    return _head(params, x, args), pool, counts, picks

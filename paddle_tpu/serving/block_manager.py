@@ -123,6 +123,7 @@ class _RadixIndex:
         self.root = _RadixNode((), 0, [], None)
         self._owner = {}          # page id -> owning node
         self._clock = 0
+        self._reclaimable = (None, 0)   # (versions, pages) of the last walk
 
     def _tick(self):
         self._clock += 1
@@ -394,7 +395,14 @@ class _RadixIndex:
         trailing run of cached pages of every node whose whole subtree
         is evictable (an interior page only frees up once everything
         hanging off it is gone). Iterative post-order — tree depth grows
-        with registrations, not page counts."""
+        with registrations, not page counts. The walk is kept until the
+        tree or the set of cached pages changes (`prefix_version`,
+        `cached_version`): the gauge behind every `alloc()` asks again
+        once a page a decoding row, and a page taken from the free list
+        changes neither."""
+        key = (self._a.prefix_version, self._a.cached_version)
+        if self._reclaimable[0] == key:
+            return self._reclaimable[1]
         cached = self._a._cached
         order, stack = [], [self.root]
         while stack:
@@ -418,7 +426,8 @@ class _RadixIndex:
                 total += tail
                 fully = tail == len(nd.pages)
             res[id(nd)] = (total, fully)
-        return res[id(self.root)][0]
+        self._reclaimable = (key, res[id(self.root)][0])
+        return self._reclaimable[1]
 
 
 class _HashChainIndex:
@@ -549,6 +558,9 @@ class BlockAllocator:
         # scans (the chunked-prefill anti-convoy admission walk) until a
         # change could alter the answer
         self.prefix_version = 0
+        # bumped whenever a page joins or leaves `_cached` without a change
+        # to the index (a release to refcount 0, a hit's revival)
+        self.cached_version = 0
         if self.policy == "radix":
             self._index = _RadixIndex(self)
         elif self.policy == "hash":
@@ -648,6 +660,7 @@ class BlockAllocator:
             self._ref[page] += 1
         elif page in self._cached:
             del self._cached[page]
+            self.cached_version += 1
             self._ref[page] = 1
         else:
             raise KeyError(f"ref of unallocated page {page}")
@@ -667,6 +680,7 @@ class BlockAllocator:
         del self._ref[page]
         if self._index.owns(page):
             self._cached[page] = True       # most-recently-used position
+            self.cached_version += 1
         else:
             self._free.append(page)
         self._gauges()

@@ -13,7 +13,9 @@ type of the model description the engine was given. The engine calls:
       host arrays. Returns the token after the window (a device scalar).
   decode(bt, active, sample, sampling_args) -> next
       one batched step over the block tables `bt` [slots, pages a slot];
-      reads `eng._last_tok` / `eng._npos`. Returns the next tokens [slots].
+      reads `eng._last_tok` / `eng._npos`. Returns the next tokens, a
+      slot's at its index (a path may append what else should ride the
+      one read-back a step makes: the engine reads a slot's row only).
   copy_page(src, dst)       device half of a copy-on-write
   prompt_done(slot)         the slot's last prefill window ran
   load_snapshot(slot, sid)  a prefix hit that ends at snapshot `sid`
@@ -34,14 +36,17 @@ forward under `models/`, a path beside `dense.py`, an entry in `PATHS`.
 from __future__ import annotations
 
 from paddle_tpu.models.hybrid_functional import HybridArgs
+from paddle_tpu.models.latent_moe_functional import LatentMoEArgs
 from paddle_tpu.models.llama_functional import LlamaArgs
 from paddle_tpu.serving.dense import DensePath
 from paddle_tpu.serving.hybrid import HybridPath
+from paddle_tpu.serving.latent import LatentPath
 
 __all__ = ["PATHS", "path_for"]
 
 # type of the model description -> the family's device half
-PATHS = {LlamaArgs: DensePath, HybridArgs: HybridPath}
+PATHS = {LlamaArgs: DensePath, HybridArgs: HybridPath,
+         LatentMoEArgs: LatentPath}
 
 
 def path_for(eng):

@@ -52,14 +52,17 @@ class SlotSampler:
         self._top_p = np.ones(self.max_slots, np.float32)
         self._top_k = np.zeros(self.max_slots, np.int32)
         self._seed = np.zeros(self.max_slots, np.int32)
+        self._device = None     # `device_args` of the rows as they stand
 
     def admit(self, slot, req):
+        self._device = None
         self._temp[slot] = req.temperature
         self._top_p[slot] = req.top_p
         self._top_k[slot] = req.top_k
         self._seed[slot] = np.int32(req.seed)
 
     def clear(self, slot):
+        self._device = None
         self._temp[slot] = 0.0
         self._top_p[slot] = 1.0
         self._top_k[slot] = 0
@@ -75,6 +78,9 @@ class SlotSampler:
         return any(self._temp[s] > 0 for s in slots)
 
     def device_args(self):
-        """The per-row operands the traced `pick` consumes."""
-        return (jnp.asarray(self._temp), jnp.asarray(self._top_p),
-                jnp.asarray(self._top_k), jnp.asarray(self._seed))
+        """The per-row operands the traced `pick` consumes: moved to the
+        device when a row was admitted or cleared since, not every step."""
+        if self._device is None:
+            self._device = (jnp.asarray(self._temp), jnp.asarray(self._top_p),
+                            jnp.asarray(self._top_k), jnp.asarray(self._seed))
+        return self._device

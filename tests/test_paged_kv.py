@@ -269,6 +269,42 @@ class TestRadixTree:
         assert a.pages_in_use == 0
         assert a.available == a.capacity
 
+    @pytest.mark.parametrize("seed", [0, 1, 2])
+    def test_kept_reclaimable_count_equals_a_fresh_walk(self, seed):
+        # `available` keeps its walk of the radix tree until the tree or
+        # the cached set changes; after every operation of a random
+        # sequence (shared prefixes, splits, hits, releases, evictions) it
+        # must read what a walk from nothing reads
+        rng = np.random.default_rng(seed)
+        a = BlockAllocator(num_pages=40, page_size=2)
+        index = a._index
+
+        def fresh():
+            index._reclaimable = (None, 0)
+            return a.free_count + index.reclaimable()
+
+        held = []                            # (tokens, pages) of live requests
+        for _ in range(400):
+            op = rng.integers(4)
+            if op == 0 and a.available >= 6:
+                toks = [int(t) for t in rng.integers(1, 3, rng.integers(2, 9))]
+                m = a.match_prefix(toks)
+                pages = list(m.pages)
+                need = -(-len(toks) // 2) - len(pages)
+                pages += a.alloc_many(need)
+                a.register_prefix(toks, pages)
+                held.append((toks, pages))
+            elif op == 1 and held:
+                _, pages = held.pop(int(rng.integers(len(held))))
+                a.release_many(pages)
+            elif op == 2 and a.available > 0:
+                held.append(([], [a.alloc()]))
+            elif op == 3 and held:
+                toks, _ = held[int(rng.integers(len(held)))]
+                a.match_prefix(toks, commit=False)
+            kept = a.available
+            assert kept == fresh()
+
     def test_leaf_lru_never_evicts_referenced_or_interior_pages(self):
         a = BlockAllocator(num_pages=16, page_size=2)
         sys = [7, 8, 7, 8]                  # 2 shared system pages

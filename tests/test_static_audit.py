@@ -409,6 +409,39 @@ class TestProgramFamilies:
             assert [c["prim"] for c in census] == ["psum", "psum"], name
             assert all(c["axes"] == ("mp",) for c in census), name
 
+    def test_latent_serving_family_clean(self):
+        assert presets.audit_latent_serving() == []
+
+    def test_latent_serving_captured_all_programs(self):
+        progs = programs.latent_serving_programs()
+        assert set(presets.LATENT_SERVING) <= set(progs), \
+            "a latent serving program stopped being captured"
+
+    @pytest.mark.parametrize("name", presets.LATENT_SERVING)
+    def test_latent_pool_is_donated_and_the_step_calls_no_host(self, name):
+        p = programs.latent_serving_programs()[name]
+        assert p.donated and donation_audit.check_donation(
+            p.lowered_text, p.example_args, p.donated, p.name, kept=p.kept,
+            compiled_text=p.compiled_text) == []
+        assert host_sync_audit.check_host_sync(p.jaxpr, p.name) == []
+        assert collective_audit.collective_census(p.jaxpr) == []
+
+    def test_a_window_that_formed_every_rows_logits_would_be_caught(self):
+        p = programs.latent_serving_programs()["latent_decode"]
+        v = buffer_audit.check_forbidden_shape(
+            p.jaxpr, (p.meta["slots"], p.meta["vocab"]), p.name, "logits")
+        assert v, "the probe no longer sees the head's [slots, vocab]"
+
+    def test_missing_latent_program_is_reported_not_silent(self,
+                                                           monkeypatch):
+        real = programs.latent_serving_programs()
+        pruned = {k: v for k, v in real.items() if k != "latent_page_copy"}
+        monkeypatch.setattr(programs, "latent_serving_programs",
+                            lambda: pruned)
+        v = presets.audit_latent_serving()
+        assert any(x.rule == "audit.program-not-captured"
+                   and x.program == "latent_page_copy" for x in v)
+
     def test_disagg_family_clean(self):
         assert presets.audit_disagg() == []
 

@@ -149,3 +149,111 @@ def test_decode_program_touches_pages_and_never_a_whole_pool(one_chip, kind):
     assert not moved, "\n".join(moved)
     layer_bytes = NP * nkv * ps * hd * jnp.dtype(kind).itemsize
     assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
+
+
+# ---------------------------------------------------------------------------
+# the latent-attention expert family's kernels and its decode program at the
+# cell's widths (`serve_deepseek_v2_long_answers`: 64 rows, 128 heads over a
+# cached row of 512 + 64 values laid out as 640 lanes, 256 pages a slot of a
+# pool of 6 x 8,192 pages x 64 tokens; 20 experts of 5120 x 1536 held)
+# ---------------------------------------------------------------------------
+
+def test_latent_decode_kernel_compiles_for_v5e(one_chip):
+    from paddle_tpu.kernels import latent_attention as la
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    args = [sds((64, 128, 640), jnp.bfloat16),
+            sds((6 * 8192, 64, 640), jnp.bfloat16),
+            sds((64, 256), jnp.int32), sds((64,), jnp.int32),
+            sds((), jnp.int32)]
+    assert la.latent_decode_supported(args[0].shape, args[1].shape,
+                                      args[2].shape, 512)
+    # a row of 576 values is no whole lane tile: Mosaic refuses to copy a
+    # page of it, which is why the pool states 640
+    assert not la.latent_decode_supported((64, 128, 576), (8, 64, 576),
+                                          (64, 256), 512)
+
+    def call(q, pool, bt, pos, base):
+        return la._decode_pallas(q, pool, bt, pos, 0.1147, 512, base, False)
+
+    text = jax.jit(call).lower(*args).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+
+
+@pytest.mark.parametrize("window", [64, 2048])
+def test_latent_prefill_kernel_compiles_for_v5e(one_chip, window):
+    from paddle_tpu.kernels import latent_attention as la
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    args = [sds((window, 128, 128), jnp.bfloat16),
+            sds((window, 128, 64), jnp.bfloat16),
+            sds((16384, 128 * 256), jnp.bfloat16),
+            sds((16384, 64), jnp.bfloat16), sds((), jnp.int32),
+            sds((), jnp.int32)]
+    assert la.latent_prefill_supported(args[0].shape, args[1].shape,
+                                       args[2].shape, 128)
+
+    def call(qn, qr, kv, kr, h, last):
+        return la._prefill_pallas(qn, qr, kv, kr, h, last, 0.1147, 128, False)
+
+    text = jax.jit(call).lower(*args).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+
+
+def test_latent_decode_program_copies_no_experts_and_no_pool(one_chip):
+    """The family's decode program at the cell's widths, depth 2, the pool
+    donated: three Mosaic-free grouped matmuls a layer over the WHOLE stack
+    of experts (a layer's slice of it handed to the custom call was a copy
+    of 315 MB a projection a step), one latent kernel, and no temporary of
+    an expert stack's or the pool's size."""
+    from paddle_tpu.models import latent_moe_functional as lm
+
+    L, b, ps, P, NP = 2, 64, 64, 256, 8192
+    args = lm.LatentMoEArgs(
+        vocab_size=12800, hidden_size=5120, num_layers=L, num_heads=128,
+        q_rank=1536, kv_rank=512, nope_dim=128, rope_dim=64, v_dim=128,
+        dense_intermediate=12288, expert_intermediate=1536, shared_experts=2,
+        routed_experts=160, first_expert=0, experts_held=20, n_group=8,
+        topk_group=3, experts_per_tok=6, routed_scaling=16.0,
+        first_k_dense=0, rope_theta=10000.0, rms_eps=1e-6,
+        yarn=lm.YarnConfig(40.0, 4096, 32.0, 1.0, 0.707, 0.707))
+    h, H, E, m = 5120, 128, 20, 1536
+
+    def sds(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    layers = {"ln1": (h,), "ln2": (h,), "w_qa": (h, 1536), "q_norm": (1536,),
+              "w_qb": (1536, H * 192), "w_kva": (h, 576), "kv_norm": (512,),
+              "w_kvb": (512, H * 256), "wo": (H * 128, h),
+              "router": (h, 160), "ws_gate": (h, 2 * m), "ws_up": (h, 2 * m),
+              "ws_down": (2 * m, h), "we_gate": (E, h, m), "we_up": (E, h, m),
+              "we_down": (E, m, h)}
+    params = {"embedding": sds((12800, h)), "final_norm": sds((h,)),
+              "lm_head": sds((h, 12800)),
+              "layers": {k: sds((L,) + s) for k, s in layers.items()}}
+    pool = sds((L * NP, ps, args.row_width))
+    assert args.row_width == 640
+    table = sds((2 * P * ps, 64), jnp.float32)
+
+    def decode(params, tokens, bt, pos, live, pool, cos, sin):
+        with qm.fused_dispatch(True):
+            return lm.decode_step(params, tokens, bt, pos, live, pool, cos,
+                                  sin, args)
+
+    compiled = jax.jit(decode, donate_argnums=(5,)).lower(
+        params, sds((b,), jnp.int32), sds((b, P), jnp.int32),
+        sds((b,), jnp.int32), sds((b,), jnp.bool_), pool, table,
+        table).compile()
+    text = compiled.as_text()
+    # the latent kernel, and XLA's own grouped-matmul kernels (three
+    # projections and their tile schedule), all Mosaic calls
+    assert "latent_decode_attention" in text
+    assert text.count("ragged-dot-none") >= 3
+    assert text.count('custom_call_target="tpu_custom_call"') >= 4
+    # one expert stack of a layer is 315 MB, the pool 1.3 GB here: what the
+    # program plans beside its arguments stays far under either
+    assert compiled.memory_analysis().temp_size_in_bytes < 150e6
