@@ -59,13 +59,16 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.harness import weights
-from benchmarks.harness.reference import (HEAD_ROWS, HIGHEST, f32_mm,
-                                          rms_norm)
+from benchmarks.harness.reference import (HIGHEST, f32_mm, pad_rows,
+                                          rms_norm, served_rows)
 
 SPARSE, LIGHTNING = "minicpm4", "lightning-attn"
 T_BLOCK = 1024      # tokens a projection / feed-forward call takes
 Q_SPARSE = 128      # queries a sparse layer attends at once
-K_BUCKET = 8192     # a query block sees its keys padded up to a multiple
+K_BUCKET = 16384    # a query block sees its keys padded up to a multiple:
+                    # at most four key counts, so four programs, whatever
+                    # the requests' lengths (PR 36; 8,192 and the request's
+                    # own length before: a program a length)
 
 
 # -- the program's side: imported here and nowhere in the reference ----------
@@ -368,9 +371,13 @@ def layer_forward(x, w, arch, mm, index, real):
             outs.append(o)
     else:
         outs, qb = [], min(Q_SPARSE, tb)
-        bucket = max(qb, B) if s <= K_BUCKET else K_BUCKET
+        bucket = K_BUCKET if s > T_BLOCK else max(qb, B)
+        if s > T_BLOCK:
+            # rows past a query's position are never read: the key counts
+            # are the buckets' alone, whatever the request's own length
+            k, v = pad_rows(K_BUCKET, k, v)
         for a, b in _blocks(s, qb):
-            m = min(-(-b // bucket) * bucket, s)
+            m = min(-(-b // bucket) * bucket, k.shape[0])
             outs.append(_sparse_fn(fz)(q[a:b], jnp.arange(a, b), k[:m],
                                        v[:m]))
     attn = jnp.concatenate(outs)
@@ -412,12 +419,10 @@ def served_logits(arch, seed, requests, mm=f32_mm):
             arch, seq,
             lambda i: weights.layer_params(_LEAVES, arch, seed, i),
             outer["embedding"], mm)
-        n, m = len(prompt), len(tokens)
-        rows = n - 1 + np.arange(-(-m // HEAD_ROWS) * HEAD_ROWS)
-        rows = np.minimum(rows, x.shape[0] - 1)
-        out.append(np.asarray(head_logits(
-            arch, x[jnp.asarray(rows)], outer["final_norm"],
-            outer["lm_head"], mm))[:m])
+        out.append(served_rows(
+            lambda rows: head_logits(arch, rows, outer["final_norm"],
+                                     outer["lm_head"], mm),
+            x, len(prompt), len(tokens)))
     return out
 
 

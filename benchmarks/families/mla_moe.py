@@ -104,12 +104,15 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.harness import weights
-from benchmarks.harness.reference import (HEAD_ROWS, HIGHEST, f32_mm,
-                                          rms_norm)
+from benchmarks.harness.reference import (HIGHEST, f32_mm, pad_rows,
+                                          rms_norm, served_rows)
 
 T_BLOCK = 1024      # tokens a projection / feed-forward call takes
 Q_BLOCK = 256       # queries attended at once: [heads, Q_BLOCK, keys] scores
-K_BUCKET = 2048     # a query block sees its keys padded up to a multiple
+K_BUCKET = 4096     # a query block sees its keys padded up to a multiple:
+                    # at most four key counts, so four programs, whatever
+                    # the requests' lengths (PR 36; 2,048 and the request's
+                    # own length before: a dozen)
 DENSE_FOLD = 1 << 10    # a dense leading layer's seed index lies past these
 REQUEST_RECORD = "routing"      # the finished request's attribute (a
                                 # `serving.latent.RoutingTrace`) that
@@ -523,10 +526,14 @@ def layer_forward(x, w, arch, mm, index, given=None, notes=None):
              for a, b in _blocks(s, tb)]
     q_nope, q_pe, k_nope, k_pe, v = (jnp.concatenate(p) for p in zip(*parts))
     del parts
-    bucket = K_BUCKET if s > K_BUCKET else s
+    bucket = K_BUCKET if s > T_BLOCK else s
+    if s > T_BLOCK:
+        # rows past a query's position are never read: the key counts are
+        # the buckets' alone, whatever the request's own length
+        k_nope, k_pe, v = pad_rows(K_BUCKET, k_nope, k_pe, v)
     outs = []
     for a, b in _blocks(s, qb):
-        m = min(-(-b // bucket) * bucket, s)
+        m = -(-b // bucket) * bucket
         outs.append(_attend_fn(fz)(q_nope[a:b], q_pe[a:b], jnp.arange(a, b),
                                    k_nope[:m], k_pe[:m], v[:m]))
     attn = jnp.concatenate(outs)
@@ -621,12 +628,10 @@ def served_logits(arch, seed, requests, mm=f32_mm):
             found[:4] += [recorded, differ, followed, own]
             found[4] = max(found[4], np.max(np.asarray(notes[r])[:, :, 2]))
             found[5] = max(found[5], followed / max(recorded, 1))
-        n, m = len(prompt), len(tokens)
-        rows = n - 1 + np.arange(-(-m // HEAD_ROWS) * HEAD_ROWS)
-        rows = np.minimum(rows, x.shape[0] - 1)
-        out.append(np.asarray(head_logits(
-            arch, x[jnp.asarray(rows)], outer["final_norm"],
-            outer["lm_head"], mm))[:m])
+        out.append(served_rows(
+            lambda rows: head_logits(arch, rows, outer["final_norm"],
+                                     outer["lm_head"], mm),
+            x, len(prompt), len(tokens)))
         xs[r] = None
     if found[0]:
         recorded, differ, followed, own, short, most = found

@@ -27,17 +27,48 @@ from benchmarks.harness.weights import make_params
 TRACE_S = 3.0            # the traced slice at the end of the window
 DRAIN_LIMIT_S = 20.0     # first tokens still missing this long after the
                          # close count as failed
-COMPARE_MAX = 32         # finished requests compared: every one while the
-                         # run finishes no more than this (23-24 at PR 23's
-                         # pace); past it the longest and a seeded draw of
-                         # the others, so the reference stays shorter than
-                         # the window
-
+COMPARE_MAX = 32         # finished requests compared at most, and
+COMPARE_TOKENS = 65536   # their tokens (prompt + served) at most: the
+                         # longest and a seeded draw of the others within
+                         # both (`compared`), so the float32 reference's
+                         # time is bounded whatever a cell's lengths. Chosen
+                         # from chip runs (PR 36, PERF.md section 2): the
+                         # references go through a token in 0.43 to 1.0 ms
+                         # warm, so a check lasts 16-66 s and every cell's
+                         # warm run ends within 180 s of the driver's 360,
+                         # a fully cold one within 320; a cell of ~1.7k-token
+                         # requests keeps its 32. Requests alone bounded it
+                         # before (PR 23), and 30 requests of 9k-49k tokens
+                         # took 225 s of a 345 s run
 
 def median_step_ms(spans, kind):
     """Median host time of the `step()` calls of one kind, whole run."""
     d = [b - a for k, a, b, _ in spans if k == kind]
     return 1e3 * float(np.median(d)) if d else None
+
+
+def compared(sizes, seed, max_requests, max_tokens):
+    """Which of the finished requests to compare: indices into `sizes`
+    (each request's prompt + served tokens), in their order. The longest
+    always; then the others in the order of a draw from the seed, each
+    taken if the tokens held with it stay within `max_tokens` and passed
+    over if not, until `max_requests` are held. The first drawn is taken
+    whatever its size, so two are compared wherever two finished; a
+    finished set within both bounds is compared whole."""
+    if not len(sizes):
+        return []
+    longest = int(np.argmax(sizes))          # the first of equals
+    rest = [i for i in range(len(sizes)) if i != longest]
+    rng = np.random.default_rng([int(seed), 7])
+    held, tokens = [longest], int(sizes[longest])
+    for j in rng.permutation(len(rest)):
+        if len(held) >= max_requests:
+            break
+        size = int(sizes[rest[j]])
+        if len(held) == 1 or tokens + size <= max_tokens:
+            held.append(rest[j])
+            tokens += size
+    return sorted(held)
 
 
 class _Rec:
@@ -246,12 +277,13 @@ class ServeRun:
                                 if r.req is not None and r.req.finished)}
 
     def sample(self):
-        """The finished requests to compare: every one, or past COMPARE_MAX
-        the longest (prompt + served tokens) and a draw from the seed of the
-        others. Returns (prompt, tokens, record) and checks every finished
-        request has its asked length. `record` is the attribute of the
-        finished `Request` that the family's `REQUEST_RECORD` names, for the
-        family's own `served_logits` to read (None where it names none)."""
+        """The finished requests to compare (`compared`: the longest and a
+        draw from the seed of the others, within COMPARE_MAX requests and
+        COMPARE_TOKENS tokens). Returns (prompt, tokens, record) and checks
+        every finished request has its asked length. `record` is the
+        attribute of the finished `Request` that the family's
+        `REQUEST_RECORD` names, for the family's own `served_logits` to read
+        (None where it names none)."""
         done = [r for r in self.recs.values()
                 if r.req is not None and r.req.finished]
         short = [r.rid for r in done if len(r.req.token_ids) != r.want]
@@ -260,12 +292,9 @@ class ServeRun:
                                f"length: {short[:5]}")
         done.sort(key=lambda r: r.rid)
         self.finished = len(done)
-        if len(done) > COMPARE_MAX:
-            longest = max(done, key=lambda r: len(r.prompt) + r.want)
-            rest = [r for r in done if r is not longest]
-            rng = np.random.default_rng([int(self.seed), 7])
-            picks = rng.choice(len(rest), COMPARE_MAX - 1, replace=False)
-            done = [longest] + [rest[i] for i in sorted(picks)]
+        done = [done[i] for i in compared(
+            [len(r.prompt) + r.want for r in done], self.seed, COMPARE_MAX,
+            COMPARE_TOKENS)]
         record = getattr(self.family, "REQUEST_RECORD", None)
         return [(r.prompt, np.asarray(r.req.token_ids, np.int32),
                  getattr(r.req, record) if record else None) for r in done]
@@ -310,7 +339,9 @@ class ServeRun:
             gaps.append(gap)
         gaps = np.concatenate(gaps) if gaps else np.zeros(1)
         self.log(f"correct: {len(sample)} of {self.finished} finished "
-                 f"requests, {len(gaps)} served tokens compared")
+                 f"requests, {len(gaps)} served tokens compared, "
+                 f"{sum(len(p) for p, _, _ in sample)} prompt tokens gone "
+                 f"through")
         return [("served_gap_widest", float(gaps.max()),
                  limits["served_gap_widest"]),
                 ("served_gap_mean", float(gaps.mean()),

@@ -18,6 +18,7 @@ layer at a time and updates it at once), so it fits where the program did.
 """
 
 import functools
+import json
 import math
 
 import jax
@@ -79,24 +80,41 @@ def _f32(w):
 
 
 def _frozen(arch):
-    return tuple(sorted((k, v) for k, v in arch.items()
-                        if isinstance(v, (int, float)) and v is not None))
+    """The configuration as a hashable key that `json.loads` gives back
+    whole, nested groups included."""
+    return json.dumps(arch, sort_keys=True)
+
+
+def _call(layer, arch, mm, kind):
+    """A family's `decoder_layer` as (x, w) -> y; a family of several kinds
+    (`weights.layer_kinds`) is told the layer's kind."""
+    if kind is None:
+        return lambda x, w: layer(x, w, arch, mm)
+    return lambda x, w: layer(x, w, arch, mm, kind)
+
+
+def _kinds(family, arch):
+    """The kind to hand each layer's `decoder_layer`: None for a family
+    that states no kinds."""
+    if not weights.states_kinds(family):
+        return (None,) * arch["num_hidden_layers"]
+    return weights.layer_kinds(family, arch)
 
 
 @functools.lru_cache(maxsize=None)
-def _layer_fwd(layer, frozen, mm):
+def _layer_fwd(layer, frozen, mm, kind=None):
     """`layer` is a family's `decoder_layer`; the jitted function takes x
     [b, s, h] float32 and w, one layer's weights in any float type."""
-    arch = dict(frozen)
-    return jax.jit(lambda x, w: layer(x, _f32(w), arch, mm))
+    call = _call(layer, json.loads(frozen), mm, kind)
+    return jax.jit(lambda x, w: call(x, _f32(w)))
 
 
 @functools.lru_cache(maxsize=None)
-def _layer_bwd(layer, frozen, mm):
-    arch = dict(frozen)
+def _layer_bwd(layer, frozen, mm, kind=None):
+    call = _call(layer, json.loads(frozen), mm, kind)
 
     def bwd(x, w, dy):
-        _, vjp = jax.vjp(lambda x_, w_: layer(x_, w_, arch, mm), x, _f32(w))
+        _, vjp = jax.vjp(call, x, _f32(w))
         return vjp(dy)
 
     return jax.jit(bwd, donate_argnums=(2,))
@@ -115,7 +133,31 @@ def _head_fn(eps, mm):
     return jax.jit(head)
 
 
-HEAD_ROWS = 128    # served positions go through the head in multiples of it
+HEAD_ROWS = 128    # served positions go through the head this many a call
+
+
+def served_rows(head, x, n, m):
+    """The logits [m, vocab] (numpy) at the positions where the m served
+    tokens were chosen after a prompt of n: rows n - 1 .. n + m - 2 of the
+    last layer's output x [s, h], through `head(rows)` HEAD_ROWS at a call,
+    so that the head is one program whatever a request's length (the last
+    call's spare rows repeat x's last row and are dropped)."""
+    out = []
+    for a in range(0, m, HEAD_ROWS):
+        rows = np.minimum(n - 1 + a + np.arange(HEAD_ROWS), x.shape[0] - 1)
+        out.append(np.asarray(head(x[jnp.asarray(rows)])))
+    return np.concatenate(out)[:m]
+
+
+def pad_rows(multiple, *arrays):
+    """Each array with zero rows appended up to a whole multiple of rows: a
+    reference that cuts its keys to whole buckets of positions compiles a
+    program a bucket count and none a request's own length."""
+    extra = -arrays[0].shape[0] % multiple
+    if not extra:
+        return arrays
+    return tuple(jnp.pad(a, ((0, extra),) + ((0, 0),) * (a.ndim - 1))
+                 for a in arrays)
 
 
 def served_logits(family, arch, seed, requests, mm=f32_mm):
@@ -133,20 +175,17 @@ def served_logits(family, arch, seed, requests, mm=f32_mm):
         ids[:len(seq)] = seq
         xs.append(outer["embedding"][jnp.asarray(ids)]
                   .astype(jnp.float32)[None])
-    fwd = _layer_fwd(family.decoder_layer, _frozen(arch), mm)
-    for i in range(arch["num_hidden_layers"]):
+    for i, kind in enumerate(_kinds(family, arch)):
+        fwd = _layer_fwd(family.decoder_layer, _frozen(arch), mm, kind)
         w = weights.layer_params(family, arch, seed, i)
         for j, x in enumerate(xs):
             xs[j] = fwd(x, w)
     head = _head_fn(arch["rms_norm_eps"], mm)
     out = []
     for (prompt, tokens, *_), x in zip(requests, xs):
-        n, m = len(prompt), len(tokens)
-        rows = n - 1 + np.arange(-(-m // HEAD_ROWS) * HEAD_ROWS)
-        rows = np.minimum(rows, x.shape[1] - 1)
-        out.append(np.asarray(head(x[0][jnp.asarray(rows)],
-                                   outer["final_norm"],
-                                   outer["lm_head"]))[:m])
+        out.append(served_rows(
+            lambda rows: head(rows, outer["final_norm"], outer["lm_head"]),
+            x[0], len(prompt), len(tokens)))
     return out
 
 
@@ -216,6 +255,7 @@ class TrainReference:
         self.family, self.arch, self.seed = family, arch, seed
         self.hp, self.mm, self.dtype = tuple(hp), mm, dtype
         self.n_layers = arch["num_hidden_layers"]
+        self.kinds = weights.layer_kinds(family, arch)
         self.layers = [weights.layer_params(family, arch, seed, i, dtype)
                        for i in range(self.n_layers)]
         self.outer = weights.outer_params(arch, seed, dtype)
@@ -235,7 +275,7 @@ class TrainReference:
         store[name], m[name], v[name], gsq = _adamw(
             store[name], g, m[name], v[name], jnp.float32(self.step),
             self.hp)
-        key = f"layers/{name}" if group == "layers" else name
+        key = f"{self.kinds[idx]}/{name}" if group == "layers" else name
         sq[key] = sq.get(key, 0.0) + gsq
 
     def train_step(self, ids, labels):
@@ -244,11 +284,10 @@ class TrainReference:
         self.step += 1
         ids, labels = jnp.asarray(ids), jnp.asarray(labels)
         B, s = ids.shape
-        fwd = _layer_fwd(self.family.decoder_layer, frozen, mm)
-        bwd = _layer_bwd(self.family.decoder_layer, frozen, mm)
+        layer, told = self.family.decoder_layer, _kinds(self.family, arch)
         xs = [self.outer["embedding"][ids].astype(jnp.float32)]
-        for w in self.layers:
-            xs.append(fwd(xs[-1], w))
+        for w, kind in zip(self.layers, told):
+            xs.append(_layer_fwd(layer, frozen, mm, kind)(xs[-1], w))
 
         # head and loss in chunks of rows; d(final_norm), d(lm_head) add up
         h = arch["hidden_size"]
@@ -272,7 +311,8 @@ class TrainReference:
         del dnorm, dhead
 
         for i in reversed(range(self.n_layers)):
-            dy, dw = bwd(xs.pop(), self.layers[i], dy)
+            dy, dw = _layer_bwd(layer, frozen, mm, told[i])(
+                xs.pop(), self.layers[i], dy)
             for name in sorted(dw):
                 self._update("layers", i, name, dw.pop(name), sq)
         demb = jnp.zeros(self.outer["embedding"].shape, jnp.float32)
@@ -283,7 +323,7 @@ class TrainReference:
 
     def grad_norms(self):
         """Leaf -> norm of the last step's gradient (a stacked leaf of the
-        program, `layers/<name>`, is all its layers together)."""
+        program, `<kind>/<name>`, is all that kind's layers together)."""
         return {k: math.sqrt(x) for k, x in self.grad_sq.items()}
 
     def delta_norms(self):
@@ -293,7 +333,7 @@ class TrainReference:
             w0 = weights.layer_params(self.family, self.arch, self.seed, i,
                                       self.dtype)
             for name in w:
-                key = f"layers/{name}"
+                key = f"{self.kinds[i]}/{name}"
                 out[key] = out.get(key, 0.0) + float(_delta_sq(w[name],
                                                                w0[name]))
         o0 = weights.outer_params(self.arch, self.seed, self.dtype)

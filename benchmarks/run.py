@@ -8,11 +8,16 @@ warms up the cell's own programs (set-up), measures for `--seconds`,
 decides `correct` against the plain reference, and prints one JSON object
 as its last line. Without the chips the cell asks for it exits 2 and
 prints no result: a CPU number is never a device number.
+
+Every number compared stands beside its limit in the result's last key,
+`compared`, and in the last lines of standard error. Before the result a
+run says where its time went (`phases:`, see `Phases`).
 """
 
 import argparse
 import json
 import os
+import re
 import sys
 import time
 
@@ -27,6 +32,42 @@ TRACE_DIR = os.path.join(".bench_out", "trace")
 
 def log(msg):
     print(msg, flush=True)
+
+
+class Phases:
+    """Where a run's time went, in seconds on the process's own clock.
+    `mark(name)` ends the phase `name` (now, or at a time the driver noted)
+    and says so at once, so a run that is stopped has said how far it got;
+    `line()` is the summary that goes before the result:
+
+        phases: start-up 19.1 s, warm-up 18.2, ramp 52.4, window 30.0,
+        check 224.6, whole run 345.0          (on one line)
+    """
+
+    def __init__(self):
+        self.ends = [("", T_START)]
+
+    def mark(self, name, at=None):
+        at = time.perf_counter() if at is None else at
+        self.ends.append((name, at))
+        log(f"phase: {name} ended {at - T_START:.1f} s after the start")
+
+    def line(self):
+        parts = [f"{name} {b - a:.1f}" for (_, a), (name, b)
+                 in zip(self.ends, self.ends[1:])]
+        parts[0] += " s"
+        whole = self.ends[-1][1] - T_START
+        return "phases: " + ", ".join(parts) + f", whole run {whole:.1f}"
+
+
+def parse_phases(text):
+    """{phase: seconds} from the `phases:` line of a run's output, the
+    last one where `text` holds several; None where it holds none."""
+    lines = [ln for ln in text.splitlines() if ln.startswith("phases: ")]
+    if not lines:
+        return None
+    return {name: float(value) for name, value in re.findall(
+        r"([^,]+?) (\d+(?:\.\d+)?)(?: s)?(?:, |$)", lines[-1][8:])}
 
 
 class Context:
@@ -44,24 +85,28 @@ def _device_info(devices):
 
 
 def _verdict(rows):
-    ok = True
+    """(every number within its limit, the lines that say so)."""
+    ok, lines = True, []
     for name, value, limit in rows:
         good = value <= limit
         ok = ok and good
-        log(f"compare: {name} = {value:.6g}  limit {limit:.6g}  "
-            f"{'ok' if good else 'NOT OK'}")
-    return ok
+        lines.append(f"compare: {name} = {value:.6g}  limit {limit:.6g}  "
+                     f"{'ok' if good else 'NOT OK'}")
+    return ok, lines
 
 
-def run_train(cell, opts, devices, tracer):
+def run_train(cell, opts, devices, tracer, phases):
     import numpy as np
 
     from benchmarks.harness.driver_train import TrainRun
 
     run = TrainRun(cell, opts.seed, devices, log)
+    phases.mark("start-up")
     run.first_steps()
     setup_s = time.perf_counter() - T_START
+    phases.mark("first steps", T_START + setup_s)
     rate, steps, losses = run.window(opts.seconds, tracer)
+    phases.mark("window")
     device = _device_info(devices)
     bad = int(np.sum(~np.isfinite(losses)))
     log(f"window: {steps} steps, {rate:.1f} tokens/s, first loss "
@@ -75,12 +120,16 @@ def run_train(cell, opts, devices, tracer):
     return e2e, rows, steps, bad, device, ctx
 
 
-def run_serve(cell, opts, devices, tracer):
+def run_serve(cell, opts, devices, tracer, phases):
     from benchmarks.harness.driver_serve import ServeRun
 
     run = ServeRun(cell, opts.seed, devices, log)
+    phases.mark("start-up")
     run.warm_up()
+    phases.mark("warm-up")
     run.run(opts.seconds, tracer)
+    phases.mark("ramp", run.origin)
+    phases.mark("window", run.end)
     setup_s = run.origin - T_START
     device = _device_info(devices)
     res = run.results()
@@ -141,9 +190,13 @@ def main(argv=None, require_chip=True, root=ROOT):
         shutil.rmtree(trace_dir, ignore_errors=True)
         tracer = reduce_trace.Tracer(trace_dir)
     runner = {"train": run_train, "serve": run_serve}[cell.kind]
+    phases = Phases()
     e2e, rows, attempted, failed, device, ctx = runner(
-        cell, opts, devices, tracer)
-    correct = _verdict(rows)
+        cell, opts, devices, tracer, phases)
+    phases.mark("check")
+    correct, said = _verdict(rows)
+    for line in said:
+        log(line)
 
     result = {"correct": bool(correct), "attempted": int(attempted),
               "failed": int(failed), "metrics": {}, "device": device}
@@ -176,7 +229,12 @@ def main(argv=None, require_chip=True, root=ROOT):
             if value is not None:
                 result["metrics"][m["name"]] = {"value": float(value),
                                                 "unit": m["unit"]}
+        phases.mark("readers")
+    result["compared"] = {name: {"value": value, "limit": limit}
+                          for name, value, limit in rows}
+    log(phases.line())
     print(json.dumps(result), flush=True)
+    print("\n".join(said), file=sys.stderr, flush=True)
     return 0
 
 

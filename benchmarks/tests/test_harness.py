@@ -109,7 +109,8 @@ def test_a_family_that_is_not_there_stops_the_run_with_those_that_are(
     with pytest.raises(SystemExit) as e:
         Cell("tiny_train", root)
     assert "no_such_block" in str(e.value)
-    assert "'dense_gqa_swiglu', 'tiny_alt'" in str(e.value)
+    assert "'dense_gqa_swiglu'" in str(e.value)
+    assert "'tiny_alt'" in str(e.value)
 
 
 def test_run_refuses_without_a_chip():
@@ -427,8 +428,24 @@ def _run(root, cell, trace, capsys, seed=2**31 + 17, seconds=2):
 def test_added_cell_runs_end_to_end(root, cell, metric, capsys):
     rc, res, out = _run(root, cell, 0, capsys)
     assert rc == 0 and res["correct"] is True and res["failed"] == 0
-    assert set(res) == {"correct", "attempted", "failed", "metrics",
-                        "device"}
+    assert list(res) == ["correct", "attempted", "failed", "metrics",
+                         "device", "compared"]
+    # every number compared beside its limit, as the `compare:` lines say
+    rows = [line.split() for line in out if line.startswith("compare:")]
+    assert [r[1] for r in rows] == list(res["compared"])
+    for r in rows:
+        got = res["compared"][r[1]]
+        assert float(r[3]) == pytest.approx(got["value"], rel=1e-5)
+        assert float(r[5]) == pytest.approx(got["limit"], rel=1e-5)
+    # and where the run's time went, before the result
+    assert out[-2].startswith("phases: ")
+    phases = run.parse_phases("\n".join(out))
+    first = "first steps" if cell == "tiny_train" else "warm-up"
+    assert list(phases)[:2] == ["start-up", first]
+    assert list(phases)[-2:] == ["check", "whole run"]
+    assert phases["window"] >= 2.0
+    assert sum(v for k, v in phases.items() if k != "whole run") == \
+        pytest.approx(phases["whole run"], abs=0.4)
     assert res["metrics"][metric]["value"] > 0
     assert res["metrics"]["setup_s"]["value"] > 0
     assert res["attempted"] > 0
@@ -448,6 +465,32 @@ def test_added_family_runs_end_to_end_and_its_reference_decides(
     assert res["correct"] is correct
     rows = [line for line in out if line.startswith("compare:")]
     assert rows and any("NOT OK" in r for r in rows) is not correct
+
+
+def test_a_family_of_two_kinds_of_layer_is_served_and_correct(root, capsys):
+    """`data/tiny_kinds.py`, added by files alone: a dense leading layer and
+    expert layers whose leaves differ in name and shape, each kind stacked
+    apart by the harness, served through `PagedEngine` and judged by the
+    generic reference, which hands each layer its kind's leaves."""
+    import jax.numpy as jnp
+
+    cell = _cell(root, "kinds_backlog")
+    tree = weights.make_params(cell.family, cell.config, 3, jnp.bfloat16)
+    assert set(tree) == {"dense_layers", "layers", "embedding", "final_norm",
+                         "lm_head"}
+    assert tree["dense_layers"]["w_gate"].shape == (1, 64, 160)
+    assert tree["layers"]["we_gate"].shape == (2, 4, 64, 32)
+    assert "router" not in tree["dense_layers"]
+    assert "w_gate" not in tree["layers"]
+    rc, res, out = _run(root, "kinds_backlog", 0, capsys)
+    rows = [line for line in out if line.startswith("compare:")]
+    assert rc == 0 and res["correct"] is True and res["failed"] == 0, rows
+    assert res["attempted"] > 0
+    # the reference's DENSE layer alone made wrong: not correct, so the
+    # dense kind's leaves went to the layer they belong to
+    rc, res, out = _run(root, "kindswrong_backlog", 0, capsys)
+    rows = [line for line in out if line.startswith("compare:")]
+    assert rc == 0 and res["correct"] is False and res["failed"] == 0, rows
 
 
 def test_the_added_family_and_cells_needed_no_edit(root):
@@ -545,14 +588,16 @@ def test_broken_train_step_is_not_correct(root, capsys, monkeypatch):
 def test_altered_served_token_is_not_correct(root, capsys, monkeypatch, which):
     """A token altered where it is emitted: one in five of all, or the
     tokens of ONE request among those the run finishes (every finished
-    request is compared while they are no more than COMPARE_MAX, so a fault
-    in one slot fails the run; the toy finishes hundreds, so the cap is
-    lifted for that case and stands for the other)."""
+    request is compared while they are within COMPARE_MAX requests and
+    COMPARE_TOKENS tokens, so a fault in one slot fails the run; the toy
+    finishes hundreds, so both bounds are lifted for that case and stand
+    for the other)."""
     from benchmarks.harness import driver_serve
     from paddle_tpu.serving.engine import Engine
 
     if which == "one_request":
         monkeypatch.setattr(driver_serve, "COMPARE_MAX", 10**6)
+        monkeypatch.setattr(driver_serve, "COMPARE_TOKENS", 10**9)
 
     real, n = Engine._emit, [0]
 

@@ -303,3 +303,36 @@ def test_traced_works_counts_by_hand(arch, fam):
     assert need["latent"] == pytest.approx(latent)
     ctx.counters = {"observations": {}}       # the parent's program
     assert fam.traced_work(ctx) is None
+
+
+def test_keys_padded_to_whole_buckets_change_no_logit(fam, monkeypatch):
+    """Past one token block the reference pads the decompressed keys and
+    values up to whole buckets, so that a request's own length compiles
+    nothing (PR 36). With the token block cut to 32 and the bucket to 64, 90
+    tokens (96 positions) take that path, their keys padded to 128; at the
+    real sizes they are attended unpadded: the same hidden state to float32
+    rounding."""
+    ids = np.random.default_rng(3).integers(1, 256, 90)
+    emb = weights.outer_params(MLA_ARCH, 5, jnp.float32)["embedding"]
+
+    def hidden():
+        return np.asarray(fam.forward_hidden(
+            MLA_ARCH, ids, lambda i: fam.layer_weights(
+                MLA_ARCH, 5, i, jnp.float32), emb))
+
+    whole = hidden()
+    shapes, real = [], fam._attend_fn
+
+    def noting(fz):
+        fn = real(fz)
+        return lambda qn, qr, qpos, kn, kr, v: (
+            shapes.append((kn.shape[0], kr.shape[0], v.shape[0])),
+            fn(qn, qr, qpos, kn, kr, v))[1]
+
+    monkeypatch.setattr(fam, "T_BLOCK", 32)
+    monkeypatch.setattr(fam, "K_BUCKET", 64)
+    monkeypatch.setattr(fam, "_attend_fn", noting)
+    padded = hidden()
+    assert set(shapes) == {(128, 128, 128)}        # whole buckets alone
+    assert np.abs(whole).max() > 1
+    np.testing.assert_allclose(padded, whole, rtol=0, atol=2e-5)

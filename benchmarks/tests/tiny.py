@@ -1,8 +1,8 @@
 """A tiny benchmark in a temporary root: the committed harness, readers
 and BENCHMARK.json's metrics, with toy configurations, toy mixes, toy
-cells and a second model family ADDED as new files and entries. It is how
-the tests rehearse the drivers on the CPU, and it shows that a new cell or
-a new family needs no edit."""
+cells, a second model family and a family of two kinds of layer ADDED as
+new files and entries. It is how the tests rehearse the drivers on the CPU,
+and it shows that a new cell or a new family needs no edit."""
 
 import json
 import os
@@ -64,6 +64,41 @@ ALT_ARCH = {
 ALT_SERVE_CELL = dict(TINY_SERVE_CELL, limits={"served_gap_widest": 0.02,
                                                "served_gap_mean": 1e-4})
 
+# the family of two kinds of layer (`data/tiny_kinds.py`): one dense leading
+# layer and two expert layers whose leaves differ in name and shape, every
+# ratio small; both groups stay and all four experts are picked for every
+# token, so nothing discrete stands between a bfloat16 program and the
+# float32 reference
+KINDS_FAMILY = os.path.join(os.path.dirname(__file__), "data",
+                            "tiny_kinds.py")
+KINDS_ARCH = {
+    "source": "none: a toy for the CPU tests", "family": "tiny_kinds",
+    "hidden_size": 64, "intermediate_size": 160,
+    "moe_intermediate_size": 32, "num_hidden_layers": 3,
+    "first_k_dense_replace": 1, "num_attention_heads": 4,
+    "q_lora_rank": 24, "kv_lora_rank": 32, "qk_nope_head_dim": 16,
+    "qk_rope_head_dim": 8, "v_head_dim": 16, "n_routed_experts": 4,
+    "n_shared_experts": 2, "n_group": 2, "topk_group": 2,
+    "num_experts_per_tok": 4, "routed_scaling_factor": 4,
+    "vocab_size": 256, "rms_norm_eps": 1e-06, "rope_theta": 10000,
+    "initializer_range": 0.15,
+    "rope_scaling": {"beta_fast": 32, "beta_slow": 1, "factor": 40,
+                     "mscale": 0.707, "mscale_all_dim": 0.707,
+                     "original_max_position_embeddings": 64,
+                     "type": "yarn"},
+    "reduced": [], "assumed": {}}
+# the same with the second norm's weight dropped from the REFERENCE's dense
+# layer alone: a run over that one has to come out not correct, which shows
+# that the dense kind's leaves reach the layer they belong to
+KINDS_WRONG = (re.compile(r"return _eq\.decoder_layer\(x, w, arch, mm, index\)"),
+               'return _eq.decoder_layer(x, dict(w, ln2=1.0 + 0 * w["ln2"]) '
+               'if kind == DENSE else w, arch, mm, index)')
+# over 5 seeds on the CPU (PR 36): sound mean 1.6e-5 .. 1.0e-4, widest up to
+# 0.0073; with that norm's weight dropped mean 2.4e-3 .. 5.3e-3, widest
+# 0.044 .. 0.135 (4 seeds)
+KINDS_SERVE_CELL = dict(TINY_SERVE_CELL, limits={"served_gap_widest": 0.02,
+                                                 "served_gap_mean": 5e-4})
+
 # (cell, configuration, traffic, the cell's file, its end-to-end metric)
 CELLS = [
     ("tiny_train", "tiny", "tiny_train", TINY_TRAIN_CELL,
@@ -79,7 +114,11 @@ CELLS = [
     ("altwrong_train", "tiny_alt_wrong", "tiny_train", TINY_TRAIN_CELL,
      "train_tokens_per_s"),
     ("altwrong_backlog", "tiny_alt_wrong", "tiny_backlog", ALT_SERVE_CELL,
-     "serve_out_tokens_per_s")]
+     "serve_out_tokens_per_s"),
+    ("kinds_backlog", "tiny_kinds", "tiny_backlog", KINDS_SERVE_CELL,
+     "serve_out_tokens_per_s"),
+    ("kindswrong_backlog", "tiny_kinds_wrong", "tiny_backlog",
+     KINDS_SERVE_CELL, "serve_out_tokens_per_s")]
 E2E = {"train_tokens_per_s": ("tokens/s/chip", "higher"),
        "itl_mean_ms": ("ms", "lower"),
        "serve_out_tokens_per_s": ("tokens/s", "higher")}
@@ -99,16 +138,22 @@ def tiny_root(tmp):
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     b = os.path.join(tmp, "benchmarks")
-    with open(ALT_FAMILY) as f:
-        alt = f.read()
-    wrong, n = ALT_WRONG[0].subn(ALT_WRONG[1], alt)
-    if n != 2:
-        raise RuntimeError("data/tiny_alt.py lost the two lines to break")
-    for name, text in (("tiny_alt", alt), ("tiny_alt_wrong", wrong)):
-        with open(os.path.join(b, "families", name + ".py"), "w") as f:
-            f.write(text)
+    for path, name, (pattern, repl), times in (
+            (ALT_FAMILY, "tiny_alt", ALT_WRONG, 2),
+            (KINDS_FAMILY, "tiny_kinds", KINDS_WRONG, 1)):
+        with open(path) as f:
+            text = f.read()
+        wrong, n = pattern.subn(repl, text)
+        if n != times:
+            raise RuntimeError(f"{path} lost the {times} line(s) to break")
+        for fname, body in ((name, text), (name + "_wrong", wrong)):
+            with open(os.path.join(b, "families", fname + ".py"), "w") as f:
+                f.write(body)
     configs = {"tiny": TINY_ARCH, "tiny_alt": ALT_ARCH,
-               "tiny_alt_wrong": dict(ALT_ARCH, family="tiny_alt_wrong")}
+               "tiny_alt_wrong": dict(ALT_ARCH, family="tiny_alt_wrong"),
+               "tiny_kinds": KINDS_ARCH,
+               "tiny_kinds_wrong": dict(KINDS_ARCH,
+                                        family="tiny_kinds_wrong")}
     for name, arch in configs.items():
         _dump(os.path.join(b, "configs", name + ".json"), arch)
         bench["configs"].append({"name": name, "source": "none",
