@@ -85,6 +85,14 @@ BYTE_CEILINGS = {
     "latent_prefill": 152 * 1024,
     "latent_decode": 152 * 1024,
     "latent_page_copy": 152 * 1024,
+    # the gated delta-rule hybrid family (through the one `HybridPath`):
+    # the largest buffer is a full layer's page pool (9,216 B at the toy
+    # size), which every program carries through and none copies; the
+    # state's mover tops out at the snapshots' matrix states (8,192 B)
+    "delta_prefill": 13 * 1024,
+    "delta_decode": 13 * 1024,
+    "delta_page_copy": 13 * 1024,
+    "delta_state_move": 12 * 1024,
     # int8 pool: the pool buffers shrink 2-4x but the prefill gather
     # dequantizes pages to f32 before attention, so the ceilings stay at
     # the model-dtype budget rather than scaling with the pool
@@ -215,6 +223,36 @@ def audit_latent_serving():
     return out
 
 
+DELTA_SERVING = ("delta_prefill", "delta_decode", "delta_page_copy",
+                 "delta_state_move")
+
+
+def audit_delta_serving():
+    """The gated delta-rule hybrid family's programs through the one
+    `HybridPath`: the pools and the tree of per-slot state donated and
+    aliased, no collective (the family has no mesh), no host callback, a
+    byte ceiling, and logits for the head's rows alone."""
+    progs = programs.delta_serving_programs()
+    out = []
+    from paddle_tpu.analysis.base import Violation
+    for name in sorted(set(DELTA_SERVING) - set(progs)):
+        out.append(Violation(
+            rule="audit.program-not-captured", program=name,
+            message="delta hybrid serving program was never dispatched/"
+                    "captured — scheduler or capture-harness change?"))
+    for name, p in sorted(progs.items()):
+        out += collective_audit.check_collectives(
+            p.jaxpr, name, expect_count=0, expect_fingerprint=_EMPTY_FP)
+        _donation(p, out)
+        _common(p, out)
+    if "delta_prefill" in progs:
+        p = progs["delta_prefill"]
+        out += buffer_audit.check_forbidden_shape(
+            p.jaxpr, (p.meta["bucket"], p.meta["vocab"]), p.name,
+            "logits of a whole window")
+    return out
+
+
 def audit_disagg():
     """The disaggregated-serving family: KV-page migration programs
     (model-dtype + int8 pools) and the router's GPT stripe programs —
@@ -238,7 +276,8 @@ def audit_disagg():
 
 
 def run_cpu_audits(families=("fused_ce", "train_step", "opt_writeback",
-                             "serving", "latent_serving", "disagg")):
+                             "serving", "latent_serving", "delta_serving",
+                             "disagg")):
     """Run every audit family; returns the full list of Violations
     (empty = the repo's compiled programs uphold every invariant)."""
     runners = {
@@ -247,6 +286,7 @@ def run_cpu_audits(families=("fused_ce", "train_step", "opt_writeback",
         "opt_writeback": audit_opt_writeback,
         "serving": audit_serving,
         "latent_serving": audit_latent_serving,
+        "delta_serving": audit_delta_serving,
         "disagg": audit_disagg,
     }
     out = []

@@ -30,7 +30,7 @@ import numpy as np
 __all__ = ["AuditProgram", "TOY", "toy_args", "fused_ce_programs",
            "train_step_program", "opt_writeback_program",
            "serving_programs", "latent_serving_programs",
-           "disagg_programs"]
+           "delta_serving_programs", "disagg_programs"]
 
 # one toy geometry for every family: 2 layers, divisible by a degree-2
 # TP mesh (heads, kv heads, intermediate), tiny enough that every build
@@ -356,6 +356,73 @@ def latent_serving_programs():
     recs["latent_page_copy"] = path._copy = _Recorder(path._copy)
     donated = {"latent_prefill": (6,), "latent_decode": (5,),
                "latent_page_copy": (0,)}
+    base = rng.integers(1, 96, size=12).astype(np.int32)
+    eng.serve([Request(base, max_new_tokens=3)])
+    eng.serve([Request(np.concatenate([base, base[:5]]), max_new_tokens=3)])
+    meta = {"tp": 0, "num_layers": args.num_layers, "vocab": args.vocab_size,
+            "slots": 2, "bucket": 16}
+    out = {}
+    for name, rec in recs.items():
+        traced = rec.trace()
+        if traced is not None:
+            out[name] = _from_traced(name, traced, rec.args,
+                                     donated=donated[name], meta=meta)
+    return out
+
+
+@functools.lru_cache(maxsize=None)
+def delta_serving_programs():
+    """The gated delta-rule hybrid family's step programs through the one
+    `serving/hybrid.HybridPath`, captured as `latent_serving_programs`
+    captures the latent ones: a tiny stack (one period: three linear layers
+    and a full one) serves two requests, the second a prefix hit that ends
+    mid-page (so the page copy and the snapshot's load run), and the
+    recorded callables are re-traced. Single chip: the family has no mesh.
+    The pools AND the tree of per-slot state are each step program's donated
+    arguments; the state's mover donates its destination."""
+    from paddle_tpu.models import gated_delta_functional as gdf
+    from paddle_tpu.serving import PagedEngine, Request
+
+    args = gdf.GatedDeltaArgs(
+        vocab_size=96, hidden_size=32, intermediate_size=48, num_heads=2,
+        head_dim=16, linear_heads=2, linear_key_dim=8, linear_value_dim=16,
+        conv_kernel=4, layer_kinds=(gdf.LINEAR,) * 3 + (gdf.FULL,),
+        rms_eps=1e-6)
+    h, H, dk, dv = 32, 2, 8, 16
+    C = args.conv_channels
+    rng = np.random.default_rng(3)
+
+    def leaf(*shape):
+        return jnp.asarray(0.1 * rng.standard_normal(shape), jnp.float32)
+
+    def ffn(n):
+        return {"ln1": jnp.ones((n, h)), "ln2": jnp.ones((n, h)),
+                "w_gate": leaf(n, h, 48), "w_up": leaf(n, h, 48),
+                "w_down": leaf(n, 48, h)}
+
+    params = {
+        "embedding": leaf(96, h), "final_norm": jnp.ones(h),
+        "lm_head": leaf(h, 96),
+        gdf.LINEAR: dict(
+            ffn(3), wq=leaf(3, h, H * dk), wk=leaf(3, h, H * dk),
+            wv=leaf(3, h, H * dv), wg=leaf(3, h, H * dv),
+            wo=leaf(3, H * dv, h), wa=leaf(3, h, H), wb=leaf(3, h, H),
+            conv_w=leaf(3, C, 4), A_log=leaf(3, H), dt_bias=leaf(3, H),
+            o_norm=jnp.ones((3, dv))),
+        gdf.FULL: dict(
+            ffn(1), wq=leaf(1, h, h), wk=leaf(1, h, h), wv=leaf(1, h, h),
+            wo=leaf(1, h, h), q_norm=jnp.ones((1, h)),
+            k_norm=jnp.ones((1, h)))}
+    eng = PagedEngine(params, args, max_slots=2, max_len=32, page_size=8,
+                      min_bucket=8, donate_steps=True)
+    path, recs = eng.path, {}
+    for name, table in (("delta_prefill", path._prefill),
+                        ("delta_decode", path._decode)):
+        recs[name] = table[False] = _Recorder(table[False])
+    recs["delta_page_copy"] = path._copy = _Recorder(path._copy)
+    recs["delta_state_move"] = path._move = _Recorder(path._move)
+    donated = {"delta_prefill": (8, 9), "delta_decode": (6, 7),
+               "delta_page_copy": (0,), "delta_state_move": (0,)}
     base = rng.integers(1, 96, size=12).astype(np.int32)
     eng.serve([Request(base, max_new_tokens=3)])
     eng.serve([Request(np.concatenate([base, base[:5]]), max_new_tokens=3)])

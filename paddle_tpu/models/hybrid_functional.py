@@ -21,10 +21,14 @@ head_dim` columns of the same `wk` / `wv` and no `o_norm`. The layer loop is
 unrolled (the kinds differ); the stack is indexed at run time (`_layer`).
 
 Per-request state beside the pages: `state`, one `[slots, heads, d, d]`
-float32 array a lightning layer; the pools `pk`, `pv` `[num_pages, nkv, B,
-d]` and the compressed keys `kc` `[num_pages, nkv, per, d]`, one a sparse
-layer. All are tuples of per-layer arrays so that a step updates each in
-place.
+float32 array a lightning layer; the pools `(pk, pv, kc)`: `pk`, `pv`
+`[num_pages, nkv, B, d]` and the compressed keys `kc` `[num_pages, nkv, per,
+d]`, one a sparse layer. All are tuples of per-layer arrays so that a step
+updates each in place.
+
+What `serving/hybrid.HybridPath` asks of a family's functional module is
+the last section: `pools`, `slot_state`, `tables`, `check_engine`,
+`observe_decode`, `prefill_window`, `decode_step`.
 """
 
 from __future__ import annotations
@@ -33,6 +37,7 @@ from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from paddle_tpu.kernels import lightning_attention as la
 from paddle_tpu.kernels import sparse_attention as sa
@@ -195,36 +200,82 @@ def _sparse_window(lp, x, pk, pv, kc, h, last_idx, pos, bt_row, new_pages,
     return _mlp(lp, x, args), pk, pv, kc
 
 
+# ---------------------------------------------------------------------------
+# what `serving/hybrid.HybridPath` asks of a family
+# ---------------------------------------------------------------------------
+
+def pools(args, num_pages, page_size, dtype):
+    """(pk, pv, kc): a tuple of one array a sparse layer each; the page
+    axis is axis 0 of every leaf."""
+    cfg, nkv, d = args.sparse, args.sparse_kv_heads, args.head_dim
+    n = len(args.layers_of(SPARSE))
+    page = (num_pages, nkv, page_size, d)
+    return (tuple(jnp.zeros(page, dtype) for _ in range(n)),
+            tuple(jnp.zeros(page, dtype) for _ in range(n)),
+            tuple(jnp.zeros((num_pages, nkv, cfg.per, d), dtype)
+                  for _ in range(n)))
+
+
+def slot_state(args, slots, dtype):
+    """One `[slots, heads, d, d]` float32 array a lightning layer: 2 MiB a
+    slot and layer whatever the context's length."""
+    H, d = args.num_heads, args.head_dim
+    return tuple(jnp.zeros((slots, H, d, d), jnp.float32)
+                 for _ in args.layers_of(LIGHTNING))
+
+
+def tables(args, max_len):
+    """(cos, sin) of the lightning layers' rotary positions; 2 * max_len: a
+    window's padding may pass max_len before it is cut."""
+    return lf.rope_tables(2 * max_len, args.head_dim, args.rope_theta)
+
+
+def check_engine(args, eng):
+    if eng.page_size != args.sparse.block_size:
+        raise ValueError(
+            f"page_size={eng.page_size} must equal the sparse layers' "
+            f"block_size={args.sparse.block_size}: a selection is a block "
+            "table")
+
+
+def observe_decode(args, eng, active):
+    """Pages a sparse layer's KV head reads over pages the rows hold: all
+    of a context that is still dense, the selection past that."""
+    cfg = args.sparse
+    held = eng._npos[active] // cfg.block_size + 1
+    read = np.where(eng._npos[active] + 1 <= cfg.dense_len, held,
+                    np.minimum(held, cfg.topk))
+    return {"sparse_read_share": float(read.sum()) / float(held.sum())}
+
+
 def prefill_window(params, layer_ids, ids, h, last_idx, bt_row, new_pages,
-                   slot, pk, pv, kc, state, cos, sin, args):
+                   pools, state, tables, args):
     """One prefill window of one slot: ids [s] at positions h .. h + s - 1,
     real up to `last_idx`; bt_row [P] the slot's block table; new_pages the
-    pages the window writes, from the one that holds h on. A window that
-    starts at h == 0 starts from a zero recurrent state (a recycled slot
-    keeps nothing); any other continues the slot's. layer_ids: `arange(
-    layers)` as an operand (see `_layer`). Returns (logits [vocab] at
-    last_idx, pk, pv, kc, state)."""
+    pages the window writes, from the one that holds h on; `state` the
+    SLOT's own recurrent states (no slot axis), already zero where h == 0.
+    layer_ids: `arange(layers)` as an operand (see `_layer`). Returns
+    (logits [vocab] at last_idx, pools, the slot's state)."""
     s = ids.shape[0]
     idx = jnp.arange(s, dtype=jnp.int32)
     pos, valid = h + idx, idx <= last_idx
     x = _embed(params, ids, args)
-    pk, pv, kc, state = list(pk), list(pv), list(kc), list(state)
+    (pk, pv, kc), (cos, sin) = (list(p) for p in pools), tables
+    state = list(state)
     n_sparse = n_light = 0
     for i, kind in enumerate(args.layer_kinds):
         lp = _layer(params, layer_ids[i])
         if kind == LIGHTNING:
             j, n_light = n_light, n_light + 1
-            S = jnp.where(h == 0, 0.0, state[j][slot])
-            x, S = _lightning_window(lp, x, S, pos, valid, cos, sin, args)
-            state[j] = jax.lax.dynamic_update_slice_in_dim(
-                state[j], S[None], slot, 0)
+            x, state[j] = _lightning_window(lp, x, state[j], pos, valid,
+                                            cos, sin, args)
         else:
             j, n_sparse = n_sparse, n_sparse + 1
             x, pk[j], pv[j], kc[j] = _sparse_window(
                 lp, x, pk[j], pv[j], kc[j], h, last_idx, pos, bt_row,
                 new_pages, args)
     logits = _head(params, x[last_idx][None], args)[0]
-    return logits, tuple(pk), tuple(pv), tuple(kc), tuple(state)
+    return logits, (tuple(pk), tuple(pv), tuple(kc)), tuple(state)
 
 
 # ---------------------------------------------------------------------------
@@ -266,14 +317,15 @@ def _sparse_decode(lp, x, pk, pv, kc, bt, pos, args):
     return _mlp(lp, x, args), pk, pv, kc
 
 
-def decode_step(params, layer_ids, tokens, bt, pos, live, pk, pv, kc, state,
-                cos, sin, args):
+def decode_step(params, layer_ids, tokens, bt, pos, live, pools, state,
+                tables, args):
     """One token a slot: tokens [b] at positions pos [b] through block
     tables bt [b, P]; live [b] marks the rows that decode (the others keep
     their recurrent state and write to the null page). Returns (logits [b,
-    vocab], pk, pv, kc, state)."""
+    vocab], pools, state)."""
     x = _embed(params, tokens, args)
-    pk, pv, kc, state = list(pk), list(pv), list(kc), list(state)
+    (pk, pv, kc), (cos, sin) = (list(p) for p in pools), tables
+    state = list(state)
     n_sparse = n_light = 0
     for i, kind in enumerate(args.layer_kinds):
         lp = _layer(params, layer_ids[i])
@@ -285,5 +337,5 @@ def decode_step(params, layer_ids, tokens, bt, pos, live, pk, pv, kc, state,
             j, n_sparse = n_sparse, n_sparse + 1
             x, pk[j], pv[j], kc[j] = _sparse_decode(
                 lp, x, pk[j], pv[j], kc[j], bt, pos, args)
-    return _head(params, x, args), tuple(pk), tuple(pv), tuple(kc), \
+    return _head(params, x, args), (tuple(pk), tuple(pv), tuple(kc)), \
         tuple(state)

@@ -1,25 +1,40 @@
-"""The device half of `PagedEngine` for a hybrid stack
-(`models/hybrid_functional.HybridArgs`): what a model with lightning and
-sparse layers keeps beside the block tables, and the programs over it.
+"""The device half of `PagedEngine` for a HYBRID stack: a model whose
+layers keep two kinds of per-request state, pages for its attention layers
+and a recurrent state, whatever the context's length, for its linear ones.
+One path serves every such family; what is a family's comes from its
+functional module (`FAMILIES`):
 
-  - pages for the SPARSE layers only: `pk`, `pv` [num_pages, nkv, B, d] and
-    the selector's compressed keys `kc` [num_pages, nkv, per, d], one array
-    a sparse layer, all under the allocator's page ids (a copy-on-write
-    page copy copies the three);
-  - the RECURRENT STATE of the lightning layers: one `[slots, heads, d, d]`
-    float32 array a layer, 2 MiB a slot and layer whatever the context's
-    length. A slot's state restarts from zero in the prefill window that
-    starts at position 0, and is kept through a window's padding and
-    through decode steps the slot takes no part in;
-  - `SNAPSHOTS` buffers of one slot's state, whose ids `BlockAllocator`
-    hands out: saved when a prompt's last window ends (the state after the
-    whole prompt), hung on the radix tree when the request retires, loaded
-    into a slot that hits that prefix.
+  `models/hybrid_functional` (`HybridArgs`): lightning layers (one `[slots,
+      heads, d, d]` float32 state a layer) beside block-sparse layers (pages
+      of K, V and the selector's compressed keys);
+  `models/gated_delta_functional` (`GatedDeltaArgs`): gated delta-rule
+      layers (a `[slots, H, dk, dv]` float32 matrix state AND the last rows
+      of a short convolution's input, a layer) beside full multi-head
+      attention layers (pages of K and V).
+
+A family's module gives `pools(args, num_pages, page_size, dtype)` (a tree
+whose every leaf has the PAGE axis first: a copy-on-write page copy copies
+them all), `slot_state(args, slots, dtype)` (a tree whose every leaf has the
+SLOT axis first), `tables(args, max_len)` (constants of the programs),
+`check_engine(args, eng)` (what the family needs of the engine's sizes),
+`observe_decode(args, eng, active)` (its own per-step observations) and the
+two step functions `prefill_window` / `decode_step`.
+
+What is the PATH's is written once over those trees:
+
+  - a slot's state restarts from zero in the prefill window that starts at
+    position 0 (a recycled slot keeps nothing), and is kept through a
+    window's padding and through decode steps the slot takes no part in;
+  - `SNAPSHOTS` buffers of one slot's state (every leaf of it), whose ids
+    `BlockAllocator` hands out: saved when a prompt's last window ends (the
+    state after the whole prompt), hung on the radix tree when the request
+    retires, loaded into a slot that hits that prefix;
+  - preempt / resume carry the slot's state out and back in;
+  - the refusals: `mesh=`, `kv_dtype='int8'`, `draft_params=`, a hand-off.
 
 One prefill program a window bucket and one decode program serve every
 context length: block tables, positions, the slot and the page vectors are
-traced, the sparse layers' loops follow the traced position, and the dense
-rule (context <= dense_len) is a `where` beside the selection.
+traced.
 """
 
 from __future__ import annotations
@@ -30,111 +45,105 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from paddle_tpu.models import gated_delta_functional as gdf
 from paddle_tpu.models import hybrid_functional as hf
-from paddle_tpu.models import llama_functional as lf
 from paddle_tpu.serving.sampler import pick as _pick
 
-__all__ = ["HybridPath", "SNAPSHOTS"]
+__all__ = ["HybridPath", "SNAPSHOTS", "FAMILIES"]
 
 SNAPSHOTS = 8      # snapshot buffers (one slot's state each)
 
+# type of the model description -> the family's functional module
+FAMILIES = {hf.HybridArgs: hf, gdf.GatedDeltaArgs: gdf}
+
+
+def _move_rows(dst, src, to, frm):
+    """dst[to] = src[frm] along axis 0 of every leaf of two like trees."""
+    return jax.tree_util.tree_map(
+        lambda d, s: jax.lax.dynamic_update_slice_in_dim(
+            d, jax.lax.dynamic_slice_in_dim(s, frm, 1, axis=0), to, axis=0),
+        dst, src)
+
 
 def _prefill_traced(params, layer_ids, ids, h, last_idx, bt_row, new_pages,
-                    slot, pk, pv, kc, state, cos, sin, temp, top_p, top_k,
-                    seeds, *, args, metrics, sample=False):
+                    slot, pools, state, tables, temp, top_p, top_k, seeds, *,
+                    family, args, metrics, sample=False):
     metrics.inc("prefill_compiles")
-    logits, pk, pv, kc, state = hf.prefill_window(
-        params, layer_ids, ids[0], h, last_idx, bt_row, new_pages, slot, pk,
-        pv, kc, state, cos, sin, args)
+    # the slot's own state; a window that starts at position 0 starts from
+    # zero in every leaf (a recycled slot keeps nothing)
+    own = jax.tree_util.tree_map(
+        lambda a: jnp.where(h == 0, jnp.zeros((), a.dtype), a[slot]), state)
+    logits, pools, own = family.prefill_window(
+        params, layer_ids, ids[0], h, last_idx, bt_row, new_pages, pools,
+        own, tables, args)
+    state = jax.tree_util.tree_map(
+        lambda a, o: jax.lax.dynamic_update_slice_in_dim(a, o[None], slot, 0),
+        state, own)
     first = _pick(logits[None], sample, temp, top_p, top_k, seeds,
                   h + last_idx + 1)[0]
-    return pk, pv, kc, state, first
+    return pools, state, first
 
 
-def _decode_traced(params, layer_ids, tokens, bt, pos, live, pk, pv, kc,
-                   state, cos, sin, temp, top_p, top_k, seeds, *, args,
+def _decode_traced(params, layer_ids, tokens, bt, pos, live, pools, state,
+                   tables, temp, top_p, top_k, seeds, *, family, args,
                    metrics, sample=False):
     metrics.inc("decode_compiles")
-    logits, pk, pv, kc, state = hf.decode_step(
-        params, layer_ids, tokens, bt, pos, live, pk, pv, kc, state, cos,
-        sin, args)
-    return pk, pv, kc, state, _pick(logits, sample, temp, top_p, top_k,
-                                    seeds, pos + 1)
+    logits, pools, state = family.decode_step(
+        params, layer_ids, tokens, bt, pos, live, pools, state, tables, args)
+    return pools, state, _pick(logits, sample, temp, top_p, top_k, seeds,
+                               pos + 1)
 
 
 @jax.named_scope("pt.kv_write")
-def _copy_page_traced(pk, pv, kc, src, dst):
-    """Copy-on-write: one page's K, V and compressed keys, every sparse
-    layer (the page axis is axis 0 of every leaf)."""
-    def cp(a):
-        return jax.lax.dynamic_update_slice_in_dim(
-            a, jax.lax.dynamic_slice_in_dim(a, src, 1, axis=0), dst, axis=0)
-
-    return jax.tree_util.tree_map(cp, (pk, pv, kc))
-
-
-def _move_state_traced(dst, src, to, frm):
-    """dst[to] = src[frm] in every lightning layer's array."""
-    return tuple(jax.lax.dynamic_update_slice_in_dim(
-        d, jax.lax.dynamic_slice_in_dim(s, frm, 1, axis=0), to, axis=0)
-        for d, s in zip(dst, src))
+def _copy_page_traced(pools, src, dst):
+    """Copy-on-write: one page of every pool (the page axis is axis 0 of
+    every leaf)."""
+    return _move_rows(pools, pools, dst, src)
 
 
 class HybridPath:
-    """Pools, recurrent state, snapshots and step programs of one engine."""
+    """Pools, per-slot state, snapshots and step programs of one engine."""
 
     def __init__(self, eng):
         args, self.eng = eng.args, eng
+        family = self.family = FAMILIES[type(args)]
         for given, what, why in (
-                (eng.mesh, "mesh=", "the recurrent state and the selection "
-                 "have no tensor-parallel placement yet"),
-                (eng.kv_dtype, "kv_dtype='int8'", "the selector's "
-                 "compressed keys are means of unquantized keys"),
+                (eng.mesh, "mesh=", "the recurrent state has no "
+                 "tensor-parallel placement yet"),
+                (eng.kv_dtype, "kv_dtype='int8'", "the hybrid families' "
+                 "pools hold unquantized keys (a selector's compressed keys "
+                 "are means of them) and have no int8 write path"),
                 (eng.draft_params, "draft_params=", "a rejected draft "
                  "token cannot be taken back out of a recurrent state")):
             if given is not None:
                 raise ValueError(f"{what} is not supported for a "
                                  f"hybrid model: {why}")
         args.validate()
-        cfg = args.sparse
-        if eng.page_size != cfg.block_size:
-            raise ValueError(
-                f"page_size={eng.page_size} must equal the sparse layers' "
-                f"block_size={cfg.block_size}: a selection is a block table")
+        family.check_engine(args, eng)
         if eng.prefix_policy != "radix":
             raise ValueError("a hybrid model needs prefix_policy='radix': "
                              "its state snapshots hang on the radix tree")
         dtype = jax.tree_util.tree_leaves(eng.params["embedding"])[0].dtype
-        n_sparse = len(args.layers_of(hf.SPARSE))
-        n_light = len(args.layers_of(hf.LIGHTNING))
-        nkv, d, H = args.sparse_kv_heads, args.head_dim, args.num_heads
-        page = (eng.num_pages, nkv, cfg.block_size, d)
-        self.pk = tuple(jnp.zeros(page, dtype) for _ in range(n_sparse))
-        self.pv = tuple(jnp.zeros(page, dtype) for _ in range(n_sparse))
-        self.kc = tuple(jnp.zeros((eng.num_pages, nkv, cfg.per, d), dtype)
-                        for _ in range(n_sparse))
-        self.state = tuple(jnp.zeros((eng.max_slots, H, d, d), jnp.float32)
-                           for _ in range(n_light))
-        self.snaps = tuple(jnp.zeros((self.snapshots, H, d, d), jnp.float32)
-                           for _ in range(n_light))
+        self.pools = family.pools(args, eng.num_pages, eng.page_size, dtype)
+        self.state = family.slot_state(args, eng.max_slots, dtype)
+        self.snaps = family.slot_state(args, self.snapshots, dtype)
+        self.tables = family.tables(args, eng.max_len)
         self.layer_ids = jnp.arange(args.num_layers, dtype=jnp.int32)
-        self.cos, self.sin = lf.rope_tables(2 * eng.max_len, d,
-                                            args.rope_theta)
         self.reset()
 
         donate = eng._donate_enabled()
-        kw = dict(args=args, metrics=eng.metrics)
+        kw = dict(family=family, args=args, metrics=eng.metrics)
         self._prefill, self._decode = {}, {}
         for sample in (False, True):
             self._prefill[sample] = jax.jit(
                 functools.partial(_prefill_traced, sample=sample, **kw),
-                donate_argnums=(8, 9, 10, 11) if donate else ())
+                donate_argnums=(8, 9) if donate else ())
             self._decode[sample] = jax.jit(
                 functools.partial(_decode_traced, sample=sample, **kw),
-                donate_argnums=(6, 7, 8, 9) if donate else ())
+                donate_argnums=(6, 7) if donate else ())
         self._copy = jax.jit(_copy_page_traced,
-                             donate_argnums=(0, 1, 2) if donate else ())
-        self._move = jax.jit(_move_state_traced,
+                             donate_argnums=(0,) if donate else ())
+        self._move = jax.jit(_move_rows,
                              donate_argnums=(0,) if donate else ())
 
     snapshots = SNAPSHOTS
@@ -150,21 +159,20 @@ class HybridPath:
                        for x in jax.tree_util.tree_leaves(tree))
 
         m = self.eng.metrics
-        m.set_gauge("kv_pool_bytes", nbytes((self.pk, self.pv, self.kc)))
+        m.set_gauge("kv_pool_bytes", nbytes(self.pools))
         m.set_gauge("recurrent_state_bytes", nbytes(self.state))
 
     # -- pages ----------------------------------------------------------------
     def copy_page(self, src, dst):
-        self.pk, self.pv, self.kc = self._copy(
-            self.pk, self.pv, self.kc, jnp.int32(src), jnp.int32(dst))
+        self.pools = self._copy(self.pools, jnp.int32(src), jnp.int32(dst))
 
     def check_handoff(self):
         raise ValueError(
             "disaggregated workers do not serve a hybrid model: a "
-            "`KVHandoff` ships pages, and the lightning layers' recurrent "
+            "`KVHandoff` ships pages, and the linear layers' recurrent "
             "state is in none of them")
 
-    # -- recurrent state --------------------------------------------------------
+    # -- per-slot state -----------------------------------------------------------
     def load_snapshot(self, slot, sid):
         self.state = self._move(self.state, self.snaps, jnp.int32(slot),
                                 jnp.int32(sid))
@@ -197,14 +205,14 @@ class HybridPath:
     def take_state(self, slot):
         """What a preempted slot leaves with: its state, out of the slot's
         row, and the snapshot waiting for the request's retirement."""
-        one = tuple(jnp.zeros((1,) + s.shape[1:], s.dtype)
-                    for s in self.state)
+        one = jax.tree_util.tree_map(
+            lambda a: jnp.zeros((1,) + a.shape[1:], a.dtype), self.state)
         return (self._move(one, self.state, jnp.int32(0), jnp.int32(slot)),
                 self.pending.pop(slot, None))
 
     def put_state(self, slot, saved):
-        recurrent, sid = saved
-        self.state = self._move(self.state, recurrent, jnp.int32(slot),
+        own, sid = saved
+        self.state = self._move(self.state, own, jnp.int32(slot),
                                 jnp.int32(0))
         if sid is not None:
             self.pending[slot] = sid
@@ -212,30 +220,26 @@ class HybridPath:
     # -- the two step programs ----------------------------------------------------
     def prefill(self, ids, start, last_idx, bt_row, new_vec, slot, req,
                 sample):
-        self.pk, self.pv, self.kc, self.state, first = self._prefill[sample](
+        self.pools, self.state, first = self._prefill[sample](
             self.eng.params, self.layer_ids, jnp.asarray(ids),
             jnp.int32(start),
             jnp.int32(last_idx), jnp.asarray(bt_row), jnp.asarray(new_vec),
-            jnp.int32(slot), self.pk, self.pv, self.kc, self.state,
-            self.cos, self.sin, jnp.float32(req.temperature),
+            jnp.int32(slot), self.pools, self.state, self.tables,
+            jnp.float32(req.temperature),
             jnp.float32(req.top_p), jnp.int32(req.top_k),
             jnp.asarray([req.seed], jnp.int32))
         return first
 
     def decode(self, bt, active, sample, sampling_args):
-        eng, cfg = self.eng, self.eng.args.sparse
+        eng = self.eng
         live = np.zeros(eng.max_slots, bool)
         live[active] = True
-        # pages a sparse layer's KV head reads over pages the rows hold:
-        # all of a context that is still dense, the selection past that
-        held = eng._npos[active] // cfg.block_size + 1
-        read = np.where(eng._npos[active] + 1 <= cfg.dense_len, held,
-                        np.minimum(held, cfg.topk))
-        eng.metrics.observe("sparse_read_share",
-                            float(read.sum()) / float(held.sum()))
-        self.pk, self.pv, self.kc, self.state, nxt = self._decode[sample](
+        for name, value in self.family.observe_decode(
+                eng.args, eng, active).items():
+            eng.metrics.observe(name, value)
+        self.pools, self.state, nxt = self._decode[sample](
             eng.params, self.layer_ids, jnp.asarray(eng._last_tok),
             jnp.asarray(bt),
-            jnp.asarray(eng._npos), jnp.asarray(live), self.pk, self.pv,
-            self.kc, self.state, self.cos, self.sin, *sampling_args)
+            jnp.asarray(eng._npos), jnp.asarray(live), self.pools,
+            self.state, self.tables, *sampling_args)
         return nxt

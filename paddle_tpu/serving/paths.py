@@ -35,6 +35,7 @@ forward under `models/`, a path beside `dense.py`, an entry in `PATHS`.
 
 from __future__ import annotations
 
+from paddle_tpu.models.gated_delta_functional import GatedDeltaArgs
 from paddle_tpu.models.hybrid_functional import HybridArgs
 from paddle_tpu.models.latent_moe_functional import LatentMoEArgs
 from paddle_tpu.models.llama_functional import LlamaArgs
@@ -46,7 +47,7 @@ __all__ = ["PATHS", "path_for"]
 
 # type of the model description -> the family's device half
 PATHS = {LlamaArgs: DensePath, HybridArgs: HybridPath,
-         LatentMoEArgs: LatentPath}
+         GatedDeltaArgs: HybridPath, LatentMoEArgs: LatentPath}
 
 
 def path_for(eng):

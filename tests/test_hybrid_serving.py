@@ -346,21 +346,25 @@ class Stepper:
         new = np.zeros(self.P, np.int32)
         touched = self.bt_row[h // B: -(-e // B)]
         new[:len(touched)] = touched
-        logits, self.pk, self.pv, self.kc, self.state = _PREFILL(
+        # what `serving/hybrid._prefill_traced` does around the family's
+        # window: the slot's own state, zero where the window starts at 0
+        own = tuple(jnp.where(h == 0, 0.0, s[1]) for s in self.state)
+        logits, (self.pk, self.pv, self.kc), own = _PREFILL(
             self.params, self.layer_ids, jnp.asarray(padded), jnp.int32(h),
             jnp.int32(e - 1 - h), jnp.asarray(self.bt_row), jnp.asarray(new),
-            jnp.int32(1), self.pk, self.pv, self.kc, self.state, self.cos,
-            self.sin, args=self.args)
+            (self.pk, self.pv, self.kc), own, (self.cos, self.sin),
+            args=self.args)
+        self.state = tuple(s.at[1].set(o) for s, o in zip(self.state, own))
         return np.asarray(logits)
 
     def step(self, token, t):
         bt = np.zeros((self.SLOTS, self.P), np.int32)
         bt[1] = self.bt_row
-        logits, self.pk, self.pv, self.kc, self.state = _DECODE(
+        logits, (self.pk, self.pv, self.kc), self.state = _DECODE(
             self.params, self.layer_ids, jnp.asarray([0, token], jnp.int32),
             jnp.asarray(bt), jnp.asarray([0, t], jnp.int32),
-            jnp.asarray([False, True]), self.pk, self.pv, self.kc,
-            self.state, self.cos, self.sin, args=self.args)
+            jnp.asarray([False, True]), (self.pk, self.pv, self.kc),
+            self.state, (self.cos, self.sin), args=self.args)
         return np.asarray(logits)[1]
 
 

@@ -442,6 +442,36 @@ class TestProgramFamilies:
         assert any(x.rule == "audit.program-not-captured"
                    and x.program == "latent_page_copy" for x in v)
 
+    def test_delta_serving_family_clean(self):
+        assert presets.audit_delta_serving() == []
+
+    def test_delta_serving_captured_all_programs(self):
+        progs = programs.delta_serving_programs()
+        assert set(presets.DELTA_SERVING) <= set(progs), \
+            "a delta hybrid serving program stopped being captured"
+
+    @pytest.mark.parametrize("name", presets.DELTA_SERVING)
+    def test_delta_pools_and_state_are_donated_and_no_host_is_called(
+            self, name):
+        """The pools AND the tree of per-slot state (matrix states and
+        convolution rows) alias their outputs; empty collective golden."""
+        p = programs.delta_serving_programs()[name]
+        assert p.donated and donation_audit.check_donation(
+            p.lowered_text, p.example_args, p.donated, p.name, kept=p.kept,
+            compiled_text=p.compiled_text) == []
+        assert host_sync_audit.check_host_sync(p.jaxpr, p.name) == []
+        assert collective_audit.collective_census(p.jaxpr) == []
+
+    def test_missing_delta_program_is_reported_not_silent(self,
+                                                          monkeypatch):
+        real = programs.delta_serving_programs()
+        pruned = {k: v for k, v in real.items() if k != "delta_state_move"}
+        monkeypatch.setattr(programs, "delta_serving_programs",
+                            lambda: pruned)
+        v = presets.audit_delta_serving()
+        assert any(x.rule == "audit.program-not-captured"
+                   and x.program == "delta_state_move" for x in v)
+
     def test_disagg_family_clean(self):
         assert presets.audit_disagg() == []
 

@@ -64,6 +64,10 @@ _SHAPES = {
     # compacted table of a selection (64) or a dense context (128 pages)
     "minicpm_sala_compacted_table": (64, 16, 1, 128, 64, 128, 16384,
                                      "bfloat16", None),
+    # a delta hybrid model's full layers: multi-head attention, 30 query
+    # heads over 30 KV heads a page (a page of K is 491,520 B)
+    "olmo_hybrid_chat_replies_cell": (64, 30, 30, 128, 64, 96, 1920,
+                                      "bfloat16", None),
 }
 
 
