@@ -214,6 +214,27 @@ def kernel_cases(args):
          (qd, codes(), codes(), bt, pos, scales(num_pages, nkv),
           scales(num_pages, nkv)))]
 
+    # a prefill window through one slot's table (a chunk that starts inside a
+    # page and ends short of its bucket: a padded row sees what the last
+    # real one does, in the kernel and the oracle), model-dtype and int8 pools
+    from paddle_tpu.kernels import paged_prefill_attention as ppa
+
+    qw, h, last = normal(CHUNK, nh, hd), jnp.int32(300), jnp.int32(CHUNK - 57)
+    cases += [
+        ("paged_prefill", ("paged_prefill_attention",),
+         ppa.paged_prefill_attention,
+         lambda q, k, v, b, h, l: ppa._xla(*up(q, k, v), b, h, l, None, None,
+                                           None, scale),
+         (qw, normal(num_pages, nkv, PAGE, hd),
+          normal(num_pages, nkv, PAGE, hd), bt[0], h, last)),
+        ("paged_prefill_int8", ("paged_prefill_attention",),
+         lambda q, k, v, b, h, l, ks, vs: ppa.paged_prefill_attention(
+             q, k, v, b, h, l, k_scale=ks, v_scale=vs),
+         lambda q, k, v, b, h, l, ks, vs: ppa._xla(
+             q.astype(f32), k, v, b, h, l, None, ks, vs, scale),
+         (qw, codes(), codes(), bt[0], h, last, scales(num_pages, nkv),
+          scales(num_pages, nkv)))]
+
     # weight-only int8 matmul at the decode batch, every weight shape (and
     # batch 1 against the K-tail weight: fewer rows than a sublane tile)
     H, I, V = args.hidden_size, args.intermediate_size, args.vocab_size
@@ -399,7 +420,7 @@ def serve(label, params, args, requests, refs, forward, *, gap_bar,
     P, path = eng.pages_per_slot, eng.path
     zeros = jnp.zeros((P,), jnp.int32)
     prefill_names = _mosaic_kernels(
-        ("_window_kernel",), path._prefill[False], eng.params,
+        ("paged_prefill_attention",), path._prefill[False], eng.params,
         jnp.zeros((1, MIN_BUCKET), jnp.int32), jnp.int32(0), jnp.int32(0),
         zeros, zeros, path.pk, path.pv, path.cos, path.sin, jnp.float32(0),
         jnp.float32(1), jnp.int32(0), jnp.zeros((1,), jnp.int32))

@@ -528,6 +528,119 @@ def _paged_forward_decode(params, ids, pool_k, pool_v, bt, pos, cos, sin,
     return logits.astype(jnp.float32), new_k, new_v
 
 
+@jax.named_scope("pt.kv_write")
+def _quant_write_window_pages(pool, new, h, end, bt_row, new_pages, ps):
+    """`hybrid_functional._write_window_pages` for an int8 pool: the
+    window's rows `new` [s, nkv, hd] (positions h ..; s whole pages, as
+    there: `s // ps + 1` pages then hold the window from any h) go into
+    `new_pages` (from the page that holds h on), QUANTISED as they are
+    written: per (page, kv head) absmax over the valid positions only (`end`
+    is the first position past the window's last real token: what a padded
+    row computed would inflate the scale and crush the real values; masked
+    positions store 0), the kept half of a straddled page dequantised
+    first."""
+    s, nkv, hd = new.shape
+    n = min(s // ps + 1, new_pages.shape[0])
+    page0 = bt_row[h // ps]
+    first = (pool.q[page0].astype(jnp.float32)
+             * (pool.scale[page0] / 127.0)[:, None, None])      # [nkv, ps, hd]
+    buf = jnp.zeros(((n + 1) * ps, nkv, hd), jnp.float32)
+    buf = jax.lax.dynamic_update_slice_in_dim(
+        buf, jnp.swapaxes(first, 0, 1), 0, 0)
+    buf = jax.lax.dynamic_update_slice_in_dim(
+        buf, new.astype(jnp.float32), h % ps, 0)
+    pos = h - h % ps + jnp.arange(n * ps, dtype=jnp.int32)
+    x = jnp.where((pos < end)[:, None, None], buf[:n * ps], 0.0)
+    x = x.reshape(n, ps, nkv, hd)
+    scale = jnp.max(jnp.abs(x), axis=(1, 3))                    # [n, nkv]
+    codes = jnp.clip(jnp.round(
+        x / jnp.maximum(scale, 1e-9)[:, None, :, None] * 127.0),
+        -127, 127).astype(jnp.int8)
+    # heads-major pages last, next to the write, as `_write_window_pages`
+    # has it: a transpose further up the chain has XLA re-lay the POOL
+    return QuantizedKVPage(
+        pool.q.at[new_pages[:n]].set(jnp.swapaxes(codes, 1, 2)),
+        pool.scale.at[new_pages[:n]].set(scale))
+
+
+def _paged_forward_prefill(params, ids, pool_k, pool_v, h, last_idx, bt_row,
+                           new_pages, cos, sin, args, page_size,
+                           tp_axis=None, tp_degree=1):
+    """One prefill window of one slot over the PAGED cache: ids [1, sb] at
+    positions h .. h + sb - 1 (h traced; real up to `last_idx`) -> (logits
+    [1, vocab] at `last_idx`, new pools). The prefill twin of
+    `_paged_forward_decode`: the pools [L, num_pages, nkv, ps, hd] are the
+    layer scan's carry, viewed layer-major; a layer writes the window's k /
+    v into the window's own pages of its run (`new_pages`: the slot's pages
+    from the one that holds h on; write-before-attend) and attends over the
+    pool through the slot's table `bt_row` [P], as far as the window's last
+    position. Nothing here has the table's width in positions, a layer's or
+    a pool's size but the pools themselves, returned updated in place."""
+    from paddle_tpu.kernels.paged_prefill_attention import (
+        paged_prefill_attention)
+    from paddle_tpu.models.hybrid_functional import _write_window_pages
+
+    x = jnp.take(params["embedding"], ids, axis=0)
+    sb, ps = ids.shape[1], page_size
+    L, num_pages = jax.tree_util.tree_leaves(pool_k)[0].shape[:2]
+    quantized = isinstance(pool_k, QuantizedKVPage)
+    tree_map = jax.tree_util.tree_map
+    cos_w = jax.lax.dynamic_slice_in_dim(cos, h, sb, 0)
+    sin_w = jax.lax.dynamic_slice_in_dim(sin, h, sb, 0)
+
+    def rows(a):
+        # the writers put `s // ps + 1` pages, which hold a window from
+        # any h only if s is whole pages: a bucket that is not (the
+        # engine's `min_bucket` may lie below `page_size`) is written as
+        # whole pages of rows, zeros past its own (positions no query
+        # reads before its own token's write)
+        pad = -sb % ps
+        return jnp.pad(a[0], ((0, pad), (0, 0), (0, 0))) if pad else a[0]
+
+    def step(carry, xs):
+        x, pk, pv = carry
+        lp, layer = xs
+        base = layer * num_pages
+        table, pages = base + bt_row, base + new_pages
+
+        def attend(q, k, v):
+            q, k = lf.apply_rope(q, k, cos_w, sin_w)
+            k, v = rows(k), rows(v)
+            if quantized:
+                end = h + last_idx + 1
+                nk = _quant_write_window_pages(pk, k, h, end, table, pages,
+                                               ps)
+                nv = _quant_write_window_pages(pv, v, h, end, table, pages,
+                                               ps)
+                kq, vq = nk.q, nv.q
+                # the layer's own scales: what the kernel holds in SMEM
+                ks = jax.lax.dynamic_slice_in_dim(nk.scale, base, num_pages)
+                vs = jax.lax.dynamic_slice_in_dim(nv.scale, base, num_pages)
+            else:
+                nk = _write_window_pages(pk, k.astype(pk.dtype), h, table,
+                                         pages, ps)
+                nv = _write_window_pages(pv, v.astype(pv.dtype), h, table,
+                                         pages, ps)
+                kq, ks, vq, vs = nk, None, nv, None
+            attn = paged_prefill_attention(q[0], kq, vq, bt_row, h, last_idx,
+                                           page_base=base, k_scale=ks,
+                                           v_scale=vs)
+            return attn[None], nk, nv
+
+        return _serving_layer(lp, x, args, attend, tp_axis, tp_degree), None
+
+    (x, *pools), _ = jax.lax.scan(
+        step,
+        (x, *tree_map(lambda a: a.reshape((L * num_pages,) + a.shape[2:]),
+                      (pool_k, pool_v))),
+        (params["layers"], jnp.arange(L, dtype=jnp.int32)))
+    new_k, new_v = tree_map(
+        lambda a: a.reshape((L, num_pages) + a.shape[1:]), pools)
+    x = lf.rms_norm(x, params["final_norm"], args.rms_eps)
+    logits = _wmm(_last_hidden(x, last_idx), params["lm_head"])
+    return logits.astype(jnp.float32), new_k, new_v
+
+
 def _paged_forward_verify(params, ids, pool_k, pool_v, bt, pos, limit,
                           cos, sin, args, page_size, tp_axis=None,
                           tp_degree=1):

@@ -7,15 +7,19 @@ over them. `PagedEngine` holds the block tables and never a pool.
     (heads-major pages, the layout the Pallas paged decode kernel consumes),
     in the model dtype or, with `kv_dtype="int8"`, a
     `generation.QuantizedKVPage` of int8 codes and per-(page, kv-head)
-    absmax scales: prefill scatters quantize whole pages, decode / verify
-    writes keep a RUNNING absmax (re-scaling a page's codes in registers
-    when a token exceeds its scale) and attention dequantizes inside the
-    paged kernel (on TPU it needs page_size % 32 == 0 and head_dim % 128 ==
-    0; other shapes ride the dequant-gather fall-back);
-  - PREFILL gathers a slot's pages into a contiguous stripe, forwards the
-    window at a traced position and scatters the written pages back (one
-    program a window bucket); DECODE is one batched paged step through the
-    block tables; a copy-on-write clones one page across layers;
+    absmax scales: a prefill window quantizes its pages as it writes them
+    (absmax over the valid positions, a straddled page's kept half
+    dequantized first), decode / verify writes keep a RUNNING absmax
+    (re-scaling a page's codes in registers when a token exceeds its scale)
+    and attention dequantizes inside the paged kernels (on TPU they need
+    page_size % 32 == 0 and head_dim % 128 == 0; other shapes ride the
+    jnp fall-backs, which dequantize what they gather);
+  - PREFILL writes a window's K / V into the window's own pages and
+    attends over the pool through the slot's block table, as far as the
+    window's last position (`kernels/paged_prefill_attention.py`; one
+    program a window bucket): nothing in it has the table's width; DECODE
+    is one batched paged step through the block tables; a copy-on-write
+    clones one page across layers;
   - with a `mesh`, weights take the Megatron split and the pools shard on
     their nkv axis (`serving/tp.py`); every program then runs as one
     shard_map SPMD program (`sharded`, which `SpecDecoder`'s verify programs
@@ -45,116 +49,32 @@ __all__ = ["DensePath"]
 
 def _paged_prefill_traced(params, ids, h, last_idx, bt_row, new_pages,
                           pk, pv, cos, sin, temp, top_p, top_k, seeds, *,
-                          args, metrics, page_size, pages_per_slot,
-                          sample=False, tp_axis=None, tp_degree=1):
+                          args, metrics, page_size, sample=False,
+                          tp_axis=None, tp_degree=1):
     """Prefill a suffix window whose first `h` positions are already
-    cached: gather the slot's pages into a contiguous scratch stripe,
-    forward the window tokens at position h, scatter the freshly written
-    pages back.
+    cached: write the window's K / V into the window's own pages and attend
+    over the pool through the slot's block table
+    (`generation._paged_forward_prefill`).
 
     ids: [1, sb] window right-padded to a length bucket; h: traced token
     count already cached (prefix hits AND previously prefilled chunks —
     TOKEN-granular under the radix cache, so h may sit mid-page: the
-    straddled page is gathered from the frozen cached page and the
-    scatter rewrites the slot's COW copy of it from the page-aligned
-    base); last_idx: index of the window's last real token WITHIN the
-    block; bt_row/new_pages: [P] page indices (unused entries -> null
-    page 0). One XLA program per window bucket — h, last_idx and the
-    page vectors are traced operands, so neither hit depth nor chunk
-    position recompiles."""
+    straddled page is the slot's COW copy of the frozen cached page, whose
+    positions below h keep what they hold); last_idx: index of the window's
+    last real token WITHIN the block; bt_row: [P] the slot's pages;
+    new_pages: [P] the pages the window writes, from the one that holds h
+    on (unused entries -> null page 0). One XLA program per window bucket —
+    h, last_idx and the page vectors are traced operands, so neither hit
+    depth nor chunk position recompiles."""
     metrics.inc("prefill_compiles")
-    quantized = isinstance(pk, gen.QuantizedKVPage)
-    arr = pk.q if quantized else pk
-    L, nkv, hd = arr.shape[0], arr.shape[2], arr.shape[4]
-    ps, Pn = page_size, pages_per_slot
-    sb = ids.shape[1]
-    dtype = params["embedding"].dtype if quantized else pk.dtype
-
-    # gather the block-table row into contiguous [L, 1, nkv, P*ps, hd]
-    # (hit pages carry real prefix K/V; later entries are garbage that the
-    # suffix writes + position mask keep unread), then pad by the suffix
-    # bucket so the write at [h, h+sb) can never clamp. An int8 pool
-    # dequantizes in the gather — the scratch stripe the forward runs
-    # over is always the compute dtype
-    with jax.named_scope("pt.kv_gather"):
-        if quantized:
-            def dq(pool):
-                raw = pool.q[:, bt_row].astype(jnp.float32)  # [L,P,nkv,ps,hd]
-                sc = (pool.scale[:, bt_row] / 127.0)[..., None, None]
-                return (raw * sc).astype(dtype)
-
-            g_k = jnp.swapaxes(dq(pk), 1, 2).reshape(L, 1, nkv, Pn * ps, hd)
-            g_v = jnp.swapaxes(dq(pv), 1, 2).reshape(L, 1, nkv, Pn * ps, hd)
-        else:
-            g_k = jnp.swapaxes(pk[:, bt_row], 1, 2).reshape(
-                L, 1, nkv, Pn * ps, hd)
-            g_v = jnp.swapaxes(pv[:, bt_row], 1, 2).reshape(
-                L, 1, nkv, Pn * ps, hd)
-        # (the pad itself rounds up to the 128-position tile: the Pallas
-        # window kernel only takes a 128-aligned stripe, and with a bare
-        # `sb` pad the smallest bucket's stripe never was)
-        pad = jnp.zeros((L, 1, nkv, -(-sb // 128) * 128, hd), dtype)
-        temp_k = jnp.concatenate([g_k, pad], axis=3)
-        temp_v = jnp.concatenate([g_v, pad], axis=3)
-
-    logits, temp_k, temp_v = gen._forward_cached(
-        params, ids, temp_k, temp_v, h, cos, sin, args, last_idx=last_idx,
-        tp_axis=tp_axis, tp_degree=tp_degree)
+    logits, pk, pv = gen._paged_forward_prefill(
+        params, ids, pk, pv, h, last_idx, bt_row, new_pages, cos, sin, args,
+        page_size, tp_axis=tp_axis, tp_degree=tp_degree)
     # the emitted token sits at sequence index h + last_idx + 1 — the
     # (seed, position) the offline generate(seeds=...) would use
     first = _pick(logits, sample, temp, top_p, top_k, seeds,
                   h + last_idx + 1)[0]
-
-    # scatter the freshly written pages back from the page-aligned base
-    # below h: when h is mid-page the first chunk carries the gathered
-    # cached half [base, h) plus the new tokens — exactly the COW-copy
-    # content. Unused entries land on the null page.
-    base = h - h % ps
-    pk, pv = _scatter_window(pk, pv, temp_k, temp_v, new_pages, base,
-                             h + last_idx + 1, ps, Pn)
     return pk, pv, first
-
-
-@jax.named_scope("pt.kv_write")
-def _scatter_window(pk, pv, temp_k, temp_v, new_pages, base, end, ps, Pn):
-    """Cut the scratch stripe into pages from `base` on and write them to
-    `new_pages` of the pool (quantizing them for an int8 pool; `end` is the
-    first position past the window's last real token)."""
-    quantized = isinstance(pk, gen.QuantizedKVPage)
-
-    def chunk(t, i):
-        return jax.lax.dynamic_slice_in_dim(t, base + i * ps, ps, axis=3)
-
-    new_k = jnp.concatenate([chunk(temp_k, i) for i in range(Pn)], axis=1)
-    new_v = jnp.concatenate([chunk(temp_v, i) for i in range(Pn)], axis=1)
-    if quantized:
-        # scatter-time quantization: per-(page, kv-head) absmax over the
-        # VALID positions only — the scratch stripe beyond the window's
-        # last real token [end = h + last_idx + 1] is garbage (pad +
-        # forward junk) that would otherwise inflate the scale and crush
-        # the real values' precision. Masked positions store 0.
-        pos_abs = (base + (jnp.arange(Pn, dtype=jnp.int32) * ps)[:, None]
-                   + jnp.arange(ps, dtype=jnp.int32)[None, :])   # [Pn, ps]
-        valid = (pos_abs < end)[None, :, None, :, None]
-
-        def quant(newx):
-            x = jnp.where(valid, newx.astype(jnp.float32), 0.0)
-            s = jnp.max(jnp.abs(x), axis=(3, 4))                 # [L, Pn, nkv]
-            qx = jnp.clip(jnp.round(
-                x / jnp.maximum(s, 1e-9)[..., None, None] * 127.0),
-                -127, 127).astype(jnp.int8)
-            return qx, s
-
-        qk, sk = quant(new_k)
-        qv, sv = quant(new_v)
-        pk = gen.QuantizedKVPage(pk.q.at[:, new_pages].set(qk),
-                                 pk.scale.at[:, new_pages].set(sk))
-        pv = gen.QuantizedKVPage(pv.q.at[:, new_pages].set(qv),
-                                 pv.scale.at[:, new_pages].set(sv))
-    else:
-        pk = pk.at[:, new_pages].set(new_k)   # [L, P, nkv, ps, hd]
-        pv = pv.at[:, new_pages].set(new_v)
-    return pk, pv
 
 
 def _paged_decode_traced(params, tokens, pk, pv, bt, pos, cos, sin, temp,
@@ -280,9 +200,7 @@ class DensePath:
             self._prefill[sample] = self.sharded(
                 functools.partial(
                     _paged_prefill_traced, args=args, metrics=eng.metrics,
-                    page_size=eng.page_size,
-                    pages_per_slot=eng.pages_per_slot, sample=sample,
-                    **tp_kw),
+                    page_size=eng.page_size, sample=sample, **tp_kw),
                 donate=(6, 7) if donate else (), **prefill_specs)
             self._decode[sample] = self.sharded(
                 functools.partial(
@@ -357,8 +275,14 @@ class DensePath:
     # -- the two step programs ------------------------------------------------
     def prefill(self, ids, start, last_idx, bt_row, new_vec, slot, req,
                 sample):
+        eng = self.eng
+        # the share of the slot's table the window's attention walks: the
+        # pages up to its last position, over pages a slot
+        eng.metrics.observe(
+            "prefill_live_page_share",
+            ((start + last_idx) // eng.page_size + 1) / eng.pages_per_slot)
         self.pk, self.pv, first = self._prefill[sample](
-            self.eng.params, jnp.asarray(ids), jnp.int32(start),
+            eng.params, jnp.asarray(ids), jnp.int32(start),
             jnp.int32(last_idx), jnp.asarray(bt_row), jnp.asarray(new_vec),
             self.pk, self.pv, self.cos, self.sin,
             jnp.float32(req.temperature), jnp.float32(req.top_p),

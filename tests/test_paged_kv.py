@@ -1221,6 +1221,37 @@ class TestRadixEngineParity:
         assert radix.get("prefix_partial_hits", 0) >= 1
 
 
+    @pytest.mark.parametrize("kv_dtype", [None, "int8"])
+    def test_window_bucket_below_a_page_crosses_the_page(self, params,
+                                                         kv_dtype):
+        """`min_bucket` < `page_size`: after a mid-page hit (28 of 16-token
+        pages) a 6-token suffix in a bucket of 8 covers positions 28..33,
+        the tail of one page and the head of the next. Both are written:
+        no position is read before its own token wrote it."""
+        rng = np.random.default_rng(131)
+        base = rng.integers(1, ARGS.vocab_size, 28).astype(np.int32)
+        prompts = [np.concatenate([base, rng.integers(
+            1, ARGS.vocab_size, k).astype(np.int32)]) for k in (2, 6)]
+        # the first request ends inside the second page: the third is fresh
+        new = (2, 6)
+        ref = [_sequential(params, [p], max_new=n)[0]
+               for p, n in zip(prompts, new)]
+        eng = PagedEngine(params, ARGS, max_slots=1, max_len=64,
+                          page_size=16, min_bucket=4, kv_dtype=kv_dtype)
+        # a position nobody wrote holds anything: here, what would win
+        # every softmax it entered
+        eng.path.pk, eng.path.pv = jax.tree_util.tree_map(
+            lambda a: jnp.full_like(a, 100), (eng.path.pk, eng.path.pv))
+        reqs = eng.serve([Request(p, n) for p, n in zip(prompts, new)])
+        assert eng.metrics.summary()["counters"]["prefix_tokens_hit"] == 28
+        for r, s in zip(reqs, ref):
+            if kv_dtype is None:
+                np.testing.assert_array_equal(np.asarray(r.token_ids), s)
+            else:
+                assert np.mean(np.asarray(r.token_ids) == s) >= 0.8
+        assert eng._alloc.pages_in_use == 0
+
+
 class TestInt8KVPool:
     """kv_dtype='int8' swaps the page pools for QuantizedKVPage pairs
     (int8 codes + per-(page, kv-head) absmax scales). The parity bar is
@@ -1422,7 +1453,8 @@ def _lowered_text(engine, program):
     ("decode", "pt.paged_attention"), ("decode", "pt.kv_write"),
     ("decode", "pt.attention"), ("decode", "pt.mlp"), ("decode", "pt.norm"),
     ("decode", "pt.sample"),
-    ("prefill", "pt.kv_gather"), ("prefill", "pt.kv_write"),
+    ("prefill", "pt.attention/pt.paged_attention"),
+    ("prefill", "pt.attention/pt.kv_write"),
     ("prefill", "pt.paged_attention"), ("prefill", "pt.sample"),
     ("copy_page", "pt.kv_write")])
 def test_serve_programs_carry_stable_scope_names(engine, program, scope):
@@ -1448,6 +1480,71 @@ def test_pallas_paged_kernel_keeps_the_scan_body_as_innermost_scope(params):
         text = _lowered_text(eng, "decode")
     assert re.search(r"pt\.attention/pt\.paged_attention/closed_call/"
                      r"pallas_call", text)
+
+
+def test_prefill_program_names_its_kernel_and_never_the_decode_kernels(
+        params):
+    """The prefill window's kernel carries a name of its own: the trace
+    calls an unnamed one by its innermost scope, the layer scan's body, a
+    second `closed_call.N` beside the paged decode kernel, and the
+    benchmark's reader would count it into that kernel's time."""
+    args128 = ARGS._replace(hidden_size=512, num_heads=4, num_kv_heads=2)
+    p128 = lf.init_params(args128, jax.random.key(1))
+    eng = PagedEngine(p128, args128, max_slots=2, max_len=128, page_size=16,
+                      min_bucket=16)
+    with qm.fused_dispatch(True, interpret=True):
+        text = _lowered_text(eng, "prefill")
+    assert ("pt.attention/pt.paged_attention/paged_prefill_attention/"
+            "pallas_call") in text
+    assert "closed_call/pallas_call" not in text
+
+
+@pytest.mark.parametrize("kv_dtype", [None, "int8"])
+def test_prefill_program_holds_no_stripe(params, kv_dtype):
+    """A window's K / V goes into the window's own pages and attention
+    walks the table: no tensor of the program has as many positions as a
+    slot's table (2,048 here, or that plus a window's pad: the gathered
+    stripe the program used to forward over and cut pages back out of).
+    The CPU backend's `temp_size_in_bytes` counts its own copies of
+    undonated pools, so the figure itself is read where the program is
+    compiled for the chip (`tests/test_tpu_compile.py`)."""
+    import re
+
+    eng = PagedEngine(params, ARGS, max_slots=2, max_len=2048, page_size=8,
+                      min_bucket=8, kv_dtype=kv_dtype)
+    positions = eng.pages_per_slot * eng.page_size
+    dims = {int(d) for shape in re.findall(
+        r"tensor<((?:\d+x)+)[a-z]", _lowered_text(eng, "prefill"))
+        for d in shape.rstrip("x").split("x")}
+    assert eng.num_pages in dims and 16 in dims     # the pools, the window
+    # the rotary tables have 2 * max_len rows; nothing lies between a
+    # window's blocks of keys and those but the pools' pages
+    assert not {d for d in dims if positions <= d < 2 * positions
+                and d not in (eng.num_pages, 2 * eng.num_pages)}, dims
+
+
+def test_prefill_live_page_share_counts_the_pages_the_window_walks(
+        params, monkeypatch):
+    """One observation a prefill window: the pages up to the window's last
+    position over pages a slot: how much of the table its attention walks,
+    from the host's own numbers."""
+    eng = PagedEngine(params, ARGS, max_slots=2, max_len=64, page_size=8,
+                      min_bucket=8, prefill_chunk=8)
+    seen, observe = [], eng.metrics.observe
+
+    def record(name, value, **kw):
+        if name == "prefill_live_page_share":
+            seen.append(value)
+        return observe(name, value, **kw)
+
+    monkeypatch.setattr(eng.metrics, "observe", record)
+    a, b = _prompts([5, 19], seed=41)
+    # a: one window that ends at position 4; b: chunks of 8 that end at
+    # positions 7, 15 and 18, in the order the scheduler runs them
+    eng.serve([Request(a, 2), Request(b, 2)])
+    assert sorted(seen) == [1 / 8, 1 / 8, 2 / 8, 3 / 8]
+    got = eng.metrics.observation("prefill_live_page_share")
+    assert got["count"] == 4 and abs(got["mean"] - 7 / 32) < 1e-9
 
 
 def test_decode_live_page_share_counts_the_pages_the_rows_hold(params,

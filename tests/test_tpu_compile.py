@@ -25,14 +25,18 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topology():
     from jax.experimental import topologies
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def one_chip(topology):
+    return SingleDeviceSharding(topology.devices[0])
 
 
 @pytest.fixture(autouse=True)
@@ -153,6 +157,174 @@ def test_decode_program_touches_pages_and_never_a_whole_pool(one_chip, kind):
     assert not moved, "\n".join(moved)
     layer_bytes = NP * nkv * ps * hd * jnp.dtype(kind).itemsize
     assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
+
+
+# name -> window, query heads, kv heads, head dim, page size, pages a slot,
+# pages in the pool (a stack of layers' runs), pool dtype
+_PREFILL_SHAPES = {
+    "mistral7b_saturated_cell_chunk": (512, 32, 8, 128, 64, 128, 3 * 896,
+                                       "bfloat16"),
+    "mistral7b_saturated_cell_smallest_bucket": (64, 32, 8, 128, 64, 128,
+                                                 3 * 896, "bfloat16"),
+    "mistral7b_int8_pool": (512, 32, 8, 128, 64, 128, 3 * 1792, "int8"),
+    "one_local_kv_head_of_a_tp_shard": (256, 4, 1, 128, 64, 128, 896,
+                                        "bfloat16"),
+    "chip_smoke_llama_1b_mha": (128, 16, 16, 128, 64, 16, 129, "bfloat16"),
+    "float32_pool_16_token_pages": (64, 8, 2, 128, 16, 8, 33, "float32"),
+}
+
+
+@pytest.mark.parametrize("name", list(_PREFILL_SHAPES))
+def test_paged_prefill_kernel_compiles_for_v5e(one_chip, name):
+    from paddle_tpu.kernels import paged_prefill_attention as ppa
+
+    s, nh, nkv, hd, ps, P, NP, dtype = _PREFILL_SHAPES[name]
+    pool_dtype = jnp.dtype(dtype)
+    q_dtype = jnp.dtype("bfloat16") if dtype == "int8" else pool_dtype
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    args = [sds((s, nh, hd), q_dtype), sds((NP, nkv, ps, hd), pool_dtype),
+            sds((NP, nkv, ps, hd), pool_dtype), sds((P,), jnp.int32),
+            sds((), jnp.int32), sds((), jnp.int32), sds((), jnp.int32)]
+    if dtype == "int8":
+        args += [sds((NP // 3, nkv), jnp.float32)] * 2
+    assert ppa._supported(args[0].shape, args[1].shape, args[3].shape,
+                          q_dtype.itemsize, pool_dtype.itemsize)
+
+    def call(q, k, v, bt, h, last, base, ks=None, vs=None):
+        return ppa._pallas(q, k, v, bt, h, last, base, ks, vs, hd ** -0.5,
+                           False)
+
+    text = jax.jit(call).lower(*args).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "paged_prefill_attention" in text
+
+
+@pytest.mark.parametrize("kind", ["bfloat16", "int8"])
+def test_prefill_program_writes_its_pages_and_holds_no_stripe(one_chip, kind):
+    """The dense prefill program at the serving cell's widths (a 512-token
+    window, 32 / 8 heads x 128, FFN 14,336, 896 pages x 64, 128 pages a
+    slot; depth 3), pools donated: the window's K / V goes into the
+    window's own pages of the carried pools and one kernel a layer walks
+    the table, so the program plans no temporary as large as ONE layer's
+    stripe `[nkv, pages_per_slot * page_size, hd]` (16.8 MB in bf16; it
+    gathered two of `[L, 1, nkv, 8,704, hd]`, forwarded over them and cut
+    128 pages a layer back out), and computes nothing shaped like pages as
+    large as a layer's run of the pool but the in-place page writes."""
+    import re
+
+    from paddle_tpu.models import generation as gen
+    from paddle_tpu.models import llama_functional as lf
+
+    L, sb, nkv, hd, ps, P, NP = 3, 512, 8, 128, 64, 128, 896
+    args = lf.LlamaArgs(32768, 4096, 14336, L, 32, nkv, 1e6, 1e-5)
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda: lf.init_params(args, jax.random.key(0),
+                                              jnp.bfloat16)))
+    pool = sds((L, NP, nkv, ps, hd), jnp.dtype(kind))
+    if kind == "int8":
+        pool = gen.QuantizedKVPage(pool, sds((L, NP, nkv), jnp.float32))
+    table = sds((2 * P * ps, hd), jnp.float32)
+
+    def prefill(params, ids, pk, pv, h, last_idx, bt_row, new_pages, cos,
+                sin):
+        with qm.fused_dispatch(True):
+            return gen._paged_forward_prefill(
+                params, ids, pk, pv, h, last_idx, bt_row, new_pages, cos,
+                sin, args, ps)
+
+    compiled = jax.jit(prefill, donate_argnums=(2, 3)).lower(
+        params, sds((1, sb), jnp.int32), pool, pool, sds((), jnp.int32),
+        sds((), jnp.int32), sds((P,), jnp.int32), sds((P,), jnp.int32),
+        table, table).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "paged_prefill_attention" in text
+
+    moved = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (.*?) ([\w\-]+)\(", line)
+        if not m or m.group(2) in ("parameter", "tuple", "get-tuple-element",
+                                   "while", "bitcast"):
+            continue
+        pages = [math.prod(map(int, dims.split(","))) for dims in re.findall(
+            rf"\[([\d,]+),{nkv},{ps},{hd}\]", m.group(1))]
+        if pages and max(pages) >= P:
+            if not re.search(r'op_name="[^"]*pt\.kv_write/scatter"', line):
+                moved.append(line.strip()[:200])
+    assert not moved, "\n".join(moved)
+    stripe_bytes = nkv * P * ps * hd * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < stripe_bytes
+
+
+# name -> LlamaArgs' widths (vocab, hidden, FFN, heads, KV heads), window,
+# page size, pages a slot, pages in the pool
+_TP_PREFILL = {
+    "chip_smoke_mp4_llama_1b_chunk": ((32000, 2048, 5504, 16, 16), 256, 64,
+                                      16, 129),
+    "mistral7b_cell_widths_mp4": ((32768, 4096, 14336, 32, 8), 512, 64, 128,
+                                  896),
+}
+
+
+@pytest.mark.parametrize("name", list(_TP_PREFILL))
+def test_tensor_parallel_prefill_program_compiles_for_four_chips(topology,
+                                                                 name):
+    """`DensePath`'s prefill program as `mesh=` builds it (one shard_map
+    SPMD program over `mp`, the pools sharded on their KV heads), compiled
+    for the four described chips: a shard's kernel sees `nkv / 4` KV heads
+    and their whole groups of query heads, the table scalar-prefetched."""
+    import functools
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from paddle_tpu.models import llama_functional as lf
+    from paddle_tpu.observability import MetricsRegistry
+    from paddle_tpu.serving import dense, tp as tp_lib
+
+    (vocab, hidden, ffn, nh, nkv), sb, ps, Pn, NP = _TP_PREFILL[name]
+    L, hd = 2, hidden // nh
+    args = lf.LlamaArgs(vocab, hidden, ffn, L, nh, nkv, 1e6, 1e-5)
+    mesh = Mesh(np.array(topology.devices), ("mp",))
+    tp_lib.tp_validate(args, 4)
+
+    def sds(shape, dt, spec=P()):
+        return jax.ShapeDtypeStruct(shape, dt,
+                                    sharding=NamedSharding(mesh, spec))
+
+    shapes = jax.eval_shape(
+        lambda: lf.init_params(args, jax.random.key(0), jnp.bfloat16))
+    pspecs = tp_lib.llama_tp_specs(shapes, "mp")
+    params = jax.tree_util.tree_map(
+        lambda a, spec: sds(a.shape, a.dtype, spec), shapes, pspecs)
+    pool = sds((L, NP, nkv, ps, hd), jnp.bfloat16, tp_lib.pool_spec("mp"))
+    i32, f32 = sds((), jnp.int32), sds((), jnp.float32)
+    table = sds((2 * Pn * ps, hd), jnp.float32)
+    rep, pspec = P(), tp_lib.pool_spec("mp")
+    body = functools.partial(
+        dense._paged_prefill_traced, args=args, metrics=MetricsRegistry(),
+        page_size=ps, sample=False, tp_axis="mp", tp_degree=4)
+    program = jax.jit(jax.shard_map(
+        body, mesh=mesh,
+        in_specs=(pspecs, rep, rep, rep, rep, rep, pspec, pspec, rep, rep,
+                  rep, rep, rep, rep),
+        out_specs=(pspec, pspec, rep), check_vma=False),
+        donate_argnums=(6, 7))
+    with qm.fused_dispatch(True):
+        text = program.lower(
+            params, sds((1, sb), jnp.int32), i32, i32, sds((Pn,), jnp.int32),
+            sds((Pn,), jnp.int32), pool, pool, table, table, f32, f32, i32,
+            sds((1,), jnp.int32)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "paged_prefill_attention" in text
 
 
 # ---------------------------------------------------------------------------
