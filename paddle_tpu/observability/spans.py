@@ -18,7 +18,10 @@ one locked histogram update, whatever the run's length.
 
 `ids` are the identifiers that tie spans together: `step` (the engine's step
 count; a phase's parent is the step span of the same `step`), `request_id`,
-`slot`. They become the event's stats in the trace.
+`slot`, and what a serving phase's entry says of its step: `kind`, `part`,
+`rows`, `tokens`, `bucket`, `start` (a sub-division of a phase is an
+identifier, never a span nested in it). They become the event's stats in the
+trace, each under its key.
 
 Device-side names are `jax.named_scope("pt.<what>")` in the traced code
 itself (ARCHITECTURE.md "Observability" has both tables).

@@ -243,9 +243,10 @@ class PrefillWorker(PagedEngine):
 
     def _build_handoff(self, req, slot, first):
         pages = np.asarray(self._bt[slot], np.int32)
-        with self.metrics.timer("page_extract_s"), self._phase("stage"):
+        ids = dict(kind="copy", request_id=req.request_id, slot=slot)
+        with self._phase("stage", part="dispatch", **ids):
             pk, pv = self.path.extract_pages(pages)
-        with self._phase("wait"):
+        with self._phase("wait", **ids):
             pk = jax.tree_util.tree_map(np.asarray, pk)
             pv = jax.tree_util.tree_map(np.asarray, pv)
         return KVHandoff(
@@ -261,7 +262,6 @@ class PrefillWorker(PagedEngine):
             pkg = self._build_handoff(req, slot, first)
             self.transport.send(pkg.to_bytes())
             self.metrics.inc("handoffs_sent")
-            self.metrics.inc("handoff_pages", pkg.num_pages)
             self.metrics.inc("handoff_bytes", pkg.nbytes())
             # release the refcounts / refund the reservation on THIS
             # side — the decode worker owns the sequence now. _retire
@@ -323,7 +323,8 @@ class DecodeWorker(PagedEngine):
         slot = self._admit(req)
         n_pages = pkg.num_pages
         pages = [self._alloc.alloc() for _ in range(n_pages)]
-        with self.metrics.timer("page_scatter_s"), self._phase("stage"):
+        with self._phase("stage", kind="copy", part="dispatch",
+                         request_id=req.request_id, slot=slot):
             self.path.scatter_pages(pages, pkg.pages_k, pkg.pages_v)
         self._bt[slot] = pages
         resv = pages_for(n, pkg.max_new_tokens, self.page_size) - n_pages

@@ -52,6 +52,7 @@ from __future__ import annotations
 
 import functools
 import itertools
+import logging
 import time
 
 import jax
@@ -173,31 +174,60 @@ def _decode_traced(params, tokens, ck, cv, pos, cos, sin, temp, top_p,
 # the four phases every microsecond of a step()'s action belongs to
 PHASES = ("schedule", "stage", "wait", "emit")
 
+# a step that took more than STALL_FACTOR x the running mean of its own kind
+# and size, once STALL_MIN_STEPS of them were seen, is a stall
+STALL_FACTOR = 4.0
+STALL_MIN_STEPS = 16
+
+# the stepping thread's own CPU seconds, read as a step starts and again only
+# where it stalled: a stalled step that used none of them waited (a test
+# replaces it, as `spans._clock`)
+_thread_clock = time.thread_time
+
+_log = logging.getLogger("paddle_tpu.serving")
+
 
 class StepPhases:
     """One step's accumulator, handed to `span` in a registry's place.
 
-    `with phases("stage", slot=3):` opens the span `pt.serve.stage` (its own
-    `TraceAnnotation`, with the step's number) and adds its duration to the
-    phase's total; a phase entered inside another one takes its time out of
-    the one around it, so the four totals tile the step's action however
-    the phases nest or repeat. `Engine.step()` observes each total once
-    when the step ends: an observation's count is the number of steps."""
+    `with phases("stage", kind="decode", part="build", rows=3):` opens the
+    span `pt.serve.stage` (its own `TraceAnnotation`, with the step's number
+    and the identifiers) and adds its duration to the phase's total; a phase
+    entered inside another one takes its time out of the one around it, so
+    the four totals tile the step's action however the phases nest or
+    repeat. `Engine.step()` observes each total once when the step ends: an
+    observation's count is the number of steps.
+
+    A sub-division of a phase is an IDENTIFIER on the entry, never a span
+    nested in it: `part="dispatch"` marks a `stage` entry that is the path's
+    call (the argument transfers and the jitted program until it returns),
+    and those entries' own time is also summed as `dispatch_s`; `kind` (with
+    `bucket`, a prefill window's) says what the step is: the last entry's
+    that is no page copy, which `Engine.step()` keeps its running means by."""
 
     def __init__(self, step):
         self.step = step
         self.totals = {f"serve.{p}_s": 0.0 for p in PHASES}   # by observation
-        self._inner = []      # per open phase: seconds of phases inside it
+        self.dispatch_s = 0.0     # of `serve.stage_s`: the part="dispatch"
+        self.kind = self.bucket = None
+        # per open phase: [seconds of phases inside it, is a dispatch entry]
+        self._inner = []
 
     def __call__(self, phase, **ids):
-        self._inner.append(0.0)
+        kind = ids.get("kind")
+        if kind is not None and (kind != "copy" or self.kind is None):
+            self.kind, self.bucket = kind, ids.get("bucket")
+        self._inner.append([0.0, ids.get("part") == "dispatch"])
         return span("serve." + phase, self, step=self.step, **ids)
 
     def observe(self, name, seconds):
         """A phase's span closed after `seconds` (the call `span` makes)."""
-        self.totals[name] += seconds - self._inner.pop()
+        inside, dispatch = self._inner.pop()
+        self.totals[name] += seconds - inside
+        if dispatch:
+            self.dispatch_s += seconds - inside
         if self._inner:
-            self._inner[-1] += seconds
+            self._inner[-1][0] += seconds
 
 
 class Engine:
@@ -236,6 +266,7 @@ class Engine:
         self.step_count = 0
         self._stall_steps = 0     # decode work delayed by a prefill step
         self._phase = StepPhases(0)   # the running step's; step() renews it
+        self._forget_steps()
         self._setup_device_state()
 
     def _donate_enabled(self):
@@ -294,12 +325,17 @@ class Engine:
         else one batched decode step over all active slots, else idle.
         Returns a small event dict. The step is the span `pt.serve.step`;
         its action is split over the four `PHASES` (`StepPhases`), each
-        observed once a step as `serve.<phase>_s`."""
+        observed once a step as `serve.<phase>_s`, and beside them
+        `serve.stage_dispatch_s`: the part of `stage` inside the path's
+        calls. A step far longer than its kind's mean is counted as a stall
+        (`_note_step`)."""
         phase = self._phase = StepPhases(self.step_count)
         with span("serve.step", step=self.step_count):
             # whatever `_step_action` does outside a stage, wait or emit
-            # phase of its own is scheduling
+            # phase of its own is scheduling; the thread's CPU clock is read
+            # inside the phase, so the read is host work like the rest
             with phase("schedule"):
+                cpu0 = _thread_clock()
                 ev = self._step_action()
                 with phase("emit"):
                     self.step_count += 1
@@ -307,9 +343,59 @@ class Engine:
                                          self.slots.occupancy())
                     self.metrics.set_gauge("active_slots",
                                            len(self.slots.active_slots))
+                    # every `stage` entry has closed: the total is final
+                    self.metrics.observe("serve.stage_dispatch_s",
+                                         phase.dispatch_s)
         for name, seconds in phase.totals.items():
             self.metrics.observe(name, seconds)
+        self._note_step(phase, cpu0)
         return ev
+
+    def _forget_steps(self):
+        """No step seen yet (construction and `reset`): the running means a
+        stall is measured against are empty, and the stall counters stand at
+        0, so a run without a stall reads 0 and not "no such counter"."""
+        self._step_means = {}     # (kind, bucket) -> [steps, mean seconds]
+        for name in ("steps", "s", "cpu_s") + tuple(p + "_s" for p in PHASES):
+            self.metrics.inc("serve.stalled_" + name, 0)
+
+    def _note_step(self, phase, cpu0):
+        """Count a stalled step where it happens. A step's seconds are its
+        four totals' sum; its class is its kind and, for a prefill window,
+        its bucket. Past `STALL_MIN_STEPS` of a class, a step that took more
+        than `STALL_FACTOR` x the class's running mean is a stall: counted,
+        charged to the phases it sat in, logged once, and kept out of the
+        mean; only then is the thread's CPU clock read a second time (`cpu0`
+        is its reading as the step started). Read it so: seconds in `wait` with no CPU = the device or the
+        runtime was late; seconds in schedule / stage / emit with CPU about
+        equal = host work; the same with CPU far below = the thread was off
+        the processor."""
+        if phase.kind is None:
+            return                  # an idle step
+        took = sum(phase.totals.values())
+        seen = self._step_means.setdefault((phase.kind, phase.bucket),
+                                           [0, 0.0])
+        n, mean = seen
+        if n < STALL_MIN_STEPS or took <= STALL_FACTOR * mean:
+            seen[0], seen[1] = n + 1, mean + (took - mean) / (n + 1)
+            return
+        cpu_s = _thread_clock() - cpu0
+        m = self.metrics
+        m.inc("serve.stalled_steps")
+        m.inc("serve.stalled_s", took - mean)
+        for p in PHASES:
+            m.inc(f"serve.stalled_{p}_s", phase.totals[f"serve.{p}_s"])
+        m.inc("serve.stalled_cpu_s", cpu_s)
+        m.set_gauge("serve.last_stall_step", phase.step)
+        m.set_gauge("serve.last_stall_s", took)
+        _log.warning(
+            "serving step %d stalled: %s%s took %.4f s against a mean of "
+            "%.4f s over %d steps (%s; thread cpu %.4f s)",
+            phase.step, phase.kind,
+            "" if phase.bucket is None else f" bucket {phase.bucket}",
+            took, mean, n,
+            ", ".join(f"{p} {phase.totals[f'serve.{p}_s']:.4f}"
+                      for p in PHASES), cpu_s)
 
     def _step_action(self):
         """Pick and run this iteration's unit of work (subclass hook: the
@@ -401,6 +487,7 @@ class Engine:
         self.sampler.reset()
         self.step_count = 0
         self._stall_steps = 0
+        self._forget_steps()
 
     # -- internals ----------------------------------------------------------
     def _admit(self, req):
@@ -478,22 +565,22 @@ class Engine:
     def _prefill_device(self, req, slot, n):
         """Run the device half of a prefill (subclass hook). Returns
         (bucket, first_token)."""
-        ids = dict(request_id=req.request_id, slot=slot)
-        # prefill_s: stage + wait of a prefill-shaped step
-        with self.metrics.timer("prefill_s"):
-            with self._phase("stage", **ids):
-                bucket = bucket_for(n, self.min_bucket, self.max_len)
-                padded = np.full((1, bucket), self.pad_id, np.int32)
-                padded[0, :n] = req.prompt_ids
-                self._ck, self._cv, first = self._prefill(
-                    self.params, jnp.asarray(padded), jnp.int32(n),
-                    self._ck, self._cv, jnp.int32(slot), self._cos,
-                    self._sin, jnp.float32(req.temperature),
-                    jnp.float32(req.top_p), jnp.int32(req.top_k),
-                    jnp.asarray([req.seed], jnp.int32),
-                    sample=req.temperature > 0)
-            with self._phase("wait", **ids):
-                first = int(first)
+        bucket = bucket_for(n, self.min_bucket, self.max_len)
+        ids = dict(request_id=req.request_id, slot=slot, kind="prefill",
+                   tokens=n, bucket=bucket, start=0)
+        with self._phase("stage", part="build", **ids):
+            padded = np.full((1, bucket), self.pad_id, np.int32)
+            padded[0, :n] = req.prompt_ids
+        with self._phase("stage", part="dispatch", **ids):
+            self._ck, self._cv, first = self._prefill(
+                self.params, jnp.asarray(padded), jnp.int32(n),
+                self._ck, self._cv, jnp.int32(slot), self._cos,
+                self._sin, jnp.float32(req.temperature),
+                jnp.float32(req.top_p), jnp.int32(req.top_k),
+                jnp.asarray([req.seed], jnp.int32),
+                sample=req.temperature > 0)
+        with self._phase("wait", **ids):
+            first = int(first)
         return bucket, first
 
     def _decode_step(self):
@@ -520,12 +607,13 @@ class Engine:
     def _decode_device(self, active):
         """Run the device half of one batched decode step (subclass
         hook). Returns the next-token array [S] on host."""
-        with self._phase("stage"):
+        ids = dict(kind="decode", rows=len(active))
+        with self._phase("stage", part="dispatch", **ids):
             self._ck, self._cv, nxt = self._decode(
                 self.params, jnp.asarray(self._last_tok), self._ck,
                 self._cv, jnp.asarray(self._npos), self._cos, self._sin,
                 *self._sampling_args(), sample=self._sampling_active())
-        with self._phase("wait"):
+        with self._phase("wait", **ids):
             return np.asarray(nxt)
 
     def _emit(self, req, token):

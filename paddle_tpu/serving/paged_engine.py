@@ -294,14 +294,16 @@ class PagedEngine(Engine):
             # contents; the first window then overwrites [h, ...) in place
             src = hit.partial_page
             copy, _ = self._alloc.ensure_writable(src)
-            with self._phase("stage", request_id=req.request_id, slot=slot):
+            with self._phase("stage", kind="copy", part="dispatch",
+                             request_id=req.request_id, slot=slot):
                 self.path.copy_page(src, copy)
             self._bt[slot].append(copy)
             held += 1
         if hit.state is not None:
             # the match ends at a snapshot of the per-request state kept
             # beside the pages: the slot resumes from it
-            with self._phase("stage", request_id=req.request_id, slot=slot):
+            with self._phase("stage", kind="copy", part="dispatch",
+                             request_id=req.request_id, slot=slot):
                 self.path.load_snapshot(slot, hit.state)
         resv = pages_for(n, req.max_new_tokens, ps) - held
         self._resv[slot] = resv
@@ -309,7 +311,6 @@ class PagedEngine(Engine):
         self.metrics.inc("prompt_tokens", n)
         self.metrics.inc("prefix_tokens_hit", h)
         self.metrics.inc("prefix_pages_hit", len(hit.pages))
-        self.metrics.inc("prefix_pages_queried", (n - 1) // ps)
         return h
 
     def _window_prefill_device(self, req, slot, start, end, n):
@@ -330,30 +331,30 @@ class PagedEngine(Engine):
         self._bt[slot].extend(new_pages)
         pages = self._bt[slot]
 
-        ids = dict(request_id=req.request_id, slot=slot)
-        # prefill_s: stage + wait of a prefill-shaped step
-        with self.metrics.timer("prefill_s"):
-            with self._phase("stage", **ids):
-                bt_row = np.zeros(Pn, np.int32)
-                bt_row[:len(pages)] = pages
-                # every page the window touches gets scattered: the
-                # straddled page at start//ps (the mid-page-hit COW copy on
-                # the first window, the slot's own tail page on later
-                # chunks) is rewritten from the gathered stripe plus the
-                # new tokens
-                touched = pages[start // ps:]
-                new_vec = np.full(Pn, NULL_PAGE, np.int32)
-                new_vec[:len(touched)] = touched
-                sb = bucket_for(end - start, self.min_bucket, self.max_len)
-                padded = np.full((1, sb), self.pad_id, np.int32)
-                padded[0, :end - start] = req.prompt_ids[start:end]
-                sample = final and req.temperature > 0
-                first = self.path.prefill(padded, start, end - 1 - start,
-                                          bt_row, new_vec, slot, req, sample)
-                if final:
-                    self.path.prompt_done(slot)
-            with self._phase("wait", **ids):
-                first = int(first)
+        sb = bucket_for(end - start, self.min_bucket, self.max_len)
+        ids = dict(request_id=req.request_id, slot=slot, kind="prefill",
+                   tokens=end - start, bucket=sb, start=start)
+        with self._phase("stage", part="build", **ids):
+            bt_row = np.zeros(Pn, np.int32)
+            bt_row[:len(pages)] = pages
+            # every page the window touches gets scattered: the
+            # straddled page at start//ps (the mid-page-hit COW copy on
+            # the first window, the slot's own tail page on later
+            # chunks) is rewritten from the gathered stripe plus the
+            # new tokens
+            touched = pages[start // ps:]
+            new_vec = np.full(Pn, NULL_PAGE, np.int32)
+            new_vec[:len(touched)] = touched
+            padded = np.full((1, sb), self.pad_id, np.int32)
+            padded[0, :end - start] = req.prompt_ids[start:end]
+            sample = final and req.temperature > 0
+        with self._phase("stage", part="dispatch", **ids):
+            first = self.path.prefill(padded, start, end - 1 - start,
+                                      bt_row, new_vec, slot, req, sample)
+            if final:
+                self.path.prompt_done(slot)
+        with self._phase("wait", **ids):
+            first = int(first)
         if final:
             # make this prompt's FULL pages hittable right away (a
             # concurrent identical prompt shares them while this one is
@@ -460,7 +461,8 @@ class PagedEngine(Engine):
             old = pages[pi]
             page, copied = self._alloc.ensure_writable(old)
             if copied:
-                with self._phase("stage", slot=slot):
+                with self._phase("stage", kind="copy", part="dispatch",
+                                 slot=slot):
                     self.path.copy_page(old, page)
                 pages[pi] = page
         while len(pages) * ps <= top:
@@ -472,7 +474,8 @@ class PagedEngine(Engine):
         Pn = self.pages_per_slot
         for slot in active:
             self._ensure_tail_pages(slot, int(self._npos[slot]))
-        with self._phase("stage"):
+        ids = dict(kind="decode", rows=len(active))
+        with self._phase("stage", part="build", **ids):
             bt = np.full((self.max_slots, Pn), NULL_PAGE, np.int32)
             for slot in active:
                 bt[slot, :len(self._bt[slot])] = self._bt[slot]
@@ -481,9 +484,12 @@ class PagedEngine(Engine):
             live = int(np.sum(self._npos[active] // self.page_size + 1))
             self.metrics.observe("decode_live_page_share",
                                  live / (self.max_slots * Pn))
+        with self._phase("stage", part="dispatch", **ids):
+            # with the path's call: the sampler's four per-row operands go
+            # to the device here whenever a row was admitted or cleared
             nxt = self.path.decode(bt, active, self._sampling_active(),
                                    self._sampling_args())
-        with self._phase("wait"):
+        with self._phase("wait", **ids):
             return np.asarray(nxt)
 
     # -- lifecycle ----------------------------------------------------------

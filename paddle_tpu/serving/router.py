@@ -121,22 +121,19 @@ class GptEngine(Engine):
         bucket = bucket_for(n, self.min_bucket, self.max_len)
         padded = np.full((1, bucket), self.pad_id, np.int32)
         padded[0, :n] = req.prompt_ids
-        with self.metrics.timer("prefill_s"):
-            self._ck, self._cv, first = self._prefill(
-                self.params, jnp.asarray(padded), jnp.int32(n),
-                self._ck, self._cv, jnp.int32(slot),
-                jnp.float32(req.temperature), jnp.float32(req.top_p),
-                jnp.int32(req.top_k), jnp.asarray([req.seed], jnp.int32),
-                sample=req.temperature > 0)
-            first = int(first)
-        return bucket, first
+        self._ck, self._cv, first = self._prefill(
+            self.params, jnp.asarray(padded), jnp.int32(n),
+            self._ck, self._cv, jnp.int32(slot),
+            jnp.float32(req.temperature), jnp.float32(req.top_p),
+            jnp.int32(req.top_k), jnp.asarray([req.seed], jnp.int32),
+            sample=req.temperature > 0)
+        return bucket, int(first)
 
     def _decode_device(self, active):
-        with self.metrics.timer("decode_step_s"):
-            self._ck, self._cv, nxt = self._decode(
-                self.params, jnp.asarray(self._last_tok), self._ck,
-                self._cv, jnp.asarray(self._npos), *self._sampling_args(),
-                sample=self._sampling_active())
+        self._ck, self._cv, nxt = self._decode(
+            self.params, jnp.asarray(self._last_tok), self._ck,
+            self._cv, jnp.asarray(self._npos), *self._sampling_args(),
+            sample=self._sampling_active())
         return np.asarray(nxt)
 
 

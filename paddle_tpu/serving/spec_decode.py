@@ -281,8 +281,9 @@ class SpecDecoder:
         bucket = bucket_for(n, eng.min_bucket, eng.max_len)
         padded = np.full((1, bucket), eng.pad_id, np.int32)
         padded[0, :n] = req.prompt_ids
-        with eng.metrics.timer("draft_prefill_s"), \
-                eng._phase("stage", request_id=req.request_id, slot=slot):
+        with eng._phase("stage", kind="draft", part="dispatch",
+                        request_id=req.request_id, slot=slot, tokens=n,
+                        bucket=bucket, start=0):
             self._dck, self._dcv, _ = self._draft_prefill(
                 self.draft_params, jnp.asarray(padded), jnp.int32(n),
                 self._dck, self._dcv, jnp.int32(slot), self._dcos,
@@ -302,8 +303,9 @@ class SpecDecoder:
         sb = bucket_for(end - start, eng.min_bucket, eng.max_len)
         padded = np.full((1, sb), eng.pad_id, np.int32)
         padded[0, :end - start] = req.prompt_ids[start:end]
-        with eng.metrics.timer("draft_prefill_s"), \
-                eng._phase("stage", request_id=req.request_id, slot=slot):
+        with eng._phase("stage", kind="draft", part="dispatch",
+                        request_id=req.request_id, slot=slot,
+                        tokens=end - start, bucket=sb, start=start):
             self._dck, self._dcv = self._draft_window(
                 self.draft_params, jnp.asarray(padded), jnp.int32(start),
                 self._dck, self._dcv, jnp.int32(slot), self._dcos,
@@ -340,19 +342,18 @@ class SpecDecoder:
         adversarial draft). Returns (outs, probs) — probs is None on
         greedy rounds."""
         eng = self.eng
-        with eng.metrics.timer("draft_propose_s"):
-            with eng._phase("stage"):
-                out = self._draft_propose(
-                    self.draft_params, jnp.asarray(forced),
-                    jnp.asarray(n_forced), jnp.asarray(start), self._dck,
-                    self._dcv, self._dcos, self._dsin,
-                    *eng.sampler.device_args(), sample=sample)
-            with eng._phase("wait"):
-                if sample:
-                    self._dck, self._dcv, outs, probs = out
-                    return np.asarray(outs), np.asarray(probs)
-                self._dck, self._dcv, outs = out
-                return np.asarray(outs), None             # [S, steps]
+        with eng._phase("stage", kind="draft", part="dispatch"):
+            out = self._draft_propose(
+                self.draft_params, jnp.asarray(forced),
+                jnp.asarray(n_forced), jnp.asarray(start), self._dck,
+                self._dcv, self._dcos, self._dsin,
+                *eng.sampler.device_args(), sample=sample)
+        with eng._phase("wait", kind="draft"):
+            if sample:
+                self._dck, self._dcv, outs, probs = out
+                return np.asarray(outs), np.asarray(probs)
+            self._dck, self._dcv, outs = out
+            return np.asarray(outs), None             # [S, steps]
 
     def step(self):
         """One speculation round: draft proposes g tokens (one traced
@@ -373,7 +374,7 @@ class SpecDecoder:
         # start[r] + j, so start MUST be each row's own frontier (_dpos):
         # writes then hit positions later windows / decode steps rewrite,
         # never the valid mirrored prefix below the frontier
-        with eng._phase("stage"):
+        with eng._phase("stage", kind="draft", part="build"):
             forced = np.zeros((S, steps), np.int32)
             n_forced = np.ones(S, np.int32)
             start = np.asarray(self._dpos, np.int32).copy()
@@ -398,7 +399,8 @@ class SpecDecoder:
                 slot, min(int(eng._npos[slot]) + g, int(limit[slot])))
 
         # ---- verify ------------------------------------------------------
-        with eng._phase("stage"):
+        step_ids = dict(kind="verify", rows=len(active))
+        with eng._phase("stage", part="build", **step_ids):
             ids = np.full((S, g + 1), eng.pad_id, np.int32)
             for slot in active:
                 ids[slot, 0] = eng._last_tok[slot]
@@ -410,26 +412,24 @@ class SpecDecoder:
             bt = np.full((S, Pn), NULL_PAGE, np.int32)
             for slot in active:
                 bt[slot, :len(eng._bt[slot])] = eng._bt[slot]
-        # `verify_s`: the verify program's dispatch and read-back alone
         path = eng.path
-        with eng.metrics.timer("verify_s"):
-            tprobs = None
-            with eng._phase("stage"):
-                if sampling:
-                    path.pk, path.pv, tgt, tprobs = self._verify_sampled(
-                        eng.params, jnp.asarray(ids), path.pk, path.pv,
-                        jnp.asarray(bt), jnp.asarray(eng._npos),
-                        jnp.asarray(limit), path.cos, path.sin,
-                        *eng.sampler.device_args()[:3])
-                else:
-                    path.pk, path.pv, tgt = self._verify(
-                        eng.params, jnp.asarray(ids), path.pk, path.pv,
-                        jnp.asarray(bt), jnp.asarray(eng._npos),
-                        jnp.asarray(limit), path.cos, path.sin)
-            with eng._phase("wait"):
-                if sampling:
-                    tprobs = np.asarray(tprobs)           # [S, g+1, V]
-                tgt = np.asarray(tgt)                     # [S, g+1]
+        tprobs = None
+        with eng._phase("stage", part="dispatch", **step_ids):
+            if sampling:
+                path.pk, path.pv, tgt, tprobs = self._verify_sampled(
+                    eng.params, jnp.asarray(ids), path.pk, path.pv,
+                    jnp.asarray(bt), jnp.asarray(eng._npos),
+                    jnp.asarray(limit), path.cos, path.sin,
+                    *eng.sampler.device_args()[:3])
+            else:
+                path.pk, path.pv, tgt = self._verify(
+                    eng.params, jnp.asarray(ids), path.pk, path.pv,
+                    jnp.asarray(bt), jnp.asarray(eng._npos),
+                    jnp.asarray(limit), path.cos, path.sin)
+        with eng._phase("wait", **step_ids):
+            if sampling:
+                tprobs = np.asarray(tprobs)           # [S, g+1, V]
+            tgt = np.asarray(tgt)                     # [S, g+1]
 
         # ---- accept + roll back ------------------------------------------
         with eng._phase("emit"):

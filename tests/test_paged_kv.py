@@ -28,8 +28,11 @@ from paddle_tpu.kernels import quantized_matmul as qm
 from paddle_tpu.models import llama_functional as lf
 from paddle_tpu.models.generation import generate, quantize_params
 from paddle_tpu.serving import (BlockAllocator, Engine, NULL_PAGE,
-                                PagedEngine, PrefixMatch, Request, pages_for)
+                                PagedEngine, PrefixMatch, Request, bucket_for,
+                                pages_for)
 
+from phase_ids import (check_identifiers, entries, record_annotations,
+                       step_and_check_dispatch)
 from step_phases import counting_clock, run_and_collect
 
 _INTERPRET = jax.default_backend() != "tpu"
@@ -1416,6 +1419,101 @@ class TestStepPhases:
         eng.submit(Request(np.concatenate([base, base[:3]]), 3))
         run_and_collect(eng, ["prefill", "decode"])
         assert eng.metrics.counter("cow_copies") > copies
+
+    def test_dispatch_is_one_sample_a_step_within_stage(self, chunked,
+                                                        monkeypatch):
+        """`serve.stage_dispatch_s`, the fifth observation: the `stage`
+        entries that are the path's call. A decode step and a prefill
+        window each build their arrays first, so both halves are there."""
+        counting_clock(monkeypatch)
+        for p in _prompts([40, 6], seed=37):
+            chunked.submit(Request(p, 4))
+        seen = set()
+        while chunked.queue or chunked.slots.active_slots:
+            ev, phases, dispatch = step_and_check_dispatch(chunked)
+            assert 1 <= dispatch < phases["stage"], (ev, phases, dispatch)
+            seen.add(ev["type"])
+        assert seen == {"prefill", "prefill_chunk", "decode"}
+
+    def test_phase_entries_say_kind_part_and_size(self, chunked,
+                                                  monkeypatch):
+        """Every `stage` entry of a decode step, a prefill window and a page
+        copy carries `kind` and `part`, every `wait` entry `kind`; a
+        decode's `rows` is the rows it decoded and a window's `tokens`,
+        `bucket`, `start` are its arguments."""
+        windows, decoded = [], []
+        window, decode = chunked._window_prefill_device, \
+            chunked._decode_device
+
+        def spy_window(req, slot, start, end, n):
+            windows.append(dict(request_id=req.request_id, slot=slot,
+                                start=start, tokens=end - start,
+                                bucket=bucket_for(end - start,
+                                                  chunked.min_bucket,
+                                                  chunked.max_len)))
+            return window(req, slot, start, end, n)
+
+        def spy_decode(active):
+            decoded.append(len(active))
+            return decode(active)
+
+        monkeypatch.setattr(chunked, "_window_prefill_device", spy_window)
+        monkeypatch.setattr(chunked, "_decode_device", spy_decode)
+        (base, other) = _prompts([44, 7], seed=38)
+        chunked.serve([Request(base, 2)])
+        seen = record_annotations(monkeypatch)
+        del windows[:], decoded[:]
+        copies = chunked.metrics.counter("cow_copies")
+        # a 40-token prompt in three chunks beside a short one, and a prefix
+        # hit that ends mid-page (a copy-on-write page copy)
+        chunked.serve([Request(np.concatenate([base[:12], base[:3]]), 3),
+                       Request(other, 5)])
+        assert chunked.metrics.counter("cow_copies") > copies
+        check_identifiers(seen)
+        assert len(windows) >= 2
+        for w in windows:
+            assert [ids["part"] for ids in entries(
+                seen, "stage", kind="prefill", **w)] == ["build", "dispatch"]
+            assert len(entries(seen, "wait", kind="prefill", **w)) == 1
+        assert len(entries(seen, "stage", kind="prefill")) == 2 * len(windows)
+        rows = [ids["rows"] for ids in entries(seen, "stage", kind="decode",
+                                               part="dispatch")]
+        assert rows == decoded and set(rows) == {1, 2}
+        assert [ids["rows"] for ids in entries(seen, "wait", kind="decode")] \
+            == decoded
+        page_copies = entries(seen, "stage", kind="copy")
+        assert page_copies and all(ids["part"] == "dispatch"
+                                   for ids in page_copies)
+
+    def test_a_verify_steps_entries_say_kind_and_part(self, params,
+                                                      monkeypatch):
+        from paddle_tpu.models.generation import draft_from_params
+
+        dp, da = draft_from_params(params, ARGS, 1)
+        eng = PagedEngine(params, ARGS, max_slots=2, max_len=64, page_size=8,
+                          min_bucket=8, draft_params=dp, draft_args=da,
+                          spec_tokens=3)
+        seen = record_annotations(monkeypatch)
+        eng.serve([Request(p, 6) for p in _prompts([7, 12], seed=39)])
+        check_identifiers(seen)
+        rounds = eng.metrics.counter("spec_rounds")
+        assert rounds >= 1
+        verify = entries(seen, "stage", kind="verify")
+        assert [ids["part"] for ids in verify] == ["build", "dispatch"] * rounds
+        assert all(ids["rows"] in (1, 2) for ids in verify)
+        assert len(entries(seen, "wait", kind="verify")) == rounds
+        # the draft proposes (build, dispatch, wait) once a round and
+        # mirrors each finished prompt (a dispatch with the window's size)
+        draft = entries(seen, "stage", kind="draft")
+        assert len([ids for ids in draft if "tokens" not in ids]) \
+            == 2 * rounds
+        assert sorted(ids["tokens"] for ids in draft if "tokens" in ids) \
+            == [7, 12]
+        assert len(entries(seen, "wait", kind="draft")) == rounds
+        obs = eng.metrics.summary()["observations"]
+        for gone in ("verify_s", "draft_propose_s", "draft_prefill_s",
+                     "prefill_s"):
+            assert gone not in obs
 
     def test_admit_time_and_queue_wait(self, engine):
         before = engine.metrics.observation("queue_wait_s")

@@ -25,6 +25,8 @@ from paddle_tpu.models.generation import (decode_step, generate, prefill,
                                           quantize_params)
 from paddle_tpu.serving import Engine, Request, bucket_for
 
+from phase_ids import (check_identifiers, entries, record_annotations,
+                       step_and_check_dispatch)
 from step_phases import counting_clock, run_and_collect
 
 ARGS = lf.LlamaArgs(vocab_size=128, hidden_size=64, intermediate_size=176,
@@ -360,6 +362,43 @@ class TestStepPhases:
             assert phases["stage"] >= 1 and phases["wait"] >= 1
             assert phases["emit"] >= 1
 
+    def test_dispatch_is_one_sample_a_step_within_stage(self, engine,
+                                                        monkeypatch):
+        counting_clock(monkeypatch)
+        for p in _prompts([5, 9], seed=24):
+            engine.submit(Request(p, 3))
+        seen = set()
+        while engine.queue or engine.slots.active_slots:
+            ev, phases, dispatch = step_and_check_dispatch(engine)
+            # the jitted call's own two clock reads' gap at the least; a
+            # decode step's stage is all dispatch, a prefill pads first
+            assert dispatch >= 1
+            if ev["type"] == "decode":
+                assert dispatch == phases["stage"]
+            else:
+                assert dispatch < phases["stage"]
+            seen.add(ev["type"])
+        assert seen == {"prefill", "decode"}
+
+    def test_phase_entries_say_kind_part_and_size(self, engine, monkeypatch):
+        seen = record_annotations(monkeypatch)
+        (prompt,) = _prompts([11], seed=25)
+        engine.serve([Request(prompt, 3, request_id="ids")])
+        check_identifiers(seen)
+        window = dict(kind="prefill", tokens=11,
+                      bucket=bucket_for(11, engine.min_bucket,
+                                        engine.max_len),
+                      start=0, request_id="ids")
+        assert [ids["part"] for ids in entries(seen, "stage", **window)] \
+            == ["build", "dispatch"]
+        assert len(entries(seen, "wait", **window)) == 1
+        # one request in flight: every decode step has one row
+        decodes = entries(seen, "stage", kind="decode")
+        assert decodes and all(ids["rows"] == 1 and ids["part"] == "dispatch"
+                               for ids in decodes)
+        assert len(entries(seen, "wait", kind="decode", rows=1)) \
+            == len(decodes)
+
     def test_admit_time_and_queue_wait(self, engine):
         before = engine.metrics.observation("queue_wait_s")
         before = before["count"] if before else 0
@@ -378,4 +417,4 @@ class TestStepPhases:
         obs = engine.metrics.summary()["observations"]
         assert "decode_step_s" not in obs
         assert "tokens_per_decode_step" not in obs
-        assert obs["prefill_s"]["count"] >= 1
+        assert "prefill_s" not in obs
