@@ -33,9 +33,15 @@ import time
 
 from jax.profiler import TraceAnnotation
 
-__all__ = ["PREFIX", "span"]
+__all__ = ["PREFIX", "recording", "span"]
 
 PREFIX = "pt."
+
+
+# whether a profiler session records annotations now: what a `TraceAnnotation`
+# itself asks before it records anything (one atomic read)
+recording = TraceAnnotation.is_enabled
+
 
 # the one clock every span reads, once on entry and once on exit (a test
 # replaces it with a counter to make a step's accounting exact arithmetic)

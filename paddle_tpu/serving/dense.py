@@ -42,7 +42,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from paddle_tpu.models import generation as gen
 from paddle_tpu.models import llama_functional as lf
-from paddle_tpu.serving.sampler import pick as _pick
+from paddle_tpu.serving.sampler import pick as _pick, seat_token, token_vector
 
 __all__ = ["DensePath"]
 
@@ -172,6 +172,9 @@ class DensePath:
         else:
             self.pk = jnp.zeros(pool_shape, dtype)
             self.pv = jnp.zeros_like(self.pk)
+        # the rows' last tokens: a decode step's output is the next one's
+        # operand, a prompt's first token is seated (`seat`)
+        self.tokens = token_vector(eng.max_slots, eng.pad_id)
         self.reset()
         if mesh is not None:
             # both halves of a QuantizedKVPage shard on nkv, so the bf16
@@ -179,6 +182,9 @@ class DensePath:
             sh = NamedSharding(mesh, self.poolspec)
             self.pk = jax.device_put(self.pk, sh)
             self.pv = jax.device_put(self.pv, sh)
+            # replicated, as every program returns it
+            self.tokens = jax.device_put(self.tokens,
+                                         NamedSharding(mesh, P()))
         # 2*max_len: suffix prefills write at [h, h+bucket), which can
         # overshoot max_len before masking trims it
         self.cos, self.sin = lf.rope_tables(2 * eng.max_len, hd,
@@ -210,6 +216,11 @@ class DensePath:
         self._copy = self.sharded(
             _copy_page_traced, in_specs=(pool, pool, rep, rep),
             out_specs=(pool, pool), donate=(0, 1) if donate else ())
+        # never donates: the vector it is given may be a step's output that
+        # the host has not read yet
+        self._seat = self.sharded(
+            functools.partial(seat_token, metrics=eng.metrics),
+            in_specs=(rep, rep, rep), out_specs=rep, donate=())
         # extraction never donates: the pool must survive the gather (the
         # slot retires on the HOST side after the ship)
         self._extract = self.sharded(
@@ -272,7 +283,14 @@ class DensePath:
     def put_state(self, slot, saved):
         pass
 
-    # -- the two step programs ------------------------------------------------
+    def landed(self, out):
+        """Nothing but the tokens rides a decode step's read-back."""
+
+    # -- the token vector and the two step programs ---------------------------
+    def seat(self, slot, token):
+        self.tokens = self._seat(self.tokens, jnp.int32(slot),
+                                 jnp.asarray(token, jnp.int32))
+
     def prefill(self, ids, start, last_idx, bt_row, new_vec, slot, req,
                 sample):
         eng = self.eng
@@ -291,8 +309,10 @@ class DensePath:
 
     def decode(self, bt, active, sample, sampling_args):
         eng = self.eng
-        self.pk, self.pv, nxt = self._decode[sample](
-            eng.params, jnp.asarray(eng._last_tok), self.pk, self.pv,
-            jnp.asarray(bt), jnp.asarray(eng._npos), self.cos, self.sin,
-            *sampling_args)
-        return nxt
+        # a COPY of the positions: the engine moves them on as soon as this
+        # returns, and a host array handed to the device may be read later
+        self.pk, self.pv, self.tokens = self._decode[sample](
+            eng.params, self.tokens, self.pk, self.pv,
+            jnp.asarray(bt), jnp.asarray(eng._npos.copy()), self.cos,
+            self.sin, *sampling_args)
+        return self.tokens

@@ -308,6 +308,8 @@ class DecodeWorker(PagedEngine):
     def admit_handoff(self, pkg):
         """Seat one migrated sequence; returns its (new, local) Request.
         The caller must have checked `_can_admit`."""
+        # a token from elsewhere is seated with nothing in flight
+        self.settle()
         n = int(pkg.prompt_ids.size)
         req = Request(pkg.prompt_ids, pkg.max_new_tokens,
                       eos_token_id=pkg.eos_token_id,
@@ -335,6 +337,7 @@ class DecodeWorker(PagedEngine):
         # after a local prefill)
         self._npos[slot] = n
         self._last_tok[slot] = pkg.first
+        self.path.seat(slot, pkg.first)
         self.metrics.inc("handoffs_admitted")
         if pkg.sent_at is not None:
             self.metrics.observe("handoff_latency_s",
@@ -356,15 +359,15 @@ class DecodeWorker(PagedEngine):
         return admitted
 
     def _step_action(self):
+        # before anything of this call is dispatched: seating a hand-off
+        # settles what is in flight (`admit_handoff`)
         admitted = self._drain_inbox()
-        if self._decodable_slots():
-            ev = self._decode_step()
-            if admitted:
-                ev = dict(ev, admitted=admitted)
+        ev = super()._step_action()
+        if not admitted:
             return ev
-        if admitted:
+        if ev["type"] == "idle":
             return {"type": "handoff_admit", "count": admitted}
-        return {"type": "idle"}
+        return dict(ev, admitted=admitted)
 
     @property
     def busy(self):

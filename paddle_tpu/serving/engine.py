@@ -41,6 +41,15 @@ the two traced programs (per-bucket prefill, one decode) contain no
 wall-clock reads and re-compile only when a NEW bucket shape arrives —
 compile counts are metered at trace time (`serving/metrics.py`).
 
+The step loop (`Engine._step_action`) keeps ONE PROGRAM IN FLIGHT: a
+`step()` schedules, builds and dispatches the next program from the
+state that is known at dispatch, then reads the program that was in
+flight, emits its tokens and returns its event, so the host's work hides
+behind the device's. An engine whose device half reads its own output
+(this stripe engine: its tokens are fed from the host mirror `_last_tok`)
+makes records that are complete at once and runs the same loop at depth
+0; the paged engine's tokens stay on the device and it runs at depth 1.
+
 `serving/paged_engine.PagedEngine` subclasses this scheduler loop but
 swaps the per-slot stripes for a paged KV cache (page pool + block
 tables + hash-based prefix reuse) — far more concurrent requests per
@@ -60,7 +69,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from paddle_tpu.models import generation as gen
-from paddle_tpu.observability.spans import span
+from paddle_tpu.observability.spans import recording, span
 from paddle_tpu.serving.metrics import Metrics
 from paddle_tpu.serving.sampler import SlotSampler, pick as _pick
 from paddle_tpu.serving.scheduler import AdmissionQueue, SlotTable, bucket_for
@@ -124,6 +133,9 @@ class Request:
         # non-negative int32
         self.seed = int(seed) & 0x7FFFFFFF
         self.token_ids = []
+        # tokens of programs handed to the device whose output the host has
+        # not read yet (the engine's: what it schedules ahead by)
+        self.in_flight = 0
         self.finished = False
         self.finish_reason = None
         self.submit_time = None
@@ -230,6 +242,35 @@ class StepPhases:
             self._inner[-1][0] += seconds
 
 
+class _Flight:
+    """One unit of work handed to the device whose output the host has not
+    read: `out` is what the program returns for the host (a device value),
+    `land(host copy of out)` emits its tokens and returns the step's event,
+    `rows` are the `(slot, request)` pairs whose tokens `out` holds (none
+    for a prefill window that is not a prompt's last) and `ids` the
+    identifiers of the program, which its `wait` entry carries.
+
+    A record is COMPLETE where `out` is on the host already (a device half
+    that read its own output, a path without a device, a round that emitted
+    its tokens itself: `done`): nothing is dispatched behind it, because
+    nothing is left to overlap with."""
+
+    __slots__ = ("ids", "out", "land", "rows")
+
+    def __init__(self, ids, out, land, rows=()):
+        self.ids, self.out, self.land, self.rows = ids, out, land, rows
+
+    @classmethod
+    def done(cls, event):
+        """A unit of work that needs no read-back: `event` is its step's."""
+        return cls({}, None, lambda _: event)
+
+    @property
+    def complete(self):
+        return self.out is None or isinstance(
+            self.out, (int, np.integer, np.ndarray))
+
+
 class Engine:
     """Continuous-batching serving engine over a Llama functional param
     tree (float or `quantize_params` int8).
@@ -266,6 +307,9 @@ class Engine:
         self.step_count = 0
         self._stall_steps = 0     # decode work delayed by a prefill step
         self._phase = StepPhases(0)   # the running step's; step() renews it
+        self._flight = None       # the program in flight (`_Flight`)
+        self._kept = None         # a settled program's event, not returned yet
+        self._recorded = False    # a profiler session recorded the last step
         self._forget_steps()
         self._setup_device_state()
 
@@ -320,9 +364,13 @@ class Engine:
 
     # -- the iteration-level scheduler --------------------------------------
     def step(self):
-        """One engine iteration: admit-and-prefill if a request is waiting
-        and a slot is free (paged engines also require page capacity),
-        else one batched decode step over all active slots, else idle.
+        """One engine iteration: the event of ONE program (a prefill window:
+        admit-and-prefill if a request is waiting and a slot is free, paged
+        engines also require page capacity; else one batched decode step
+        over all decodable slots; else idle), with its tokens emitted inside
+        the call. The loop keeps one program in flight (`_step_action`): the
+        call first hands the device the NEXT program, then reads and emits
+        the one it returns the event of.
         Returns a small event dict. The step is the span `pt.serve.step`;
         its action is split over the four `PHASES` (`StepPhases`), each
         observed once a step as `serve.<phase>_s`, and beside them
@@ -358,6 +406,10 @@ class Engine:
         self._step_means = {}     # (kind, bucket) -> [steps, mean seconds]
         for name in ("steps", "s", "cpu_s") + tuple(p + "_s" for p in PHASES):
             self.metrics.inc("serve.stalled_" + name, 0)
+        # the look-ahead's own counters (`_program`, `settle`, `_land`)
+        for name in ("dispatched", "dispatched_ahead", "settled",
+                     "discarded_rows"):
+            self.metrics.inc("serve." + name, 0)
 
     def _note_step(self, phase, cpu0):
         """Count a stalled step where it happens. A step's seconds are its
@@ -398,15 +450,110 @@ class Engine:
                       for p in PHASES), cpu_s)
 
     def _step_action(self):
-        """Pick and run this iteration's unit of work (subclass hook: the
-        paged engine interleaves chunked-prefill streams and swaps decode
-        for speculate-and-verify here)."""
+        """One program ahead. Two kinds of state part here: what the
+        scheduler needs to choose and build the next program (`_npos`, a
+        paged engine's block tables, reservations and chunk streams, whether
+        a row ends by length: `Request.in_flight`) advances when a program
+        is DISPATCHED; what needs token values (`token_ids`, `stream_cb`,
+        EOS, `_last_tok`, retirement) advances when its output is READ, one
+        program later. So with program k in flight this call schedules,
+        builds and dispatches k + 1, THEN reads k's output, emits its tokens
+        and returns its event; with nothing in flight it dispatches k first.
+        The device always has the next program queued behind the one the
+        host waits for.
+
+        Nothing is dispatched behind a complete record (`_Flight.complete`)
+        nor where `_looks_ahead` says no: the same loop at depth 0. A row
+        that hit EOS in k has already run in k + 1: that token is never
+        emitted (`_emit_decode`), and a program whose rows all retired is
+        dropped unread (`_land`). A slot or pages freed by k's tokens are
+        seen by the scheduler one call later than the synchronous loop saw
+        them."""
+        opened, self._recorded = not self._recorded, recording()
+        if opened and self._recorded:
+            # a profiler session opened since the last call, with the device
+            # still in the program dispatched ahead of it: the recording
+            # holds that program's end but not its `stage` entry, and the
+            # device's operations before the session's host clock started.
+            # Settled, the session's first whole program is one it saw
+            # dispatched, after an instant of idle it can attribute
+            self.settle()
+        if self._kept is not None:
+            # `settle` read and emitted what was in flight between two
+            # calls: that event is this call's, and the next program goes out
+            ev, self._kept = self._kept, None
+            self._flight = self._next_program()
+            return ev
+        flight = self._flight
+        if flight is None:
+            flight = self._flight = self._next_program()
+            if flight is None:
+                return {"type": "idle"}
+        self._flight = self._next_program() \
+            if self._looks_ahead(flight) else None
+        return self._land(flight)
+
+    def _looks_ahead(self, flight):
+        """Whether the next program may be chosen and dispatched before
+        `flight`'s output is read (subclass hook)."""
+        return not flight.complete
+
+    def _program(self, ids, out, land, rows=()):
+        """The record of a program just handed to the device (`_Flight`);
+        the rows' tokens are in flight from here on."""
+        self.metrics.inc("serve.dispatched")
+        if self._flight is not None:
+            self.metrics.inc("serve.dispatched_ahead")
+        for _, req in rows:
+            req.in_flight += 1
+        return _Flight(ids, out, land, rows)
+
+    _done = staticmethod(_Flight.done)
+
+    def _land(self, flight):
+        """The emission point: read `flight`'s output (the one host read of
+        a device value a step makes), emit its tokens, return its event."""
+        out = flight.out
+        if not flight.complete:
+            with self._phase("wait", **flight.ids):
+                out = np.asarray(out)
+        for _, req in flight.rows:
+            req.in_flight -= 1
+        ev = flight.land(out)
+        ahead = self._flight
+        if ahead is not None and ahead.rows and \
+                all(req.finished for _, req in ahead.rows):
+            # every row of the program dispatched ahead retired with these
+            # tokens (EOS, found a program late): nothing of it is emitted
+            for _, req in ahead.rows:
+                req.in_flight -= 1
+            self.metrics.inc("serve.discarded_rows", len(ahead.rows))
+            self._flight = None
+        return ev
+
+    def settle(self):
+        """Read and emit what is in flight NOW, for a caller that needs the
+        emitted state to be the scheduled state (`preempt` / `resume`, a
+        hand-off, `reset`): the tokens are emitted here, the event is kept
+        for the next `step()` to return. Not to be called from inside
+        `_next_program`."""
+        flight, self._flight = self._flight, None
+        if flight is not None:
+            self.metrics.inc("serve.settled")
+            self._kept = self._land(flight)
+
+    def _next_program(self):
+        """Pick, build and dispatch the next unit of work from the scheduled
+        state; returns its `_Flight`, or None where there is none (subclass
+        hook: the paged engine interleaves chunked-prefill streams and swaps
+        decode for speculate-and-verify here)."""
         if self._can_prefill():
             self._note_prefill_stall()
             return self._prefill_step()
-        if self._decodable_slots():
-            return self._decode_step()
-        return {"type": "idle"}
+        active = self._decodable_slots()
+        if active:
+            return self._decode_step(active)
+        return None
 
     def _note_prefill_stall(self):
         """Account one prefill-shaped step taken while decodable slots
@@ -419,10 +566,17 @@ class Engine:
             self.metrics.set_gauge("prefill_stall_steps", self._stall_steps)
 
     def _decodable_slots(self):
-        """Slots eligible for a batched decode step (subclass hook: the
-        paged engine excludes slots whose prompt is still mid-chunked-
-        prefill)."""
-        return self.slots.active_slots
+        """Slots eligible for a batched decode step: those whose request
+        wants a token beyond the ones in flight (a row that ends BY LENGTH
+        with a token still on the device is not in the next program).
+        Subclass hook: the paged engine excludes slots whose prompt is
+        still mid-chunked-prefill."""
+        wanting = []
+        for slot in self.slots.active_slots:
+            req = self.slots.owner(slot)
+            if len(req.token_ids) + req.in_flight < req.max_new_tokens:
+                wanting.append(slot)
+        return wanting
 
     def _can_prefill(self):
         """True when the next queued request can be admitted this step
@@ -470,8 +624,10 @@ class Engine:
         """Forget all requests/slots (keeps compiled programs AND compile
         counters; per-run metrics are cleared) — benchmark warmup then
         timed replay on one engine without recompiling."""
+        self.settle()
         if self.queue or self.slots.active_slots:
             raise RuntimeError("reset() with requests still in flight")
+        self._kept = None         # its tokens were emitted when it settled
         # every trace-time compile counter survives: warm replay compiles,
         # reset, timed replay hits the jit cache — wiping any of these
         # would report 0 programs built for the timed run's artifacts
@@ -501,13 +657,14 @@ class Engine:
                                  req.admit_time - req.submit_time)
         return slot
 
-    def _sampling_active(self):
+    def _sampling_active(self, active=None):
         """True when any slot in the decode batch samples — selects the
         decode program variant (greedy-only traffic never compiles the
-        sampling ops). Scoped to the DECODABLE slots: a sampling request
-        still mid-chunked-prefill must not push the greedy rows' decode
-        steps onto the sampling program."""
-        return self.sampler.any_sampling(self._decodable_slots())
+        sampling ops). Scoped to the DECODABLE slots (`active`, where the
+        caller has them): a sampling request still mid-chunked-prefill must
+        not push the greedy rows' decode steps onto the sampling program."""
+        return self.sampler.any_sampling(
+            self._decodable_slots() if active is None else active)
 
     def _record_prefill_done(self, req):
         """The prompt is fully in the target's KV cache. This is NOT
@@ -543,18 +700,30 @@ class Engine:
         slot = self._admit(req)
         n = int(req.prompt_ids.size)
         bucket, first = self._prefill_device(req, slot, n)
-        return self._complete_prefill(req, slot, bucket, first, n)
+        return self._prefill_dispatched(req, slot, bucket, first, n)
+
+    def _prefill_dispatched(self, req, slot, bucket, first, n, start=0):
+        """A prompt's LAST window [start, n) was handed to the device: the
+        slot decodes from position n on, and `first` (on the device still,
+        or read already) is a token in flight (shared by the monolithic path
+        and the paged engine's final chunk)."""
+        self._npos[slot] = n
+        ids = dict(request_id=req.request_id, slot=slot, kind="prefill",
+                   tokens=n - start, bucket=bucket, start=start)
+        return self._program(
+            ids, first,
+            lambda tok: self._complete_prefill(req, slot, bucket, int(tok),
+                                               n),
+            [(slot, req)])
 
     def _complete_prefill(self, req, slot, bucket, first, n):
-        """Book-keep a finished prompt prefill: TTFT, counters, position,
-        the first emitted token (shared by the monolithic path and the
-        paged engine's final chunk)."""
+        """A finished prompt's first token was read: TTFT, counters, the
+        emitted token, retirement where it was the last."""
         with self._phase("emit", request_id=req.request_id, slot=slot):
             self._record_prefill_done(req)
             self._record_first_token(req)
             self.metrics.inc("prefills")
             self.metrics.inc("tokens_generated")
-            self._npos[slot] = n
             self._last_tok[slot] = first
             self._emit(req, first)
             if req.finished:
@@ -564,7 +733,7 @@ class Engine:
 
     def _prefill_device(self, req, slot, n):
         """Run the device half of a prefill (subclass hook). Returns
-        (bucket, first_token)."""
+        (bucket, first_token): read here, so the record is complete."""
         bucket = bucket_for(n, self.min_bucket, self.max_len)
         ids = dict(request_id=req.request_id, slot=slot, kind="prefill",
                    tokens=n, bucket=bucket, start=0)
@@ -583,22 +752,37 @@ class Engine:
             first = int(first)
         return bucket, first
 
-    def _decode_step(self):
-        active = self._decodable_slots()
+    def _decode_step(self, active):
+        """Hand the device one batched decode step over `active`; every row
+        stands one position further from here on."""
         nxt = self._decode_device(active)
+        owner = self.slots.owner
+        rows = [(slot, owner(slot)) for slot in active]
+        self._npos[active] += 1
+        return self._program(dict(kind="decode", rows=len(active)), nxt,
+                             functools.partial(self._emit_decode, rows),
+                             rows)
+
+    def _emit_decode(self, rows, nxt):
+        """A decode step's tokens were read (`nxt`, a slot's at its index):
+        emit each row's, retire the rows that end."""
         emitted = {}
         with self._phase("emit"):
-            for slot in active:
-                self._npos[slot] += 1
-                tok = int(nxt[slot])
+            nxt = nxt.tolist()
+            for slot, req in rows:
+                if req.finished:
+                    # it hit EOS a program ago, found when that one was
+                    # read: this token was never asked for
+                    self.metrics.inc("serve.discarded_rows")
+                    continue
+                tok = nxt[slot]
                 self._last_tok[slot] = tok
-                req = self.slots.owner(slot)
                 self._emit(req, tok)
                 emitted[req.request_id] = tok
                 if req.finished:
                     self._retire(slot)
             self.metrics.inc("decode_steps")
-            self.metrics.inc("tokens_generated", len(active))
+            self.metrics.inc("tokens_generated", len(emitted))
         return {"type": "decode", "tokens": emitted}
 
     def _sampling_args(self):
@@ -606,13 +790,17 @@ class Engine:
 
     def _decode_device(self, active):
         """Run the device half of one batched decode step (subclass
-        hook). Returns the next-token array [S] on host."""
+        hook). Returns the next-token array [S]: on the host here (the
+        stripe engine feeds its tokens from `_last_tok`, so its record is
+        complete and nothing is dispatched behind it), on the device where
+        the tokens are fed there (the paged engine's)."""
         ids = dict(kind="decode", rows=len(active))
         with self._phase("stage", part="dispatch", **ids):
             self._ck, self._cv, nxt = self._decode(
                 self.params, jnp.asarray(self._last_tok), self._ck,
                 self._cv, jnp.asarray(self._npos), self._cos, self._sin,
-                *self._sampling_args(), sample=self._sampling_active())
+                *self._sampling_args(),
+                sample=self._sampling_active(active))
         with self._phase("wait", **ids):
             return np.asarray(nxt)
 

@@ -47,7 +47,7 @@ import numpy as np
 
 from paddle_tpu.models import gated_delta_functional as gdf
 from paddle_tpu.models import hybrid_functional as hf
-from paddle_tpu.serving.sampler import pick as _pick
+from paddle_tpu.serving.sampler import pick as _pick, seat_token, token_vector
 
 __all__ = ["HybridPath", "SNAPSHOTS", "FAMILIES"]
 
@@ -129,6 +129,9 @@ class HybridPath:
         self.snaps = family.slot_state(args, self.snapshots, dtype)
         self.tables = family.tables(args, eng.max_len)
         self.layer_ids = jnp.arange(args.num_layers, dtype=jnp.int32)
+        # the rows' last tokens: a decode step's output is the next one's
+        # operand, a prompt's first token is seated (`seat`)
+        self.tokens = token_vector(eng.max_slots, eng.pad_id)
         self.reset()
 
         donate = eng._donate_enabled()
@@ -145,6 +148,10 @@ class HybridPath:
                              donate_argnums=(0,) if donate else ())
         self._move = jax.jit(_move_rows,
                              donate_argnums=(0,) if donate else ())
+        # never donates: the vector it is given may be a step's output that
+        # the host has not read yet
+        self._seat = jax.jit(functools.partial(seat_token,
+                                               metrics=eng.metrics))
 
     snapshots = SNAPSHOTS
 
@@ -217,7 +224,14 @@ class HybridPath:
         if sid is not None:
             self.pending[slot] = sid
 
-    # -- the two step programs ----------------------------------------------------
+    def landed(self, out):
+        """Nothing but the tokens rides a decode step's read-back."""
+
+    # -- the token vector and the two step programs -------------------------------
+    def seat(self, slot, token):
+        self.tokens = self._seat(self.tokens, jnp.int32(slot),
+                                 jnp.asarray(token, jnp.int32))
+
     def prefill(self, ids, start, last_idx, bt_row, new_vec, slot, req,
                 sample):
         self.pools, self.state, first = self._prefill[sample](
@@ -237,9 +251,10 @@ class HybridPath:
         for name, value in self.family.observe_decode(
                 eng.args, eng, active).items():
             eng.metrics.observe(name, value)
-        self.pools, self.state, nxt = self._decode[sample](
-            eng.params, self.layer_ids, jnp.asarray(eng._last_tok),
-            jnp.asarray(bt),
-            jnp.asarray(eng._npos), jnp.asarray(live), self.pools,
+        # a COPY of the positions: the engine moves them on as soon as this
+        # returns, and a host array handed to the device may be read later
+        self.pools, self.state, self.tokens = self._decode[sample](
+            eng.params, self.layer_ids, self.tokens, jnp.asarray(bt),
+            jnp.asarray(eng._npos.copy()), jnp.asarray(live), self.pools,
             self.state, self.tables, *sampling_args)
-        return nxt
+        return self.tokens

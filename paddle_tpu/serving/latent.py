@@ -17,10 +17,13 @@ routed experts (`models/latent_moe_functional.LatentMoEArgs`).
     step's existing read-back: the program appends them to the rows' next
     tokens (`[slots + 4]`; the engine reads a slot's row and never the
     tail), so they reach the host in the one transfer a step already
-    makes, and the path looks at that host copy at the NEXT decode step's
-    start: no wait and no transfer is added. They become the observations
-    `serve.expert_load_max_over_mean`, `serve.routed_here_share` and
-    `serve.held_experts_hit` (a layer).
+    makes, and the engine hands the path that host copy when it has read it
+    (`landed`): no wait and no transfer is added. They become the
+    observations `serve.expert_load_max_over_mean`,
+    `serve.routed_here_share` and `serve.held_experts_hit` (a layer). The
+    same `[slots + 4]` vector is the NEXT decode step's token operand, as it
+    lies on the device (the program reads its first `slots` rows): the
+    path's token vector has that length;
 
   - where the description asks for it (`args.record_routing`: an operator
     or a judge of the served tokens does, a deployment does not), both step
@@ -49,7 +52,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from paddle_tpu.models import latent_moe_functional as lm
-from paddle_tpu.serving.sampler import pick as _pick
+from paddle_tpu.serving.sampler import pick as _pick, seat_token, token_vector
 
 __all__ = ["LatentPath", "RoutingTrace"]
 
@@ -120,8 +123,10 @@ def _prefill_traced(params, ids, h, last_idx, bt_row, new_pages, pool, cos,
 def _decode_traced(params, tokens, bt, pos, live, pool, cos, sin, temp,
                    top_p, top_k, seeds, *, args, metrics, sample=False):
     metrics.inc("decode_compiles")
+    # the token operand is the step before's whole output: the rows' tokens
+    # and, behind them, its counts
     logits, pool, counts, picks = lm.decode_step(
-        params, tokens, bt, pos, live, pool, cos, sin, args)
+        params, tokens[:pos.shape[0]], bt, pos, live, pool, cos, sin, args)
     nxt = _pick(logits, sample, temp, top_p, top_k, seeds, pos + 1)
     return (pool, jnp.concatenate([nxt, counts.astype(nxt.dtype)]),
             picks if args.record_routing else None)
@@ -161,6 +166,10 @@ class LatentPath:
                                eng.page_size, args.row_width), dtype)
         # 2 * max_len: a window's padding may pass max_len before it is cut
         self.cos, self.sin = lm.rope_tables(2 * eng.max_len, args)
+        # the rows' last tokens, with room for the four counts behind them:
+        # a decode step's output is the next one's operand as it is, a
+        # prompt's first token is seated (`seat`)
+        self.tokens = token_vector(eng.max_slots + 4, eng.pad_id)
         self.reset()
 
         donate = eng._donate_enabled()
@@ -176,11 +185,13 @@ class LatentPath:
         self._copy = jax.jit(
             functools.partial(_copy_page_traced, layers=args.num_layers),
             donate_argnums=(0,) if donate else ())
+        # never donates: the vector it is given may be a step's output that
+        # the host has not read yet
+        self._seat = jax.jit(functools.partial(seat_token,
+                                               metrics=eng.metrics))
 
     def reset(self):
-        """An empty engine: the pool stays (and its byte gauge with it);
-        the last step's counts belong to the requests that are gone."""
-        self._out = None    # the last decode step's [next tokens; counts]
+        """An empty engine: the pool stays (and its byte gauge with it)."""
         self._log = []      # a decode step's [picks, live rows, positions]
         self.eng.metrics.set_gauge(
             "kv_pool_bytes", self.pool.size * self.pool.dtype.itemsize)
@@ -216,7 +227,11 @@ class LatentPath:
         if trace is not None:
             trace.seat(slot)
 
-    # -- the two step programs --------------------------------------------------
+    # -- the token vector and the two step programs -----------------------------
+    def seat(self, slot, token):
+        self.tokens = self._seat(self.tokens, jnp.int32(slot),
+                                 jnp.asarray(token, jnp.int32))
+
     def prefill(self, ids, start, last_idx, bt_row, new_vec, slot, req,
                 sample):
         self.pool, first, picks = self._prefill[sample](
@@ -232,13 +247,11 @@ class LatentPath:
             req.routing.seat(slot)
         return first
 
-    def _observe_counts(self):
-        """The routing counts of the decode step before this one."""
-        if self._out is None:
-            return
-        # the host copy the engine made when it read the step's tokens
+    def landed(self, out):
+        """A decode step's output was read (`out`, the host copy the engine
+        made): the four routing counts behind the rows' tokens."""
         busiest, here, picks, hit = (
-            int(x) for x in np.asarray(self._out)[self.eng.max_slots:])
+            int(x) for x in out[self.eng.max_slots:])
         m, args = self.eng.metrics, self.eng.args
         if picks:
             m.observe("serve.routed_here_share", here / picks)
@@ -250,12 +263,14 @@ class LatentPath:
 
     def decode(self, bt, active, sample, sampling_args):
         eng = self.eng
-        self._observe_counts()
         live = np.zeros(eng.max_slots, bool)
         live[active] = True
-        self.pool, self._out, picks = self._decode[sample](
-            eng.params, eng._last_tok, bt, eng._npos, live, self.pool,
+        # a COPY of the positions: the engine moves them on as soon as this
+        # returns, and a host array handed to the device may be read later
+        pos = eng._npos.copy()
+        self.pool, self.tokens, picks = self._decode[sample](
+            eng.params, self.tokens, bt, pos, live, self.pool,
             self.cos, self.sin, *sampling_args)
         if picks is not None:
-            self._log.append([picks, live, eng._npos.copy()])
-        return self._out
+            self._log.append([picks, live, pos])
+        return self.tokens

@@ -25,7 +25,13 @@ scheduler:
     `path.prefill` (one program per window-length bucket); DECODE = one
     batched step through the block tables, `path.decode`;
   - ADMISSION reserves the worst-case page count minus hits and defers
-    the FIFO head under page pressure.
+    the FIFO head under page pressure;
+  - the step loop is `Engine`'s, ONE PROGRAM AHEAD: block tables,
+    reservations, chunk streams and positions advance when a program is
+    dispatched, token values (EOS, retirement, the tail page's
+    registration) when it is read, a call later; the rows' tokens stay on
+    the device (`path.tokens`, `path.seat`). `preempt` / `resume` and
+    `reset` settle first; a draft model keeps the loop at depth 0.
 
 On top of that scheduler sit the three serving-throughput levers (ROADMAP
 item 1). Two are not this file's: TENSOR PARALLELISM (`mesh=`) is the dense
@@ -245,7 +251,7 @@ class PagedEngine(Engine):
         return need <= self._alloc.available - self._reserved_total
 
     # -- the interleaving scheduler -----------------------------------------
-    def _step_action(self):
+    def _next_program(self):
         """Chunked-prefill interleave: while a prompt is mid-stream, the
         engine alternates one chunk with one unit of other work (admit a
         waiting request or run a decode/speculation step), so queued and
@@ -260,17 +266,24 @@ class PagedEngine(Engine):
             self._chunk_turn = True
             self._note_prefill_stall()
             return self._prefill_step()
-        if self._decodable_slots():
+        active = self._decodable_slots()
+        if active:
             self._chunk_turn = True
             if self.spec_enabled:
-                return self._spec.step()
-            return self._decode_step()
+                return self._done(self._spec.step())
+            return self._decode_step(active)
         if self._chunk_streams:
             return self._chunk_step()
-        return {"type": "idle"}
+        return None
+
+    def _looks_ahead(self, flight):
+        # a verify round's accepted length is data: where the rows stand
+        # after it is not known when it is dispatched, so an engine with a
+        # draft never dispatches behind a program it has not read
+        return not self.spec_enabled and super()._looks_ahead(flight)
 
     def _decodable_slots(self):
-        active = self.slots.active_slots
+        active = super()._decodable_slots()
         if not self._chunk_streams:
             return active
         return [s for s in active if s not in self._chunk_streams]
@@ -313,12 +326,22 @@ class PagedEngine(Engine):
         self.metrics.inc("prefix_pages_hit", len(hit.pages))
         return h
 
+    def _window_ids(self, req, slot, start, end):
+        """The identifiers of the prefill window [start, end): what its
+        `stage` entries carry and, when it is read, its `wait` entry."""
+        return dict(request_id=req.request_id, slot=slot, kind="prefill",
+                    tokens=end - start,
+                    bucket=bucket_for(end - start, self.min_bucket,
+                                      self.max_len), start=start)
+
     def _window_prefill_device(self, req, slot, start, end, n):
-        """Run one prefill window [start, end) of the prompt (the whole
-        suffix, or one chunk of it) through the suffix program. Returns
-        (bucket, token) — the token is meaningful only for the final
-        window (end == n), which also registers the prompt's full pages
-        in the prefix cache."""
+        """Hand the device one prefill window [start, end) of the prompt
+        (the whole suffix, or one chunk of it) through the suffix program.
+        Returns (bucket, token), the token on the device still — it is
+        meaningful only for the final window (end == n), which also
+        registers the prompt's full pages in the prefix cache and seats the
+        token in the path's token vector, where the slot's first decode step
+        finds it."""
         ps, Pn = self.page_size, self.pages_per_slot
         final = end == n
         # pages this window adds beyond those already seated (hits, the
@@ -331,9 +354,8 @@ class PagedEngine(Engine):
         self._bt[slot].extend(new_pages)
         pages = self._bt[slot]
 
-        sb = bucket_for(end - start, self.min_bucket, self.max_len)
-        ids = dict(request_id=req.request_id, slot=slot, kind="prefill",
-                   tokens=end - start, bucket=sb, start=start)
+        ids = self._window_ids(req, slot, start, end)
+        sb = ids["bucket"]
         with self._phase("stage", part="build", **ids):
             bt_row = np.zeros(Pn, np.int32)
             bt_row[:len(pages)] = pages
@@ -348,52 +370,48 @@ class PagedEngine(Engine):
             padded = np.full((1, sb), self.pad_id, np.int32)
             padded[0, :end - start] = req.prompt_ids[start:end]
             sample = final and req.temperature > 0
+            if final:
+                # make this prompt's FULL pages hittable right away (a
+                # concurrent identical prompt shares them while this one
+                # is still decoding): prompt positions only, which the
+                # program dispatched below writes before any later one
+                # reads them. The partial tail page stays unregistered
+                # until _retire — decode keeps writing into it, and
+                # freezing it now would force an unreserved COW on the
+                # first decode
+                self._alloc.register_prefix(req.prompt_ids, pages[:n // ps])
         with self._phase("stage", part="dispatch", **ids):
             first = self.path.prefill(padded, start, end - 1 - start,
                                       bt_row, new_vec, slot, req, sample)
             if final:
                 self.path.prompt_done(slot)
-        with self._phase("wait", **ids):
-            first = int(first)
-        if final:
-            # make this prompt's FULL pages hittable right away (a
-            # concurrent identical prompt shares them while this one is
-            # still decoding). The partial tail page stays unregistered
-            # until _retire — decode keeps writing into it, and freezing
-            # it now would force an unreserved COW on the first decode
-            with self._phase("emit", **ids):
-                self._alloc.register_prefix(req.prompt_ids, pages[:n // ps])
-            # chunk-streamed prompts mirror into the draft window by
-            # window instead (see _chunk_step) — one monolithic draft
-            # prefill here would reintroduce the stall chunking removes
-            if self.spec_enabled and slot not in self._chunk_streams:
-                self._spec.prefill_slot(req, slot, n)
+                self.path.seat(slot, first)
+        # chunk-streamed prompts mirror into the draft window by
+        # window instead (see _chunk_step) — one monolithic draft
+        # prefill here would reintroduce the stall chunking removes
+        if final and self.spec_enabled and slot not in self._chunk_streams:
+            self._spec.prefill_slot(req, slot, n)
         return sb, first
-
-    def _prefill_device(self, req, slot, n):
-        """Monolithic prefill (no chunking, or suffix within one chunk)."""
-        h = self._begin_paged_prefill(req, slot, n)
-        return self._window_prefill_device(req, slot, h, n, n)
 
     def _prefill_step(self):
         """Admit the queue head; suffixes longer than `prefill_chunk`
         become a chunk STREAM advanced by later steps instead of one
         monolithic program."""
-        if self.prefill_chunk is None:
-            return super()._prefill_step()
         idx = self._admit_idx if self._admit_idx is not None \
             else self._admission_index()
         req = self.queue.pop_at(idx)
         slot = self._admit(req)
         n = int(req.prompt_ids.size)
         h = self._begin_paged_prefill(req, slot, n)
-        if n - h <= self.prefill_chunk:
+        if self.prefill_chunk is None or n - h <= self.prefill_chunk:
             bucket, first = self._window_prefill_device(req, slot, h, n, n)
-            self.metrics.observe("chunks_per_prompt", 1)
-            return self._complete_prefill(req, slot, bucket, first, n)
+            if self.prefill_chunk is not None:
+                self.metrics.observe("chunks_per_prompt", 1)
+            return self._prefill_dispatched(req, slot, bucket, first, n, h)
         self._chunk_streams[slot] = {"req": req, "n": n, "done": h,
                                      "ddone": 0, "chunks": 0,
-                                     "bucket": None, "first": None}
+                                     "bucket": None, "first": None,
+                                     "start": h}
         self.metrics.inc("chunked_prefills")
         return self._chunk_step()
 
@@ -403,7 +421,8 @@ class PagedEngine(Engine):
         target chunk, or — when speculation is on and the draft's mirror
         of the prompt lags the target's progress — one draft window of
         the same size, so the draft prefill never runs monolithically
-        inside a single scheduler step."""
+        inside a single scheduler step. A stream's progress is scheduled
+        state: it advances as a chunk is dispatched."""
         slot = next(iter(self._chunk_streams))
         st = self._chunk_streams[slot]
         req, n = st["req"], st["n"]
@@ -415,9 +434,9 @@ class PagedEngine(Engine):
             st["ddone"] = dend
             self.metrics.inc("draft_prefill_chunks")
             if dend < n or st["done"] < n:
-                return {"type": "draft_prefill_chunk",
-                        "request_id": req.request_id, "slot": slot,
-                        "from": dstart, "to": dend}
+                return self._done({"type": "draft_prefill_chunk",
+                                   "request_id": req.request_id,
+                                   "slot": slot, "from": dstart, "to": dend})
             return self._finish_stream(slot, st)
         start = st["done"]
         end = min(start + self.prefill_chunk, n)
@@ -427,24 +446,40 @@ class PagedEngine(Engine):
         self.metrics.inc("prefill_chunks")
         self.metrics.inc("prefill_chunk_tokens", end - start)
         if end == n:
-            st["bucket"], st["first"] = bucket, first
-            # the TARGET's prompt KV is complete here; the first token is
-            # only emitted at _finish_stream, which may wait whole steps
-            # for the draft mirror — the prefill_done_s / ttft_s split
-            self._record_prefill_done(req)
+            st["bucket"], st["first"], st["start"] = bucket, first, start
             if not (self.spec_enabled and st["ddone"] < n):
                 return self._finish_stream(slot, st)
-        return {"type": "prefill_chunk", "request_id": req.request_id,
-                "slot": slot, "from": start, "to": end}
+        ev = {"type": "prefill_chunk", "request_id": req.request_id,
+              "slot": slot, "from": start, "to": end}
+
+        def land(tok):
+            if end == n:
+                # the TARGET's prompt KV is complete here; the first token
+                # is only emitted at _finish_stream, which waits whole
+                # steps for the draft mirror — the prefill_done_s / ttft_s
+                # split. The token was read with the window: stash it so
+                self._record_prefill_done(req)
+                st["first"] = int(tok)
+            return ev
+
+        return self._program(self._window_ids(req, slot, start, end), first,
+                             land)
 
     def _finish_stream(self, slot, st):
         """Both the target chunks and (under speculation) the draft
-        mirror are complete: retire the stream and emit the stashed
-        first token."""
+        mirror were dispatched: retire the stream; its last window's token
+        is the prompt's first."""
         del self._chunk_streams[slot]
         self.metrics.observe("chunks_per_prompt", st["chunks"])
-        return self._complete_prefill(st["req"], slot, st["bucket"],
-                                      st["first"], st["n"])
+        req, n = st["req"], st["n"]
+        if isinstance(st["first"], int):
+            # under a draft the window was read when it went out, steps ago
+            # (`_chunk_step`): no program goes out here, its token is emitted
+            self._npos[slot] = n
+            return self._done(self._complete_prefill(
+                req, slot, st["bucket"], st["first"], n))
+        return self._prefill_dispatched(req, slot, st["bucket"], st["first"],
+                                        n, st["start"])
 
     # -- decode -------------------------------------------------------------
     def _ensure_tail_pages(self, slot, top):
@@ -486,11 +521,18 @@ class PagedEngine(Engine):
                                  live / (self.max_slots * Pn))
         with self._phase("stage", part="dispatch", **ids):
             # with the path's call: the sampler's four per-row operands go
-            # to the device here whenever a row was admitted or cleared
-            nxt = self.path.decode(bt, active, self._sampling_active(),
-                                   self._sampling_args())
-        with self._phase("wait", **ids):
-            return np.asarray(nxt)
+            # to the device here whenever a row was admitted or cleared.
+            # The rows' tokens are on the device already (the path's token
+            # vector), and the step's output stays there until it is
+            # emitted (`Engine._land`)
+            return self.path.decode(bt, active,
+                                    self._sampling_active(active),
+                                    self._sampling_args())
+
+    def _emit_decode(self, rows, nxt):
+        # what else rode the step's one read-back is the path's to look at
+        self.path.landed(nxt)
+        return super()._emit_decode(rows, nxt)
 
     # -- lifecycle ----------------------------------------------------------
     def _retire(self, slot):
@@ -533,10 +575,16 @@ class PagedEngine(Engine):
         (seed, pos) sampling stream — all preserved. The slot's
         remaining page reservation is refunded while preempted, which is
         the point: a waiting request can use it."""
-        req = self.slots.owner(slot)
         if slot in self._chunk_streams:
             raise ValueError(f"slot {slot} is mid-prefill-stream; only "
                              "decoding slots are preemptible")
+        # the slot's last token and position leave with it: what is in
+        # flight is read and emitted first
+        self.settle()
+        if slot not in self.slots.active_slots:
+            raise ValueError(f"slot {slot} finished with the tokens that "
+                             "were in flight; nothing is left to preempt")
+        req = self.slots.owner(slot)
         if self.spec_enabled:
             raise ValueError("preemption with speculative decoding is "
                              "unsupported (the draft's stripe cache is "
@@ -564,6 +612,7 @@ class PagedEngine(Engine):
     def resume(self, state):
         """Re-seat a preempted request (see `preempt`); returns its new
         slot. Caller must have checked `can_resume`."""
+        self.settle()
         req = state["req"]
         slot = self._admit(req)
         self._bt[slot] = state["pages"]
@@ -571,6 +620,7 @@ class PagedEngine(Engine):
         self._reserved_total += state["resv"]
         self._npos[slot] = state["npos"]
         self._last_tok[slot] = state["last_tok"]
+        self.path.seat(slot, state["last_tok"])
         self.path.put_state(slot, state["path_state"])
         self.metrics.inc("resumes")
         return slot

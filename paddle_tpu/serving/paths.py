@@ -5,17 +5,30 @@ block tables, reservations, chunk streams, the step loop, copy-on-write
 bookkeeping, retire / preempt / resume, counters and spans. Everything on the
 DEVICE (the pools and their layout, what a request keeps beside its pages,
 the step programs) is one object a family, `eng.path`, built here from the
-type of the model description the engine was given. The engine calls:
+type of the model description the engine was given. The engine keeps one
+program in flight (`Engine._step_action`): it reads what `prefill` and
+`decode` return one call later, after the next program went out, and nothing
+here may read a device value back itself. The engine calls:
 
   prefill(ids, start, last_idx, bt_row, new_vec, slot, req, sample) -> first
       one prefill window [start, start + last_idx] of a slot's prompt, `ids`
       [1, bucket] padded; the block-table row and the pages to write are
       host arrays. Returns the token after the window (a device scalar).
   decode(bt, active, sample, sampling_args) -> next
-      one batched step over the block tables `bt` [slots, pages a slot];
-      reads `eng._last_tok` / `eng._npos`. Returns the next tokens, a
-      slot's at its index (a path may append what else should ride the
-      one read-back a step makes: the engine reads a slot's row only).
+      one batched step over the block tables `bt` [slots, pages a slot].
+      Its token operand is the path's TOKEN VECTOR, on the device (`tokens`:
+      the step before's output as it is, with the rows `seat` set since),
+      and its output becomes the vector; the positions are a copy of
+      `eng._npos`. Returns the next tokens, a slot's at its index (a path
+      may append what else should ride the one read-back a step makes: the
+      engine reads a slot's row only, and hands the path the host copy it
+      made: `landed`).
+  seat(slot, token)         one row of the token vector set on the device:
+                            a prompt's first token (`first`, a device
+                            scalar) or a host token from elsewhere (a
+                            resumed or handed-over request)
+  landed(out)               the host copy of a decode step's output, when
+                            the engine has read it
   copy_page(src, dst)       device half of a copy-on-write
   prompt_done(slot)         the slot's last prefill window ran
   load_snapshot(slot, sid)  a prefix hit that ends at snapshot `sid`

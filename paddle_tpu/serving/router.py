@@ -318,6 +318,9 @@ class Router:
             or bool(self._waiting[model]["interactive"]))
         if not head_is_interactive:
             return
+        # a victim is picked from what the rows HAVE emitted: a program in
+        # flight is read first (its tokens may finish a row)
+        engine.settle()
         streams = getattr(engine, "_chunk_streams", {})
         victims = [s for s in engine.slots.active_slots
                    if self._slo_of(engine.slots.owner(s)) == "batch"

@@ -24,7 +24,7 @@ import numpy as np
 
 from paddle_tpu.models import generation as gen
 
-__all__ = ["pick", "SlotSampler"]
+__all__ = ["pick", "seat_token", "token_vector", "SlotSampler"]
 
 
 @jax.named_scope("pt.sample")
@@ -38,6 +38,22 @@ def pick(logits, sample, temp, top_p, top_k, seeds, pos):
         return jnp.argmax(logits, axis=-1).astype(jnp.int32)
     return gen._sample(logits, True, temp, top_p, None, top_k,
                        row_keys=gen._row_keys(seeds, pos))
+
+
+def token_vector(rows, pad_id):
+    """The rows' last tokens, on the device: a paged path's decode program
+    takes it as its token operand and its output IS the next one's, so a
+    decoding row's token never goes through the host."""
+    return jnp.full((rows,), pad_id, jnp.int32)
+
+
+def seat_token(tokens, slot, token, *, metrics):
+    """`tokens` with row `slot` set to `token`: how a token that no decode
+    step made gets into the token vector (a prompt's first, a device scalar
+    as its last window returned it; a host token of a resumed or handed-over
+    request). One tiny program an engine, compiled with its first prompt."""
+    metrics.inc("seat_compiles")
+    return tokens.at[slot].set(token)
 
 
 class SlotSampler:
@@ -79,8 +95,10 @@ class SlotSampler:
 
     def device_args(self):
         """The per-row operands the traced `pick` consumes: moved to the
-        device when a row was admitted or cleared since, not every step."""
+        device when a row was admitted or cleared since, not every step.
+        Copies: a row is admitted or cleared while the program that was
+        given these may still be waiting for the device."""
         if self._device is None:
-            self._device = (jnp.asarray(self._temp), jnp.asarray(self._top_p),
-                            jnp.asarray(self._top_k), jnp.asarray(self._seed))
+            self._device = tuple(jnp.asarray(x.copy()) for x in (
+                self._temp, self._top_p, self._top_k, self._seed))
         return self._device

@@ -38,6 +38,16 @@ def _sums(engine):
                 (obs.get(n) or {}).get("sum", 0.0)) for n in names}
 
 
+def synchronous(engine):
+    """`engine` with its loop at depth 0: every `step()` reads the program it
+    dispatched before it returns, so the scheduled state (`_npos`, block
+    tables, reservations) is the emitted state after every call, as it was
+    before the loop looked ahead. For a test that compares those internals
+    step by step; no engine has a switch for it."""
+    engine._looks_ahead = lambda flight: False
+    return engine
+
+
 def step_and_check(engine):
     """One `step()`: each phase gains exactly one sample, and the four
     samples sum to the step span less the reads outside any phase.
