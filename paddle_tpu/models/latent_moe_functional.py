@@ -8,7 +8,8 @@ Every layer is `x += Attn(norm(x)); x += FFN(norm(x))`.
              is `[q_nope; q_pe]`) against ONE cached row a token and layer,
              `[c_kv; k_pe]` = `kv_rank + rope_dim` values: the normed latent
              and the rotary key all heads share (YaRN frequencies on the
-             rotary slice). A head's key is `[c_kv W_uk_h; k_pe]`, its
+             rotary slice where `yarn` is given, plain ones where it is
+             None). A head's key is `[c_kv W_uk_h; k_pe]`, its
              value `c_kv W_uv_h` (`W_kvb` split by head).
              DECODE takes the ABSORBED form: `q_nope` is carried through
              `W_uk` into the latent space, every head attends the cached
@@ -23,13 +24,48 @@ Every layer is `x += Attn(norm(x)); x += FFN(norm(x))`.
              form would pay (2 * kv_rank + rope) against (nope + rope + v)
              multiply-adds a (query, key) pair and head, 1,088 against 320
              at the published widths.
+  selector   where `indexer` is given (DeepSeek Sparse Attention's learned
+             indexer, as GLM-5 publishes it), a query attends the
+             `index_topk` keys of largest INDEX SCORE alone, every head the
+             same ones: I(t, s) = sum_j w_j(t) ReLU(qI_j(t) . kI(s)) over
+             the index heads j, `qI = c_q W_iq`, `kI = LayerNorm(h W_ik)`
+             (ONE vector a token), both rotated on their leading `rope_dim`
+             columns, `w = h W_iw` times heads^-1/2 dim^-1/2; ties to the
+             lower position; a context of at most `index_topk` is attended
+             whole. `kI` is cached beside the latent row in a SECOND pool
+             `[layers * num_pages, page, index width]` under the same page
+             numbers (256 B a token where the latent row is 1,280: the
+             selector reads every visible key, and must not read the rows
+             to do it), so the cache is the pair (latent pool, index pool).
+             ONE selection rule for both steps: a query's scores as sortable
+             integer keys, its `index_topk`-th largest found bit by bit
+             with no sort and no index list (`kernels/latent_attention.
+             kth_largest`: exact, ties counted off from the left), the
+             attention under that MASK. DECODE: the scores of a row's live
+             pages (`index_decode_scores`), then the absorbed decode kernel
+             every step uses with the keys not selected masked: every live
+             row of the latent pool is still read (on the chip a sort of
+             71,680 scores a row and a gather of the 2,048 selected rows
+             cost more than reading all of them at the cell's contexts:
+             `kernels/latent_attention.py`'s head). A row whose context is
+             no longer than `index_topk` goes the same way and selects all
+             of it: no branch a batch could not share. PREFILL: the
+             window's keys `[window, context]` (0.6 GB at 2,048 x 71,680),
+             the same search, and the decompressed form over
+             the context a chunk of keys at a time under that mask
+             (`latent_masked_prefill_attention`: the whole context's keys
+             and values would be 4 GB at 64 heads).
   FFN        `first_k_dense` leading layers: a SwiGLU. The others: shared
              experts (one SwiGLU of their summed width) plus routed
-             experts. The router scores ALL `n_routed_experts` (softmax,
-             float32), keeps the `topk_group` best of `n_group` groups by
-             each group's best score, picks `experts_per_tok` among what
-             stays (ties to the lower index) and weighs a pick by
-             `routed_scaling_factor` times its score. This program HOLDS
+             experts. The router scores ALL `n_routed_experts` in float32
+             (`scoring`: softmax, or sigmoid with the router's correction
+             bias added for the CHOICE alone), keeps the `topk_group` best
+             of `n_group` groups by each group's best (no group step where
+             `n_group` is 1), picks `experts_per_tok` among what stays
+             (ties to the lower index) and weighs a pick by
+             `routed_scaling_factor` times its score, over the sum of the
+             picked scores where `norm_topk` (`route`: one function, the
+             rule read from the description). This program HOLDS
              the experts `[first_expert, first_expert + experts_held)`: a
              pick that lands elsewhere adds nothing here (its chip's part
              of an expert-parallel layer), and no code stands in for the
@@ -40,7 +76,9 @@ Every layer is `x += Attn(norm(x)); x += FFN(norm(x))`.
 The parameter tree is `llama_functional`'s (`embedding`, `layers/*` stacked
 on a leading layer axis, `final_norm`, `lm_head`) with the expert layer's
 leaves `ln1 ln2 w_qa q_norm w_qb w_kva kv_norm w_kvb wo router ws_gate
-ws_up ws_down we_gate we_up we_down`; where `first_k_dense` > 0 a second
+ws_up ws_down we_gate we_up we_down` (`router_bias` where the rule has one;
+`w_iq w_ik ik_norm ik_bias w_iw` in every layer behind a selector); where
+`first_k_dense` > 0 a second
 stacked group `dense_layers/*` holds the leading layers (`w_gate w_up
 w_down` in the experts' place). The page pool `[layers * num_pages, page,
 row_width]` (a row: kv_rank + rope_dim values, padded to whole lane tiles)
@@ -63,10 +101,13 @@ from paddle_tpu.models import llama_functional as lf
 from paddle_tpu.models.generation import _wmm, _write_rows
 from paddle_tpu.models.hybrid_functional import _write_window_pages
 
-__all__ = ["YarnConfig", "LatentMoEArgs", "rope_tables", "softmax_scale",
-           "route", "prefill_window", "decode_step"]
+__all__ = ["YarnConfig", "IndexerConfig", "LatentMoEArgs", "rope_tables",
+           "softmax_scale", "route", "prefill_window", "decode_step"]
 
 DECOMPRESS_BLOCK = 1024     # keys rebuilt at once in a prefill window
+INDEX_BLOCK = 1024          # keys a window's index scores are made for at once
+SELECT_ROWS = 8             # consecutive queries of a window whose selection
+                            # `record_selection` keeps
 
 
 class YarnConfig(NamedTuple):
@@ -76,6 +117,16 @@ class YarnConfig(NamedTuple):
     beta_slow: float
     mscale: float
     mscale_all_dim: float
+
+
+class IndexerConfig(NamedTuple):
+    """The learned token selector in front of the attention (see the
+    module's head)."""
+
+    heads: int                  # index heads
+    dim: int                    # an index head's (and the index key's) width
+    topk: int                   # keys a query attends
+    norm_eps: float = 1e-6      # the index key's LayerNorm
 
 
 class LatentMoEArgs(NamedTuple):
@@ -103,10 +154,18 @@ class LatentMoEArgs(NamedTuple):
     first_k_dense: int
     rope_theta: float
     rms_eps: float
-    yarn: YarnConfig
+    yarn: YarnConfig | None     # None: plain rotary positions
     # the serving path keeps the experts every token picked, for whoever
     # judges the served tokens (`serving/latent.py`, RoutingTrace)
     record_routing: bool = False
+    indexer: IndexerConfig | None = None
+    # "softmax": group-limited greedy over softmax scores; "sigmoid": sigmoid
+    # scores, picked by score + the router's correction bias (`route`)
+    scoring: str = "softmax"
+    norm_topk: bool = False     # a token's picked weights sum to one
+    # both step programs also return the positions a few queries selected
+    # (`serving/latent.py`, SelectionTrace)
+    record_selection: bool = False
 
     @property
     def row_width(self):
@@ -134,6 +193,15 @@ class LatentMoEArgs(NamedTuple):
             raise ValueError("first_k_dense must leave an expert layer")
         if self.rope_dim % 2:
             raise ValueError("rope_dim must be even")
+        if self.scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"scoring {self.scoring!r}: softmax or sigmoid")
+        if self.indexer is not None and not (
+                0 < self.rope_dim <= self.indexer.dim
+                and self.indexer.topk > 0):
+            raise ValueError("the selector's heads hold the rotary slice "
+                             "and keep at least one key")
+        if self.record_selection and self.indexer is None:
+            raise ValueError("record_selection without a selector")
 
 
 # ---------------------------------------------------------------------------
@@ -151,6 +219,8 @@ def yarn_inv_freq(args):
     context (the published `DeepseekV2YarnRotaryEmbedding`)."""
     y, d, base = args.yarn, args.rope_dim, args.rope_theta
     j = np.arange(0, d, 2, dtype=np.float64) / d
+    if y is None:
+        return 1.0 / base ** j
     extra, inter = 1.0 / base ** j, 1.0 / (y.factor * base ** j)
 
     def correction(rotations):
@@ -174,8 +244,9 @@ def rope_tables(seq_len, args):
     freqs = np.outer(np.arange(seq_len, dtype=np.float64),
                      yarn_inv_freq(args))
     emb = np.concatenate([freqs, freqs], axis=-1)
-    m = (_yarn_mscale(args.yarn.factor, args.yarn.mscale)
-         / _yarn_mscale(args.yarn.factor, args.yarn.mscale_all_dim))
+    m = 1.0 if args.yarn is None else (
+        _yarn_mscale(args.yarn.factor, args.yarn.mscale)
+        / _yarn_mscale(args.yarn.factor, args.yarn.mscale_all_dim))
     return (jnp.asarray(np.cos(emb) * m, jnp.float32),
             jnp.asarray(np.sin(emb) * m, jnp.float32))
 
@@ -183,7 +254,7 @@ def rope_tables(seq_len, args):
 def softmax_scale(args):
     """(nope + rope)^-1/2 times YaRN's mscale(factor, mscale_all_dim)^2."""
     m = _yarn_mscale(args.yarn.factor, args.yarn.mscale_all_dim) \
-        if args.yarn.mscale_all_dim else 1.0
+        if args.yarn is not None and args.yarn.mscale_all_dim else 1.0
     return (args.nope_dim + args.rope_dim) ** -0.5 * m * m
 
 
@@ -191,12 +262,18 @@ def softmax_scale(args):
 # attention: the projections around the two cores
 # ---------------------------------------------------------------------------
 
-def _queries_and_row(lp, hin, cos, sin, args):
+def _query_latent(lp, hin, args):
+    return lf.rms_norm(_wmm(hin, lp["w_qa"]), lp["q_norm"], args.rms_eps)
+
+
+def _queries_and_row(lp, hin, cos, sin, args, c_q=None):
     """hin [n, h] at rotary rows cos, sin [n, rope_dim] -> q_nope [n, H,
     nope], q_pe [n, H, rope] (rotated) and the row to cache [n, row_width]:
-    the normed latent, the rotated shared key, zeros."""
+    the normed latent, the rotated shared key, zeros. `c_q`: the normed
+    query latent where the caller made it already."""
     n, H = hin.shape[0], args.num_heads
-    c_q = lf.rms_norm(_wmm(hin, lp["w_qa"]), lp["q_norm"], args.rms_eps)
+    if c_q is None:
+        c_q = _query_latent(lp, hin, args)
     q = _wmm(c_q, lp["w_qb"]).reshape(n, H, args.nope_dim + args.rope_dim)
     q_nope, q_pe = q[..., :args.nope_dim], q[..., args.nope_dim:]
     kv = _wmm(hin, lp["w_kva"])
@@ -208,20 +285,55 @@ def _queries_and_row(lp, hin, cos, sin, args):
     return q_nope, q_pe, jnp.concatenate([c_kv, k_pe[:, 0], pad], axis=-1)
 
 
+@jax.named_scope("pt.index_scores")
+def _index_operands(lp, hin, c_q, cos, sin, args):
+    """The selector's side of a token: its index queries qI [n, J, d] (from
+    the query latent), the index key to cache kI [n, d] (LayerNorm with
+    bias of `hin W_ik`), both rotated on their leading `rope_dim` columns,
+    and its head weights w [n, J], float32, times J^-1/2 d^-1/2."""
+    ix, r, n = args.indexer, args.rope_dim, hin.shape[0]
+    qi = _wmm(c_q, lp["w_iq"]).reshape(n, ix.heads, ix.dim)
+    ki = _wmm(hin, lp["w_ik"]).astype(jnp.float32)
+    ki = ki - jnp.mean(ki, axis=-1, keepdims=True)
+    ki = ki * jax.lax.rsqrt(jnp.mean(ki * ki, axis=-1, keepdims=True)
+                            + ix.norm_eps)
+    ki = (ki * lp["ik_norm"].astype(jnp.float32)
+          + lp["ik_bias"].astype(jnp.float32)).astype(hin.dtype)
+    q_r, k_r = lf.apply_rope_bcast(qi[..., :r], ki[:, None, :r],
+                                   cos[:, None, :], sin[:, None, :])
+    w = _wmm(hin, lp["w_iw"]).astype(jnp.float32) * (
+        ix.heads ** -0.5 * ix.dim ** -0.5)
+    return (jnp.concatenate([q_r, qi[..., r:]], axis=-1),
+            jnp.concatenate([k_r[:, 0], ki[:, r:]], axis=-1), w)
+
+
 def _w_kvb_by_head(lp, args):
     """W_kvb [kv_rank, H, nope + v]: a head's key and value maps."""
     return lp["w_kvb"].reshape(args.kv_rank, args.num_heads,
                                args.nope_dim + args.v_dim)
 
 
-def _decode_attention(lp, x, pool, bt, pos, cos, sin, base, args):
+def _topk(args, positions):
+    """The keys a query attends: `index_topk`, or every position of a table
+    that holds fewer."""
+    return min(args.indexer.topk, positions)
+
+
+def _decode_attention(lp, x, cache, bt, pos, cos, sin, base, record, args):
     """x [b, h], one token a row at positions pos [b] -> (x + attention,
-    pool). The absorbed form over the rows' pages."""
+    cache, the selection of row `record` or None). The absorbed form over
+    the rows' pages; behind a selector under each row's selection: its
+    index scores over its live pages of the index pool, its `index_topk`-th
+    largest (`la.kth_largest`, ties to the lower position; a row whose
+    context is no longer selects all of it, through the same code), and the
+    same kernel with the keys not selected masked."""
+    pool, ipool = cache if args.indexer else (cache, None)
     ps = pool.shape[1]
     hin = lf.rms_norm(x, lp["ln1"], args.rms_eps)
     with jax.named_scope("pt.attention"):
+        c_q = _query_latent(lp, hin, args) if args.indexer else None
         q_nope, q_pe, row = _queries_and_row(lp, hin, cos[pos], sin[pos],
-                                             args)
+                                             args, c_q)
         w = _w_kvb_by_head(lp, args)
         q_lat = jnp.einsum("bhn,chn->bhc", q_nope, w[..., :args.nope_dim])
         pad = jnp.zeros(q_pe.shape[:2] + (row.shape[1] - args.kv_rank
@@ -231,13 +343,30 @@ def _decode_attention(lp, x, pool, bt, pos, cos, sin, base, args):
     # null pages, the layer's garbage sink
     page = base + jnp.take_along_axis(bt, (pos // ps)[:, None], axis=1)[:, 0]
     pool = _write_rows(pool[:, None], row[:, None, :], page, pos % ps)[:, 0]
+    bias = selected = None
+    if args.indexer:
+        qi, ki, wi = _index_operands(lp, hin, c_q, cos[pos], sin[pos], args)
+        ipool = _write_rows(ipool[:, None], ki[:, None, :], page,
+                            pos % ps)[:, 0]
+        with jax.named_scope("pt.index_scores"):
+            scores = la.index_decode_scores(qi, wi, ipool, bt, pos,
+                                            page_base=base)   # [b, T]
+        with jax.named_scope("pt.index_select"):
+            keys = jnp.where(scores > -jnp.inf, la.sortable(scores),
+                             la._KEY_MIN)
+            mine = la.selected_of(keys, *la.kth_largest(
+                keys, _topk(args, keys.shape[1]), pos + 1))
+            bias = jnp.where(mine, 0.0, la._NEG_INF)
+            if args.record_selection:
+                selected = la.packed(mine[record])
     with jax.named_scope("pt.latent_attention"):
         o_lat = la.latent_decode_attention(
             q, pool, bt, pos, softmax_scale(args), args.kv_rank,
-            page_base=base)                                # [b, H, kv_rank]
+            page_base=base, bias=bias)                     # [b, H, kv_rank]
     with jax.named_scope("pt.attention"):
         o = jnp.einsum("bhc,chv->bhv", o_lat, w[..., args.nope_dim:])
-        return x + _wmm(o.reshape(x.shape[0], -1), lp["wo"]), pool
+        return (x + _wmm(o.reshape(x.shape[0], -1), lp["wo"]),
+                (pool, ipool) if args.indexer else pool, selected)
 
 
 @jax.named_scope("pt.attention")
@@ -271,25 +400,103 @@ def _decompress(lp, pool, bt_row, base, n_keys, args):
     return jax.lax.fori_loop(0, live, body, (kv, k_pe))
 
 
-def _window_attention(lp, x, pool, h, last_idx, bt_row, new_pages, cos, sin,
-                      base, args):
+def _selected_window(lp, q_nope, q_pe, qi, wi, pool, ipool, h, last_idx,
+                     bt_row, base, record, args):
+    """A window's attention behind the selector: the index scores of every
+    (query, visible key) as sortable keys [s, T], each query's
+    `index_topk`-th largest found bit by bit (`la.kth_largest`: no sort, no
+    index list; ties to the lower position: the decode step's rule), and
+    the decompressed form over the context a chunk of keys at a time under
+    that mask. Returns (o [s, H, v], the selections of the SELECT_ROWS
+    queries from `record` on as packed bits [SELECT_ROWS, T / 8], or
+    None)."""
+    ps, P = pool.shape[1], bt_row.shape[0]
+    T = P * ps
+    chunk = min(la.MASKED_CHUNK, T)
+    block = min(INDEX_BLOCK, chunk)
+    if chunk % block or block % ps:
+        raise ValueError(f"a table of {T} positions in pages of {ps} does "
+                         f"not cut into blocks of {block} in chunks of "
+                         f"{chunk}")
+    n_chunks = -(-T // chunk)
+    table = jnp.zeros(n_chunks * chunk // ps, jnp.int32).at[:P].set(bt_row)
+    s = qi.shape[0]
+    with jax.named_scope("pt.index_scores"):
+        keys = la.index_window_keys(qi, wi, ipool, table, base, h, last_idx,
+                                    block)
+    with jax.named_scope("pt.index_select"):
+        thr, room = la.kth_largest(
+            keys, _topk(args, T), jnp.full((s,), h + last_idx + 1, jnp.int32))
+        selected = None
+        if args.record_selection:
+            part = [jax.lax.dynamic_slice_in_dim(a, record, min(
+                SELECT_ROWS, s)) for a in (keys, thr, room)]
+            selected = la.packed(la.selected_of(*part))
+    w = _w_kvb_by_head(lp, args)
+
+    def chunk_kv(c):
+        with jax.named_scope("pt.attention"):
+            pages = base + jax.lax.dynamic_slice_in_dim(
+                table, c * (chunk // ps), chunk // ps)
+            rows = pool[pages].reshape(chunk, -1)
+            latent = rows[:, :args.kv_rank]
+            # heads-major as they are made: the kernel's blocks are a head's
+            return (jnp.einsum("tc,chn->htn", latent,
+                               w[..., :args.nope_dim]),
+                    jnp.einsum("tc,chv->htv", latent,
+                               w[..., args.nope_dim:]),
+                    rows[:, args.kv_rank:args.kv_rank + args.rope_dim])
+
+    with jax.named_scope("pt.latent_attention"):
+        o = la.latent_masked_prefill_attention(
+            q_nope, q_pe, chunk_kv, keys, thr, room, h, last_idx,
+            softmax_scale(args), chunk)
+    return o, selected
+
+
+def _window_rows(a, ps):
+    """A window's rows to cache as WHOLE pages of rows, zeros past its own:
+    the writer puts `s // ps + 1` pages, which hold a window from any h only
+    if s is whole pages, and the engine's `min_bucket` may lie below
+    `page_size` (positions no query reads before its own token's write)."""
+    pad = -a.shape[0] % ps
+    return jnp.pad(a, ((0, pad), (0, 0))) if pad else a
+
+
+def _window_attention(lp, x, cache, h, last_idx, bt_row, new_pages, cos, sin,
+                      base, record, args):
     """x [s, h], a window of one slot at positions h .. h + s - 1, real up
-    to `last_idx` -> (x + attention, pool). The decompressed form."""
+    to `last_idx` -> (x + attention, cache, the positions the queries
+    `record` selected or None). The decompressed form."""
+    pool, ipool = cache if args.indexer else (cache, None)
     s, ps = x.shape[0], pool.shape[1]
     hin = lf.rms_norm(x, lp["ln1"], args.rms_eps)
     pos = h + jnp.arange(s, dtype=jnp.int32)
     with jax.named_scope("pt.attention"):
+        c_q = _query_latent(lp, hin, args) if args.indexer else None
         q_nope, q_pe, row = _queries_and_row(lp, hin, cos[pos], sin[pos],
-                                             args)
-    pool = _write_window_pages(pool[:, None], row[:, None, :], h,
-                               base + bt_row, base + new_pages, ps)[:, 0]
-    kv, k_pe = _decompress(lp, pool, bt_row, base, h + last_idx + 1, args)
-    with jax.named_scope("pt.latent_attention"):
-        o = la.latent_prefill_attention(
-            q_nope, q_pe, kv, k_pe, h, last_idx, softmax_scale(args),
-            args.v_dim)                                    # [s, H, v]
+                                             args, c_q)
+    pool = _write_window_pages(pool[:, None], _window_rows(row, ps)[:, None],
+                               h, base + bt_row, base + new_pages, ps)[:, 0]
+    selected = None
+    if args.indexer:
+        qi, ki, wi = _index_operands(lp, hin, c_q, cos[pos], sin[pos], args)
+        ipool = _write_window_pages(
+            ipool[:, None], _window_rows(ki, ps)[:, None], h, base + bt_row,
+            base + new_pages, ps)[:, 0]
+        o, selected = _selected_window(lp, q_nope, q_pe, qi, wi, pool, ipool,
+                                       h, last_idx, bt_row, base, record,
+                                       args)
+    else:
+        kv, k_pe = _decompress(lp, pool, bt_row, base, h + last_idx + 1,
+                               args)
+        with jax.named_scope("pt.latent_attention"):
+            o = la.latent_prefill_attention(
+                q_nope, q_pe, kv, k_pe, h, last_idx, softmax_scale(args),
+                args.v_dim)                                # [s, H, v]
     with jax.named_scope("pt.attention"):
-        return x + _wmm(o.reshape(s, -1), lp["wo"]), pool
+        return (x + _wmm(o.reshape(s, -1), lp["wo"]),
+                (pool, ipool) if args.indexer else pool, selected)
 
 
 # ---------------------------------------------------------------------------
@@ -300,21 +507,36 @@ def _swiglu(x, w_gate, w_up, w_down):
     return _wmm(jax.nn.silu(_wmm(x, w_gate)) * _wmm(x, w_up), w_down)
 
 
-def route(logits, args):
+def route(logits, args, bias=None):
     """Router logits [n, routed_experts] (float32) -> (experts [n, k] int32,
-    weights [n, k] float32): group-limited greedy routing. A group scores
-    the best of its experts; the `topk_group` best groups stay; the
-    `experts_per_tok` best experts among them are picked, each weighing
-    `routed_scaling` times its softmax score (not renormalised). Ties go to
-    the lower index (`jax.lax.top_k`)."""
+    weights [n, k] float32). Both published rules are a top-k over masked
+    scores: an expert scores softmax(logits) (`scoring` "softmax") or
+    sigmoid(logits) ("sigmoid"), and is CHOSEN by that score plus the
+    router's correction `bias` [routed_experts] where one is given; a group
+    scores the best of its experts' choice values; the `topk_group` best
+    groups stay (all of them where `n_group` is 1); the `experts_per_tok`
+    best among them are picked, each weighing `routed_scaling` times its
+    SCORE (never the bias), over the sum of the picked scores where
+    `norm_topk`. Ties go to the lower index (`jax.lax.top_k`)."""
     n = logits.shape[0]
-    scores = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    per = args.routed_experts // args.n_group
-    group_best = jnp.max(scores.reshape(n, args.n_group, per), axis=-1)
-    kept = jax.lax.top_k(group_best, args.topk_group)[1]          # [n, g]
-    stays = jnp.any(jax.nn.one_hot(kept, args.n_group, dtype=bool), axis=1)
-    masked = jnp.where(jnp.repeat(stays, per, axis=1), scores, 0.0)
-    w, experts = jax.lax.top_k(masked, args.experts_per_tok)
+    logits = logits.astype(jnp.float32)
+    scores = (jax.nn.sigmoid(logits) if args.scoring == "sigmoid"
+              else jax.nn.softmax(logits, axis=-1))
+    choice = scores if bias is None else scores + bias.astype(jnp.float32)
+    if args.n_group > 1:
+        per = args.routed_experts // args.n_group
+        group_best = jnp.max(choice.reshape(n, args.n_group, per), axis=-1)
+        kept = jax.lax.top_k(group_best, args.topk_group)[1]      # [n, g]
+        stays = jnp.any(jax.nn.one_hot(kept, args.n_group, dtype=bool),
+                        axis=1)
+        # a sigmoid score plus a bias may be negative: what leaves sorts last
+        choice = jnp.where(jnp.repeat(stays, per, axis=1), choice,
+                           0.0 if bias is None else -jnp.inf)
+    w, experts = jax.lax.top_k(choice, args.experts_per_tok)
+    if bias is not None:
+        w = jnp.take_along_axis(scores, experts, axis=-1)
+    if args.norm_topk:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
     return experts.astype(jnp.int32), w * args.routed_scaling
 
 
@@ -330,7 +552,7 @@ def _routed_experts(lp, stack, first, hin, live, args):
     with jax.named_scope("pt.moe_route"):
         logits = jnp.matmul(hin.astype(jnp.float32),
                             lp["router"].astype(jnp.float32))
-        experts, weights = route(logits, args)
+        experts, weights = route(logits, args, lp.get("router_bias"))
         local = experts - args.first_expert
         held = (local >= 0) & (local < E) & live[:, None]
         # sorted by expert; what is not held sorts past the held experts
@@ -380,11 +602,14 @@ def _dense_ffn(lp, x, args):
 # the two step programs' bodies
 # ---------------------------------------------------------------------------
 
-def _stack(params, x, pool, attention, live, args):
+def _stack(params, x, cache, attention, live, args):
     """The dense leading layers, then the expert layers, each group one scan
-    whose carry holds the activations and the whole pool. `attention(lp, x,
-    pool, base)` is the step's own. Returns (x, pool, counts [4], picks
-    [expert layers, rows, experts a token])."""
+    whose carry holds the activations and the whole cache (the latent pool,
+    or it and the index pool). `attention(lp, x, cache, base)` is the
+    step's own and returns (x, cache, what it recorded or None). Returns
+    (x, cache, counts [4], picks [expert layers, rows, experts a token],
+    the records stacked over ALL layers or None)."""
+    pool = jax.tree_util.tree_leaves(cache)[0]
     num_pages = pool.shape[0] // args.num_layers
     kd, E = args.first_k_dense, args.experts_held
     # the experts' leaves stay out of the scan's slices (`_routed_experts`)
@@ -395,24 +620,27 @@ def _stack(params, x, pool, attention, live, args):
 
     def dense(carry, xs):
         lp, layer = xs
-        x, pool = attention(lp, *carry, layer * num_pages)
-        return (_dense_ffn(lp, x, args), pool), None
+        x, cache, rec = attention(lp, *carry, layer * num_pages)
+        return (_dense_ffn(lp, x, args), cache), rec
 
     def expert(carry, xs):
         lp, layer = xs
-        x, pool = attention(lp, *carry, layer * num_pages)
+        x, cache, rec = attention(lp, *carry, layer * num_pages)
         x, c, picks = _expert_ffn(lp, experts, (layer - kd) * E, x, live,
                                   args)
-        return (x, pool), (c, picks)
+        return (x, cache), (c, picks, rec)
 
+    recs = None
     if kd:
-        (x, pool), _ = jax.lax.scan(
-            dense, (x, pool),
+        (x, cache), recs = jax.lax.scan(
+            dense, (x, cache),
             (params["dense_layers"], jnp.arange(kd, dtype=jnp.int32)))
-    (x, pool), (per_layer, picks) = jax.lax.scan(
-        expert, (x, pool),
+    (x, cache), (per_layer, picks, more) = jax.lax.scan(
+        expert, (x, cache),
         (scanned, jnp.arange(kd, args.num_layers, dtype=jnp.int32)))
-    return x, pool, jnp.sum(per_layer, axis=0), picks
+    if more is not None and recs is not None:
+        more = jnp.concatenate([recs, more])
+    return x, cache, jnp.sum(per_layer, axis=0), picks, more
 
 
 def _head(params, x, args):
@@ -420,36 +648,56 @@ def _head(params, x, args):
     return _wmm(x, params["lm_head"]).astype(jnp.float32)
 
 
-def prefill_window(params, ids, h, last_idx, bt_row, new_pages, pool, cos,
-                   sin, args):
+def prefill_window(params, ids, h, last_idx, bt_row, new_pages, cache, cos,
+                   sin, args, record=None):
     """One prefill window of one slot: ids [s] at positions h .. h + s - 1,
     real up to `last_idx`; bt_row [P] the slot's block table (a layer's
     page numbers); new_pages [P] the pages the window writes, from the one
-    that holds h on (unused entries the null page). Returns (logits [vocab]
-    at last_idx, pool, picks [expert layers, s, experts a token]: the
-    experts each token picked)."""
+    that holds h on (unused entries the null page); `cache` the latent pool
+    or, behind a selector, (latent pool, index pool). Returns (logits
+    [vocab] at last_idx, cache, picks [expert layers, s, experts a token]:
+    the experts each token picked) and, where the description records the
+    selection, that of the SELECT_ROWS queries from window row `record` on,
+    as packed bits [layers, SELECT_ROWS, table positions / 8] (`la.packed`;
+    a query at position t < index_topk selects all t + 1)."""
     s = ids.shape[0]
     live = jnp.arange(s, dtype=jnp.int32) <= last_idx
 
-    def attention(lp, x, pool, base):
-        return _window_attention(lp, x, pool, h, last_idx, bt_row,
-                                 new_pages, cos, sin, base, args)
+    def attention(lp, x, cache, base):
+        return _window_attention(lp, x, cache, h, last_idx, bt_row,
+                                 new_pages, cos, sin, base, record, args)
 
     x = jnp.take(params["embedding"], ids, axis=0)
-    x, pool, _, picks = _stack(params, x, pool, attention, live, args)
-    return _head(params, x[last_idx][None], args)[0], pool, picks
+    x, cache, _, picks, selected = _stack(params, x, cache, attention, live,
+                                          args)
+    out = _head(params, x[last_idx][None], args)[0], cache, picks
+    return out + (selected,) if args.record_selection else out
 
 
-def decode_step(params, tokens, bt, pos, live, pool, cos, sin, args):
+def decode_step(params, tokens, bt, pos, live, cache, cos, sin, args,
+                record=None):
     """One token a slot: tokens [b] at positions pos [b] through block
     tables bt [b, P]; live [b] marks the rows that decode (the others write
     to the null page and count for nothing). Returns (logits [b, vocab],
-    pool, counts int32 [4] summed over the expert layers: tokens at the
+    cache, counts int32 [4] summed over the expert layers: tokens at the
     busiest held expert, picks on held experts, picks in all, held experts
-    with a token; picks [expert layers, b, experts a token])."""
-    def attention(lp, x, pool, base):
-        return _decode_attention(lp, x, pool, bt, pos, cos, sin, base, args)
+    with a token; behind a selector two more, over the live rows: the keys
+    they selected and the keys they could see; picks [expert layers, b,
+    experts a token]) and, where the description records the selection,
+    row `record`'s as packed bits [layers, table positions / 8]
+    (`la.packed`)."""
+    def attention(lp, x, cache, base):
+        return _decode_attention(lp, x, cache, bt, pos, cos, sin, base,
+                                 record, args)
 
     x = jnp.take(params["embedding"], tokens, axis=0)
-    x, pool, counts, picks = _stack(params, x, pool, attention, live, args)
-    return _head(params, x, args), pool, counts, picks
+    x, cache, counts, picks, selected = _stack(params, x, cache, attention,
+                                               live, args)
+    if args.indexer:
+        K = _topk(args, bt.shape[1]
+                  * jax.tree_util.tree_leaves(cache)[0].shape[1])
+        seen = jnp.where(live, pos + 1, 0)
+        counts = jnp.concatenate([counts, jnp.stack([
+            jnp.sum(jnp.minimum(seen, K)), jnp.sum(seen)])])
+    out = _head(params, x, args), cache, counts, picks
+    return out + (selected,) if args.record_selection else out

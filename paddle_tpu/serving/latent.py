@@ -39,6 +39,25 @@ routed experts (`models/latent_moe_functional.LatentMoEArgs`).
     there when it is read: nothing waits for them and no step does work a
     row. The log lives until `reset`: 9 KB a step at 64 rows.
 
+  - behind a TOKEN SELECTOR (`args.indexer`: a learned indexer picks the
+    `index_topk` keys a query attends) the cache is TWO pools under the one
+    block table and the allocator's one page id: the latent pool and an
+    INDEX pool `[layers * num_pages, page_size, index width]` that holds a
+    token's index key, 256 B at the published width where the latent row is
+    1,280: the selector scores a row's whole context and must not read the
+    latent rows to do it. `self.pool` is then the pair, carried, donated,
+    written (`pt.kv_write`) and copied on write as one; a prefix hit,
+    preempt / resume and `reset` see pages, and a page is a page of both.
+    A decode step's counts are six: behind the four, the keys its live rows
+    selected and the keys they could see (`serve.selected_keys`,
+    `serve.visible_keys`). Where the description asks (`record_selection`),
+    a request's trace also holds the selections of a SAMPLE of its queries,
+    every layer, as packed bits (a bit a table position: 9 KB a query a
+    layer): `lm.SELECT_ROWS` consecutive queries of each prefill window from
+    a row drawn from a seed of the window, and one live row of every
+    `SELECT_EVERY`-th decode step (the rows in turn), for whoever judges
+    the selection itself (`RoutingTrace.selections`);
+
 Refused at construction, with the reason: a mesh, an int8 pool, a draft
 model; `check_handoff` refuses the disaggregated workers.
 """
@@ -56,6 +75,8 @@ from paddle_tpu.serving.sampler import pick as _pick, seat_token, token_vector
 
 __all__ = ["LatentPath", "RoutingTrace"]
 
+SELECT_EVERY = 8     # decode steps between two kept selections
+
 
 class RoutingTrace:
     """The experts one request's tokens picked: its prefill windows' picks
@@ -66,9 +87,15 @@ class RoutingTrace:
 
     def __init__(self, log):
         self._log, self._windows, self._stays = log, [], []
+        # (a window's first position, its count, the first row kept, the
+        # kept rows' selections as packed bits [layers, rows, positions / 8])
+        self._selected = []
 
-    def window(self, position, count, picks):
+    def window(self, position, count, picks, row=None, selected=None):
         self._windows.append((int(position), int(count), picks))
+        if selected is not None:
+            self._selected.append((int(position), int(count), int(row),
+                                   selected))
 
     def seat(self, slot):
         """Its decode rows are row `slot` of the steps logged from now on
@@ -80,6 +107,21 @@ class RoutingTrace:
     def leave(self):
         """Preempted: its stay ends with the last step logged."""
         self._stays[-1][2] = len(self._log)
+
+    def _decode_rows(self, positions):
+        """(position, the step's log entry, its slot) for the request's
+        decode rows below `positions`: one position after the other from its
+        last window's end on, in every logged step of its stays that ran
+        its row."""
+        at = self._windows[-1][0] + self._windows[-1][1]
+        for slot, first, end in self._stays:
+            for entry in self._log[first:end]:
+                live, pos = entry[1], entry[2]
+                if at >= positions or (live[slot] and pos[slot] != at):
+                    break           # read to the end, or the slot's next owner
+                if live[slot]:
+                    yield at, entry, slot
+                    at += 1
 
     def table(self, positions):
         """int32 [positions, expert layers, experts a token]: the picks of
@@ -93,53 +135,74 @@ class RoutingTrace:
             if count > 0:
                 out[first:first + count] = np.swapaxes(
                     np.asarray(picks)[:, :count], 0, 1)
-        # its decode rows: one position after the other from its last
-        # window's end on, in every logged step of its stays that ran its row
-        at = self._windows[-1][0] + self._windows[-1][1]
-        for slot, first, end in self._stays:
-            for entry in self._log[first:end]:
-                picks, live, pos = entry
-                if at >= positions or (live[slot] and pos[slot] != at):
-                    break           # read to the end, or the slot's next owner
-                if live[slot]:
-                    if not isinstance(picks, np.ndarray):
-                        entry[0] = picks = np.asarray(picks)
-                    out[at] = picks[:, slot]
-                    at += 1
+        for at, entry, slot in self._decode_rows(positions):
+            if not isinstance(entry[0], np.ndarray):
+                entry[0] = np.asarray(entry[0])
+            out[at] = entry[0][:, slot]
+        return out
+
+    def selections(self, positions):
+        """[(position, [layers] int arrays)]: the positions that the sampled
+        queries below `positions` selected in each layer, lowest first."""
+        def unpacked(bits):
+            return np.unpackbits(np.asarray(bits), axis=-1,
+                                 bitorder="little")
+
+        out = []
+        for first, count, row, kept in self._selected:
+            kept = unpacked(kept)                   # [layers, rows, T]
+            for j in range(min(kept.shape[1], count - row)):
+                if first + row + j < positions:
+                    out.append((first + row + j, [
+                        np.nonzero(layer[j])[0] for layer in kept]))
+        for at, entry, slot in self._decode_rows(positions):
+            if entry[3] is not None and entry[4] == slot:
+                out.append((at, [np.nonzero(layer)[0]
+                                 for layer in unpacked(entry[3])]))
         return out
 
 
 def _prefill_traced(params, ids, h, last_idx, bt_row, new_pages, pool, cos,
-                    sin, temp, top_p, top_k, seeds, *, args, metrics,
+                    sin, temp, top_p, top_k, seeds, record, *, args, metrics,
                     sample=False):
     metrics.inc("prefill_compiles")
-    logits, pool, picks = lm.prefill_window(
-        params, ids[0], h, last_idx, bt_row, new_pages, pool, cos, sin, args)
+    logits, pool, picks, *selected = lm.prefill_window(
+        params, ids[0], h, last_idx, bt_row, new_pages, pool, cos, sin, args,
+        record)
     first = _pick(logits[None], sample, temp, top_p, top_k, seeds,
                   h + last_idx + 1)[0]
-    return pool, first, picks if args.record_routing else None
+    return (pool, first, picks if args.record_routing else None,
+            selected[0] if selected else None)
 
 
 def _decode_traced(params, tokens, bt, pos, live, pool, cos, sin, temp,
-                   top_p, top_k, seeds, *, args, metrics, sample=False):
+                   top_p, top_k, seeds, record, *, args, metrics,
+                   sample=False):
     metrics.inc("decode_compiles")
     # the token operand is the step before's whole output: the rows' tokens
     # and, behind them, its counts
-    logits, pool, counts, picks = lm.decode_step(
-        params, tokens[:pos.shape[0]], bt, pos, live, pool, cos, sin, args)
+    logits, pool, counts, picks, *selected = lm.decode_step(
+        params, tokens[:pos.shape[0]], bt, pos, live, pool, cos, sin, args,
+        record)
     nxt = _pick(logits, sample, temp, top_p, top_k, seeds, pos + 1)
     return (pool, jnp.concatenate([nxt, counts.astype(nxt.dtype)]),
-            picks if args.record_routing else None)
+            picks if args.record_routing else None,
+            selected[0] if selected else None)
 
 
 @jax.named_scope("pt.kv_write")
 def _copy_page_traced(pool, src, dst, *, layers):
-    """Copy-on-write: page `src` onto page `dst` in every layer's run."""
-    num_pages = pool.shape[0] // layers
-    view = pool.reshape((layers, num_pages) + pool.shape[1:])
-    view = jax.lax.dynamic_update_slice_in_dim(
-        view, jax.lax.dynamic_slice_in_dim(view, src, 1, axis=1), dst, axis=1)
-    return view.reshape(pool.shape)
+    """Copy-on-write: page `src` onto page `dst` in every layer's run of
+    every pool."""
+    def one(pool):
+        num_pages = pool.shape[0] // layers
+        view = pool.reshape((layers, num_pages) + pool.shape[1:])
+        view = jax.lax.dynamic_update_slice_in_dim(
+            view, jax.lax.dynamic_slice_in_dim(view, src, 1, axis=1), dst,
+            axis=1)
+        return view.reshape(pool.shape)
+
+    return jax.tree.map(one, pool)
 
 
 class LatentPath:
@@ -164,12 +227,18 @@ class LatentPath:
         dtype = jax.tree_util.tree_leaves(eng.params["embedding"])[0].dtype
         self.pool = jnp.zeros((args.num_layers * eng.num_pages,
                                eng.page_size, args.row_width), dtype)
+        self.counts = 4
+        if args.indexer:
+            self.pool = (self.pool, jnp.zeros(
+                (args.num_layers * eng.num_pages, eng.page_size,
+                 args.indexer.dim), dtype))
+            self.counts = 6
         # 2 * max_len: a window's padding may pass max_len before it is cut
         self.cos, self.sin = lm.rope_tables(2 * eng.max_len, args)
         # the rows' last tokens, with room for the four counts behind them:
         # a decode step's output is the next one's operand as it is, a
         # prompt's first token is seated (`seat`)
-        self.tokens = token_vector(eng.max_slots + 4, eng.pad_id)
+        self.tokens = token_vector(eng.max_slots + self.counts, eng.pad_id)
         self.reset()
 
         donate = eng._donate_enabled()
@@ -191,10 +260,16 @@ class LatentPath:
                                                metrics=eng.metrics))
 
     def reset(self):
-        """An empty engine: the pool stays (and its byte gauge with it)."""
-        self._log = []      # a decode step's [picks, live rows, positions]
+        """An empty engine: the pools stay (and their byte gauges)."""
+        # a decode step's [picks, live rows, positions, the selection kept
+        # or None, its slot]
+        self._log, self._steps = [], 0
+        pools = jax.tree_util.tree_leaves(self.pool)
         self.eng.metrics.set_gauge(
-            "kv_pool_bytes", self.pool.size * self.pool.dtype.itemsize)
+            "kv_pool_bytes", sum(p.size * p.dtype.itemsize for p in pools))
+        if len(pools) > 1:
+            self.eng.metrics.set_gauge(
+                "index_pool_bytes", pools[1].size * pools[1].dtype.itemsize)
 
     # -- pages ----------------------------------------------------------------
     def copy_page(self, src, dst):
@@ -234,25 +309,36 @@ class LatentPath:
 
     def prefill(self, ids, start, last_idx, bt_row, new_vec, slot, req,
                 sample):
-        self.pool, first, picks = self._prefill[sample](
+        # the first of the window's queries whose selection is kept: a
+        # draw seeded by the window
+        row = None
+        if self.eng.args.record_selection:
+            row = np.int32(np.random.default_rng(
+                [len(req.prompt_ids), start]).integers(
+                    0, max(1, last_idx + 2 - lm.SELECT_ROWS)))
+        self.pool, first, picks, selected = self._prefill[sample](
             self.eng.params, jnp.asarray(ids), jnp.int32(start),
             jnp.int32(last_idx), jnp.asarray(bt_row), jnp.asarray(new_vec),
             self.pool, self.cos, self.sin, jnp.float32(req.temperature),
             jnp.float32(req.top_p), jnp.int32(req.top_k),
-            jnp.asarray([req.seed], jnp.int32))
-        if picks is not None:
+            jnp.asarray([req.seed], jnp.int32), row)
+        if picks is not None or selected is not None:
             if getattr(req, "routing", None) is None:
                 req.routing = RoutingTrace(self._log)
-            req.routing.window(start, last_idx + 1, picks)
+            req.routing.window(start, last_idx + 1, picks, row, selected)
             req.routing.seat(slot)
         return first
 
     def landed(self, out):
         """A decode step's output was read (`out`, the host copy the engine
-        made): the four routing counts behind the rows' tokens."""
-        busiest, here, picks, hit = (
+        made): the routing's four counts behind the rows' tokens and, behind
+        a selector, the keys selected and visible."""
+        busiest, here, picks, hit, *keys = (
             int(x) for x in out[self.eng.max_slots:])
         m, args = self.eng.metrics, self.eng.args
+        if keys:
+            m.observe("serve.selected_keys", keys[0])
+            m.observe("serve.visible_keys", keys[1])
         if picks:
             m.observe("serve.routed_here_share", here / picks)
             m.observe("serve.held_experts_hit",
@@ -268,9 +354,17 @@ class LatentPath:
         # a COPY of the positions: the engine moves them on as soon as this
         # returns, and a host array handed to the device may be read later
         pos = eng._npos.copy()
-        self.pool, self.tokens, picks = self._decode[sample](
+        # the row whose selection this step returns: the live rows in turn,
+        # kept every SELECT_EVERY-th step
+        row, keep = None, 0
+        if eng.args.record_selection:
+            turn, keep = divmod(self._steps, SELECT_EVERY)
+            row = np.int32(active[turn % len(active)])
+            self._steps += 1
+        self.pool, self.tokens, picks, selected = self._decode[sample](
             eng.params, self.tokens, bt, pos, live, self.pool,
-            self.cos, self.sin, *sampling_args)
-        if picks is not None:
-            self._log.append([picks, live, pos])
+            self.cos, self.sin, *sampling_args, row)
+        if picks is not None or selected is not None:
+            self._log.append([picks, live, pos,
+                              None if keep else selected, row])
         return self.tokens

@@ -433,3 +433,76 @@ def test_latent_decode_program_copies_no_experts_and_no_pool(one_chip):
     # one expert stack of a layer is 315 MB, the pool 1.3 GB here: what the
     # program plans beside its arguments stays far under either
     assert compiled.memory_analysis().temp_size_in_bytes < 150e6
+
+
+def test_selector_programs_compile_at_the_glm5_widths(one_chip):
+    """GLM-5's two step programs (`LatentMoEArgs` with an `IndexerConfig`)
+    at the cell's widths, tables and window, depth 2 (one dense leading
+    layer, one expert layer), both pools donated: Mosaic takes the index
+    pool's decode kernel (`index_decode_scores`: 128-wide pages, a [1,
+    71,680] float32 output block a row), the absorbed decode kernel over
+    the gathered rows, and the masked prefill kernel (`[H, chunk, 192]`
+    keys, the running softmax aliased in and out); neither program plans a
+    temporary of a pool's size, and the window's stay within what the chip
+    has beside the cell's 11.9 GB of weights and pools."""
+    from paddle_tpu.models import latent_moe_functional as lm
+
+    L, b, ps, max_len, NP = 2, 32, 64, 71680, 2048
+    P = max_len // ps
+    args = lm.LatentMoEArgs(
+        vocab_size=19360, hidden_size=6144, num_layers=L, num_heads=64,
+        q_rank=2048, kv_rank=512, nope_dim=192, rope_dim=64, v_dim=256,
+        dense_intermediate=12288, expert_intermediate=2048, shared_experts=1,
+        routed_experts=256, first_expert=0, experts_held=16, n_group=1,
+        topk_group=1, experts_per_tok=8, routed_scaling=2.5, first_k_dense=1,
+        rope_theta=1e6, rms_eps=1e-5, yarn=None,
+        indexer=lm.IndexerConfig(32, 128, 2048), scoring="sigmoid",
+        norm_topk=True, record_selection=True)
+    h, H, E, m = 6144, 64, 16, 2048
+
+    def sds(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    both = {"ln1": (h,), "ln2": (h,), "w_qa": (h, 2048), "q_norm": (2048,),
+            "w_qb": (2048, H * 256), "w_kva": (h, 576), "kv_norm": (512,),
+            "w_kvb": (512, H * 448), "wo": (H * 256, h),
+            "w_iq": (2048, 32 * 128), "w_ik": (h, 128), "ik_norm": (128,),
+            "ik_bias": (128,), "w_iw": (h, 32)}
+    expert = dict(both, router=(h, 256), router_bias=(256,), ws_gate=(h, m),
+                  ws_up=(h, m), ws_down=(m, h), we_gate=(E, h, m),
+                  we_up=(E, h, m), we_down=(E, m, h))
+    dense = dict(both, w_gate=(h, 12288), w_up=(h, 12288),
+                 w_down=(12288, h))
+    params = {"embedding": sds((19360, h)), "final_norm": sds((h,)),
+              "lm_head": sds((h, 19360)),
+              "layers": {k: sds((1,) + s) for k, s in expert.items()},
+              "dense_layers": {k: sds((1,) + s) for k, s in dense.items()}}
+    cache = (sds((L * NP, ps, 640)), sds((L * NP, ps, 128)))
+    table = sds((2 * max_len, 64), jnp.float32)
+
+    def decode(params, tokens, bt, pos, live, cache, cos, sin, record):
+        with qm.fused_dispatch(True):
+            return lm.decode_step(params, tokens, bt, pos, live, cache, cos,
+                                  sin, args, record)
+
+    compiled = jax.jit(decode, donate_argnums=(5,)).lower(
+        params, sds((b,), jnp.int32), sds((b, P), jnp.int32),
+        sds((b,), jnp.int32), sds((b,), jnp.bool_), cache, table, table,
+        sds((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "index_decode_scores" in text
+    assert "latent_decode_attention" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 200e6
+
+    def prefill(params, ids, at, last, bt_row, new_pages, cache, cos, sin,
+                record):
+        with qm.fused_dispatch(True):
+            return lm.prefill_window(params, ids, at, last, bt_row, new_pages,
+                                     cache, cos, sin, args, record)
+
+    compiled = jax.jit(prefill, donate_argnums=(6,)).lower(
+        params, sds((2048,), jnp.int32), sds((), jnp.int32),
+        sds((), jnp.int32), sds((P,), jnp.int32), sds((P,), jnp.int32), cache,
+        table, table, sds((), jnp.int32)).compile()
+    assert "latent_masked_prefill_attention" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 3.0e9
