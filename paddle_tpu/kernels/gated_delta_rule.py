@@ -39,9 +39,24 @@ right-padded to a length bucket: a padded token has log a = 0 and b = 0, so
 it neither decays the state nor writes to it, and the convolution keeps the
 last REAL rows of its input.
 
-`delta_step` is one token a row for a decode batch: two reductions over the
-state in one read (k^T S and q^T S; o_t = a_t q^T S + (q.k) u_t needs no
-read of the new state) and one read-modify-write.
+`delta_step` is one token a row for a decode batch. With kS = k^T S and qS
+= q^T S of the OLD state: u = b (v - a kS), o = a qS + (q.k) u (no read of
+the new state), S' = a S + k u^T. The equations need the state once in and
+once out. On a TPU, where the shape fits, that is what the step does: ONE
+Pallas pass (`delta_rule_step`), a grid step a row (and block of its
+row-groups) that holds the block in VMEM, forms both reductions, the
+pseudo-value and the output from it and writes the new block over the one
+it read (`input_output_aliases`: the program holds one copy of the state; a
+row that is not decoding gets its block back bit for bit). Every product
+and sum is float32 on the VPU; the MXU stays out (at `highest` it would take
+the state in three splits). The jnp form (`_step_jnp`) makes TWO passes,
+because `u` depends on the first reduction's result and XLA cannot fuse
+across that: 0.79 ms a layer at the Olmo cell's `[64, 15, 96, 384]` where
+the kernel takes 0.43, the time of its copies (PERF.md, PR 42). It stays as the CPU's form and
+as the fallback for a shape the kernel does not take (rows that fill no
+whole 128-lane tiles, a key width off the 8 sublanes, more than 64 heads):
+the choice is read from the shapes and the backend (`qm._mode()`), never
+from a flag. The chunked scan stays jnp: its work is matmuls.
 
 THE STATE'S LAYOUT between steps is `[.., H / p, dk, p * dv]`: `p` heads
 side by side in a row (`heads_per_row`: 2 where dv is no multiple of the
@@ -49,20 +64,28 @@ TPU's 128 lanes and H is even, else 1). The TPU pads an array's last axis to
 whole lanes: a `[.., 96, 192]` float32 state is stored and moved as `[.., 96,
 256]`, a third more bytes in every pass of every decode step, and two heads
 of 192 fill 384 = 3 x 128 lanes exactly. `delta_step` works on that layout
-as it is (a head's k and q are spread over its own lanes with a `where`,
-per-head scalars repeated over them: no reshape of the state); a prefill
-window unpacks its one slot's state before the scan and packs it after
-(`unpack_state` / `pack_state`).
+as it is, in both forms (a head's k and q are spread over its own lanes,
+per-head scalars repeated over them: no reshape of the state; the kernel
+takes a head's k / q column and its three gates out of one `[dk + 8, 128]`
+tile a row, a head a lane, by a lane gather, where spread over the state's
+lanes in HBM they would be a second state's worth of bytes); a prefill window unpacks its one slot's state
+before the scan and packs it after (`unpack_state` / `pack_state`).
 
-Plain `jax.numpy`, float32 at matmul precision `highest` wherever the state
-is touched. Device scopes: `pt.delta_rule` (both entry points),
-`pt.short_conv` (the convolution's two).
+Float32 at matmul precision `highest` wherever the state is touched. Device
+scopes: `pt.delta_rule` (both entry points), `pt.short_conv` (the
+convolution's two).
 """
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from paddle_tpu.kernels import quantized_matmul as qm
 
 __all__ = ["unit_lower_inverse", "delta_chunk_scan", "delta_step",
            "heads_per_row", "pack_state", "unpack_state",
@@ -178,21 +201,16 @@ def _over_lanes(x, p, dv):
     return jnp.where(first, x[:, :, 0, :, None], x[:, :, 1, :, None])
 
 
-@jax.named_scope("pt.delta_rule")
-def delta_step(q, k, v, log_a, b, state, live):
-    """One token a row: q, k [r, H, dk], v [r, H, dv], log_a, b [r, H];
-    state [r, H / p, dk, p * dv] float32 (`pack_state`'s layout); live [r]
-    bool (a row that is not decoding keeps its state untouched). Returns
-    (out [r, H, dv] float32, state)."""
-    f32 = jnp.float32
+def _step_jnp(q, k, v, a, b, qk, state, live):
+    """The step in plain `jax.numpy`: two passes over the state (XLA cannot
+    make one of them: `u` needs the first's result). The CPU's form, and
+    the TPU's where the shape does not fit the kernel."""
     r, H, dv = v.shape
     p = H // state.shape[1]
-    q, k = q.astype(f32), k.astype(f32)
-    # per-head scalars and per-(head, value) rows, over the state's lanes
-    lanes = lambda x: jnp.repeat(x.astype(f32).reshape(r, H // p, p), dv, -1)
-    a, b = lanes(jnp.exp(log_a.astype(f32))), lanes(b)
-    qk = lanes(jnp.sum(q * k, -1))
-    v = v.astype(f32).reshape(r, H // p, p * dv)
+    # per-head scalars over the state's lanes
+    lanes = lambda x: jnp.repeat(x.reshape(r, H // p, p), dv, -1)
+    a, b, qk = lanes(a), lanes(b), lanes(qk)
+    v = v.reshape(r, H // p, p * dv)
     # k^T S and q^T S in one read of the state (k and q stacked while they
     # are small: spread over the lanes they stay a broadcast inside the
     # reduction, where a stack of two spread operands is written out)
@@ -204,6 +222,151 @@ def delta_step(q, k, v, log_a, b, state, live):
     new = a[:, :, None] * state + _over_lanes(k, p, dv) * u[:, :, None]
     return (out.reshape(r, H, dv),
             jnp.where(live[:, None, None, None], new, state))
+
+
+_LANES = 128
+
+
+def _step_block(state_shape, heads):
+    """Row-groups of the state a grid step of the kernel holds (a divisor of
+    H / p: the largest whose four blocks, in and out, each double-buffered,
+    fit the budget), or 0 where the shape does not fit the kernel: the rows
+    must fill whole lanes and whole sublanes, and every head's k and q
+    column must find a lane in ONE tile."""
+    _, G, dk, width = state_shape
+    if width % _LANES or dk % 8 or 2 * heads > _LANES:
+        return 0
+    fit = qm._VMEM_BUDGET_BYTES // (4 * dk * width * 4)
+    return max((g for g in range(1, G + 1) if G % g == 0 and g <= fit),
+               default=0)
+
+
+def _by_head(per_head, dv, width):
+    """per_head: one [n, 128] tile a head of the row-group, each constant
+    over its lanes -> [n, width] that holds head j's values in lanes [j *
+    dv, (j + 1) * dv), a 128-lane tile at a time: a tile inside one head is
+    that head's as it is, only a tile that straddles two heads is a
+    select."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, _LANES), 1)
+    tiles = []
+    for t in range(width // _LANES):
+        first, last = t * _LANES // dv, ((t + 1) * _LANES - 1) // dv
+        tile = per_head[last]
+        for j in range(last - 1, first - 1, -1):
+            tile = jnp.where(lane + t * _LANES < (j + 1) * dv, per_head[j],
+                             tile)
+        tiles.append(tile)
+    return jnp.concatenate(tiles, axis=-1)
+
+
+def _column(tile, lane):
+    """Every lane of the [n, 128] tile takes its lane `lane`: a head's
+    column, as wide as a tile."""
+    return jnp.take_along_axis(tile, jnp.full(tile.shape, lane, jnp.int32),
+                               axis=1)
+
+
+def _step_kernel(live_ref, cols_ref, v_ref, s_ref, o_ref, new_ref, *, heads,
+                 dv):
+    """Grid step (row i, block j of its row-groups). live [r] int32 in SMEM;
+    cols [dk + 8, 128], what the row's heads bring, a head a LANE: head h's k
+    down lane h and its q down lane H + h of the first dk rows, its a, b and
+    q.k in lane h of the next three; v, o [gb, p * dv]; s, new [gb, dk, p *
+    dv], ONE array outside (`input_output_aliases`): what is read here is
+    written here, by this step alone."""
+    i, j = pl.program_id(0), pl.program_id(1)
+    gb, dk, width = s_ref.shape
+    p = width // dv
+
+    @pl.when(live_ref[i] == 0)
+    def _():
+        new_ref[...] = s_ref[...]
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(live_ref[i] != 0)
+    def _():
+        kq, gates = cols_ref[:dk], cols_ref[dk:]
+
+        def group(g, carry):
+            h0 = (j * gb + g) * p
+            over = lambda tile, first: _by_head(
+                [_column(tile, first + m) for m in range(p)], dv, width)
+            k, q, abq = over(kq, h0), over(kq, heads + h0), over(gates, h0)
+            a, b, qk = abq[0:1], abq[1:2], abq[2:3]
+            S = s_ref[g]
+            u = b * (v_ref[pl.ds(g, 1), :]
+                     - a * jnp.sum(k * S, axis=0, keepdims=True))
+            o_ref[pl.ds(g, 1), :] = (
+                a * jnp.sum(q * S, axis=0, keepdims=True) + qk * u)
+            new_ref[g] = a * S + k * u
+            return carry
+
+        # unrolled (traced once): the compiler's schedule for a v5e is 172
+        # bundles a head pair against 305 as a loop, a pair's copy 338
+        jax.lax.fori_loop(0, gb, group, 0, unroll=True)
+
+
+def _step_pallas(q, k, v, a, b, qk, state, live):
+    r, H, dv = v.shape
+    _, G, dk, width = state.shape
+    gb = _step_block(state.shape, H)
+    # a head a lane of one 128-lane tile a row: its k and q as columns over
+    # dk, its three gates under them. 53 KB a row, where k and q spread
+    # over the state's lanes (what the jnp form fuses into its passes)
+    # would be a second state's worth
+    kq = jnp.pad(jnp.concatenate([k, q], 1),
+                 ((0, 0), (0, _LANES - 2 * H), (0, 0)))
+    gates = jnp.pad(jnp.stack([a, b, qk], 1),
+                    ((0, 0), (0, 5), (0, _LANES - H)))
+    cols = jnp.concatenate([jnp.swapaxes(kq, 1, 2), gates], 1)
+    rows = lambda i, j, _: (i, j, 0, 0)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(r, G // gb),
+        in_specs=[
+            pl.BlockSpec((None, dk + 8, _LANES), lambda i, j, _: (i, 0, 0)),
+            pl.BlockSpec((None, None, gb, width), rows),
+            pl.BlockSpec((None, gb, dk, width), rows),
+        ],
+        out_specs=[
+            pl.BlockSpec((None, None, gb, width), rows),
+            pl.BlockSpec((None, gb, dk, width), rows),
+        ],
+    )
+    out, new = pl.pallas_call(
+        functools.partial(_step_kernel, heads=H, dv=dv),
+        out_shape=[jax.ShapeDtypeStruct((r, G // gb, gb, width), jnp.float32),
+                   jax.ShapeDtypeStruct(state.shape, jnp.float32)],
+        grid_spec=grid_spec,
+        # operand 3 (after the prefetched one) is the state, result 1 its
+        # successor: one buffer
+        input_output_aliases={3: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        name="delta_rule_step",
+        interpret=qm._mode()[1],
+    )(live.astype(jnp.int32), cols, v.reshape(r, G // gb, gb, width), state)
+    return out.reshape(r, H, dv), new
+
+
+def step_is_pallas(state_shape, heads):
+    """Whether `delta_step` on a state of this shape is the Pallas kernel
+    (a TPU, or `fused_dispatch`, and a shape that fits) or the jnp form."""
+    return bool(qm._mode()[0] and _step_block(state_shape, heads))
+
+
+@jax.named_scope("pt.delta_rule")
+def delta_step(q, k, v, log_a, b, state, live):
+    """One token a row: q, k [r, H, dk], v [r, H, dv], log_a, b [r, H];
+    state [r, H / p, dk, p * dv] float32 (`pack_state`'s layout); live [r]
+    bool (a row that is not decoding keeps its state untouched). Returns
+    (out [r, H, dv] float32, state)."""
+    f32 = jnp.float32
+    q, k, v = q.astype(f32), k.astype(f32), v.astype(f32)
+    a, b, qk = jnp.exp(log_a.astype(f32)), b.astype(f32), jnp.sum(q * k, -1)
+    step = (_step_pallas if step_is_pallas(state.shape, v.shape[1])
+            else _step_jnp)
+    return step(q, k, v, a, b, qk, state, live)
 
 
 def _taps(u_ext, w, s):

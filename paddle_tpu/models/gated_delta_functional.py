@@ -32,7 +32,7 @@ rows of the convolution's input, in the model's type). The pools are `(pk, pv)`,
 array a full layer each.
 
 What `serving/hybrid.HybridPath` asks of a family's functional module is
-the last section: `pools`, `slot_state`, `tables`, `check_engine`,
+the last section: `pools`, `slot_state`, `tables`, `check_engine`, `gauges`,
 `observe_decode`, `prefill_window`, `decode_step`.
 """
 
@@ -292,6 +292,13 @@ def tables(args, max_len):
 
 def check_engine(args, eng):
     """Nothing of the engine's sizes is this family's to constrain."""
+
+
+def gauges(args, state):
+    """Which form the decode program's delta-rule step takes for this
+    state: 1 the Pallas pass (a TPU and a shape that fits), 0 the jnp one."""
+    return {"serve.delta_step_pallas": int(gdr.step_is_pallas(
+        state[0]["S"].shape, args.linear_heads))}
 
 
 def observe_decode(args, eng, active):

@@ -238,6 +238,11 @@ def check_engine(args, eng):
             "table")
 
 
+def gauges(args, state):
+    """No record of its own."""
+    return {}
+
+
 def observe_decode(args, eng, active):
     """Pages a sparse layer's KV head reads over pages the rows hold: all
     of a context that is still dense, the selection past that."""

@@ -17,8 +17,9 @@ whose every leaf has the PAGE axis first: a copy-on-write page copy copies
 them all), `slot_state(args, slots, dtype)` (a tree whose every leaf has the
 SLOT axis first), `tables(args, max_len)` (constants of the programs),
 `check_engine(args, eng)` (what the family needs of the engine's sizes),
-`observe_decode(args, eng, active)` (its own per-step observations) and the
-two step functions `prefill_window` / `decode_step`.
+`gauges(args, state)` (records of how its step programs are built for this
+state), `observe_decode(args, eng, active)` (its own per-step observations)
+and the two step functions `prefill_window` / `decode_step`.
 
 What is the PATH's is written once over those trees:
 
@@ -168,6 +169,9 @@ class HybridPath:
         m = self.eng.metrics
         m.set_gauge("kv_pool_bytes", nbytes(self.pools))
         m.set_gauge("recurrent_state_bytes", nbytes(self.state))
+        for name, value in self.family.gauges(self.eng.args,
+                                              self.state).items():
+            m.set_gauge(name, value)
 
     # -- pages ----------------------------------------------------------------
     def copy_page(self, src, dst):

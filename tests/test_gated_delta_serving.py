@@ -17,8 +17,10 @@ missed write or a stale convolution row costs (each moves a logit by more
 than 5e-3 here).
 """
 
+import functools
 import importlib.util
 import os
+import re
 import sys
 
 import numpy as np
@@ -32,6 +34,7 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 from paddle_tpu.kernels import gated_delta_rule as gdr  # noqa: E402
+from paddle_tpu.kernels import quantized_matmul as qm  # noqa: E402
 from paddle_tpu.models import gated_delta_functional as gdf  # noqa: E402
 from paddle_tpu.models import hybrid_functional as hf  # noqa: E402
 from paddle_tpu.serving import PagedEngine, Request, paths  # noqa: E402
@@ -83,15 +86,15 @@ def _ids(n, seed=0):
     return np.random.default_rng(seed).integers(1, 256, n).astype(np.int32)
 
 
-def _ref_logits(fam, params, ids):
+def _ref_logits(fam, params, ids, arch=ARCH):
     """The reference's logits at every position of `ids`."""
-    kinds = fam.layer_kinds(ARCH)
+    kinds = fam.layer_kinds(arch)
     place = [kinds[:i].count(k) for i, k in enumerate(kinds)]
     x = fam.forward_hidden(
-        ARCH, ids,
+        arch, ids,
         lambda i: {k: v[place[i]] for k, v in params[kinds[i]].items()},
         params["embedding"])
-    return np.asarray(fam.head_logits(ARCH, x, params["final_norm"],
+    return np.asarray(fam.head_logits(arch, x, params["final_norm"],
                                       params["lm_head"]))
 
 
@@ -100,17 +103,34 @@ def _ref_logits(fam, params, ids):
 # ---------------------------------------------------------------------------
 
 _SCAN = jax.jit(gdr.delta_chunk_scan, static_argnames=("chunk",))
-_STEP = jax.jit(gdr.delta_step)
+
+# the one step's two forms: the jnp passes, and the Pallas pass interpreted.
+# The kernel takes rows of whole 128-lane tiles, so its cases are 128 / p
+# wide where the jnp form's are this preset's 16
+FORMS = ["jnp", "kernel"]
+
+
+@functools.lru_cache(maxsize=None)
+def _step(form):
+    def step(*a):
+        with qm.fused_dispatch(form == "kernel", interpret=True):
+            return gdr.delta_step(*a)
+
+    return jax.jit(step)
+
+
+def _dv(form, p):
+    return DV if form == "jnp" else 128 // p
 
 
 def _operands(s, seed, b_range=(0.0, 2.0), log_a_range=(-3.0, 0.0),
-              repeat_keys=False):
+              repeat_keys=False, dv=DV):
     rng = np.random.default_rng(seed)
     unit = lambda x: x / np.linalg.norm(x, axis=-1, keepdims=True)
     q = unit(rng.normal(size=(s, H, DK))) / np.sqrt(DK)
     k = unit(rng.normal(size=(1 if repeat_keys else s, H, DK)))
     k = np.broadcast_to(k, (s, H, DK))
-    v = rng.normal(size=(s, H, DV))
+    v = rng.normal(size=(s, H, dv))
     log_a = rng.uniform(*log_a_range, size=(s, H))
     b = rng.uniform(*b_range, size=(s, H))
     return [jnp.asarray(np.ascontiguousarray(x), jnp.float32)
@@ -186,20 +206,24 @@ def test_the_convolution_keeps_its_last_real_rows(fam, real):
 
 
 @pytest.mark.parametrize("p", [1, 2])
-def test_one_step_is_the_recurrence_and_a_dead_row_keeps_both_states(fam, p):
+@pytest.mark.parametrize("form", FORMS)
+def test_one_step_is_the_recurrence_and_a_dead_row_keeps_both_states(
+        fam, form, p):
     """On the stored layout, one head a row (p = 1) and two heads side by
     side (p = 2: what `heads_per_row` gives wherever the value width is no
-    multiple of 128 lanes, at the cell's 192 and at this preset's 16)."""
+    multiple of 128 lanes, at the cell's 192 and at this preset's 16), in
+    both forms of the step."""
     assert gdr.heads_per_row(H, DV) == 2 and gdr.heads_per_row(30, 192) == 2
     assert gdr.heads_per_row(32, 128) == 1 and gdr.heads_per_row(3, 16) == 1
-    q, k, v, log_a, b = _operands(3, 5)
+    dv = _dv(form, p)
+    q, k, v, log_a, b = _operands(3, 5, dv=dv)
     rng = np.random.default_rng(2)
-    S = jnp.asarray(rng.normal(size=(3, H, DK, DV)), jnp.float32)
+    S = jnp.asarray(rng.normal(size=(3, H, DK, dv)), jnp.float32)
     packed = gdr.pack_state(S, p)
-    assert packed.shape == (3, H // p, DK, p * DV)
+    assert packed.shape == (3, H // p, DK, p * dv)
     np.testing.assert_array_equal(gdr.unpack_state(packed, p), S)
     live = jnp.asarray([True, False, True])
-    out, S_new = _STEP(q, k, v, log_a, b, packed, live)
+    out, S_new = _step(form)(q, k, v, log_a, b, packed, live)
     S_new = gdr.unpack_state(S_new, p)
     for r in range(3):
         want, S_want = fam.delta_scan(q[r:r + 1], k[r:r + 1], v[r:r + 1],
@@ -222,20 +246,97 @@ def test_one_step_is_the_recurrence_and_a_dead_row_keeps_both_states(fam, p):
             if live[r] else conv[r])
 
 
-def test_a_window_then_steps_carry_one_state(fam):
-    q, k, v, log_a, b = _operands(40, 3)
+@pytest.mark.parametrize("p", [1, 2])
+@pytest.mark.parametrize("form", FORMS)
+def test_a_window_then_steps_carry_one_state(fam, form, p):
+    dv = _dv(form, p)
+    q, k, v, log_a, b = _operands(40, 3, dv=dv)
     want, _ = fam.delta_scan(q, k, v, jnp.exp(log_a), b,
-                             jnp.zeros((H, DK, DV)))
+                             jnp.zeros((H, DK, dv)))
     out, S = _SCAN(q[:32], k[:32], v[:32], log_a[:32], b[:32],
-                                  jnp.zeros((H, DK, DV)), jnp.ones(32, bool),
-                                  chunk=16)
+                   jnp.zeros((H, DK, dv)), jnp.ones(32, bool), chunk=16)
     np.testing.assert_allclose(out, want[:32], atol=TOL, rtol=1e-4)
+    S = gdr.pack_state(S, p)[None]
     for t in range(32, 40):
-        o, S1 = _STEP(q[t][None], k[t][None], v[t][None],
-                               log_a[t][None], b[t][None], S[None],
-                               jnp.ones(1, bool))
-        S = S1[0]
+        o, S = _step(form)(q[t][None], k[t][None], v[t][None],
+                           log_a[t][None], b[t][None], S, jnp.ones(1, bool))
         np.testing.assert_allclose(o[0], want[t], atol=TOL, rtol=1e-4)
+
+
+@pytest.mark.parametrize("p", [1, 2])
+@pytest.mark.parametrize("form", FORMS)
+def test_a_dead_rows_state_is_unchanged_bit_for_bit(form, p):
+    """Whatever the row holds: a signed zero, a subnormal, an infinity, a
+    NaN with a payload (a recycled slot's leftovers are never read, but
+    they are never rewritten either)."""
+    dv = _dv(form, p)
+    q, k, v, log_a, b = _operands(4, 7, dv=dv)
+    bits = np.random.default_rng(p).integers(
+        0, 2 ** 32, size=(4, H // p, DK, p * dv), dtype=np.uint32)
+    bits[1, 0, 0, :4] = [0x80000000, 0x00000001, 0x7F800000, 0x7FC00123]
+    live = np.asarray([True, False, False, True])
+    # the live rows hold numbers the step can work on
+    state = bits.view(np.float32).copy()
+    state[live] = np.random.default_rng(3).normal(size=state[live].shape)
+    _, new = _step(form)(q, k, v, log_a, b, jnp.asarray(state),
+                         jnp.asarray(live))
+    new = np.asarray(new)
+    np.testing.assert_array_equal(new[~live].view(np.uint32),
+                                  state[~live].view(np.uint32))
+    assert np.isfinite(new[live]).all()
+    assert not np.array_equal(new[live], state[live])
+
+
+@pytest.mark.parametrize("form", FORMS)
+@pytest.mark.parametrize("regime", [
+    dict(log_a_range=(-80.0, -20.0)),                # a near 0
+    dict(b_range=(1.9, 2.0), log_a_range=(-1e-3, 0.0), repeat_keys=True),
+], ids=["a_near_0", "repeated_keys"])
+def test_steps_are_the_recurrence_at_the_gates_edges(fam, form, regime):
+    """Token by token from a carried state: a head that loses all but e^-80
+    of it in a token, and one key 48 times at b ~ 1.95 and a ~ 1 (the
+    state's component along it changes sign every token and keeps its
+    size). Held to what the chunked scan is held to."""
+    p = 2
+    dv = _dv(form, p)
+    q, k, v, log_a, b = _operands(48, 11, dv=dv, **regime)
+    S0 = jnp.asarray(np.random.default_rng(9).normal(size=(H, DK, dv)),
+                     jnp.float32)
+    want, S_want = fam.delta_scan(q, k, v, jnp.exp(log_a), b, S0)
+    atol = 1e-3 if regime.get("repeat_keys") else TOL
+    S = gdr.pack_state(S0, p)[None]
+    for t in range(48):
+        o, S = _step(form)(q[t][None], k[t][None], v[t][None],
+                           log_a[t][None], b[t][None], S, jnp.ones(1, bool))
+        np.testing.assert_allclose(o[0], want[t], atol=atol, rtol=1e-4)
+    np.testing.assert_allclose(gdr.unpack_state(S[0], p), S_want, atol=atol,
+                               rtol=1e-4)
+
+
+@pytest.mark.parametrize("dk,dv,heads,why", [
+    (8, 16, 4, "rows of 32 lanes"), (12, 64, 4, "12 key rows: no whole tile"),
+    (8, 64, 66, "132 k and q columns: more than a tile's lanes"),
+], ids=["lanes", "sublanes", "heads"])
+def test_a_shape_that_does_not_fit_takes_the_jnp_form(dk, dv, heads, why):
+    """The form is read from the shapes: asked for the kernel, a state
+    whose rows fill no whole tiles goes through the jnp passes, and gives
+    their numbers."""
+    rng = np.random.default_rng(dk)
+    f32 = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
+    a = (f32(2, heads, dk), f32(2, heads, dk), f32(2, heads, dv),
+         -jnp.abs(f32(2, heads)), jnp.abs(f32(2, heads)),
+         f32(2, heads // 2, dk, 2 * dv), jnp.asarray([True, False]))
+    fitting = (2, 2, 8, 128)
+    with qm.fused_dispatch(True, interpret=True):
+        assert gdr.step_is_pallas(fitting, 4)
+        assert not gdr.step_is_pallas(a[5].shape, heads), why
+        assert "pallas_call" not in str(jax.make_jaxpr(gdr.delta_step)(*a))
+        got = gdr.delta_step(*a)
+    with qm.fused_dispatch(False):
+        assert not gdr.step_is_pallas(fitting, 4)
+        want = gdr.delta_step(*a)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
 
 
 # ---------------------------------------------------------------------------
@@ -355,10 +456,10 @@ def _engine(params, args, **kw):
     return _ENGINES[key]
 
 
-def _gap(fam, params, req):
+def _gap(fam, params, req, arch=ARCH):
     """How far each served token's reference logit lies below the best."""
     seq = np.concatenate([req.prompt_ids, np.asarray(req.token_ids)[:-1]])
-    lg = _ref_logits(fam, params, seq)[len(req.prompt_ids) - 1:]
+    lg = _ref_logits(fam, params, seq, arch)[len(req.prompt_ids) - 1:]
     toks = np.asarray(req.token_ids)
     return lg.max(-1) - lg[np.arange(len(toks)), toks]
 
@@ -378,6 +479,42 @@ def test_engine_serves_the_references_tokens(fam, params, args, chunk):
         2 * 80 * H * B * HD * 4
     assert obs["counters"]["state_snapshots"] == 4
     assert 0 < obs["observations"]["decode_live_page_share"]["mean"] < 1
+
+
+# value heads of 64: two side by side fill a 128-lane tile, the kernel's shape
+WIDE = dict(ARCH, linear_value_head_dim=64)
+
+
+@pytest.mark.parametrize("form,arch,reads", [
+    ("kernel", WIDE, 1), ("jnp", WIDE, 0), ("kernel", ARCH, 0),
+], ids=["kernel", "jnp", "kernel_asked_of_rows_of_32_lanes"])
+def test_the_decode_program_names_its_step_and_the_gauge_its_form(
+        fam, form, arch, reads):
+    """Through the engine: the gauge `serve.delta_step_pallas` is set with
+    the path's state and outlives `reset()`; the decode program's text
+    carries the kernel, named, under the scope the benchmark's two readers
+    sum (`pt.delta_rule`); the served tokens are the reference's."""
+    from benchmarks.harness import weights
+
+    params = weights.make_params(fam, arch, 11, jnp.float32)
+    with qm.fused_dispatch(form == "kernel", interpret=True):
+        eng = PagedEngine(params, fam.serve_args(arch), **ENGINE)
+        reqs = eng.serve([Request(_ids(n, n), 5) for n in (3, 21)])
+        eng.reset()
+        path, slots = eng.path, ENGINE["max_slots"]
+        text = path._decode[False].lower(
+            eng.params, path.layer_ids, path.tokens,
+            jnp.zeros((slots, eng.pages_per_slot), jnp.int32),
+            jnp.zeros(slots, jnp.int32), jnp.zeros(slots, bool), path.pools,
+            path.state, path.tables, *eng._sampling_args()
+        ).as_text(debug_info=True)
+    gauges = eng.metrics.summary()["gauges"]
+    assert gauges["serve.delta_step_pallas"]["value"] == reads
+    named = re.findall(r"pt\.attention/pt\.delta_rule/delta_rule_step/"
+                       r"pallas_call", text)
+    assert bool(named) == bool(reads)
+    for r in reqs:
+        assert _gap(fam, params, r, arch).max() < TOL
 
 
 def test_a_recycled_slot_starts_from_zero_in_both_states(fam, params, args):

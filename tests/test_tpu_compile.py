@@ -506,3 +506,121 @@ def test_selector_programs_compile_at_the_glm5_widths(one_chip):
         table, table, sds((), jnp.int32)).compile()
     assert "latent_masked_prefill_attention" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 3.0e9
+
+
+def _made_of_shape(text, shape):
+    """The program's operations whose result is a float32 array of `shape`
+    (its parameters and the elements taken out of a kernel's results are
+    no operations)."""
+    import re
+
+    whole = re.escape("f32[" + ",".join(map(str, shape)) + "]")
+    return [line for line in text.splitlines()
+            if re.match(rf"\s*(ROOT )?%\S+ = {whole}", line)
+            and " parameter(" not in line
+            and " get-tuple-element(" not in line]
+
+
+# name -> slots, heads, key width, value width of a delta hybrid's linear
+# layers (the state is stored `heads_per_row` heads a row)
+_DELTA_STEP = {
+    "olmo_hybrid_chat_replies_cell": (64, 30, 96, 192),   # two heads a row
+    "value_heads_of_128": (16, 32, 128, 128),             # one head a row
+    "a_row_in_three_blocks": (4, 24, 256, 256),   # 8 of 24 row-groups fit
+}
+
+
+@pytest.mark.parametrize("name", list(_DELTA_STEP))
+def test_delta_step_kernel_compiles_for_v5e(one_chip, name):
+    """The delta rule's decode step over the stored state, donated: Mosaic
+    takes the kernel (a lane gather of a head's k / q column, a dynamic row
+    of v and of the output), the state goes in and comes out as ONE buffer,
+    and nothing else of the state's size is computed or planned."""
+    import re
+
+    from paddle_tpu.kernels import gated_delta_rule as gdr
+
+    r, H, dk, dv = _DELTA_STEP[name]
+    p = gdr.heads_per_row(H, dv)
+    state = (r, H // p, dk, p * dv)
+
+    def sds(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def step(*a):
+        with qm.fused_dispatch(True):
+            assert gdr.step_is_pallas(state, H)
+            return gdr.delta_step(*a)
+
+    if name == "a_row_in_three_blocks":
+        assert gdr._step_block(state, H) == 8
+    compiled = jax.jit(step, donate_argnums=(5,)).lower(
+        sds((r, H, dk)), sds((r, H, dk)), sds((r, H, dv)), sds((r, H)),
+        sds((r, H)), sds(state), sds((r,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert re.search(r"delta_rule_step[.\d]* = .*output_to_operand_aliasing="
+                     r"\{\{1\}: \(3, \{\}\)\}", text)
+    assert not _made_of_shape(text, state)
+    assert compiled.memory_analysis().temp_size_in_bytes < math.prod(state)
+
+
+def test_delta_decode_program_holds_one_copy_of_the_state(one_chip):
+    """Olmo-Hybrid's decode program at the cell's widths and slots (one
+    period of its layers: 3 linear + 1 full), pools and state donated as
+    `HybridPath` donates them: a kernel `delta_rule_step` a linear layer
+    under `pt.delta_rule`, its state aliased in and out, and no operation
+    that copies or computes a `[64, 15, 96, 384]` float32 array (the jnp
+    step's two fusions a layer were that)."""
+    import functools
+    import importlib.util
+    import json
+    import re
+
+    from benchmarks.harness import weights
+    from paddle_tpu.models import gated_delta_functional as gdf
+    from paddle_tpu.serving import hybrid
+    from paddle_tpu.serving.metrics import Metrics
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location(
+        "family_gated_delta_compile",
+        os.path.join(root, "benchmarks", "families", "gated_delta_hybrid.py"))
+    fam = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(fam)
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "olmo-hybrid-7b-1chip.json")) as f:
+        arch = json.load(f)
+    arch = dict(arch, num_hidden_layers=4, layer_types=arch["layer_types"][:4])
+    args = fam.serve_args(arch)
+    slots, pages, ps, P = 64, 1920, 64, 96
+
+    def described(make):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip),
+            jax.eval_shape(make))
+
+    def sds(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    params = described(lambda: weights.make_params(fam, arch, 1))
+    pools = described(lambda: gdf.pools(args, pages, ps, jnp.bfloat16))
+    state = described(lambda: gdf.slot_state(args, slots, jnp.bfloat16))
+    assert state[0]["S"].shape == (64, 15, 96, 384)
+    with qm.fused_dispatch(True):
+        compiled = jax.jit(
+            functools.partial(hybrid._decode_traced, family=gdf, args=args,
+                              metrics=Metrics()),
+            donate_argnums=(6, 7)).lower(
+            params, sds((4,)), sds((slots,)), sds((slots, P)), sds((slots,)),
+            sds((slots,), jnp.bool_), pools, state, (),
+            sds((), jnp.float32), sds((), jnp.float32), sds(()),
+            sds((slots,))).compile()
+    text = compiled.as_text()
+    kernels = re.findall(
+        r"%delta_rule_step[.\d]* = .*output_to_operand_aliasing=\{\{1\}: "
+        r"\(3, \{\}\)\}.*op_name=\"[^\"]*pt\.delta_rule/delta_rule_step",
+        text)
+    assert len(kernels) == 3
+    assert not _made_of_shape(text, (64, 15, 96, 384))
