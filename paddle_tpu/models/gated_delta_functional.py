@@ -294,7 +294,7 @@ def check_engine(args, eng):
     """Nothing of the engine's sizes is this family's to constrain."""
 
 
-def gauges(args, state):
+def gauges(args, state, pools):
     """Which form the decode program's delta-rule step takes for this
     state: 1 the Pallas pass (a TPU and a shape that fits), 0 the jnp one."""
     return {"serve.delta_step_pallas": int(gdr.step_is_pallas(

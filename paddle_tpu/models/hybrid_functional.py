@@ -238,7 +238,7 @@ def check_engine(args, eng):
             "table")
 
 
-def gauges(args, state):
+def gauges(args, state, pools):
     """No record of its own."""
     return {}
 

@@ -166,6 +166,9 @@ class LatentMoEArgs(NamedTuple):
     # both step programs also return the positions a few queries selected
     # (`serving/latent.py`, SelectionTrace)
     record_selection: bool = False
+    # every SwiGLU's gate capped above and its up-projection clipped both
+    # ways at this value before they meet (`_gate`, `_up`); None: neither
+    swiglu_limit: float | None = None
 
     @property
     def row_width(self):
@@ -319,17 +322,17 @@ def _topk(args, positions):
     return min(args.indexer.topk, positions)
 
 
-def _decode_attention(lp, x, cache, bt, pos, cos, sin, base, record, args):
-    """x [b, h], one token a row at positions pos [b] -> (x + attention,
-    cache, the selection of row `record` or None). The absorbed form over
-    the rows' pages; behind a selector under each row's selection: its
-    index scores over its live pages of the index pool, its `index_topk`-th
-    largest (`la.kth_largest`, ties to the lower position; a row whose
-    context is no longer selects all of it, through the same code), and the
-    same kernel with the keys not selected masked."""
+def _decode_heads(lp, hin, cache, bt, pos, cos, sin, base, record, args):
+    """hin [b, h], the block's normed input, one token a row at positions pos
+    [b] -> (the heads' outputs [b, H * v] before `wo`, cache, the selection
+    of row `record` or None). The absorbed form over the rows' pages; behind
+    a selector under each row's selection: its index scores over its live
+    pages of the index pool, its `index_topk`-th largest (`la.kth_largest`,
+    ties to the lower position; a row whose context is no longer selects all
+    of it, through the same code), and the same kernel with the keys not
+    selected masked."""
     pool, ipool = cache if args.indexer else (cache, None)
     ps = pool.shape[1]
-    hin = lf.rms_norm(x, lp["ln1"], args.rms_eps)
     with jax.named_scope("pt.attention"):
         c_q = _query_latent(lp, hin, args) if args.indexer else None
         q_nope, q_pe, row = _queries_and_row(lp, hin, cos[pos], sin[pos],
@@ -365,8 +368,18 @@ def _decode_attention(lp, x, cache, bt, pos, cos, sin, base, record, args):
             page_base=base, bias=bias)                     # [b, H, kv_rank]
     with jax.named_scope("pt.attention"):
         o = jnp.einsum("bhc,chv->bhv", o_lat, w[..., args.nope_dim:])
-        return (x + _wmm(o.reshape(x.shape[0], -1), lp["wo"]),
+        return (o.reshape(hin.shape[0], -1),
                 (pool, ipool) if args.indexer else pool, selected)
+
+
+def _decode_attention(lp, x, cache, bt, pos, cos, sin, base, record, args):
+    """x [b, h] -> (x + attention of its norm, cache, the selection of row
+    `record` or None): `_decode_heads` inside this family's block."""
+    hin = lf.rms_norm(x, lp["ln1"], args.rms_eps)
+    o, cache, selected = _decode_heads(lp, hin, cache, bt, pos, cos, sin,
+                                       base, record, args)
+    with jax.named_scope("pt.attention"):
+        return x + _wmm(o, lp["wo"]), cache, selected
 
 
 @jax.named_scope("pt.attention")
@@ -463,14 +476,14 @@ def _window_rows(a, ps):
     return jnp.pad(a, ((0, pad), (0, 0))) if pad else a
 
 
-def _window_attention(lp, x, cache, h, last_idx, bt_row, new_pages, cos, sin,
-                      base, record, args):
-    """x [s, h], a window of one slot at positions h .. h + s - 1, real up
-    to `last_idx` -> (x + attention, cache, the positions the queries
-    `record` selected or None). The decompressed form."""
+def _window_heads(lp, hin, cache, h, last_idx, bt_row, new_pages, cos, sin,
+                  base, record, args):
+    """hin [s, h], the block's normed input over a window of one slot at
+    positions h .. h + s - 1, real up to `last_idx` -> (the heads' outputs
+    [s, H * v] before `wo`, cache, the positions the queries `record`
+    selected or None). The decompressed form."""
     pool, ipool = cache if args.indexer else (cache, None)
-    s, ps = x.shape[0], pool.shape[1]
-    hin = lf.rms_norm(x, lp["ln1"], args.rms_eps)
+    s, ps = hin.shape[0], pool.shape[1]
     pos = h + jnp.arange(s, dtype=jnp.int32)
     with jax.named_scope("pt.attention"):
         c_q = _query_latent(lp, hin, args) if args.indexer else None
@@ -494,17 +507,42 @@ def _window_attention(lp, x, cache, h, last_idx, bt_row, new_pages, cos, sin,
             o = la.latent_prefill_attention(
                 q_nope, q_pe, kv, k_pe, h, last_idx, softmax_scale(args),
                 args.v_dim)                                # [s, H, v]
+    return (o.reshape(s, -1), (pool, ipool) if args.indexer else pool,
+            selected)
+
+
+def _window_attention(lp, x, cache, h, last_idx, bt_row, new_pages, cos, sin,
+                      base, record, args):
+    """x [s, h] -> (x + attention of its norm, cache, the positions the
+    queries `record` selected or None): `_window_heads` inside this
+    family's block."""
+    hin = lf.rms_norm(x, lp["ln1"], args.rms_eps)
+    o, cache, selected = _window_heads(lp, hin, cache, h, last_idx, bt_row,
+                                       new_pages, cos, sin, base, record,
+                                       args)
     with jax.named_scope("pt.attention"):
-        return (x + _wmm(o.reshape(s, -1), lp["wo"]),
-                (pool, ipool) if args.indexer else pool, selected)
+        return x + _wmm(o, lp["wo"]), cache, selected
 
 
 # ---------------------------------------------------------------------------
 # the feed-forward half: a SwiGLU, or shared + routed experts
 # ---------------------------------------------------------------------------
 
-def _swiglu(x, w_gate, w_up, w_down):
-    return _wmm(jax.nn.silu(_wmm(x, w_gate)) * _wmm(x, w_up), w_down)
+def _gate(gate, limit=None):
+    """A SwiGLU's gate half: silu(gate), the gate capped above at `limit`
+    first where one is given."""
+    return jax.nn.silu(gate if limit is None else jnp.minimum(gate, limit))
+
+
+def _up(up, limit=None):
+    """A SwiGLU's linear half, clipped both ways at `limit` where one is
+    given."""
+    return up if limit is None else jnp.clip(up, -limit, limit)
+
+
+def _swiglu(x, w_gate, w_up, w_down, limit=None):
+    return _wmm(_gate(_wmm(x, w_gate), limit) * _up(_wmm(x, w_up), limit),
+                w_down)
 
 
 def route(logits, args, bias=None):
@@ -564,9 +602,11 @@ def _routed_experts(lp, stack, first, hin, live, args):
                         axis=0)[:E]
         xs = hin[token]                                    # [n * k, h]
     with jax.named_scope("pt.expert_ffn"):
-        act = (jax.nn.silu(gm.grouped_matmul(xs, stack["we_gate"], sizes,
-                                             first))
-               * gm.grouped_matmul(xs, stack["we_up"], sizes, first))
+        limit = args.swiglu_limit
+        act = (_gate(gm.grouped_matmul(xs, stack["we_gate"], sizes, first),
+                     limit)
+               * _up(gm.grouped_matmul(xs, stack["we_up"], sizes, first),
+                     limit))
         ys = gm.grouped_matmul(act, stack["we_down"], sizes, first)
     with jax.named_scope("pt.moe_route"):
         w = jnp.where(held, weights, 0.0).reshape(-1)[order]
@@ -586,7 +626,8 @@ def _routed_experts(lp, stack, first, hin, live, args):
 def _expert_ffn(lp, stack, first, x, live, args):
     hin = lf.rms_norm(x, lp["ln2"], args.rms_eps)
     with jax.named_scope("pt.mlp"):
-        shared = _swiglu(hin, lp["ws_gate"], lp["ws_up"], lp["ws_down"])
+        shared = _swiglu(hin, lp["ws_gate"], lp["ws_up"], lp["ws_down"],
+                         args.swiglu_limit)
     routed, counts, picks = _routed_experts(lp, stack, first, hin, live,
                                             args)
     return x + shared + routed, counts, picks
@@ -595,7 +636,8 @@ def _expert_ffn(lp, stack, first, x, live, args):
 def _dense_ffn(lp, x, args):
     hin = lf.rms_norm(x, lp["ln2"], args.rms_eps)
     with jax.named_scope("pt.mlp"):
-        return x + _swiglu(hin, lp["w_gate"], lp["w_up"], lp["w_down"])
+        return x + _swiglu(hin, lp["w_gate"], lp["w_up"], lp["w_down"],
+                           args.swiglu_limit)
 
 
 # ---------------------------------------------------------------------------

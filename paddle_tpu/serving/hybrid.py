@@ -2,7 +2,7 @@
 layers keep two kinds of per-request state, pages for its attention layers
 and a recurrent state, whatever the context's length, for its linear ones.
 One path serves every such family; what is a family's comes from its
-functional module (`FAMILIES`):
+functional module (`FAMILIES`, `ROUTED`):
 
   `models/hybrid_functional` (`HybridArgs`): lightning layers (one `[slots,
       heads, d, d]` float32 state a layer) beside block-sparse layers (pages
@@ -10,16 +10,32 @@ functional module (`FAMILIES`):
   `models/gated_delta_functional` (`GatedDeltaArgs`): gated delta-rule
       layers (a `[slots, H, dk, dv]` float32 matrix state AND the last rows
       of a short convolution's input, a layer) beside full multi-head
-      attention layers (pages of K and V).
+      attention layers (pages of K and V);
+  `models/latent_delta_functional` (`LatentDeltaMoEArgs`): gated delta-rule
+      layers (the same two leaves a layer) beside gated latent attention
+      layers (pages of one latent row a token), routed experts behind
+      either: a ROUTED family.
 
 A family's module gives `pools(args, num_pages, page_size, dtype)` (a tree
 whose every leaf has the PAGE axis first: a copy-on-write page copy copies
 them all), `slot_state(args, slots, dtype)` (a tree whose every leaf has the
 SLOT axis first), `tables(args, max_len)` (constants of the programs),
 `check_engine(args, eng)` (what the family needs of the engine's sizes),
-`gauges(args, state)` (records of how its step programs are built for this
-state), `observe_decode(args, eng, active)` (its own per-step observations)
-and the two step functions `prefill_window` / `decode_step`.
+`gauges(args, state, pools)` (records of how its step programs are built for
+these arrays), `observe_decode(args, eng, active)` (its own per-step
+observations) and the two step functions `prefill_window` / `decode_step`.
+
+A ROUTED family's step functions return two things more, and its module
+states `RIDERS`: `decode_step` the routing's counts (int32 `[RIDERS]`) and
+both the experts every row picked. The counts ride the decode step's
+read-back as `serving/latent.LatentPath`'s do (appended to the rows' next
+tokens: the token vector is `[slots + RIDERS]`, the step reads its first
+`slots` rows, the engine a slot's row alone, and `landed` gets the host
+copy), and the picks go to the requests' routing traces where
+`args.record_routing` asks for them. The host's half of both is
+`serving/latent.RoutingRiders`, the piece the two paths share: the step log,
+a trace seated at its windows and carried through preempt and resume beside
+the state, the observations.
 
 What is the PATH's is written once over those trees:
 
@@ -48,14 +64,19 @@ import numpy as np
 
 from paddle_tpu.models import gated_delta_functional as gdf
 from paddle_tpu.models import hybrid_functional as hf
+from paddle_tpu.models import latent_delta_functional as ldf
+from paddle_tpu.serving.latent import RoutingRiders
 from paddle_tpu.serving.sampler import pick as _pick, seat_token, token_vector
 
-__all__ = ["HybridPath", "SNAPSHOTS", "FAMILIES"]
+__all__ = ["HybridPath", "SNAPSHOTS", "FAMILIES", "ROUTED"]
 
 SNAPSHOTS = 8      # snapshot buffers (one slot's state each)
 
 # type of the model description -> the family's functional module
 FAMILIES = {hf.HybridArgs: hf, gdf.GatedDeltaArgs: gdf}
+# the same for the families whose step functions also return the routing's
+# counts and picks
+ROUTED = {ldf.LatentDeltaMoEArgs: ldf}
 
 
 def _move_rows(dst, src, to, frm):
@@ -74,7 +95,7 @@ def _prefill_traced(params, layer_ids, ids, h, last_idx, bt_row, new_pages,
     # zero in every leaf (a recycled slot keeps nothing)
     own = jax.tree_util.tree_map(
         lambda a: jnp.where(h == 0, jnp.zeros((), a.dtype), a[slot]), state)
-    logits, pools, own = family.prefill_window(
+    logits, pools, own, *picks = family.prefill_window(
         params, layer_ids, ids[0], h, last_idx, bt_row, new_pages, pools,
         own, tables, args)
     state = jax.tree_util.tree_map(
@@ -82,17 +103,29 @@ def _prefill_traced(params, layer_ids, ids, h, last_idx, bt_row, new_pages,
         state, own)
     first = _pick(logits[None], sample, temp, top_p, top_k, seeds,
                   h + last_idx + 1)[0]
-    return pools, state, first
+    return pools, state, first, _recorded(args, picks)
+
+
+def _recorded(args, picks):
+    """The picks a routed family's step returned, where its description
+    keeps them; None for any other."""
+    return picks[0] if picks and args.record_routing else None
 
 
 def _decode_traced(params, layer_ids, tokens, bt, pos, live, pools, state,
                    tables, temp, top_p, top_k, seeds, *, family, args,
                    metrics, sample=False):
     metrics.inc("decode_compiles")
-    logits, pools, state = family.decode_step(
+    if riders := getattr(family, "RIDERS", 0):
+        # the token operand is the step before's whole output: the rows'
+        # tokens and, behind them, its counts
+        tokens = tokens[:pos.shape[0]]
+    logits, pools, state, *routed = family.decode_step(
         params, layer_ids, tokens, bt, pos, live, pools, state, tables, args)
-    return pools, state, _pick(logits, sample, temp, top_p, top_k, seeds,
-                               pos + 1)
+    nxt = _pick(logits, sample, temp, top_p, top_k, seeds, pos + 1)
+    if riders:
+        nxt = jnp.concatenate([nxt, routed[0].astype(nxt.dtype)])
+    return pools, state, nxt, _recorded(args, routed[1:])
 
 
 @jax.named_scope("pt.kv_write")
@@ -107,7 +140,7 @@ class HybridPath:
 
     def __init__(self, eng):
         args, self.eng = eng.args, eng
-        family = self.family = FAMILIES[type(args)]
+        family = self.family = {**FAMILIES, **ROUTED}[type(args)]
         for given, what, why in (
                 (eng.mesh, "mesh=", "the recurrent state has no "
                  "tensor-parallel placement yet"),
@@ -130,9 +163,12 @@ class HybridPath:
         self.snaps = family.slot_state(args, self.snapshots, dtype)
         self.tables = family.tables(args, eng.max_len)
         self.layer_ids = jnp.arange(args.num_layers, dtype=jnp.int32)
-        # the rows' last tokens: a decode step's output is the next one's
-        # operand, a prompt's first token is seated (`seat`)
-        self.tokens = token_vector(eng.max_slots, eng.pad_id)
+        # the rows' last tokens (and room for a routed family's counts
+        # behind them): a decode step's output is the next one's operand, a
+        # prompt's first token is seated (`seat`)
+        self.tokens = token_vector(
+            eng.max_slots + getattr(family, "RIDERS", 0), eng.pad_id)
+        self.riders = RoutingRiders(eng)
         self.reset()
 
         donate = eng._donate_enabled()
@@ -161,6 +197,7 @@ class HybridPath:
         start over with it); the arrays stay, a slot's state restarts at
         position 0 anyway."""
         self.pending = {}     # slot -> snapshot id taken at its prompt's end
+        self.riders.reset()
 
         def nbytes(tree):
             return sum(x.size * x.dtype.itemsize
@@ -169,8 +206,8 @@ class HybridPath:
         m = self.eng.metrics
         m.set_gauge("kv_pool_bytes", nbytes(self.pools))
         m.set_gauge("recurrent_state_bytes", nbytes(self.state))
-        for name, value in self.family.gauges(self.eng.args,
-                                              self.state).items():
+        for name, value in self.family.gauges(self.eng.args, self.state,
+                                              self.pools).items():
             m.set_gauge(name, value)
 
     # -- pages ----------------------------------------------------------------
@@ -216,6 +253,7 @@ class HybridPath:
     def take_state(self, slot):
         """What a preempted slot leaves with: its state, out of the slot's
         row, and the snapshot waiting for the request's retirement."""
+        self.riders.leave(slot)
         one = jax.tree_util.tree_map(
             lambda a: jnp.zeros((1,) + a.shape[1:], a.dtype), self.state)
         return (self._move(one, self.state, jnp.int32(0), jnp.int32(slot)),
@@ -227,9 +265,13 @@ class HybridPath:
                                 jnp.int32(0))
         if sid is not None:
             self.pending[slot] = sid
+        self.riders.seat(slot)
 
     def landed(self, out):
-        """Nothing but the tokens rides a decode step's read-back."""
+        """A decode step's output was read (`out`, the host copy the engine
+        made): behind the rows' tokens, a routed family's counts."""
+        if len(out) > self.eng.max_slots:
+            self.riders.landed(out[self.eng.max_slots:])
 
     # -- the token vector and the two step programs -------------------------------
     def seat(self, slot, token):
@@ -238,7 +280,7 @@ class HybridPath:
 
     def prefill(self, ids, start, last_idx, bt_row, new_vec, slot, req,
                 sample):
-        self.pools, self.state, first = self._prefill[sample](
+        self.pools, self.state, first, picks = self._prefill[sample](
             self.eng.params, self.layer_ids, jnp.asarray(ids),
             jnp.int32(start),
             jnp.int32(last_idx), jnp.asarray(bt_row), jnp.asarray(new_vec),
@@ -246,6 +288,7 @@ class HybridPath:
             jnp.float32(req.temperature),
             jnp.float32(req.top_p), jnp.int32(req.top_k),
             jnp.asarray([req.seed], jnp.int32))
+        self.riders.window(req, slot, start, last_idx + 1, picks)
         return first
 
     def decode(self, bt, active, sample, sampling_args):
@@ -257,8 +300,10 @@ class HybridPath:
             eng.metrics.observe(name, value)
         # a COPY of the positions: the engine moves them on as soon as this
         # returns, and a host array handed to the device may be read later
-        self.pools, self.state, self.tokens = self._decode[sample](
+        pos = eng._npos.copy()
+        self.pools, self.state, self.tokens, picks = self._decode[sample](
             eng.params, self.layer_ids, self.tokens, jnp.asarray(bt),
-            jnp.asarray(eng._npos.copy()), jnp.asarray(live), self.pools,
+            jnp.asarray(pos), jnp.asarray(live), self.pools,
             self.state, self.tables, *sampling_args)
+        self.riders.step(picks, live, pos)
         return self.tokens

@@ -73,7 +73,7 @@ import numpy as np
 from paddle_tpu.models import latent_moe_functional as lm
 from paddle_tpu.serving.sampler import pick as _pick, seat_token, token_vector
 
-__all__ = ["LatentPath", "RoutingTrace"]
+__all__ = ["LatentPath", "RoutingTrace", "RoutingRiders"]
 
 SELECT_EVERY = 8     # decode steps between two kept selections
 
@@ -162,6 +162,74 @@ class RoutingTrace:
         return out
 
 
+class RoutingRiders:
+    """The host's half of what an expert stack's step programs return beside
+    their tokens, for whichever path runs them (`LatentPath` here,
+    `serving/hybrid.HybridPath` for a family with a state tree as well): the
+    step log every request's `RoutingTrace` reads, a request's trace made
+    and seated at its prefill windows and moved with it through preempt and
+    resume, and the observations made of the counts that ride a decode
+    step's read-back."""
+
+    def __init__(self, eng):
+        self.eng = eng
+        self.reset()
+
+    def reset(self):
+        # a decode step's [picks, live rows, positions, the selection kept
+        # or None, its slot]; a trace made before keeps the log it was
+        # made over
+        self.log = []
+
+    def window(self, req, slot, start, count, picks, row=None,
+               selected=None):
+        """A prefill window of `req` in `slot` ran: its picks (and kept
+        selection) hang on the request, whose decode rows are the slot's
+        from the next logged step on."""
+        if picks is None and selected is None:
+            return
+        if getattr(req, "routing", None) is None:
+            req.routing = RoutingTrace(self.log)
+        req.routing.window(start, count, picks, row, selected)
+        req.routing.seat(slot)
+
+    def step(self, picks, live, pos, selected=None, row=None):
+        """A decode step went out: one entry for all its rows."""
+        if picks is not None or selected is not None:
+            self.log.append([picks, live, pos, selected, row])
+
+    def _trace(self, slot):
+        return getattr(self.eng.slots.owner(slot), "routing", None)
+
+    def leave(self, slot):
+        """The slot's request is preempted: its stay ends here."""
+        if (trace := self._trace(slot)) is not None:
+            trace.leave()
+
+    def seat(self, slot):
+        """A preempted request resumes in `slot`."""
+        if (trace := self._trace(slot)) is not None:
+            trace.seat(slot)
+
+    def landed(self, counts):
+        """The counts behind a decode step's tokens, read: tokens at the
+        busiest held expert, picks on held experts, picks in all, held
+        experts with a token (summed over the expert layers) and, behind a
+        selector, the keys selected and visible."""
+        busiest, here, picks, hit, *keys = (int(x) for x in counts)
+        m, args = self.eng.metrics, self.eng.args
+        if keys:
+            m.observe("serve.selected_keys", keys[0])
+            m.observe("serve.visible_keys", keys[1])
+        if picks:
+            m.observe("serve.routed_here_share", here / picks)
+            m.observe("serve.held_experts_hit",
+                      hit / (args.num_layers - args.first_k_dense))
+        if here:
+            m.observe("serve.expert_load_max_over_mean",
+                      busiest * args.experts_held / here)
+
+
 def _prefill_traced(params, ids, h, last_idx, bt_row, new_pages, pool, cos,
                     sin, temp, top_p, top_k, seeds, record, *, args, metrics,
                     sample=False):
@@ -209,6 +277,7 @@ class LatentPath:
     """The pool, the rotary tables and the step programs of one engine."""
 
     snapshots = 0      # a request keeps nothing beside its pages
+    _log = property(lambda self: self.riders.log)   # the decode steps' log
 
     def __init__(self, eng):
         args, self.eng = eng.args, eng
@@ -239,6 +308,7 @@ class LatentPath:
         # a decode step's output is the next one's operand as it is, a
         # prompt's first token is seated (`seat`)
         self.tokens = token_vector(eng.max_slots + self.counts, eng.pad_id)
+        self.riders = RoutingRiders(eng)
         self.reset()
 
         donate = eng._donate_enabled()
@@ -261,9 +331,8 @@ class LatentPath:
 
     def reset(self):
         """An empty engine: the pools stay (and their byte gauges)."""
-        # a decode step's [picks, live rows, positions, the selection kept
-        # or None, its slot]
-        self._log, self._steps = [], 0
+        self.riders.reset()
+        self._steps = 0
         pools = jax.tree_util.tree_leaves(self.pool)
         self.eng.metrics.set_gauge(
             "kv_pool_bytes", sum(p.size * p.dtype.itemsize for p in pools))
@@ -292,15 +361,11 @@ class LatentPath:
         pass
 
     def take_state(self, slot):
-        trace = getattr(self.eng.slots.owner(slot), "routing", None)
-        if trace is not None:
-            trace.leave()
+        self.riders.leave(slot)
         return None
 
     def put_state(self, slot, saved):
-        trace = getattr(self.eng.slots.owner(slot), "routing", None)
-        if trace is not None:
-            trace.seat(slot)
+        self.riders.seat(slot)
 
     # -- the token vector and the two step programs -----------------------------
     def seat(self, slot, token):
@@ -322,30 +387,15 @@ class LatentPath:
             self.pool, self.cos, self.sin, jnp.float32(req.temperature),
             jnp.float32(req.top_p), jnp.int32(req.top_k),
             jnp.asarray([req.seed], jnp.int32), row)
-        if picks is not None or selected is not None:
-            if getattr(req, "routing", None) is None:
-                req.routing = RoutingTrace(self._log)
-            req.routing.window(start, last_idx + 1, picks, row, selected)
-            req.routing.seat(slot)
+        self.riders.window(req, slot, start, last_idx + 1, picks, row,
+                           selected)
         return first
 
     def landed(self, out):
         """A decode step's output was read (`out`, the host copy the engine
         made): the routing's four counts behind the rows' tokens and, behind
         a selector, the keys selected and visible."""
-        busiest, here, picks, hit, *keys = (
-            int(x) for x in out[self.eng.max_slots:])
-        m, args = self.eng.metrics, self.eng.args
-        if keys:
-            m.observe("serve.selected_keys", keys[0])
-            m.observe("serve.visible_keys", keys[1])
-        if picks:
-            m.observe("serve.routed_here_share", here / picks)
-            m.observe("serve.held_experts_hit",
-                      hit / (args.num_layers - args.first_k_dense))
-        if here:
-            m.observe("serve.expert_load_max_over_mean",
-                      busiest * args.experts_held / here)
+        self.riders.landed(out[self.eng.max_slots:])
 
     def decode(self, bt, active, sample, sampling_args):
         eng = self.eng
@@ -364,7 +414,5 @@ class LatentPath:
         self.pool, self.tokens, picks, selected = self._decode[sample](
             eng.params, self.tokens, bt, pos, live, self.pool,
             self.cos, self.sin, *sampling_args, row)
-        if picks is not None or selected is not None:
-            self._log.append([picks, live, pos,
-                              None if keep else selected, row])
+        self.riders.step(picks, live, pos, None if keep else selected, row)
         return self.tokens

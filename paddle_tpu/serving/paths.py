@@ -50,6 +50,7 @@ from __future__ import annotations
 
 from paddle_tpu.models.gated_delta_functional import GatedDeltaArgs
 from paddle_tpu.models.hybrid_functional import HybridArgs
+from paddle_tpu.models.latent_delta_functional import LatentDeltaMoEArgs
 from paddle_tpu.models.latent_moe_functional import LatentMoEArgs
 from paddle_tpu.models.llama_functional import LlamaArgs
 from paddle_tpu.serving.dense import DensePath
@@ -60,7 +61,8 @@ __all__ = ["PATHS", "path_for"]
 
 # type of the model description -> the family's device half
 PATHS = {LlamaArgs: DensePath, HybridArgs: HybridPath,
-         GatedDeltaArgs: HybridPath, LatentMoEArgs: LatentPath}
+         GatedDeltaArgs: HybridPath, LatentMoEArgs: LatentPath,
+         LatentDeltaMoEArgs: HybridPath}
 
 
 def path_for(eng):

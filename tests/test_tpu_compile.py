@@ -527,6 +527,9 @@ _DELTA_STEP = {
     "olmo_hybrid_chat_replies_cell": (64, 30, 96, 192),   # two heads a row
     "value_heads_of_128": (16, 32, 128, 128),             # one head a row
     "a_row_in_three_blocks": (4, 24, 256, 256),   # 8 of 24 row-groups fit
+    # 64 value heads (32 key heads repeated): k and q fill the tile's 128
+    # lanes exactly, one head a row, two blocks of 32 heads a row
+    "gigachat35_reasoning_traces_cell": (64, 64, 128, 128),
 }
 
 
@@ -554,6 +557,8 @@ def test_delta_step_kernel_compiles_for_v5e(one_chip, name):
 
     if name == "a_row_in_three_blocks":
         assert gdr._step_block(state, H) == 8
+    if name == "gigachat35_reasoning_traces_cell":
+        assert gdr._step_block(state, H) == 32
     compiled = jax.jit(step, donate_argnums=(5,)).lower(
         sds((r, H, dk)), sds((r, H, dk)), sds((r, H, dv)), sds((r, H)),
         sds((r, H)), sds(state), sds((r,), jnp.bool_)).compile()
@@ -563,6 +568,72 @@ def test_delta_step_kernel_compiles_for_v5e(one_chip, name):
                      r"\{\{1\}: \(3, \{\}\)\}", text)
     assert not _made_of_shape(text, state)
     assert compiled.memory_analysis().temp_size_in_bytes < math.prod(state)
+
+
+def test_latent_delta_decode_program_at_the_gigachat_widths(one_chip):
+    """GigaChat 3.5's decode program at the cell's widths, slots and pools
+    (all five layers: delta + dense, latent + experts, 3 x delta + experts),
+    pools and state donated as `HybridPath` donates them: a kernel
+    `delta_rule_step` a delta layer under `pt.delta_rule`, its `[64, 64,
+    128, 128]` float32 state aliased in and out and nothing of that size
+    copied or computed; the latent decode kernel once; the plan's
+    temporaries far under one layer's state."""
+    import functools
+    import importlib.util
+    import json
+    import re
+
+    from benchmarks.harness import weights
+    from paddle_tpu.models import latent_delta_functional as ldf
+    from paddle_tpu.serving import hybrid
+    from paddle_tpu.serving.metrics import Metrics
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location(
+        "family_mla_delta_compile",
+        os.path.join(root, "benchmarks", "families", "mla_delta_moe.py"))
+    fam = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(fam)
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "gigachat3.5-1chip.json")) as f:
+        arch = json.load(f)
+    args = fam.serve_args(arch)
+    slots, pages, ps, max_len = 64, 8192, 64, 10240
+
+    def described(make):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip),
+            jax.eval_shape(make))
+
+    def sds(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    params = described(lambda: weights.make_params(fam, arch, 1))
+    pools = described(lambda: ldf.pools(args, pages, ps, jnp.bfloat16))
+    state = described(lambda: ldf.slot_state(args, slots, jnp.bfloat16))
+    tables = described(lambda: ldf.tables(args, max_len))
+    assert state[0]["S"].shape == (64, 64, 128, 128)
+    with qm.fused_dispatch(True):
+        compiled = jax.jit(
+            functools.partial(hybrid._decode_traced, family=ldf, args=args,
+                              metrics=Metrics()),
+            donate_argnums=(6, 7)).lower(
+            params, sds((5,)), sds((slots + ldf.RIDERS,)),
+            sds((slots, max_len // ps)), sds((slots,)),
+            sds((slots,), jnp.bool_), pools, state, tables,
+            sds((), jnp.float32), sds((), jnp.float32), sds(()),
+            sds((slots,))).compile()
+    text = compiled.as_text()
+    kernels = re.findall(
+        r"%delta_rule_step[.\d]* = .*output_to_operand_aliasing=\{\{1\}: "
+        r"\(3, \{\}\)\}.*op_name=\"[^\"]*pt\.delta_rule/delta_rule_step",
+        text)
+    assert len(kernels) == 4
+    assert not _made_of_shape(text, (64, 64, 128, 128))
+    assert sum("pt.latent_attention" in line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line) == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 28
 
 
 def test_delta_decode_program_holds_one_copy_of_the_state(one_chip):
