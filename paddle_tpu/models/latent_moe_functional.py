@@ -69,9 +69,9 @@ Every layer is `x += Attn(norm(x)); x += FFN(norm(x))`.
              the experts `[first_expert, first_expert + experts_held)`: a
              pick that lands elsewhere adds nothing here (its chip's part
              of an expert-parallel layer), and no code stands in for the
-             absent chips. Picks are sorted by expert, those not held leave
-             the dispatch, and each projection is one grouped matmul
-             (`kernels/grouped_matmul`): no capacity, no dropped token.
+             absent chips. Many rows: picks sorted by expert, one grouped
+             matmul a projection; few (a decode step): one pass over the hit
+             experts' weights (`_routed_experts`). No capacity, no drops.
 
 The parameter tree is `llama_functional`'s (`embedding`, `layers/*` stacked
 on a leading layer axis, `final_norm`, `lm_head`) with the expert layer's
@@ -578,6 +578,15 @@ def route(logits, args, bias=None):
     return experts.astype(jnp.int32), w * args.routed_scaling
 
 
+def experts_fused(rows, args, dtype):
+    """Whether a step program of `rows` rows runs its routed experts as the
+    fused pass over the hit experts' weights (`gm.fused_expert_ffn`) or as
+    grouped matmuls over a row a pick: a fact of the static shapes and of
+    where the program runs (`gm.fused_tiles`), nothing else."""
+    return gm.fused_tiles(rows, args.hidden_size, args.expert_intermediate,
+                          jnp.dtype(dtype).itemsize) is not None
+
+
 def _routed_experts(lp, stack, first, hin, live, args):
     """hin [n, h] -> (the held experts' part of the routed sum [n, h],
     counts int32 [4]: tokens at the busiest held expert, picks that landed
@@ -585,7 +594,17 @@ def _routed_experts(lp, stack, first, hin, live, args):
     `live` is False count for nothing; the experts every row picked [n, k],
     of all the published ones). `stack`: the `we_*` leaves of the
     WHOLE stack viewed [layers * held, ..], of which this layer's start at
-    group `first` (see `kernels/grouped_matmul`)."""
+    group `first` (see `kernels/grouped_matmul`).
+
+    One sum, two forms by the STATIC row count (`experts_fused`). Up to
+    `gm.FUSED_ROWS` (128) rows, a decode step: every row against each held
+    expert some live row picked, one pass over those experts' weights, the
+    routing weights a dense [n, E] matrix that is 0 where a row did not pick
+    (`_routed_fused`): `n` times an expert's operations stay hidden behind
+    its bytes below the chip's ridge (~240 rows a v5e), and nothing is
+    sorted or gathered. More rows, a prefill window: a row a pick sorted by
+    expert through three grouped matmuls (`_routed_grouped`), whose
+    operations follow the picks."""
     n, k, E = hin.shape[0], args.experts_per_tok, args.experts_held
     with jax.named_scope("pt.moe_route"):
         logits = jnp.matmul(hin.astype(jnp.float32),
@@ -593,16 +612,49 @@ def _routed_experts(lp, stack, first, hin, live, args):
         experts, weights = route(logits, args, lp.get("router_bias"))
         local = experts - args.first_expert
         held = (local >= 0) & (local < E) & live[:, None]
-        # sorted by expert; what is not held sorts past the held experts
-        # and into no group: it leaves the dispatch
-        key = jnp.where(held, local, E).reshape(-1)
+        # what is not held keys past the held experts: no group, no column
+        key = jnp.where(held, local, E)
+    routed = (_routed_fused
+              if experts_fused(n, args, stack["we_gate"].dtype)
+              else _routed_grouped)
+    out, sizes = routed(stack, first, hin, key, held, weights, args)
+    with jax.named_scope("pt.moe_route"):
+        counts = jnp.stack([jnp.max(sizes), jnp.sum(sizes),
+                            k * jnp.sum(live.astype(jnp.int32)),
+                            jnp.sum((sizes > 0).astype(jnp.int32))])
+    return out.astype(hin.dtype), counts, experts
+
+
+def _routed_fused(stack, first, hin, key, held, weights, args):
+    """key [n, k]: a pick's held expert, E where it is not `held` (or its
+    row not live) -> (the routed sum [n, h] float32, picks a held expert
+    [E])."""
+    E, limit = args.experts_held, args.swiglu_limit
+    with jax.named_scope("pt.moe_route"):
+        picked = jax.nn.one_hot(key, E, dtype=jnp.float32)     # [n, k, E]
+        c = jnp.sum(picked * weights[:, :, None], axis=1)
+        sizes = jnp.sum(picked, axis=(0, 1)).astype(jnp.int32)
+    with jax.named_scope("pt.expert_ffn"):
+        out = gm.fused_expert_ffn(
+            hin, c, sizes > 0, stack["we_gate"], stack["we_up"],
+            stack["we_down"], first,
+            lambda gate, up: _gate(gate, limit) * _up(up, limit))
+    return out, sizes
+
+
+def _routed_grouped(stack, first, hin, key, held, weights, args):
+    """The same of a row a pick, sorted by expert; what is not held sorts
+    past the held experts and into no group: it leaves the dispatch."""
+    n, k = key.shape
+    E, limit = args.experts_held, args.swiglu_limit
+    with jax.named_scope("pt.moe_route"):
+        key = key.reshape(-1)
         order = jnp.argsort(key, stable=True)
         token = order // k
         sizes = jnp.sum(jax.nn.one_hot(key, E + 1, dtype=jnp.int32),
                         axis=0)[:E]
         xs = hin[token]                                    # [n * k, h]
     with jax.named_scope("pt.expert_ffn"):
-        limit = args.swiglu_limit
         act = (_gate(gm.grouped_matmul(xs, stack["we_gate"], sizes, first),
                      limit)
                * _up(gm.grouped_matmul(xs, stack["we_up"], sizes, first),
@@ -617,10 +669,7 @@ def _routed_experts(lp, stack, first, hin, live, args):
         # a sum (a scatter-add of the sorted rows was 7% of the device's
         # busy time at a 2,048-token window)
         out = jnp.sum(ys[jnp.argsort(order)].reshape(n, k, -1), axis=1)
-        counts = jnp.stack([jnp.max(sizes), jnp.sum(sizes),
-                            k * jnp.sum(live.astype(jnp.int32)),
-                            jnp.sum((sizes > 0).astype(jnp.int32))])
-    return out.astype(hin.dtype), counts, experts
+    return out, sizes
 
 
 def _expert_ffn(lp, stack, first, x, live, args):

@@ -288,6 +288,7 @@ class HybridPath:
             jnp.float32(req.temperature),
             jnp.float32(req.top_p), jnp.int32(req.top_k),
             jnp.asarray([req.seed], jnp.int32))
+        self.riders.ran(np.shape(ids)[-1])
         self.riders.window(req, slot, start, last_idx + 1, picks)
         return first
 
@@ -305,5 +306,6 @@ class HybridPath:
             eng.params, self.layer_ids, self.tokens, jnp.asarray(bt),
             jnp.asarray(pos), jnp.asarray(live), self.pools,
             self.state, self.tables, *sampling_args)
+        self.riders.ran(eng.max_slots)
         self.riders.step(picks, live, pos)
         return self.tokens

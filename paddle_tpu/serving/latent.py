@@ -20,7 +20,12 @@ routed experts (`models/latent_moe_functional.LatentMoEArgs`).
     makes, and the engine hands the path that host copy when it has read it
     (`landed`): no wait and no transfer is added. They become the
     observations `serve.expert_load_max_over_mean`,
-    `serve.routed_here_share` and `serve.held_experts_hit` (a layer). The
+    `serve.routed_here_share` and `serve.held_experts_hit` (a layer);
+    beside them `serve.expert_fused_share`, once a step program of either
+    kind: 1.0 where its expert layers are the fused pass over the hit
+    experts' weights, 0.0 where they are grouped matmuls (the decode steps
+    and the windows of a deployment: the mean is the decode steps' share of
+    the step programs). The
     same `[slots + 4]` vector is the NEXT decode step's token operand, as it
     lies on the device (the program reads its first `slots` rows): the
     path's token vector has that length;
@@ -173,6 +178,7 @@ class RoutingRiders:
 
     def __init__(self, eng):
         self.eng = eng
+        self._fused = {}      # a program's rows -> 1.0 / 0.0 (`ran`)
         self.reset()
 
     def reset(self):
@@ -197,6 +203,21 @@ class RoutingRiders:
         """A decode step went out: one entry for all its rows."""
         if picks is not None or selected is not None:
             self.log.append([picks, live, pos, selected, row])
+
+    def ran(self, rows):
+        """A step program of `rows` rows went out: whether its expert layers
+        are the fused pass over the hit experts' weights (1.0) or grouped
+        matmuls (0.0), by the rule the program was traced under
+        (`lm.experts_fused`); a family without experts records nothing."""
+        if not hasattr(self.eng.args, "experts_held"):
+            return
+        if rows not in self._fused:
+            dtype = jax.tree_util.tree_leaves(
+                self.eng.params["embedding"])[0].dtype
+            self._fused[rows] = float(
+                lm.experts_fused(rows, self.eng.args, dtype))
+        self.eng.metrics.observe("serve.expert_fused_share",
+                                 self._fused[rows])
 
     def _trace(self, slot):
         return getattr(self.eng.slots.owner(slot), "routing", None)
@@ -387,6 +408,7 @@ class LatentPath:
             self.pool, self.cos, self.sin, jnp.float32(req.temperature),
             jnp.float32(req.top_p), jnp.int32(req.top_k),
             jnp.asarray([req.seed], jnp.int32), row)
+        self.riders.ran(np.shape(ids)[-1])
         self.riders.window(req, slot, start, last_idx + 1, picks, row,
                            selected)
         return first
@@ -414,5 +436,6 @@ class LatentPath:
         self.pool, self.tokens, picks, selected = self._decode[sample](
             eng.params, self.tokens, bt, pos, live, self.pool,
             self.cos, self.sin, *sampling_args, row)
+        self.riders.ran(eng.max_slots)
         self.riders.step(picks, live, pos, None if keep else selected, row)
         return self.tokens

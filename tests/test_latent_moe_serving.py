@@ -255,6 +255,210 @@ def test_grouped_matmul_with_empty_and_one_token_experts(sizes, fused):
 
 
 # ---------------------------------------------------------------------------
+# few rows: the fused pass over the hit experts' weights
+# ---------------------------------------------------------------------------
+
+def _expert_args(h, m, E, k, routed, first_expert, limit):
+    return lm.LatentMoEArgs(
+        vocab_size=64, hidden_size=h, num_layers=2, num_heads=2, q_rank=16,
+        kv_rank=16, nope_dim=8, rope_dim=8, v_dim=8, dense_intermediate=32,
+        expert_intermediate=m, shared_experts=1, routed_experts=routed,
+        first_expert=first_expert, experts_held=E, n_group=1, topk_group=1,
+        experts_per_tok=k, routed_scaling=2.5, first_k_dense=0,
+        rope_theta=1e4, rms_eps=1e-5, yarn=None, scoring="sigmoid",
+        norm_topk=True, swiglu_limit=limit)
+
+
+def _picks(rng, mix, n, k, E, routed, first_expert):
+    """(experts [n, k] of the published ones, live [n]) for a mix of picks;
+    held are [first_expert, first_expert + E)."""
+    live = np.ones(n, bool)
+    if mix == "none_here":          # every pick lands on another chip
+        away = np.setdiff1d(np.arange(routed),
+                            first_expert + np.arange(E))
+        return np.stack([rng.permutation(away)[:k] for _ in range(n)]), live
+    if mix == "all_here":           # routed_here_share 1
+        return first_expert + np.stack(
+            [rng.permutation(E)[:k] for _ in range(n)]), live
+    # held expert 0 gets no row, 1 exactly one row, 2 every row; rows
+    # 3, 4 are dead and pick held experts that no live row may hit (5, 6)
+    others = np.setdiff1d(np.arange(routed), first_expert + np.arange(7))
+    experts = np.stack([rng.permutation(others)[:k] for _ in range(n)])
+    experts[:, 0] = first_expert + 2
+    experts[7, 1] = first_expert + 1
+    experts[3:5, 1:3] = first_expert + np.array([5, 6])
+    live[3:5] = False
+    if mix == "all_dead":
+        live[:] = False
+    return experts, live
+
+
+FUSED_CASES = [
+    # h : m as each cell's (7,168 : 2,048, 5,120 : 1,536, 6,144 : 2,048)
+    dict(n=64, h=896, m=256, E=16, k=8, routed=64, limit=10.0, first=16),
+    dict(n=32, h=896, m=256, E=16, k=8, routed=64, limit=None, first=0),
+    dict(n=64, h=1280, m=384, E=20, k=6, routed=160, limit=None, first=20),
+    dict(n=32, h=1280, m=384, E=20, k=6, routed=160, limit=10.0, first=0),
+    dict(n=32, h=768, m=256, E=16, k=8, routed=64, limit=None, first=16),
+    dict(n=64, h=768, m=256, E=16, k=8, routed=64, limit=10.0, first=0),
+    dict(n=64, h=896, m=256, E=16, k=8, routed=64, limit=10.0, first=16,
+         dtype="bfloat16"),
+    dict(n=64, h=1280, m=384, E=20, k=6, routed=160, limit=None, first=0,
+         dtype="bfloat16"),
+    dict(n=64, h=896, m=256, E=16, k=8, routed=64, limit=10.0, first=16,
+         mix="all_here"),
+    dict(n=32, h=768, m=256, E=16, k=8, routed=16, limit=None, first=0,
+         mix="all_here"),
+    dict(n=64, h=896, m=256, E=16, k=8, routed=64, limit=10.0, first=16,
+         mix="none_here"),
+    dict(n=32, h=1280, m=384, E=20, k=6, routed=160, limit=None, first=0,
+         mix="none_here"),
+    dict(n=64, h=768, m=256, E=16, k=8, routed=64, limit=None, first=16,
+         mix="all_dead"),
+    dict(n=8, h=896, m=256, E=16, k=8, routed=64, limit=10.0, first=0),
+]
+
+
+@pytest.mark.parametrize(
+    "case", FUSED_CASES,
+    ids=lambda c: "-".join(f"{k}{v}" for k, v in c.items()))
+def test_fused_expert_pass_is_the_grouped_sum(case, monkeypatch):
+    """`_routed_experts` at a decode step's row count, the fused Pallas pass
+    (interpreted) against `grouped_matmul_reference` composed as the grouped
+    form composes it: the sum, the four counts and the picks. A held expert
+    no live row picked (and, where nothing lands here, every expert) holds
+    NaNs: the pass never reads it. Blocks: several a matrix (a budget of
+    1-2 MB), so both kernels accumulate over blocks and walk past the count."""
+    n, h, m, E, k = (case[x] for x in "n h m E k".split())
+    mix, first = case.get("mix", "mixed"), case["first"]
+    dtype = jnp.dtype(case.get("dtype", "float32"))
+    first_expert = 0 if case["routed"] == E else E
+    args = _expert_args(h, m, E, k, case["routed"], first_expert,
+                        case["limit"])
+    rng = np.random.default_rng(n + h + E)
+    experts, live = _picks(rng, mix, n, k, E, case["routed"], first_expert)
+    weights = rng.random((n, k)).astype(np.float32) + 0.05
+    hit = np.zeros(E, bool)
+    local = experts[live] - first_expert
+    hit[local[(local >= 0) & (local < E)]] = True
+
+    def leaf(rows, cols):
+        w = rng.normal(size=(2 * E, rows, cols)).astype(np.float32)
+        w *= rows ** -0.5
+        w[first:first + E][~hit] = np.nan       # never read
+        w[:first] = np.nan                      # another layer's
+        w[first + E:] = np.nan
+        return jnp.asarray(w, dtype)
+
+    stack = {"we_gate": leaf(h, m), "we_up": leaf(h, m),
+             "we_down": leaf(m, h)}
+    lp = {"router": jnp.zeros((h, case["routed"]), dtype)}
+    hin = jnp.asarray(rng.normal(size=(n, h)).astype(np.float32), dtype)
+    monkeypatch.setattr(lm, "route", lambda logits, args, bias=None: (
+        jnp.asarray(experts, jnp.int32), jnp.asarray(weights)))
+    run = jax.jit(lambda *a: lm._routed_experts(*a, args))
+    with qm.fused_dispatch(True, interpret=True):
+        # the largest budget that leaves neither matrix in one block
+        for budget in (2 << 20, 3 << 19, 1 << 20, 3 << 18):
+            monkeypatch.setattr(qm, "_VMEM_BUDGET_BYTES", budget)
+            th, tm = gm.fused_tiles(n, h, m, dtype.itemsize) or (h, m)
+            if th < h and tm < m:
+                break
+        assert th < h and tm < m and lm.experts_fused(n, args, dtype)
+        out, counts, picks = run(lp, stack, first, hin, jnp.asarray(live))
+    with qm.fused_dispatch(False):
+        assert not lm.experts_fused(n, args, dtype)
+        # the reference multiplies every group out: no NaNs for it
+        clean = {key: jnp.nan_to_num(w) for key, w in stack.items()}
+        want, want_counts, want_picks = jax.jit(
+            lambda *a: lm._routed_experts(*a, args))(
+                lp, clean, first, hin, jnp.asarray(live))
+    assert out.dtype == want.dtype == dtype and out.shape == (n, h)
+    np.testing.assert_array_equal(np.asarray(counts),
+                                  np.asarray(want_counts))
+    np.testing.assert_array_equal(np.asarray(picks), np.asarray(want_picks))
+    assert int(counts[3]) == hit.sum()
+    out, want = (np.asarray(x.astype(jnp.float32)) for x in (out, want))
+    assert np.isfinite(out).all()
+    if mix in ("none_here", "all_dead"):
+        assert int(counts[1]) == 0 and not out.any() and not want.any()
+    else:
+        assert np.abs(want).max() > 0.1
+        # float32: the order of sums; bfloat16: the grouped form rounds the
+        # projections, the activation and the down product, the pass the
+        # weighted activation alone
+        tol = 1e-4 if dtype == jnp.float32 else 0.04
+        np.testing.assert_allclose(out, want, atol=tol * np.abs(want).max())
+    dead = ~live
+    assert not out[dead].any()
+
+
+@pytest.mark.parametrize("rows, fused", [(32, True), (128, True),
+                                         (129, False), (136, False),
+                                         (512, False)])
+def test_the_row_count_alone_picks_the_experts_form(rows, fused):
+    """Up to 128 rows (a decode step) the routed experts are the two Pallas
+    calls of the fused pass and no `ragged_dot`; one row more, or a
+    512-token window, and they are the three ragged dots, as ever."""
+    h, m, E, k = 256, 128, 4, 2
+    args = _expert_args(h, m, E, k, 16, 4, None)
+    stack = {"we_gate": jnp.zeros((2 * E, h, m)),
+             "we_up": jnp.zeros((2 * E, h, m)),
+             "we_down": jnp.zeros((2 * E, m, h))}
+    lp = {"router": jnp.zeros((h, 16))}
+    with qm.fused_dispatch(True, interpret=True):
+        assert lm.experts_fused(rows, args, jnp.float32) == fused
+        text = str(jax.make_jaxpr(
+            lambda *a: lm._routed_experts(*a, args))(
+                lp, stack, 0, jnp.zeros((rows, h)), jnp.ones(rows, bool)))
+    assert text.count("= ragged_dot_general[") == (0 if fused else 3)
+    assert text.count("= pallas_call[") == (2 if fused else 0)
+    assert ("expert_gate_up" in text) == ("expert_down" in text) == fused
+    assert ("= sort[" in text) != fused
+    # the form is the kernels' to refuse too: off the TPU, or where whole
+    # tiles do not divide the widths, every row count takes the grouped form
+    assert not lm.experts_fused(rows, args, jnp.float32)
+    with qm.fused_dispatch(True, interpret=True):
+        assert not lm.experts_fused(
+            rows, args._replace(hidden_size=192), jnp.float32)
+        assert not lm.experts_fused(
+            rows, args._replace(expert_intermediate=96), jnp.float32)
+
+
+def test_expert_fused_share_is_the_decode_programs_share():
+    """`serve.expert_fused_share`, one sample a step program: at the
+    gigachat cell's widths three decode steps of 64 rows (fused) and one
+    2,048-token window (grouped) read 0.75; with the kernels off every
+    program reads 0."""
+    from types import SimpleNamespace
+
+    from paddle_tpu.serving.latent import RoutingRiders
+    from paddle_tpu.serving.metrics import Metrics
+
+    def share(rows):
+        eng = SimpleNamespace(
+            args=_expert_args(7168, 2048, 16, 8, 256, 0, 10.0),
+            params={"embedding": jnp.zeros((2, 2), jnp.bfloat16)},
+            metrics=Metrics())
+        riders = RoutingRiders(eng)
+        for n in rows:
+            riders.ran(n)
+        return eng.metrics.summary()["observations"][
+            "serve.expert_fused_share"]
+
+    with qm.fused_dispatch(True, interpret=True):
+        seen = share([64, 2048, 64, 64])
+    assert seen["count"] == 4 and seen["mean"] == 0.75
+    seen = share([64, 2048, 64, 64])
+    assert seen["count"] == 4 and seen["mean"] == 0.0
+    # a family without experts has no such observation
+    eng = SimpleNamespace(args=SimpleNamespace(), metrics=Metrics())
+    RoutingRiders(eng).ran(64)
+    assert "serve.expert_fused_share" not in eng.metrics.summary()[
+        "observations"]
+
+
+# ---------------------------------------------------------------------------
 # the share of an expert-parallel layer
 # ---------------------------------------------------------------------------
 
@@ -426,6 +630,53 @@ def test_decode_through_the_cache_gives_the_references_logits(
     assert [int(c) for c in counts[1:3]] == [12, 12]
 
 
+@pytest.mark.parametrize("group", [None, 3])
+def test_decode_with_the_fused_expert_pass_gives_the_references_logits(
+        fam, group):
+    """The whole decode program at widths whole tiles divide (hidden 128,
+    experts 128 wide) and 8 rows, the kernels interpreted: its expert
+    layers are the fused pass, and the logits are the reference's, with
+    every expert held and as the chip that holds group 3 of 8 (against the
+    grouped form there: the reference computes every expert)."""
+    arch = dict(ARCH, hidden_size=128, moe_intermediate_size=128)
+    params = fam.make_params(arch, 5, jnp.float32)
+    ids = _ids(30, 3)
+    want = _ref_logits(fam, params, ids)
+    if group is not None:
+        arch = dict(share(group), hidden_size=128, moe_intermediate_size=128)
+        params = dict(params, layers={
+            k: v[:, 4 * group:4 * group + 4] if k.startswith("we_") else v
+            for k, v in params["layers"].items()})
+    args = fam.serve_args(arch)
+    _, pool, bt_row = _prefill(params, args, ids[:20], [20])
+    cos, sin = lm.rope_tables(256, args)
+    bt = np.zeros((8, P), np.int32)
+    bt[5] = bt_row
+    live = np.arange(8) == 5
+    # one jitted program a form: the form is decided as it is traced
+    step = {fused: jax.jit(lambda *a: lm.decode_step(*a, args))
+            for fused in (True, False)}
+    pools = {True: pool, False: pool}
+    for t in range(20, 30):
+        operands = (params, jnp.asarray(np.where(live, ids[t], 0)),
+                    jnp.asarray(bt), jnp.asarray(np.where(live, t, 0)),
+                    jnp.asarray(live))
+        got = {}
+        for fused in (True, False) if group is not None else (True,):
+            with qm.fused_dispatch(fused, interpret=True):
+                assert lm.experts_fused(8, args, jnp.float32) == fused
+                logits, pools[fused], counts, _ = step[fused](
+                    *operands, pools[fused], cos, sin)
+            got[fused] = np.asarray(logits[5])
+        if group is None:
+            np.testing.assert_allclose(got[True], want[t], atol=TOL)
+            assert [int(c) for c in counts[1:3]] == [12, 12]
+        else:
+            np.testing.assert_allclose(got[True], got[False], atol=TOL)
+            assert np.abs(got[True] - want[t]).max() > 100 * TOL
+            assert 0 <= int(counts[1]) <= 8 and int(counts[2]) == 12
+
+
 def test_the_dense_leading_layer_is_in_the_stack(fam, params, args):
     """`first_k_dense_replace` = 1: layer 0 runs the SwiGLU of `dense_layers`
     in the program and the reference alike; read as an expert layer's
@@ -463,6 +714,10 @@ def test_engine_serves_the_references_tokens(fam, params, args, chunk):
     assert obs["serve.routed_here_share"]["mean"] == 1.0    # all 32 held
     assert 1.0 <= obs["serve.expert_load_max_over_mean"]["mean"] <= 32
     assert 0 < obs["serve.held_experts_hit"]["mean"] <= 32
+    # one sample a step program; on the CPU none is the fused pass
+    assert obs["serve.expert_fused_share"]["count"] == eng.metrics.summary()[
+        "counters"]["serve.dispatched"]
+    assert obs["serve.expert_fused_share"]["max"] == 0.0
 
 
 def _own_picks(fam, params, seq, scores=None):

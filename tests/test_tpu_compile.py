@@ -380,12 +380,28 @@ def test_latent_prefill_kernel_compiles_for_v5e(one_chip, window):
     assert text.count('custom_call_target="tpu_custom_call"') == 1
 
 
+def _fused_expert_calls(text):
+    """How many of a compiled program's Mosaic calls under the scope
+    `pt.expert_ffn` are the fused expert pass's (`expert_gate_up`,
+    `expert_down`): what `expert_ffn_time_share` finds them by."""
+    import re
+
+    def calls(kernel):
+        return len(re.findall(
+            rf"%{kernel}[.\d]* = .*custom_call_target=\"tpu_custom_call\""
+            rf".*op_name=\"[^\"]*pt\.expert_ffn/{kernel}", text))
+
+    return calls("expert_gate_up"), calls("expert_down")
+
+
 def test_latent_decode_program_copies_no_experts_and_no_pool(one_chip):
     """The family's decode program at the cell's widths, depth 2, the pool
-    donated: three Mosaic-free grouped matmuls a layer over the WHOLE stack
-    of experts (a layer's slice of it handed to the custom call was a copy
-    of 315 MB a projection a step), one latent kernel, and no temporary of
-    an expert stack's or the pool's size."""
+    donated: the routed experts of its 64 rows are the fused pass's two
+    Mosaic calls under `pt.expert_ffn` over the WHOLE stack of experts (a
+    layer's slice of it handed to a custom call was a copy of 315 MB a
+    projection a step) and no grouped matmul, one latent kernel, and no
+    temporary of an expert stack's or the pool's size. A 512-token PREFILL
+    window at the same widths still holds XLA's three grouped matmuls."""
     from paddle_tpu.models import latent_moe_functional as lm
 
     L, b, ps, P, NP = 2, 64, 64, 256, 8192
@@ -425,14 +441,29 @@ def test_latent_decode_program_copies_no_experts_and_no_pool(one_chip):
         sds((b,), jnp.int32), sds((b,), jnp.bool_), pool, table,
         table).compile()
     text = compiled.as_text()
-    # the latent kernel, and XLA's own grouped-matmul kernels (three
-    # projections and their tile schedule), all Mosaic calls
+    # the latent kernel and the fused pass's two (the layers are a scan:
+    # one body), no grouped matmul and no sort of the picks
     assert "latent_decode_attention" in text
-    assert text.count("ragged-dot-none") >= 3
-    assert text.count('custom_call_target="tpu_custom_call"') >= 4
+    assert _fused_expert_calls(text) == (1, 1)
+    assert "ragged-dot" not in text
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
     # one expert stack of a layer is 315 MB, the pool 1.3 GB here: what the
     # program plans beside its arguments stays far under either
     assert compiled.memory_analysis().temp_size_in_bytes < 150e6
+
+    def prefill(params, ids, at, last, bt_row, new_pages, pool, cos, sin):
+        with qm.fused_dispatch(True):
+            return lm.prefill_window(params, ids, at, last, bt_row, new_pages,
+                                     pool, cos, sin, args)
+
+    text = jax.jit(prefill, donate_argnums=(6,)).lower(
+        params, sds((512,), jnp.int32), sds((), jnp.int32),
+        sds((), jnp.int32), sds((P,), jnp.int32), sds((P,), jnp.int32), pool,
+        table, table).compile().as_text()
+    # XLA's own grouped-matmul kernels (three projections and their tile
+    # schedule), as before the fused pass: 3,072 rows are past the ridge
+    assert text.count("ragged-dot-none") >= 3
+    assert _fused_expert_calls(text) == (0, 0)
 
 
 def test_selector_programs_compile_at_the_glm5_widths(one_chip):
@@ -492,6 +523,8 @@ def test_selector_programs_compile_at_the_glm5_widths(one_chip):
     text = compiled.as_text()
     assert "index_decode_scores" in text
     assert "latent_decode_attention" in text
+    # 32 rows: the one expert layer's fused pass, no grouped matmul
+    assert _fused_expert_calls(text) == (1, 1) and "ragged-dot" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 200e6
 
     def prefill(params, ids, at, last, bt_row, new_pages, cache, cos, sin,
@@ -504,7 +537,11 @@ def test_selector_programs_compile_at_the_glm5_widths(one_chip):
         params, sds((2048,), jnp.int32), sds((), jnp.int32),
         sds((), jnp.int32), sds((P,), jnp.int32), sds((P,), jnp.int32), cache,
         table, table, sds((), jnp.int32)).compile()
-    assert "latent_masked_prefill_attention" in compiled.as_text()
+    text = compiled.as_text()
+    assert "latent_masked_prefill_attention" in text
+    # a 2,048-token window keeps the three grouped matmuls
+    assert text.count("ragged-dot-none") >= 3
+    assert _fused_expert_calls(text) == (0, 0)
     assert compiled.memory_analysis().temp_size_in_bytes < 3.0e9
 
 
@@ -576,8 +613,10 @@ def test_latent_delta_decode_program_at_the_gigachat_widths(one_chip):
     pools and state donated as `HybridPath` donates them: a kernel
     `delta_rule_step` a delta layer under `pt.delta_rule`, its `[64, 64,
     128, 128]` float32 state aliased in and out and nothing of that size
-    copied or computed; the latent decode kernel once; the plan's
-    temporaries far under one layer's state."""
+    copied or computed; the latent decode kernel once; the routed experts
+    of every expert layer as the fused pass (`expert_gate_up`,
+    `expert_down` under `pt.expert_ffn`); the plan's temporaries far under
+    one layer's state."""
     import functools
     import importlib.util
     import json
@@ -633,6 +672,8 @@ def test_latent_delta_decode_program_at_the_gigachat_widths(one_chip):
     assert not _made_of_shape(text, (64, 64, 128, 128))
     assert sum("pt.latent_attention" in line for line in text.splitlines()
                if 'custom_call_target="tpu_custom_call"' in line) == 1
+    # 64 rows: the four expert layers' fused passes, no grouped matmul
+    assert _fused_expert_calls(text) == (4, 4) and "ragged-dot" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 28
 
 
