@@ -28,12 +28,28 @@ of one matmul).
 An int8 pool (`k_scale` / `v_scale`, per (page, KV head) absmax) is
 dequantised as it is read: in registers in the kernel (the scales fold into
 the scores and the probabilities), a key block at a time in the fall-back.
+
+UNDER A SELECTION (a block-sparse layer's window: `sparse_attention`;
+STATIC-optional, a call without one traces to the program it always did) the
+same walk over the table's entries `0 .. last` applies one more predicate:
+`page_bits` packs the per-query mask `sel [nkv, s, P]` by key block (a row's
+word i = its bits over key block i's pages, in VMEM) and says for each
+(query block, key block) whether any row picked a page of it (SMEM). A score
+stands iff its row's bit is set AND the key is at or below the row's limit,
+one `[block_q, block_k]` mask for all the heads of the group (they pick
+together); a query block none of whose rows has a bit in a key block skips
+it as it skips the blocks past its last row. Every page up to the step's
+last position is still FETCHED (a list of the picked pages alone is later
+work: ROADMAP A4). The span shrinks with the group so that the accumulator
+stays in VMEM (`SPAN_ROWS`). No int8 pool and no jnp form under a selection:
+the fall-back is `sparse_attention`'s loop.
 """
 
 from __future__ import annotations
 
 import functools
 import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -41,9 +57,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from paddle_tpu.kernels import quantized_matmul as qm
-from paddle_tpu.kernels.sparse_attention import _tile_pages
 
-__all__ = ["paged_prefill_attention"]
+__all__ = ["paged_prefill_attention", "Selection", "page_bits",
+           "takes_kernel"]
 
 _NEG_INF = -1e30
 
@@ -56,6 +72,11 @@ MAX_ROWS = 1024
 # cell's shapes; my chip runs, PR 38: 554 -> 515 us a call from a span of
 # 128 to 512, 256-key blocks, a 512-token window at 2k context)
 SPAN = 512
+# ... and rows of the accumulator a grid step: the span shrinks with the
+# query heads a KV head (at 16 a group, 512 positions are 8,192 rows of q,
+# accumulator, m and l: 23 MB of VMEM; 2,048 rows are 5.8 MB and 128
+# positions)
+SPAN_ROWS = 2048
 # keys a compute block, many pages of it: a query block's visit to a key
 # block costs as much as ~1,100 more keys whatever the block's width (the
 # two cross-lane row reductions and the rescaling of m, l and the
@@ -71,11 +92,15 @@ _VMEM_LIMIT_BYTES = 32 * 1024 * 1024
 _VMEM_BUDGET_BYTES = 16 * 1024 * 1024
 
 
+def _tile_pages(pages, want=8):
+    return max(p for p in range(1, min(want, pages) + 1) if pages % p == 0)
+
+
 def _blocks(s, group, page_size, pages_per_slot):
     """(query positions a matmul, query positions a grid step, pages a key
     block), from the shapes."""
     bq = min(s, BLOCK_Q, max(16, MAX_ROWS // group))
-    span = min(s, max(bq, SPAN // bq * bq))
+    span = min(s, max(bq, min(SPAN, SPAN_ROWS // group) // bq * bq))
     return bq, span, max(1, min(BLOCK_K // page_size, pages_per_slot))
 
 
@@ -92,17 +117,23 @@ def _block_scales(scale_ref, pages, head, nkv, page_size):
 
 
 def _kernel(*refs, page_size, pages_per_block, block_q, span, group, sm_scale,
-            quantized):
+            quantized, selected):
     # grid (nkv, s // span): one step a KV head and `span` window positions,
     # as span / block_q query blocks that share the key blocks the step
     # fetches. A query block's rows are its positions for each of the KV
     # head's `group` query heads, stacked [group * block_q, hd]. Scalars in
     # SMEM: meta = (h, last_idx, page_base), the slot's block table [P] and,
     # for an int8 pool, the K and V scales of the table's own pages
-    # [num_pages * nkv].
+    # [num_pages * nkv]. `selected` (a `Selection`): in SMEM, for each (KV
+    # head, query block, key block), whether any of the block's rows picked
+    # a page of it; in VMEM the span's rows' picks, word i of a row = its
+    # bits over key block i's pages.
     meta_ref, bt_ref, *refs = refs
     sk_ref, sv_ref = (refs.pop(0), refs.pop(0)) if quantized else (None, None)
-    (q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, slot_ref, qs_ref,
+    visit_ref = refs.pop(0) if selected else None
+    q_ref = refs.pop(0)
+    bits_ref = refs.pop(0) if selected else None
+    (k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, slot_ref, qs_ref,
      m_ref, l_ref, acc_ref) = refs
     ps, ppb, bq, g = page_size, pages_per_block, block_q, group
     bk, hd = ps * ppb, k_buf.shape[-1]
@@ -111,6 +142,7 @@ def _kernel(*refs, page_size, pages_per_block, block_q, span, group, sm_scale,
     heads, steps = pl.num_programs(0), pl.num_programs(1)
     h, last_idx, base = meta_ref[0], meta_ref[1], meta_ref[2]
     last_table = bt_ref.shape[0] - 1
+    key_blocks = -(-bt_ref.shape[0] // ppb)
 
     def last_pos(end):
         # the last position rows up to window row `end` - 1 may see: that
@@ -181,17 +213,31 @@ def _kernel(*refs, page_size, pages_per_block, block_q, span, group, sm_scale,
     acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
     row = jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
 
-    def limit(u):
+    def limit(u, heads_stacked=g):
         # the last key a row sees: its own position, and for a padded row
         # the window's last real one (finite, meaningless, in fetched pages)
         return jnp.concatenate(
-            [h + jnp.minimum(si * span + u * bq + row, last_idx)] * g, axis=0)
+            [h + jnp.minimum(si * span + u * bq + row, last_idx)]
+            * heads_stacked, axis=0)
 
-    limits = [limit(u) for u in range(nsub)]
+    # under a selection one [bq, bk] mask serves all the group's heads
+    limits = [limit(u, 1 if selected else g) for u in range(nsub)]
     n_blocks = last_page(si) // ppb + 1
+    if selected:
+        col_page = jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1) // ps
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, 128), 1)
     slot0 = slot_ref[0]
     last_step = jnp.logical_and(head == heads - 1, si == steps - 1)
     wraps = si + 1 == steps
+
+    def picked(u, i):
+        # [bq, bk]: whether the row picked the column's page: bit (column's
+        # place in the block) of the row's word i
+        words = bits_ref[u * bq:(u + 1) * bq,
+                         pl.ds(pl.multiple_of(i // 128 * 128, 128), 128)]
+        word = jnp.sum(jnp.where(lane == i % 128, words, 0), axis=1,
+                       keepdims=True)
+        return jnp.bitwise_and(jnp.right_shift(word, col_page), 1) == 1
 
     def body(i, _):
         slot = (slot0 + i) % 2
@@ -217,9 +263,14 @@ def _kernel(*refs, page_size, pages_per_block, block_q, span, group, sm_scale,
 
         for u in range(nsub):
             at = slice(u * rows, (u + 1) * rows)
-
             # query block u sees this key block iff its last row does
-            @pl.when(i * bk <= last_pos(si * span + (u + 1) * bq))
+            seen = i * bk <= last_pos(si * span + (u + 1) * bq)
+            if selected:
+                # ... and one of its rows picked one of the block's pages
+                seen = jnp.logical_and(seen, visit_ref[
+                    ((head * steps + si) * nsub + u) * key_blocks + i] != 0)
+
+            @pl.when(seen)
             def _block():
                 k, v = k_buf[slot], v_buf[slot]             # [bk, hd]
                 if quantized:                               # exact in bf16
@@ -228,7 +279,12 @@ def _kernel(*refs, page_size, pages_per_block, block_q, span, group, sm_scale,
                     qs_ref[at, :], k, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32)     # [g * bq, bk]
                 s = s * (k_scale if quantized else sm_scale)
-                s = jnp.where(kpos <= limits[u], s, _NEG_INF)
+                if selected:
+                    ok = jnp.logical_and(picked(u, i), kpos <= limits[u])
+                    s = jnp.where(ok[None], s.reshape(g, bq, bk),
+                                  _NEG_INF).reshape(rows, bk)
+                else:
+                    s = jnp.where(kpos <= limits[u], s, _NEG_INF)
                 m = m_ref[at, :]
                 m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
                 p = jnp.exp(s - m_new)
@@ -244,20 +300,28 @@ def _kernel(*refs, page_size, pages_per_block, block_q, span, group, sm_scale,
 
     jax.lax.fori_loop(0, n_blocks, body, None)
     slot_ref[0] = (slot0 + n_blocks) % 2
-    out = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+    # under a selection a row no visit reached (a padded row whose picks lie
+    # past the window's last position) sums to nothing
+    acc, total = acc_ref[...], l_ref[...]
+    out = (acc / (jnp.maximum(total, 1e-30) if selected else total)).astype(
+        o_ref.dtype)
     for u in range(nsub):
         for j in range(g):
             o_ref[u * bq:(u + 1) * bq, j * hd:(j + 1) * hd] = out[
                 u * rows + j * bq:u * rows + (j + 1) * bq]
 
 
-def _supported(q_shape, pool_shape, bt_shape, q_itemsize=2, pool_itemsize=2):
+def _supported(q_shape, pool_shape, bt_shape, q_itemsize=2, pool_itemsize=2,
+               selected=False):
     """True when the Pallas kernel can take q [s, nh, hd] against a page
     pool [num_pages, nkv, page_size, hd] through a table [P]: whole query
     heads a KV head, hd lane-aligned (a head is a lane block of the
     window's rows), the page and the query block whole sublane tiles of
     their types (an int8 page needs page_size % 32 == 0), the window whole
-    grid steps of whole query blocks, the working set in VMEM."""
+    grid steps of whole query blocks, the working set in VMEM. Under a
+    selection: the rows' picks beside it, a key block's pages the bits of
+    one word, the query block whole float32 tiles (one mask serves the
+    group's heads), no int8 pool."""
     if len(q_shape) != 3 or len(pool_shape) != 4 or len(bt_shape) != 1:
         return False
     s, nh, hd = q_shape
@@ -276,12 +340,56 @@ def _supported(q_shape, pool_shape, bt_shape, q_itemsize=2, pool_itemsize=2):
     need = (2 * 2 * bk * hd * pool_itemsize + 2 * rows * bk * 4
             + g * span * (hd * (q_itemsize + 4) + 2 * 128 * 4
                           + 2 * 2 * hd * q_itemsize))
+    if selected:
+        if pool_itemsize == 1 or bq % 8 or ppb > 32:
+            return False
+        # the span's picks double-buffered, a query block's mask
+        need += 2 * span * _words(bt_shape[0], ppb)[1] * 4 + bq * bk * 4
     return need <= _VMEM_BUDGET_BYTES
+
+
+def takes_kernel(q, pool, bt_row, selected=False):
+    """Whether `paged_prefill_attention` runs these operands as the Pallas
+    kernel (the mode, then the shapes)."""
+    return qm._mode()[0] and _supported(
+        q.shape, pool.shape, jnp.shape(bt_row), q.dtype.itemsize,
+        pool.dtype.itemsize, selected)
+
+
+class Selection(NamedTuple):
+    """A window's picks as the kernel reads them (`page_bits`)."""
+    bits: jax.Array     # [nkv, s, words] int32: a row's picks, by key block
+    visit: jax.Array    # [nkv, s // block_q, key blocks] int32
+
+
+def _words(pages_per_slot, ppb):
+    """(key blocks a table holds, those rounded up to whole lane tiles)."""
+    blocks = -(-pages_per_slot // ppb)
+    return blocks, -(-blocks // 128) * 128
+
+
+def page_bits(sel, group, page_size):
+    """sel [nkv, s, P] bool, the table entries each of a window's queries
+    attends (KV heads pick apart) -> the `Selection` the kernel reads: bit j
+    of a row's word i is its pick of entry `i * pages_per_block + j`, and a
+    (query block, key block) is flagged where any of its rows has a bit."""
+    nkv, s, P = sel.shape
+    bq, _, ppb = _blocks(s, group, page_size, P)
+    blocks, words = _words(P, ppb)
+    sel = jnp.pad(sel, ((0, 0), (0, 0), (0, blocks * ppb - P)))
+    bits = jnp.sum(
+        jnp.where(sel.reshape(nkv, s, blocks, ppb),
+                  jnp.left_shift(1, jnp.arange(ppb, dtype=jnp.int32)), 0),
+        axis=-1, dtype=jnp.int32)
+    visit = jnp.any(bits.reshape(nkv, s // bq, bq, blocks) != 0,
+                    axis=2).astype(jnp.int32)
+    return Selection(jnp.pad(bits, ((0, 0), (0, 0), (0, words - blocks))),
+                     visit)
 
 
 def _pallas(q, pool_k, pool_v, bt_row, h, last_idx, page_base, k_scale,
             v_scale, sm_scale, interpret, block_q=None, span=None,
-            pages_per_block=None):
+            pages_per_block=None, selection=None):
     s, nh, hd = q.shape
     nkv, ps = pool_k.shape[1], pool_k.shape[2]
     g = nh // nkv
@@ -299,12 +407,23 @@ def _pallas(q, pool_k, pool_v, bt_row, h, last_idx, page_base, k_scale,
     def q_map(kv, si, *prefetch_refs):
         return (si, kv)
 
+    operands = [q.reshape(s, nh * hd)]
+    in_specs = [pl.BlockSpec((span, g * hd), q_map)]
+    if selection is not None:
+        if blocks != (bq, span, ppb) or k_scale is not None:
+            raise ValueError("a selection is laid out for the shapes' own "
+                             "blocking, over an unquantized pool")
+        prefetch.append(selection.visit.reshape(-1))
+        operands.append(selection.bits)
+        in_specs.append(pl.BlockSpec(
+            (None, span, selection.bits.shape[-1]),
+            lambda kv, si, *prefetch_refs: (kv, si, 0)))
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         grid=(nkv, s // span),
-        in_specs=[pl.BlockSpec((span, g * hd), q_map),
-                  pl.BlockSpec(memory_space=pl.ANY),
-                  pl.BlockSpec(memory_space=pl.ANY)],
+        in_specs=in_specs + [pl.BlockSpec(memory_space=pl.ANY),
+                             pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((span, g * hd), q_map),
         scratch_shapes=[pltpu.VMEM((2, bk, hd), pool_k.dtype),
                         pltpu.VMEM((2, bk, hd), pool_v.dtype),
@@ -318,7 +437,8 @@ def _pallas(q, pool_k, pool_v, bt_row, h, last_idx, page_base, k_scale,
     out = pl.pallas_call(
         functools.partial(_kernel, page_size=ps, pages_per_block=ppb,
                           block_q=bq, span=span, group=g, sm_scale=sm_scale,
-                          quantized=k_scale is not None),
+                          quantized=k_scale is not None,
+                          selected=selection is not None),
         out_shape=jax.ShapeDtypeStruct((s, nh * hd), q.dtype),
         grid_spec=grid_spec,
         # steps in order on one core: a step starts its successor's copies
@@ -327,7 +447,7 @@ def _pallas(q, pool_k, pool_v, bt_row, h, last_idx, page_base, k_scale,
             vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=interpret,
         name="paged_prefill_attention",
-    )(*prefetch, q.reshape(s, nh * hd), pool_k, pool_v)
+    )(*prefetch, *operands, pool_k, pool_v)
     return out.reshape(s, nh, hd)
 
 
@@ -383,7 +503,8 @@ def _xla(q, pool_k, pool_v, bt_row, h, last_idx, page_base, k_scale, v_scale,
 
 @jax.named_scope("pt.paged_attention")
 def paged_prefill_attention(q, pool_k, pool_v, bt_row, h, last_idx,
-                            page_base=None, k_scale=None, v_scale=None):
+                            page_base=None, k_scale=None, v_scale=None,
+                            selection=None):
     """q [s, nh, hd] at positions h .. h + s - 1 (real up to `last_idx`; h
     and last_idx traced) over pool_k / pool_v [num_pages, nkv, page_size,
     hd], which already hold the window's own keys, through the slot's block
@@ -396,12 +517,19 @@ def paged_prefill_attention(q, pool_k, pool_v, bt_row, h, last_idx,
     starts there in the pools: one layer's pages in a stack of layers viewed
     [L * num_pages, nkv, page_size, hd]. k_scale / v_scale [num_pages, nkv]:
     the pools are int8 with per (page, KV head) absmax scales, those of the
-    run alone (`paged_decode_attention`'s conventions, both)."""
+    run alone (`paged_decode_attention`'s conventions, both).
+
+    selection (`page_bits` of a per-query mask over the table's entries):
+    a key is read iff, besides, its query picked its entry. The kernel's
+    alone (ask `takes_kernel(..., selected=True)` first): the jnp form of a
+    selection is `sparse_attention`'s loop."""
     sm_scale = 1.0 / math.sqrt(q.shape[-1])
-    use_pallas, interpret = qm._mode()
-    if use_pallas and _supported(q.shape, pool_k.shape, jnp.shape(bt_row),
-                                 q.dtype.itemsize, pool_k.dtype.itemsize):
+    _, interpret = qm._mode()
+    if takes_kernel(q, pool_k, bt_row, selection is not None):
         return _pallas(q, pool_k, pool_v, bt_row, h, last_idx, page_base,
-                       k_scale, v_scale, sm_scale, interpret)
+                       k_scale, v_scale, sm_scale, interpret,
+                       selection=selection)
+    if selection is not None:
+        raise ValueError("no jnp form under a selection: ask takes_kernel")
     return _xla(q, pool_k, pool_v, bt_row, h, last_idx, page_base, k_scale,
                 v_scale, sm_scale)

@@ -29,31 +29,39 @@ and because these layers carry no rotary position a page's place in the
 table means nothing. The KV heads select apart, so the pool [num_pages, nkv,
 B, d] is viewed as [num_pages * nkv, 1, B, d] and every (row, KV head) is a
 row of its own with the table `page * nkv + head`. A prefill chunk attends
-over the slot's pages in a loop whose trip count follows the chunk's last
-position, online softmax over key tiles under the per-query block mask:
-every page up to there is visited and masked (skipping the pages no query of
-the chunk picked is later work).
+through `paged_prefill_attention`'s Pallas kernel with the block mask as its
+selection: a grid step of 128 queries (at 16 query heads a KV head) walks
+the slot's pages up to its last position, masks a score by its own query's
+bit and skips a (query block, key block) nobody of the block picked;
+accumulator, running maximum and sum stay in VMEM. Off the chip, and for a
+shape the kernel refuses, the jnp loop: online softmax over key tiles up to
+the chunk's last position. Both visit every page up to there and mask
+(fetching the picked pages alone is later work).
 
 Device scopes: `pt.sparse_select` (gathering the compressed keys, scoring,
-top-k, the compacted table), `pt.sparse_attention` (the attention itself;
-decode's kernel keeps its own `pt.paged_attention` inside it), and the
-compressed keys' writes under `pt.kv_write` with K's.
+top-k, the compacted table, a prefill window's bits by key block),
+`pt.sparse_attention` (the attention itself; the two kernels keep their own
+`pt.paged_attention` inside it), and the compressed keys' writes under
+`pt.kv_write` with K's.
 """
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 
+from paddle_tpu.kernels import paged_prefill_attention as ppa
 from paddle_tpu.kernels import quantized_matmul as qm
 
 __all__ = ["SparseConfig", "entry_of", "compressed_keys_of_window",
            "compressed_key_of_step", "write_compressed", "block_scores",
            "select_blocks", "prefill_selection", "selected_table",
-           "sparse_prefill_attention", "sparse_decode_attention"]
+           "prefill_takes_kernel", "sparse_prefill_attention",
+           "sparse_decode_attention"]
 
 _NEG = -1e30
 
@@ -297,21 +305,55 @@ def selected_table(q, kc, bt, pos, cfg):
 # the attention
 # ---------------------------------------------------------------------------
 
-def _tile_pages(pages, want=8):
-    return max(p for p in range(1, min(want, pages) + 1) if pages % p == 0)
+def prefill_takes_kernel(q, pool_k, bt_row):
+    """Whether a window of q [s, nkv, g, d] attends through the Pallas
+    kernel (`paged_prefill_attention` under a selection) or the jnp loop:
+    the mode and the shapes decide."""
+    s, nkv, g, d = q.shape
+    return ppa.takes_kernel(jax.ShapeDtypeStruct((s, nkv * g, d), q.dtype),
+                            pool_k, bt_row, selected=True)
+
+
+def sparse_prefill_attention(q, pool_k, pool_v, bt_row, sel, qpos, last_pos,
+                             cfg):
+    """q [s, nkv, g, d] at positions qpos [s] (consecutive) over the slot's
+    pages `bt_row` [P] of pool_k / pool_v [num_pages, nkv, B, d], which
+    already hold the window's own keys; sel [nkv, s, P] the per-query block
+    mask; last_pos: the window's last position (traced: no page past it is
+    read). Online softmax in float32. Returns [s, nkv, g, d].
+
+    On the chip the window goes through `paged_prefill_attention`'s kernel
+    with the selection as bits by key block (packed under
+    `pt.sparse_select`); elsewhere, and for a shape the kernel refuses
+    (`prefill_takes_kernel`), the jnp loop."""
+    if prefill_takes_kernel(q, pool_k, bt_row):
+        return _prefill_kernel(q, pool_k, pool_v, bt_row, sel, qpos[0],
+                               last_pos, cfg.block_size, qm._mode())
+    return _prefill_loop(q, pool_k, pool_v, bt_row, sel, qpos, last_pos, cfg)
+
+
+# a jit of its own: the sparse layers of one window program are traced, and
+# lowered to Mosaic, once between them (the layer loop is unrolled, and a
+# kernel's trace is a third of a second of every start on the serving host)
+@functools.partial(jax.jit, static_argnums=(7, 8))
+def _prefill_kernel(q, pool_k, pool_v, bt_row, sel, h, last_pos, block_size,
+                    mode):
+    s, nkv, g, d = q.shape
+    with jax.named_scope("pt.sparse_select"):
+        selection = ppa.page_bits(sel, g, block_size)
+    with jax.named_scope("pt.sparse_attention"), qm.fused_dispatch(*mode):
+        return ppa.paged_prefill_attention(
+            q.reshape(s, nkv * g, d), pool_k, pool_v, bt_row, h,
+            last_pos - h, selection=selection).reshape(q.shape)
 
 
 @jax.named_scope("pt.sparse_attention")
-def sparse_prefill_attention(q, pool_k, pool_v, bt_row, sel, qpos, last_pos,
-                             cfg):
-    """q [s, nkv, g, d] at positions qpos [s] over the slot's pages
-    `bt_row` [P] of pool_k / pool_v [num_pages, nkv, B, d], which already
-    hold the window's own keys; sel [nkv, s, P] the per-query block mask;
-    last_pos: the window's last position (traced: it bounds the loop over
-    key tiles). Online softmax in float32. Returns [s, nkv, g, d]."""
+def _prefill_loop(q, pool_k, pool_v, bt_row, sel, qpos, last_pos, cfg):
+    """The jnp form: a loop over key tiles whose trip count follows
+    `last_pos`; every page up to there is visited and masked."""
     s, nkv, g, d = q.shape
     B, P = cfg.block_size, bt_row.shape[0]
-    tp = _tile_pages(P)
+    tp = ppa._tile_pages(P)
     tile = tp * B
     scale = 1.0 / math.sqrt(d)
 

@@ -33,7 +33,7 @@ array a full layer each.
 
 What `serving/hybrid.HybridPath` asks of a family's functional module is
 the last section: `pools`, `slot_state`, `tables`, `check_engine`, `gauges`,
-`observe_decode`, `prefill_window`, `decode_step`.
+`observe_prefill`, `observe_decode`, `prefill_window`, `decode_step`.
 """
 
 from __future__ import annotations
@@ -46,7 +46,7 @@ import jax.numpy as jnp
 
 from paddle_tpu.kernels import gated_delta_rule as gdr
 from paddle_tpu.kernels import quantized_matmul as qm
-from paddle_tpu.kernels.sparse_attention import _tile_pages
+from paddle_tpu.kernels.paged_prefill_attention import _tile_pages
 from paddle_tpu.models import llama_functional as lf
 from paddle_tpu.models.generation import _wmm, _write_rows
 from paddle_tpu.models.hybrid_functional import _write_window_pages
@@ -299,6 +299,11 @@ def gauges(args, state, pools):
     state: 1 the Pallas pass (a TPU and a shape that fits), 0 the jnp one."""
     return {"serve.delta_step_pallas": int(gdr.step_is_pallas(
         state[0]["S"].shape, args.linear_heads))}
+
+
+def observe_prefill(args, eng, rows):
+    """No observation of its own."""
+    return {}
 
 
 def observe_decode(args, eng, active):

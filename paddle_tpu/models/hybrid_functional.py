@@ -28,7 +28,7 @@ updates each in place.
 
 What `serving/hybrid.HybridPath` asks of a family's functional module is
 the last section: `pools`, `slot_state`, `tables`, `check_engine`,
-`observe_decode`, `prefill_window`, `decode_step`.
+`observe_prefill`, `observe_decode`, `prefill_window`, `decode_step`.
 """
 
 from __future__ import annotations
@@ -241,6 +241,19 @@ def check_engine(args, eng):
 def gauges(args, state, pools):
     """No record of its own."""
     return {}
+
+
+def observe_prefill(args, eng, rows):
+    """Whether the sparse layers of a window program of `rows` rows attend
+    through the Pallas kernel (1.0) or the jnp loop (0.0: the kernels off,
+    or a shape the kernel refuses, which falls back without a word), by the
+    rule the program was traced under (`sa.prefill_takes_kernel`)."""
+    pool, nkv = eng.path.pools[0][0], args.num_kv_heads
+    q = jax.ShapeDtypeStruct(
+        (rows, nkv, args.num_heads // nkv, args.head_dim), pool.dtype)
+    table = jax.ShapeDtypeStruct((eng.pages_per_slot,), jnp.int32)
+    return {"serve.sparse_prefill_kernel_share":
+            float(bool(sa.prefill_takes_kernel(q, pool, table)))}
 
 
 def observe_decode(args, eng, active):

@@ -351,6 +351,12 @@ def gauges(args, state, pools):
                 state[0]["S"].shape, args.linear_value_heads))}
 
 
+def observe_prefill(args, eng, rows):
+    """No observation of its own (`RoutingRiders.ran` says which form the
+    window's experts took)."""
+    return {}
+
+
 def observe_decode(args, eng, active):
     """What a decode step must move of the two kinds of per-request memory,
     from the host's own numbers: every live row's state read and written

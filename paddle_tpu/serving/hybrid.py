@@ -22,8 +22,9 @@ them all), `slot_state(args, slots, dtype)` (a tree whose every leaf has the
 SLOT axis first), `tables(args, max_len)` (constants of the programs),
 `check_engine(args, eng)` (what the family needs of the engine's sizes),
 `gauges(args, state, pools)` (records of how its step programs are built for
-these arrays), `observe_decode(args, eng, active)` (its own per-step
-observations) and the two step functions `prefill_window` / `decode_step`.
+these arrays), `observe_prefill(args, eng, rows)` / `observe_decode(args,
+eng, active)` (its own observations of a window / a step, from the host's
+numbers) and the two step functions `prefill_window` / `decode_step`.
 
 A ROUTED family's step functions return two things more, and its module
 states `RIDERS`: `decode_step` the routing's counts (int32 `[RIDERS]`) and
@@ -288,7 +289,11 @@ class HybridPath:
             jnp.float32(req.temperature),
             jnp.float32(req.top_p), jnp.int32(req.top_k),
             jnp.asarray([req.seed], jnp.int32))
-        self.riders.ran(np.shape(ids)[-1])
+        rows = np.shape(ids)[-1]
+        self.riders.ran(rows)
+        for name, value in self.family.observe_prefill(
+                self.eng.args, self.eng, rows).items():
+            self.eng.metrics.observe(name, value)
         self.riders.window(req, slot, start, last_idx + 1, picks)
         return first
 

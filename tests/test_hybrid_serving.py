@@ -447,6 +447,49 @@ def test_engine_serves_the_references_tokens(fam, params, args, chunk):
     assert obs["gauges"]["kv_pool_bytes"]["value"] > 0
 
 
+# a head of 128 lanes: what the Pallas prefill kernel takes (in the interpreter
+# here), at the tiny preset's every other size
+ARCH_LANES = dict(ARCH, head_dim=128)
+
+
+@pytest.fixture(scope="module")
+def lanes(fam):
+    from benchmarks.harness import weights
+
+    return (weights.make_params(fam, ARCH_LANES, 11, jnp.float32),
+            fam.serve_args(ARCH_LANES))
+
+
+def _served(params, args, n, kernels):
+    """One prompt of n tokens through a fresh engine, the Pallas kernels on
+    (interpreted) or off: its tokens and the run's observations."""
+    with qm.fused_dispatch(kernels, interpret=True):
+        eng = _engine(params, args)
+        req, = eng.serve([Request(_ids(n, n), 6)])
+    return list(req.token_ids), eng.metrics.summary()["observations"]
+
+
+@pytest.mark.parametrize("n", [100, 20])
+def test_the_prefill_kernel_serves_the_loops_tokens(lanes, n):
+    """A prompt past `dense_len` (32) and one within it: the same tokens
+    whether a sparse layer's windows attend through the kernel or the jnp
+    loop, and every window of the run says which it was."""
+    want, loop = _served(*lanes, n, kernels=False)
+    got, kernel = _served(*lanes, n, kernels=True)
+    assert got == want
+    for obs, share in ((loop, 0.0), (kernel, 1.0)):
+        said = obs["serve.sparse_prefill_kernel_share"]
+        # 16-token chunks
+        assert (said["mean"], said["count"]) == (share, -(-n // 16))
+
+
+def test_a_shape_the_prefill_kernel_refuses_says_so(params, args):
+    """Heads of 16 fill no lane block: the windows fall back to the loop
+    with the kernels on, and `kernel_share` reads 0."""
+    _, obs = _served(params, args, 45, kernels=True)
+    assert obs["serve.sparse_prefill_kernel_share"]["mean"] == 0.0
+
+
 def test_one_program_serves_a_short_and_a_long_context(params, args):
     eng = _engine(params, args)
     eng.serve([Request(_ids(n, n), 3) for n in (8, 16, 17)])

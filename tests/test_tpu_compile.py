@@ -202,6 +202,42 @@ def test_paged_prefill_kernel_compiles_for_v5e(one_chip, name):
     assert "paged_prefill_attention" in text
 
 
+# name -> window: a sparse layer's prefill window of the sala cell (2 KV heads
+# x 16 query heads a group x 128, 776 pages of 64 a slot, 8,192 in the pool)
+_SELECTED_WINDOWS = {"minicpm_sala_chunk": 4096,
+                     "minicpm_sala_smallest_bucket": 64}
+
+
+@pytest.mark.parametrize("name", list(_SELECTED_WINDOWS))
+def test_prefill_kernel_under_a_selection_compiles_for_v5e(one_chip, name):
+    """The (query block, key block) flags in SMEM (25 KB at the chunk), the
+    rows' picks in VMEM, one mask for the group's 16 heads: with the bits'
+    packing in front, as `sparse_prefill_attention` calls it."""
+    from paddle_tpu.kernels import paged_prefill_attention as ppa
+
+    s, nkv, g, hd, ps, P, NP = _SELECTED_WINDOWS[name], 2, 16, 128, 64, 776, \
+        8192
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    pool = sds((NP, nkv, ps, hd), jnp.bfloat16)
+    args = [sds((s, nkv * g, hd), jnp.bfloat16), pool, pool,
+            sds((P,), jnp.int32), sds((), jnp.int32), sds((), jnp.int32),
+            sds((nkv, s, P), jnp.bool_)]
+    assert ppa._supported(args[0].shape, pool.shape, (P,), 2, 2,
+                          selected=True)
+
+    def call(q, k, v, bt, h, last, sel):
+        return ppa._pallas(q, k, v, bt, h, last, None, None, None,
+                           hd ** -0.5, False,
+                           selection=ppa.page_bits(sel, g, ps))
+
+    text = jax.jit(call).lower(*args).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "paged_prefill_attention" in text
+
+
 @pytest.mark.parametrize("kind", ["bfloat16", "int8"])
 def test_prefill_program_writes_its_pages_and_holds_no_stripe(one_chip, kind):
     """The dense prefill program at the serving cell's widths (a 512-token
