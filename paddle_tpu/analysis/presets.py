@@ -85,7 +85,7 @@ BYTE_CEILINGS = {
     "latent_prefill": 152 * 1024,
     "latent_decode": 152 * 1024,
     "latent_page_copy": 152 * 1024,
-    # the gated delta-rule hybrid family (through the one `HybridPath`):
+    # the gated delta-rule hybrid family (through the one `FamilyPath`):
     # the largest buffer is a full layer's page pool (9,216 B at the toy
     # size), which every program carries through and none copies; the
     # state's mover tops out at the snapshots' matrix states (8,192 B)
@@ -229,7 +229,7 @@ DELTA_SERVING = ("delta_prefill", "delta_decode", "delta_page_copy",
 
 def audit_delta_serving():
     """The gated delta-rule hybrid family's programs through the one
-    `HybridPath`: the pools and the tree of per-slot state donated and
+    `FamilyPath`: the pools and the tree of per-slot state donated and
     aliased, no collective (the family has no mesh), no host callback, a
     byte ceiling, and logits for the head's rows alone."""
     progs = programs.delta_serving_programs()

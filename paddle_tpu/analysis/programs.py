@@ -307,13 +307,14 @@ def serving_programs(tp=2, num_heads=None):
 
 @functools.lru_cache(maxsize=None)
 def latent_serving_programs():
-    """The latent-attention expert family's step programs
-    (`serving/latent.py`), captured as `serving_programs` captures the
+    """The latent-attention expert family's step programs through the one
+    `serving/family.FamilyPath`, captured as `serving_programs` captures the
     dense ones: a tiny stack (one dense leading layer, two expert layers,
     one group of eight held) serves two requests, the second a prefix hit
     that ends mid-page (so the page copy runs), and the recorded callables
     are re-traced. Single chip: the family has no mesh. The pool is each
-    program's one donated argument."""
+    program's one donated array (beside it the state tree, which is
+    empty)."""
     from paddle_tpu.models import latent_moe_functional as lm
     from paddle_tpu.serving import PagedEngine, Request
 
@@ -354,7 +355,7 @@ def latent_serving_programs():
                         ("latent_decode", path._decode)):
         recs[name] = table[False] = _Recorder(table[False])
     recs["latent_page_copy"] = path._copy = _Recorder(path._copy)
-    donated = {"latent_prefill": (6,), "latent_decode": (5,),
+    donated = {"latent_prefill": (8, 9), "latent_decode": (6, 7),
                "latent_page_copy": (0,)}
     base = rng.integers(1, 96, size=12).astype(np.int32)
     eng.serve([Request(base, max_new_tokens=3)])
@@ -373,7 +374,7 @@ def latent_serving_programs():
 @functools.lru_cache(maxsize=None)
 def delta_serving_programs():
     """The gated delta-rule hybrid family's step programs through the one
-    `serving/hybrid.HybridPath`, captured as `latent_serving_programs`
+    `serving/family.FamilyPath`, captured as `latent_serving_programs`
     captures the latent ones: a tiny stack (one period: three linear layers
     and a full one) serves two requests, the second a prefix hit that ends
     mid-page (so the page copy and the snapshot's load run), and the

@@ -31,9 +31,8 @@ rows fill whole lanes: `gated_delta_rule.pack_state`; and the last K - 1
 rows of the convolution's input, in the model's type). The pools are `(pk, pv)`, one `[num_pages, heads, page, d]`
 array a full layer each.
 
-What `serving/hybrid.HybridPath` asks of a family's functional module is
-the last section: `pools`, `slot_state`, `tables`, `check_engine`, `gauges`,
-`observe_prefill`, `observe_decode`, `prefill_window`, `decode_step`.
+What `serving/family.FamilyPath` asks of a family's functional module
+(`models/family_protocol.py`) is the last section.
 """
 
 from __future__ import annotations
@@ -48,11 +47,13 @@ from paddle_tpu.kernels import gated_delta_rule as gdr
 from paddle_tpu.kernels import quantized_matmul as qm
 from paddle_tpu.kernels.paged_prefill_attention import _tile_pages
 from paddle_tpu.models import llama_functional as lf
+from paddle_tpu.models.family_protocol import StepRiders, _move_rows
 from paddle_tpu.models.generation import _wmm, _write_rows
-from paddle_tpu.models.hybrid_functional import _write_window_pages
+from paddle_tpu.models.hybrid_functional import (UNSUPPORTED,
+                                                 _write_window_pages)
 
-__all__ = ["GatedDeltaArgs", "LINEAR", "FULL", "prefill_window",
-           "decode_step"]
+__all__ = ["GatedDeltaArgs", "LINEAR", "FULL", "UNSUPPORTED",
+           "prefill_window", "decode_step"]
 
 LINEAR, FULL = "linear_attention", "full_attention"
 _NEG = -1e30
@@ -262,7 +263,8 @@ def _full_decode(lp, x, pk, pv, bt, pos, args):
 
 
 # ---------------------------------------------------------------------------
-# what `serving/hybrid.HybridPath` asks of a family
+# what `serving/family.FamilyPath` asks of a family; `UNSUPPORTED`, the
+# refusals of a recurrent state, is `hybrid_functional`'s
 # ---------------------------------------------------------------------------
 
 def pools(args, num_pages, page_size, dtype):
@@ -272,6 +274,10 @@ def pools(args, num_pages, page_size, dtype):
     n = len(args.layers_of(FULL))
     return (tuple(jnp.zeros(shape, dtype) for _ in range(n)),
             tuple(jnp.zeros(shape, dtype) for _ in range(n)))
+
+
+def copy_page(pools, src, dst, args):
+    return _move_rows(pools, pools, dst, src)
 
 
 def slot_state(args, slots, dtype):
@@ -301,6 +307,11 @@ def gauges(args, state, pools):
         state[0]["S"].shape, args.linear_heads))}
 
 
+def riders(args):
+    """No counts, no selection kept."""
+    return 0, 0
+
+
 def observe_prefill(args, eng, rows):
     """No observation of its own."""
     return {}
@@ -313,13 +324,9 @@ def observe_decode(args, eng, active):
 
 
 def prefill_window(params, layer_ids, ids, h, last_idx, bt_row, new_pages,
-                   pools, state, tables, args):
-    """One prefill window of one slot: ids [s] at positions h .. h + s - 1,
-    real up to `last_idx`; bt_row [P] the slot's block table; new_pages the
-    pages the window writes, from the one that holds h on; `state` the
-    SLOT's own entries (no slot axis), already zero where h == 0.
-    layer_ids: `arange(layers)` as an operand (see `_layer`). Returns
-    (logits [vocab] at last_idx, pools, the slot's state)."""
+                   pools, state, tables, args, record=None):
+    """One prefill window of one slot (`models/family_protocol.py`); `state`
+    the SLOT's own entries. Nothing rides."""
     s = ids.shape[0]
     idx = jnp.arange(s, dtype=jnp.int32)
     pos, valid = h + idx, idx <= last_idx
@@ -338,15 +345,13 @@ def prefill_window(params, layer_ids, ids, h, last_idx, bt_row, new_pages,
                 _layer(params, kind, layer_ids[j]), x, pk[j], pv[j], h,
                 last_idx, pos, bt_row, new_pages, args)
     logits = _head(params, x[last_idx][None], args)[0]
-    return logits, (tuple(pk), tuple(pv)), tuple(state)
+    return logits, (tuple(pk), tuple(pv)), tuple(state), StepRiders()
 
 
 def decode_step(params, layer_ids, tokens, bt, pos, live, pools, state,
-                tables, args):
-    """One token a slot: tokens [b] at positions pos [b] through block
-    tables bt [b, P]; live [b] marks the rows that decode (the others keep
-    both of their states and write to the null page). Returns (logits [b,
-    vocab], pools, state)."""
+                tables, args, record=None):
+    """One token a slot (`models/family_protocol.py`): a row that is not
+    live keeps both of its states. Nothing rides."""
     x = jnp.take(params["embedding"], tokens, axis=0)
     pk, pv, state = list(pools[0]), list(pools[1]), list(state)
     n_full = n_lin = 0
@@ -360,4 +365,5 @@ def decode_step(params, layer_ids, tokens, bt, pos, live, pools, state,
             x, pk[j], pv[j] = _full_decode(
                 _layer(params, kind, layer_ids[j]), x, pk[j], pv[j], bt,
                 pos, args)
-    return _head(params, x, args), (tuple(pk), tuple(pv)), tuple(state)
+    return _head(params, x, args), (tuple(pk), tuple(pv)), tuple(state), \
+        StepRiders()

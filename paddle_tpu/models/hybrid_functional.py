@@ -26,9 +26,8 @@ float32 array a lightning layer; the pools `(pk, pv, kc)`: `pk`, `pv`
 d]`, one a sparse layer. All are tuples of per-layer arrays so that a step
 updates each in place.
 
-What `serving/hybrid.HybridPath` asks of a family's functional module is
-the last section: `pools`, `slot_state`, `tables`, `check_engine`,
-`observe_prefill`, `observe_decode`, `prefill_window`, `decode_step`.
+What `serving/family.FamilyPath` asks of a family's functional module
+(`models/family_protocol.py`) is the last section.
 """
 
 from __future__ import annotations
@@ -42,6 +41,7 @@ import numpy as np
 from paddle_tpu.kernels import lightning_attention as la
 from paddle_tpu.kernels import sparse_attention as sa
 from paddle_tpu.models import llama_functional as lf
+from paddle_tpu.models.family_protocol import StepRiders, _move_rows
 from paddle_tpu.models.generation import _wmm, _write_rows
 
 __all__ = ["HybridArgs", "SPARSE", "LIGHTNING", "prefill_window",
@@ -201,8 +201,21 @@ def _sparse_window(lp, x, pk, pv, kc, h, last_idx, pos, bt_row, new_pages,
 
 
 # ---------------------------------------------------------------------------
-# what `serving/hybrid.HybridPath` asks of a family
+# what `serving/family.FamilyPath` asks of a family
 # ---------------------------------------------------------------------------
+
+# the refusals of every family whose slots keep a recurrent state
+UNSUPPORTED = {
+    "model": "a hybrid model",
+    "mesh=": "the recurrent state has no tensor-parallel placement yet",
+    "kv_dtype='int8'": "the hybrid families' pools hold unquantized keys (a "
+    "selector's compressed keys are means of them) and have no int8 write "
+    "path",
+    "draft_params=": "a rejected draft token cannot be taken back out of a "
+    "recurrent state",
+    "hand-off": "a `KVHandoff` ships pages, and the linear layers' "
+    "recurrent state is in none of them"}
+
 
 def pools(args, num_pages, page_size, dtype):
     """(pk, pv, kc): a tuple of one array a sparse layer each; the page
@@ -214,6 +227,10 @@ def pools(args, num_pages, page_size, dtype):
             tuple(jnp.zeros(page, dtype) for _ in range(n)),
             tuple(jnp.zeros((num_pages, nkv, cfg.per, d), dtype)
                   for _ in range(n)))
+
+
+def copy_page(pools, src, dst, args):
+    return _move_rows(pools, pools, dst, src)
 
 
 def slot_state(args, slots, dtype):
@@ -243,6 +260,11 @@ def gauges(args, state, pools):
     return {}
 
 
+def riders(args):
+    """No counts, no selection kept."""
+    return 0, 0
+
+
 def observe_prefill(args, eng, rows):
     """Whether the sparse layers of a window program of `rows` rows attend
     through the Pallas kernel (1.0) or the jnp loop (0.0: the kernels off,
@@ -267,13 +289,9 @@ def observe_decode(args, eng, active):
 
 
 def prefill_window(params, layer_ids, ids, h, last_idx, bt_row, new_pages,
-                   pools, state, tables, args):
-    """One prefill window of one slot: ids [s] at positions h .. h + s - 1,
-    real up to `last_idx`; bt_row [P] the slot's block table; new_pages the
-    pages the window writes, from the one that holds h on; `state` the
-    SLOT's own recurrent states (no slot axis), already zero where h == 0.
-    layer_ids: `arange(layers)` as an operand (see `_layer`). Returns
-    (logits [vocab] at last_idx, pools, the slot's state)."""
+                   pools, state, tables, args, record=None):
+    """One prefill window of one slot (`models/family_protocol.py`); `state`
+    the SLOT's own recurrent states. Nothing rides."""
     s = ids.shape[0]
     idx = jnp.arange(s, dtype=jnp.int32)
     pos, valid = h + idx, idx <= last_idx
@@ -293,7 +311,8 @@ def prefill_window(params, layer_ids, ids, h, last_idx, bt_row, new_pages,
                 lp, x, pk[j], pv[j], kc[j], h, last_idx, pos, bt_row,
                 new_pages, args)
     logits = _head(params, x[last_idx][None], args)[0]
-    return logits, (tuple(pk), tuple(pv), tuple(kc)), tuple(state)
+    return logits, (tuple(pk), tuple(pv), tuple(kc)), tuple(state), \
+        StepRiders()
 
 
 # ---------------------------------------------------------------------------
@@ -336,11 +355,8 @@ def _sparse_decode(lp, x, pk, pv, kc, bt, pos, args):
 
 
 def decode_step(params, layer_ids, tokens, bt, pos, live, pools, state,
-                tables, args):
-    """One token a slot: tokens [b] at positions pos [b] through block
-    tables bt [b, P]; live [b] marks the rows that decode (the others keep
-    their recurrent state and write to the null page). Returns (logits [b,
-    vocab], pools, state)."""
+                tables, args, record=None):
+    """One token a slot (`models/family_protocol.py`). Nothing rides."""
     x = _embed(params, tokens, args)
     (pk, pv, kc), (cos, sin) = (list(p) for p in pools), tables
     state = list(state)
@@ -356,4 +372,4 @@ def decode_step(params, layer_ids, tokens, bt, pos, live, pools, state,
             x, pk[j], pv[j], kc[j] = _sparse_decode(
                 lp, x, pk[j], pv[j], kc[j], bt, pos, args)
     return _head(params, x, args), (tuple(pk), tuple(pv), tuple(kc)), \
-        tuple(state)
+        tuple(state), StepRiders()

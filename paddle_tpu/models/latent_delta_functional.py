@@ -44,9 +44,8 @@ Per-request state beside the pages, a tuple of one entry a delta layer:
 channels]}` (`gated_delta_rule.pack_state`'s layout). The pools are a tuple
 of one `[num_pages, page, row_width]` latent pool a latent layer.
 
-What `serving/hybrid.HybridPath` asks of a family's functional module is the
-last section; this family's step functions also return the routing's counts
-and picks (`RIDERS`).
+What `serving/family.FamilyPath` asks of a family's functional module
+(`models/family_protocol.py`) is the last section.
 """
 
 from __future__ import annotations
@@ -59,14 +58,15 @@ import jax.numpy as jnp
 from paddle_tpu.kernels import gated_delta_rule as gdr
 from paddle_tpu.models import latent_moe_functional as lm
 from paddle_tpu.models import llama_functional as lf
+from paddle_tpu.models.family_protocol import _move_rows
 from paddle_tpu.models.generation import _wmm
+from paddle_tpu.models.hybrid_functional import UNSUPPORTED
 
-__all__ = ["LatentDeltaMoEArgs", "DELTA", "LATENT", "RIDERS",
+__all__ = ["LatentDeltaMoEArgs", "DELTA", "LATENT", "UNSUPPORTED",
            "prefill_window", "decode_step"]
 
 DELTA, LATENT = "delta", "latent"
 DENSE, EXPERTS = "dense", "experts"
-RIDERS = 4      # counts a decode step appends to its tokens (`lm._routed_experts`)
 _NORMS = ("ln1", "ln1_post", "ln2", "ln2_post", "q_norm", "kv_norm")
 
 
@@ -110,7 +110,7 @@ class LatentDeltaMoEArgs(NamedTuple):
     swiglu_limit: float | None
     rms_eps: float
     # both step programs also return the experts every token picked, for
-    # whoever judges the served tokens (`serving/latent.RoutingTrace`)
+    # whoever judges the served tokens (`serving/routing.RoutingTrace`)
     record_routing: bool = False
 
     # what `latent_moe_functional` asks of a description and this family
@@ -303,7 +303,8 @@ def _layers(params, layer_ids, x, mix, live, args):
 
 
 # ---------------------------------------------------------------------------
-# what `serving/hybrid.HybridPath` asks of a family
+# what `serving/family.FamilyPath` asks of a family; `UNSUPPORTED`, the
+# refusals of a recurrent state, is `hybrid_functional`'s
 # ---------------------------------------------------------------------------
 
 def pools(args, num_pages, page_size, dtype):
@@ -311,6 +312,10 @@ def pools(args, num_pages, page_size, dtype):
     leaf."""
     return tuple(jnp.zeros((num_pages, page_size, args.row_width), dtype)
                  for _ in args.layers_of(LATENT))
+
+
+def copy_page(pools, src, dst, args):
+    return _move_rows(pools, pools, dst, src)
 
 
 def slot_state(args, slots, dtype):
@@ -351,35 +356,37 @@ def gauges(args, state, pools):
                 state[0]["S"].shape, args.linear_value_heads))}
 
 
+def riders(args):
+    """A decode step's four counts (`lm._routed_experts`); no selector."""
+    return 4, 0
+
+
 def observe_prefill(args, eng, rows):
-    """No observation of its own (`RoutingRiders.ran` says which form the
-    window's experts took)."""
-    return {}
+    """Which form the window's experts took (`lm.observe_prefill`)."""
+    return lm.observe_prefill(args, eng, rows)
 
 
 def observe_decode(args, eng, active):
-    """What a decode step must move of the two kinds of per-request memory,
-    from the host's own numbers: every live row's state read and written
-    once, and every live row's cached tokens' rows in every latent layer
-    (the token the step writes among them)."""
+    """Which form the step's experts took, and what a decode step must move
+    of the two kinds of per-request memory, from the host's own numbers:
+    every live row's state read and written once, and every live row's
+    cached tokens' rows in every latent layer (the token the step writes
+    among them)."""
     path = eng.path
     state = _nbytes(path.state) // eng.max_slots
     row = args.row_width * path.pools[0].dtype.itemsize
     cached = int(eng._npos[active].sum()) + len(active)
-    return {"serve.state_bytes_step": 2 * len(active) * state,
+    return {**lm.observe_decode(args, eng, active),
+            "serve.state_bytes_step": 2 * len(active) * state,
             "serve.cache_bytes_step":
                 cached * row * len(args.layers_of(LATENT))}
 
 
 def prefill_window(params, layer_ids, ids, h, last_idx, bt_row, new_pages,
-                   pools, state, tables, args):
-    """One prefill window of one slot: ids [s] at positions h .. h + s - 1,
-    real up to `last_idx`; bt_row [P] the slot's block table; new_pages the
-    pages the window writes, from the one that holds h on; `state` the
-    SLOT's own entries (no slot axis), already zero where h == 0.
-    layer_ids: `arange(layers)` as an operand (see `_layer`). Returns
-    (logits [vocab] at last_idx, pools, the slot's state, picks [expert
-    layers, s, experts a token])."""
+                   pools, state, tables, args, record=None):
+    """One prefill window of one slot (`models/family_protocol.py`); `state`
+    the SLOT's own entries. Rides: picks [expert layers, s, experts a
+    token] where the description records the routing."""
     s = ids.shape[0]
     valid = jnp.arange(s, dtype=jnp.int32) <= last_idx
     cos, sin = tables
@@ -398,17 +405,16 @@ def prefill_window(params, layer_ids, ids, h, last_idx, bt_row, new_pages,
     x = jnp.take(params["embedding"], ids, axis=0)
     x, _, picks = _layers(params, layer_ids, x, mix, valid, args)
     logits = _head(params, x[last_idx][None], args)[0]
-    return logits, tuple(pools), tuple(state), picks
+    return logits, tuple(pools), tuple(state), lm._riders(
+        args, None, picks, None)
 
 
 def decode_step(params, layer_ids, tokens, bt, pos, live, pools, state,
-                tables, args):
-    """One token a slot: tokens [b] at positions pos [b] through block
-    tables bt [b, P]; live [b] marks the rows that decode (the others keep
-    their state, write to the null page and count for nothing). Returns
-    (logits [b, vocab], pools, state, counts int32 [4] summed over the
-    expert layers (`lm._routed_experts`), picks [expert layers, b, experts
-    a token])."""
+                tables, args, record=None):
+    """One token a slot (`models/family_protocol.py`). Rides: counts int32
+    [4] summed over the expert layers (`lm._routed_experts`); picks [expert
+    layers, b, experts a token] where the description records the
+    routing."""
     cos, sin = tables
     pools, state = list(pools), list(state)
 
@@ -422,4 +428,5 @@ def decode_step(params, layer_ids, tokens, bt, pos, live, pools, state,
 
     x = jnp.take(params["embedding"], tokens, axis=0)
     x, counts, picks = _layers(params, layer_ids, x, mix, live, args)
-    return _head(params, x, args), tuple(pools), tuple(state), counts, picks
+    return _head(params, x, args), tuple(pools), tuple(state), lm._riders(
+        args, counts, picks, None)

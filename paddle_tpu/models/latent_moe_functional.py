@@ -83,7 +83,11 @@ stacked group `dense_layers/*` holds the leading layers (`w_gate w_up
 w_down` in the experts' place). The page pool `[layers * num_pages, page,
 row_width]` (a row: kv_rank + rope_dim values, padded to whole lane tiles)
 is the layer scans' carry: layer l's pages are the run
-that starts at `l * num_pages`, written and read where they lie.
+that starts at `l * num_pages`, written and read where they lie. A request
+is its pages and nothing else: the state tree is empty.
+
+What `serving/family.FamilyPath` asks of a family's functional module
+(`models/family_protocol.py`) is the last section.
 """
 
 from __future__ import annotations
@@ -98,6 +102,7 @@ import numpy as np
 from paddle_tpu.kernels import grouped_matmul as gm
 from paddle_tpu.kernels import latent_attention as la
 from paddle_tpu.models import llama_functional as lf
+from paddle_tpu.models.family_protocol import StepRiders
 from paddle_tpu.models.generation import _wmm, _write_rows
 from paddle_tpu.models.hybrid_functional import _write_window_pages
 
@@ -156,7 +161,7 @@ class LatentMoEArgs(NamedTuple):
     rms_eps: float
     yarn: YarnConfig | None     # None: plain rotary positions
     # the serving path keeps the experts every token picked, for whoever
-    # judges the served tokens (`serving/latent.py`, RoutingTrace)
+    # judges the served tokens (`serving/routing.RoutingTrace`)
     record_routing: bool = False
     indexer: IndexerConfig | None = None
     # "softmax": group-limited greedy over softmax scores; "sigmoid": sigmoid
@@ -164,7 +169,7 @@ class LatentMoEArgs(NamedTuple):
     scoring: str = "softmax"
     norm_topk: bool = False     # a token's picked weights sum to one
     # both step programs also return the positions a few queries selected
-    # (`serving/latent.py`, SelectionTrace)
+    # (`serving/routing.RoutingTrace.selections`)
     record_selection: bool = False
     # every SwiGLU's gate capped above and its up-projection clipped both
     # ways at this value before they meet (`_gate`, `_up`); None: neither
@@ -739,56 +744,142 @@ def _head(params, x, args):
     return _wmm(x, params["lm_head"]).astype(jnp.float32)
 
 
-def prefill_window(params, ids, h, last_idx, bt_row, new_pages, cache, cos,
-                   sin, args, record=None):
-    """One prefill window of one slot: ids [s] at positions h .. h + s - 1,
-    real up to `last_idx`; bt_row [P] the slot's block table (a layer's
-    page numbers); new_pages [P] the pages the window writes, from the one
-    that holds h on (unused entries the null page); `cache` the latent pool
-    or, behind a selector, (latent pool, index pool). Returns (logits
-    [vocab] at last_idx, cache, picks [expert layers, s, experts a token]:
-    the experts each token picked) and, where the description records the
-    selection, that of the SELECT_ROWS queries from window row `record` on,
-    as packed bits [layers, SELECT_ROWS, table positions / 8] (`la.packed`;
-    a query at position t < index_topk selects all t + 1)."""
+# ---------------------------------------------------------------------------
+# what `serving/family.FamilyPath` asks of a family
+# ---------------------------------------------------------------------------
+
+UNSUPPORTED = {
+    "model": "a latent-attention expert model",
+    "mesh=": "the experts held and the latent pool have no tensor-parallel "
+    "placement yet",
+    "kv_dtype='int8'": "the latent rows are normed activations that every "
+    "head reads; no int8 latent pool exists yet",
+    "draft_params=": "no verify program over the latent pool exists yet",
+    "hand-off": "a `KVHandoff` ships a K and a V pool, and this family has "
+    "one pool of latent rows"}
+
+
+def pools(args, num_pages, page_size, dtype):
+    """The latent pool `[layers * num_pages, page, row_width]` or, behind a
+    selector, (latent pool, index pool `[.., index width]`) under the one
+    block table: carried, donated, written and copied on write as one."""
+    pool = jnp.zeros((args.num_layers * num_pages, page_size,
+                      args.row_width), dtype)
+    if not args.indexer:
+        return pool
+    return pool, jnp.zeros((args.num_layers * num_pages, page_size,
+                            args.indexer.dim), dtype)
+
+
+def copy_page(pools, src, dst, args):
+    """Page `src` onto page `dst` in every layer's run of every pool."""
+    def one(pool):
+        num_pages = pool.shape[0] // args.num_layers
+        view = pool.reshape((args.num_layers, num_pages) + pool.shape[1:])
+        view = jax.lax.dynamic_update_slice_in_dim(
+            view, jax.lax.dynamic_slice_in_dim(view, src, 1, axis=1), dst,
+            axis=1)
+        return view.reshape(pool.shape)
+
+    return jax.tree.map(one, pools)
+
+
+def slot_state(args, slots, dtype):
+    """A request keeps nothing beside its pages."""
+    return ()
+
+
+def tables(args, max_len):
+    """(cos, sin). 2 * max_len: a window's padding may pass max_len before
+    it is cut."""
+    return rope_tables(2 * max_len, args)
+
+
+def check_engine(args, eng):
+    """Nothing of the engine's sizes is this family's to constrain."""
+
+
+def gauges(args, state, pools):
+    if not args.indexer:
+        return {}
+    return {"index_pool_bytes": pools[1].size * pools[1].dtype.itemsize}
+
+
+def riders(args):
+    """A decode step's four counts (`_routed_experts`) and, behind a
+    selector, the keys selected and visible; SELECT_ROWS queries of a window
+    where the description records the selection."""
+    return (6 if args.indexer else 4,
+            SELECT_ROWS if args.record_selection else 0)
+
+
+def observe_prefill(args, eng, rows):
+    """`serve.expert_fused_share`, once a step program of `rows` rows: 1.0
+    where its expert layers are the fused pass over the hit experts'
+    weights, 0.0 where they are grouped matmuls, by the rule the program
+    was traced under (`experts_fused`). Over a deployment's windows and
+    decode steps the mean is the decode steps' share of the step programs."""
+    dtype = jax.tree_util.tree_leaves(eng.params["embedding"])[0].dtype
+    return {"serve.expert_fused_share":
+            float(experts_fused(rows, args, dtype))}
+
+
+def observe_decode(args, eng, active):
+    return observe_prefill(args, eng, eng.max_slots)
+
+
+def _riders(args, counts, picks, selected):
+    """What rides a step, each where the description keeps it."""
+    return StepRiders(counts, picks if args.record_routing else None,
+                      selected if args.record_selection else None)
+
+
+def prefill_window(params, layer_ids, ids, h, last_idx, bt_row, new_pages,
+                   pools, state, tables, args, record=None):
+    """One prefill window of one slot (`models/family_protocol.py`). Rides:
+    picks [expert layers, s, experts a token] where the description records
+    the routing; where it records the selection, that of the SELECT_ROWS
+    queries from window row `record` on, as packed bits [layers,
+    SELECT_ROWS, table positions / 8] (`la.packed`; a query at position t <
+    index_topk selects all t + 1)."""
     s = ids.shape[0]
     live = jnp.arange(s, dtype=jnp.int32) <= last_idx
+    cos, sin = tables
 
     def attention(lp, x, cache, base):
         return _window_attention(lp, x, cache, h, last_idx, bt_row,
                                  new_pages, cos, sin, base, record, args)
 
     x = jnp.take(params["embedding"], ids, axis=0)
-    x, cache, _, picks, selected = _stack(params, x, cache, attention, live,
+    x, pools, _, picks, selected = _stack(params, x, pools, attention, live,
                                           args)
-    out = _head(params, x[last_idx][None], args)[0], cache, picks
-    return out + (selected,) if args.record_selection else out
+    return (_head(params, x[last_idx][None], args)[0], pools, state,
+            _riders(args, None, picks, selected))
 
 
-def decode_step(params, tokens, bt, pos, live, cache, cos, sin, args,
-                record=None):
-    """One token a slot: tokens [b] at positions pos [b] through block
-    tables bt [b, P]; live [b] marks the rows that decode (the others write
-    to the null page and count for nothing). Returns (logits [b, vocab],
-    cache, counts int32 [4] summed over the expert layers: tokens at the
-    busiest held expert, picks on held experts, picks in all, held experts
-    with a token; behind a selector two more, over the live rows: the keys
-    they selected and the keys they could see; picks [expert layers, b,
-    experts a token]) and, where the description records the selection,
-    row `record`'s as packed bits [layers, table positions / 8]
+def decode_step(params, layer_ids, tokens, bt, pos, live, pools, state,
+                tables, args, record=None):
+    """One token a slot (`models/family_protocol.py`). Rides: counts int32
+    [4] summed over the expert layers (tokens at the busiest held expert,
+    picks on held experts, picks in all, held experts with a token; behind
+    a selector two more, over the live rows: the keys they selected and the
+    keys they could see); picks [expert layers, b, experts a token]; row
+    `record`'s selection as packed bits [layers, table positions / 8]
     (`la.packed`)."""
+    cos, sin = tables
+
     def attention(lp, x, cache, base):
         return _decode_attention(lp, x, cache, bt, pos, cos, sin, base,
                                  record, args)
 
     x = jnp.take(params["embedding"], tokens, axis=0)
-    x, cache, counts, picks, selected = _stack(params, x, cache, attention,
+    x, pools, counts, picks, selected = _stack(params, x, pools, attention,
                                                live, args)
     if args.indexer:
         K = _topk(args, bt.shape[1]
-                  * jax.tree_util.tree_leaves(cache)[0].shape[1])
+                  * jax.tree_util.tree_leaves(pools)[0].shape[1])
         seen = jnp.where(live, pos + 1, 0)
         counts = jnp.concatenate([counts, jnp.stack([
             jnp.sum(jnp.minimum(seen, K)), jnp.sum(seen)])])
-    out = _head(params, x, args), cache, counts, picks
-    return out + (selected,) if args.record_selection else out
+    return _head(params, x, args), pools, state, _riders(
+        args, counts, picks, selected)

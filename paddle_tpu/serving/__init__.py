@@ -17,8 +17,12 @@
     device is one object a model family, chosen by the type of `args` —
     dense.py for a `LlamaArgs` (the page pools in the model dtype or
     `kv_dtype="int8"`, TENSOR PARALLELISM over `mesh=` with tp.py's
-    placement, the disaggregated page mover), hybrid.py for a `HybridArgs`
-    (pages for the sparse layers, a recurrent state a slot, snapshots).
+    placement, the disaggregated page mover), family.py for every other
+    family: ONE path over a tree of page pools and a tree of per-slot state
+    (a recurrent state, snapshots of it; empty where a request is its pages
+    alone), which the family's functional module fills
+    (`models/family_protocol.py`); routing.py is the host half of what an
+    expert stack's steps return beside their tokens.
   - SPECULATIVE DECODING (spec_decode.py): `draft_params=` / `draft_args=`
     propose `spec_tokens` tokens in one traced scan and verify the window in
     one batched paged forward; the block table rolls back to what was

@@ -13,8 +13,9 @@ scheduler:
     mapping sequence positions to pages. The pools, their layout, whatever
     else a request keeps on the device and the step programs over them are
     `self.path`, one object a family (`serving/paths.py` has the table and
-    the interface: `serving/dense.py` for a `LlamaArgs`, `serving/hybrid.py`
-    for a `HybridArgs`). This file holds no device array and names no family;
+    the interface: `serving/dense.py` for a `LlamaArgs`, `serving/family.py`
+    for every other family). This file holds no device array and names no
+    family;
   - PREFIX CACHE: every prefilled prompt is registered in
     `BlockAllocator`'s radix tree and REF'd by later requests sharing
     the prefix at TOKEN granularity (refcounted, COW-protected; a

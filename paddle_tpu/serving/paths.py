@@ -42,27 +42,34 @@ here may read a device value back itself. The engine calls:
 
 A path is built as `Path(eng)` once the engine's own arguments are stored
 (`eng.args`, `params`, `page_size`, `num_pages`, `max_slots`, `mesh`, ..)
-and refuses there what its family cannot do. Adding a family: a functional
-forward under `models/`, a path beside `dense.py`, an entry in `PATHS`.
+and refuses there what its family cannot do. Two classes fill the interface:
+`dense.DensePath` (a mesh, an int8 pool, the verify programs, the page mover)
+and `family.FamilyPath`, written once over a tree of pools and a tree of
+per-slot state for every other family. Adding a family: a functional module
+under `models/` that states the protocol (`models/family_protocol.py`), one
+entry in `PATHS`.
 """
 
 from __future__ import annotations
 
-from paddle_tpu.models.gated_delta_functional import GatedDeltaArgs
-from paddle_tpu.models.hybrid_functional import HybridArgs
-from paddle_tpu.models.latent_delta_functional import LatentDeltaMoEArgs
-from paddle_tpu.models.latent_moe_functional import LatentMoEArgs
+import functools
+
+from paddle_tpu.models import gated_delta_functional as gdf
+from paddle_tpu.models import hybrid_functional as hf
+from paddle_tpu.models import latent_delta_functional as ldf
+from paddle_tpu.models import latent_moe_functional as lm
 from paddle_tpu.models.llama_functional import LlamaArgs
 from paddle_tpu.serving.dense import DensePath
-from paddle_tpu.serving.hybrid import HybridPath
-from paddle_tpu.serving.latent import LatentPath
+from paddle_tpu.serving.family import FamilyPath
 
 __all__ = ["PATHS", "path_for"]
 
-# type of the model description -> the family's device half
-PATHS = {LlamaArgs: DensePath, HybridArgs: HybridPath,
-         GatedDeltaArgs: HybridPath, LatentMoEArgs: LatentPath,
-         LatentDeltaMoEArgs: HybridPath}
+# type of the model description -> the family's device half (the ONE table)
+PATHS = {LlamaArgs: DensePath,
+         hf.HybridArgs: functools.partial(FamilyPath, family=hf),
+         gdf.GatedDeltaArgs: functools.partial(FamilyPath, family=gdf),
+         lm.LatentMoEArgs: functools.partial(FamilyPath, family=lm),
+         ldf.LatentDeltaMoEArgs: functools.partial(FamilyPath, family=ldf)}
 
 
 def path_for(eng):

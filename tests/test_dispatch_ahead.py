@@ -57,7 +57,7 @@ DENSE = lf.LlamaArgs(vocab_size=128, hidden_size=64, intermediate_size=176,
                      rope_theta=10000.0, rms_eps=1e-6, use_flash=False)
 ENGINE = dict(max_slots=3, max_len=128, page_size=8, num_pages=80,
               min_bucket=8, prefill_chunk=16)
-FAMILIES = ["dense", "hybrid", "gated_delta", "latent"]
+PATH_KINDS = ["dense", "hybrid", "gated_delta", "latent"]
 COUNTERS = ("serve.dispatched", "serve.dispatched_ahead", "serve.settled",
             "serve.discarded_rows")
 
@@ -183,7 +183,7 @@ def _spy_programs(path, log):
     path._seat = wrap(path._seat, "seat")
 
 
-@pytest.mark.parametrize("family", FAMILIES)
+@pytest.mark.parametrize("family", PATH_KINDS)
 def test_a_programs_output_is_read_after_the_next_one_went_out(family,
                                                                monkeypatch):
     eng = fresh(family)
@@ -264,7 +264,7 @@ def _serve(eng, family, eos=None):
             for wave in waves for r in wave}
 
 
-@pytest.mark.parametrize("family", FAMILIES)
+@pytest.mark.parametrize("family", PATH_KINDS)
 def test_tokens_are_the_synchronous_loops(family):
     eng = synchronous(fresh(family))
     free = _serve(eng, family)
@@ -362,7 +362,7 @@ def test_a_late_eos_beside_a_live_row_and_a_waiting_request():
 # (d) SETTLE
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("family", FAMILIES)
+@pytest.mark.parametrize("family", PATH_KINDS)
 def test_preempt_and_resume_with_a_program_in_flight(family):
     prompt, other = _ids(family, 13, 41), _ids(family, 9, 42)
     eng = synchronous(fresh(family))
@@ -432,7 +432,7 @@ def test_reset_reads_what_is_in_flight_first():
     assert _idle(eng) and _counters(eng) == dict.fromkeys(COUNTERS, 0)
 
 
-@pytest.mark.parametrize("family", FAMILIES)
+@pytest.mark.parametrize("family", PATH_KINDS)
 def test_a_recording_opens_on_a_program_it_saw_dispatched(family,
                                                           monkeypatch):
     """A profiler session that opens between two calls finds a program in

@@ -38,7 +38,7 @@ from paddle_tpu.kernels import quantized_matmul as qm  # noqa: E402
 from paddle_tpu.models import gated_delta_functional as gdf  # noqa: E402
 from paddle_tpu.models import hybrid_functional as hf  # noqa: E402
 from paddle_tpu.serving import PagedEngine, Request, paths  # noqa: E402
-from paddle_tpu.serving import hybrid  # noqa: E402
+from paddle_tpu.serving import family  # noqa: E402
 
 TOL = 5e-5
 
@@ -364,11 +364,11 @@ class Stepper:
         new = np.zeros(self.P, np.int32)
         touched = self.bt_row[h // B: -(-e // B)]
         new[:len(touched)] = touched
-        # what `serving/hybrid._prefill_traced` does around the family's
+        # what `serving/family._prefill_traced` does around the family's
         # window: the slot's own state, zero where the window starts at 0
         own = jax.tree_util.tree_map(
             lambda a: jnp.where(h == 0, 0.0, a[1]), self.state)
-        logits, self.pools, own = _PREFILL(
+        logits, self.pools, own, _ = _PREFILL(
             self.params, self.layer_ids, jnp.asarray(padded), jnp.int32(h),
             jnp.int32(e - 1 - h), jnp.asarray(self.bt_row), jnp.asarray(new),
             self.pools, own, (), args=self.args)
@@ -379,7 +379,7 @@ class Stepper:
     def step(self, token, t):
         bt = np.zeros((self.SLOTS, self.P), np.int32)
         bt[1] = self.bt_row
-        logits, self.pools, self.state = _DECODE(
+        logits, self.pools, self.state, _ = _DECODE(
             self.params, self.layer_ids, jnp.asarray([0, token], jnp.int32),
             jnp.asarray(bt), jnp.asarray([0, t], jnp.int32),
             jnp.asarray([False, True]), self.pools, self.state, (),
@@ -605,16 +605,17 @@ def test_disaggregated_workers_refuse_the_model(params, args, worker):
 
 
 def test_both_hybrid_families_go_through_the_one_path(params, args):
-    assert paths.PATHS[gdf.GatedDeltaArgs] is paths.PATHS[hf.HybridArgs] \
-        is hybrid.HybridPath
-    assert hybrid.FAMILIES == {hf.HybridArgs: hf, gdf.GatedDeltaArgs: gdf}
+    for described, module in ((gdf.GatedDeltaArgs, gdf), (hf.HybridArgs, hf)):
+        entry = paths.PATHS[described]
+        assert entry.func is family.FamilyPath
+        assert entry.keywords == {"family": module}
     eng = _engine(params, args)
-    assert type(eng.path) is hybrid.HybridPath and eng.path.family is gdf
+    assert type(eng.path) is family.FamilyPath and eng.path.family is gdf
     # what the path moves is a tree: every leaf of the slot's state has the
     # slot axis first, every leaf of the pools the page axis
     assert {a.shape[0] for a in jax.tree_util.tree_leaves(eng.path.state)} \
         == {ENGINE["max_slots"]}
     assert {a.shape[0] for a in jax.tree_util.tree_leaves(eng.path.snaps)} \
-        == {hybrid.SNAPSHOTS}
+        == {family.SNAPSHOTS}
     assert {a.shape[0] for a in jax.tree_util.tree_leaves(eng.path.pools)} \
         == {ENGINE["num_pages"]}

@@ -346,10 +346,10 @@ class Stepper:
         new = np.zeros(self.P, np.int32)
         touched = self.bt_row[h // B: -(-e // B)]
         new[:len(touched)] = touched
-        # what `serving/hybrid._prefill_traced` does around the family's
+        # what `serving/family._prefill_traced` does around the family's
         # window: the slot's own state, zero where the window starts at 0
         own = tuple(jnp.where(h == 0, 0.0, s[1]) for s in self.state)
-        logits, (self.pk, self.pv, self.kc), own = _PREFILL(
+        logits, (self.pk, self.pv, self.kc), own, _ = _PREFILL(
             self.params, self.layer_ids, jnp.asarray(padded), jnp.int32(h),
             jnp.int32(e - 1 - h), jnp.asarray(self.bt_row), jnp.asarray(new),
             (self.pk, self.pv, self.kc), own, (self.cos, self.sin),
@@ -360,7 +360,7 @@ class Stepper:
     def step(self, token, t):
         bt = np.zeros((self.SLOTS, self.P), np.int32)
         bt[1] = self.bt_row
-        logits, (self.pk, self.pv, self.kc), self.state = _DECODE(
+        logits, (self.pk, self.pv, self.kc), self.state, _ = _DECODE(
             self.params, self.layer_ids, jnp.asarray([0, token], jnp.int32),
             jnp.asarray(bt), jnp.asarray([0, t], jnp.int32),
             jnp.asarray([False, True]), (self.pk, self.pv, self.kc),
@@ -534,9 +534,9 @@ def test_the_newest_prompt_gets_a_snapshot_when_all_are_waiting(
     """More finished prompts still decoding than snapshot buffers (the
     serve driver's warm-up at a long chunk): the oldest waiting snapshot
     gives way, and the prompt submitted last is the one that hits."""
-    from paddle_tpu.serving import hybrid
+    from paddle_tpu.serving import family
 
-    monkeypatch.setattr(hybrid.HybridPath, "snapshots", 2)
+    monkeypatch.setattr(family, "SNAPSHOTS", 2)
     base = _ids(12, 5)
     eng = _engine(params, args)
     eng.serve([Request(_ids(n, n), 8) for n in (9, 17, 20)]

@@ -40,7 +40,7 @@ from paddle_tpu.kernels import quantized_matmul as qm  # noqa: E402
 from paddle_tpu.models import latent_delta_functional as ldf  # noqa: E402
 from paddle_tpu.models import latent_moe_functional as lm  # noqa: E402
 from paddle_tpu.serving import PagedEngine, Request, paths  # noqa: E402
-from paddle_tpu.serving import hybrid, latent  # noqa: E402
+from paddle_tpu.serving import family, routing  # noqa: E402
 
 TOL = 1e-4
 
@@ -146,11 +146,11 @@ class Stepper:
         new = np.zeros(self.P, np.int32)
         touched = self.bt_row[h // B: -(-e // B)]
         new[:len(touched)] = touched
-        # what `serving/hybrid._prefill_traced` does around the family's
+        # what `serving/family._prefill_traced` does around the family's
         # window: the slot's own state, zero where the window starts at 0
         own = jax.tree_util.tree_map(
             lambda a: jnp.where(h == 0, 0.0, a[1]), self.state)
-        logits, self.pools, own, picks = _PREFILL(
+        logits, self.pools, own, (_, picks, _) = _PREFILL(
             self.params, self.layer_ids, jnp.asarray(padded), jnp.int32(h),
             jnp.int32(e - 1 - h), jnp.asarray(self.bt_row), jnp.asarray(new),
             self.pools, own, self.tables, args=self.args)
@@ -162,7 +162,7 @@ class Stepper:
     def step(self, token, t):
         bt = np.zeros((self.SLOTS, self.P), np.int32)
         bt[1] = self.bt_row
-        logits, self.pools, self.state, counts, picks = _DECODE(
+        logits, self.pools, self.state, (counts, picks, _) = _DECODE(
             self.params, self.layer_ids, jnp.asarray([0, token], jnp.int32),
             jnp.asarray(bt), jnp.asarray([0, t], jnp.int32),
             jnp.asarray([False, True]), self.pools, self.state, self.tables,
@@ -261,7 +261,7 @@ def _gap(fam, params, req, follow=True):
 @pytest.mark.parametrize("chunk", [None, 16])
 def test_engine_serves_the_references_tokens(fam, params, args, chunk):
     eng = _engine(params, args, prefill_chunk=chunk)
-    assert type(eng.path) is hybrid.HybridPath and eng.path.family is ldf
+    assert type(eng.path) is family.FamilyPath and eng.path.family is ldf
     reqs = eng.serve([Request(_ids(n, n), 6) for n in (2, 9, 45, 100)])
     for r in reqs:
         assert len(r.token_ids) == 6
@@ -275,7 +275,7 @@ def test_engine_serves_the_references_tokens(fam, params, args, chunk):
     path = eng.path
     assert path.state[0]["S"].shape == (3, HV // 2, DK, 2 * DV)
     assert [p.shape for p in path.pools] == [(80, B, ROW)]
-    assert path.tokens.shape == (3 + ldf.RIDERS,)
+    assert path.tokens.shape == (3 + ldf.riders(args)[0],)
     obs = eng.metrics.summary()
     state = 4 * (HV * DK * DV + (K - 1) * C) * 4
     gauges = {k: v["value"] for k, v in obs["gauges"].items()}
@@ -548,22 +548,23 @@ def test_disaggregated_workers_refuse_the_model(params, args, worker):
                                 transport=disagg.LocalTransport(), **ENGINE)
 
 
-def test_the_family_goes_through_the_hybrid_path_and_the_shared_riders(
+def test_the_family_goes_through_the_one_path_and_the_shared_riders(
         params, args):
     """One entry in PATHS, no path of its own: the state tree, snapshots and
-    preempt / resume are `HybridPath`'s, the routing's riders and traces the
-    piece `LatentPath` uses too."""
-    assert paths.PATHS[ldf.LatentDeltaMoEArgs] is hybrid.HybridPath
-    assert hybrid.ROUTED == {ldf.LatentDeltaMoEArgs: ldf}
+    preempt / resume are `FamilyPath`'s, the routing's riders and traces the
+    piece the latent-attention expert family uses too."""
+    entry = paths.PATHS[ldf.LatentDeltaMoEArgs]
+    assert entry.func is paths.PATHS[lm.LatentMoEArgs].func \
+        is family.FamilyPath and entry.keywords == {"family": ldf}
     eng = _engine(params, args)
-    assert type(eng.path.riders) is latent.RoutingRiders
-    assert "RoutingRiders" in latent.LatentPath.__init__.__code__.co_names
+    assert type(eng.path.riders) is routing.RoutingRiders
+    assert ldf.riders(args) == (4, 0) and eng.path.riders.select_rows == 0
     # what the path moves is a tree: every leaf of the slot's state has the
     # slot axis first, every leaf of the pools the page axis
     assert {a.shape[0] for a in jax.tree_util.tree_leaves(eng.path.state)} \
         == {ENGINE["max_slots"]}
     assert {a.shape[0] for a in jax.tree_util.tree_leaves(eng.path.snaps)} \
-        == {hybrid.SNAPSHOTS}
+        == {family.SNAPSHOTS}
     assert {a.shape[0] for a in jax.tree_util.tree_leaves(eng.path.pools)} \
         == {ENGINE["num_pages"]}
 

@@ -120,9 +120,10 @@ def _prefill(params, args, ids, chunks, pool=None, bt_row=None):
         touched = bt_row[h // PS:][:bucket // PS + 1]
         new = np.zeros(P, np.int32)
         new[:len(touched)] = touched
-        logits, pool, _ = lm.prefill_window(
-            params, jnp.asarray(window), jnp.int32(h), jnp.int32(c - 1),
-            jnp.asarray(bt_row), jnp.asarray(new), pool, cos, sin, args)
+        logits, pool, _, _ = lm.prefill_window(
+            params, None, jnp.asarray(window), jnp.int32(h),
+            jnp.int32(c - 1), jnp.asarray(bt_row), jnp.asarray(new), pool,
+            (), (cos, sin), args)
         h += c
         got[h - 1] = np.asarray(logits)
     return got, pool, bt_row
@@ -432,30 +433,33 @@ def test_expert_fused_share_is_the_decode_programs_share():
     program reads 0."""
     from types import SimpleNamespace
 
-    from paddle_tpu.serving.latent import RoutingRiders
+    from paddle_tpu.models import gated_delta_functional as gdf
     from paddle_tpu.serving.metrics import Metrics
 
-    def share(rows):
+    def share(programs):
+        """What the path observes of a decode step (None) or of a window of
+        so many rows."""
         eng = SimpleNamespace(
             args=_expert_args(7168, 2048, 16, 8, 256, 0, 10.0),
             params={"embedding": jnp.zeros((2, 2), jnp.bfloat16)},
-            metrics=Metrics())
-        riders = RoutingRiders(eng)
-        for n in rows:
-            riders.ran(n)
+            metrics=Metrics(), max_slots=64)
+        for rows in programs:
+            seen = lm.observe_decode(eng.args, eng, [0]) if rows is None \
+                else lm.observe_prefill(eng.args, eng, rows)
+            for name, value in seen.items():
+                eng.metrics.observe(name, value)
         return eng.metrics.summary()["observations"][
             "serve.expert_fused_share"]
 
     with qm.fused_dispatch(True, interpret=True):
-        seen = share([64, 2048, 64, 64])
+        seen = share([None, 2048, None, None])
     assert seen["count"] == 4 and seen["mean"] == 0.75
-    seen = share([64, 2048, 64, 64])
+    seen = share([None, 2048, None, None])
     assert seen["count"] == 4 and seen["mean"] == 0.0
     # a family without experts has no such observation
-    eng = SimpleNamespace(args=SimpleNamespace(), metrics=Metrics())
-    RoutingRiders(eng).ran(64)
-    assert "serve.expert_fused_share" not in eng.metrics.summary()[
-        "observations"]
+    assert "serve.expert_fused_share" not in {
+        **gdf.observe_prefill(None, None, 64),
+        **gdf.observe_decode(None, None, [0])}
 
 
 # ---------------------------------------------------------------------------
@@ -538,15 +542,15 @@ def test_the_absorbed_form_equals_the_decompressed_form(fam, params, args):
     new[:2] = bt_row[40 // PS:][:2]
     window = np.zeros(8, np.int32)
     window[0] = ids[40]
-    w_logits, _, _ = lm.prefill_window(
-        params, jnp.asarray(window), jnp.int32(40), jnp.int32(0),
-        jnp.asarray(bt_row), jnp.asarray(new), pool, cos, sin, args)
+    w_logits, *_ = lm.prefill_window(
+        params, None, jnp.asarray(window), jnp.int32(40), jnp.int32(0),
+        jnp.asarray(bt_row), jnp.asarray(new), pool, (), (cos, sin), args)
     bt = np.zeros((2, P), np.int32)
     bt[1] = bt_row
-    d_logits, _, _, _ = lm.decode_step(
-        params, jnp.asarray([0, ids[40]]), jnp.asarray(bt),
-        jnp.asarray([0, 40]), jnp.asarray([False, True]), pool, cos, sin,
-        args)
+    d_logits, *_ = lm.decode_step(
+        params, None, jnp.asarray([0, ids[40]]), jnp.asarray(bt),
+        jnp.asarray([0, 40]), jnp.asarray([False, True]), pool, (),
+        (cos, sin), args)
     np.testing.assert_allclose(d_logits[1], w_logits, atol=TOL)
     np.testing.assert_allclose(d_logits[1], want[40], atol=TOL)
 
@@ -621,10 +625,10 @@ def test_decode_through_the_cache_gives_the_references_logits(
     bt = np.zeros((3, P), np.int32)
     bt[2] = bt_row
     for t in range(n_pre, 50):
-        logits, pool, counts, _ = lm.decode_step(
-            params, jnp.asarray([0, 0, ids[t]]), jnp.asarray(bt),
+        logits, pool, _, (counts, _, _) = lm.decode_step(
+            params, None, jnp.asarray([0, 0, ids[t]]), jnp.asarray(bt),
             jnp.asarray([0, 0, t]), jnp.asarray([False, False, True]), pool,
-            cos, sin, args)
+            (), (cos, sin), args)
         np.testing.assert_allclose(logits[2], want[t], atol=TOL)
     # one live row, two expert layers, every expert held: 6 picks a layer
     assert [int(c) for c in counts[1:3]] == [12, 12]
@@ -654,19 +658,20 @@ def test_decode_with_the_fused_expert_pass_gives_the_references_logits(
     bt[5] = bt_row
     live = np.arange(8) == 5
     # one jitted program a form: the form is decided as it is traced
-    step = {fused: jax.jit(lambda *a: lm.decode_step(*a, args))
+    step = {fused: jax.jit(lambda *a: lm.decode_step(*a, (), (cos, sin),
+                                                     args))
             for fused in (True, False)}
     pools = {True: pool, False: pool}
     for t in range(20, 30):
-        operands = (params, jnp.asarray(np.where(live, ids[t], 0)),
+        operands = (params, None, jnp.asarray(np.where(live, ids[t], 0)),
                     jnp.asarray(bt), jnp.asarray(np.where(live, t, 0)),
                     jnp.asarray(live))
         got = {}
         for fused in (True, False) if group is not None else (True,):
             with qm.fused_dispatch(fused, interpret=True):
                 assert lm.experts_fused(8, args, jnp.float32) == fused
-                logits, pools[fused], counts, _ = step[fused](
-                    *operands, pools[fused], cos, sin)
+                logits, pools[fused], _, (counts, _, _) = step[fused](
+                    *operands, pools[fused])
             got[fused] = np.asarray(logits[5])
         if group is None:
             np.testing.assert_allclose(got[True], want[t], atol=TOL)
@@ -765,7 +770,7 @@ def test_a_request_carries_the_experts_its_tokens_picked(fam, params, args):
     assert (table[:16] == -1).all() and (table[16:] >= 0).all()
     # the slot's next owner decodes at positions the first request also
     # had: its rows of the step log are not the first request's
-    assert eng.max_slots == 3 and len(eng.path._log) > 6
+    assert eng.max_slots == 3 and len(eng.path.riders.log) > 6
     np.testing.assert_array_equal(
         np.sort(req.routing.table(len(seq)), -1), _own_picks(fam, params, seq))
 
@@ -893,7 +898,9 @@ def test_preempt_and_resume_carry_the_pages(params, args):
     slot = next(s for s in eng.slots.active_slots
                 if eng.slots.owner(s) is req)
     state = eng.preempt(slot)
-    assert state["path_state"] is None and state["pages"]
+    # nothing beside its pages leaves with it: an empty state tree
+    assert not jax.tree_util.tree_leaves(state["path_state"])
+    assert state["pages"]
     other = Request(_ids(15, 9), 4)
     eng.serve([other])                  # the slot is used meanwhile
     assert eng.can_resume(state)
@@ -914,13 +921,13 @@ def test_routing_is_recorded_only_where_the_description_asks(params, args):
     eng = PagedEngine(params, args._replace(record_routing=False), **ENGINE)
     req = Request(_ids(21, 8), 5)
     eng.serve([req])
-    assert getattr(req, "routing", None) is None and eng.path._log == []
+    assert getattr(req, "routing", None) is None and eng.path.riders.log == []
     asked = Request(_ids(21, 8), 5)
     eng = PagedEngine(params, args, **ENGINE)
     eng.serve([asked, Request(_ids(9, 1), 3)])
     assert list(asked.token_ids) == list(req.token_ids)
     # one log entry a decode step, whatever the rows
-    assert len(eng.path._log) == eng.metrics.summary()["counters"][
+    assert len(eng.path.riders.log) == eng.metrics.summary()["counters"][
         "decode_steps"]
 
 

@@ -117,13 +117,21 @@ def _programs(args, fused):
     """The two step programs jitted (run op by op, a test's hundreds of
     small executables exhaust the process's memory maps), one pair a
     description and a dispatch mode."""
-    def prefill(*a):
+    def prefill(params, *a):
+        """(params, ids, h, last_idx, bt_row, new_pages, cache, cos, sin,
+        record) -> (logits, cache, what rides)"""
         with qm.fused_dispatch(fused, interpret=True):
-            return lm.prefill_window(*a[:-1], args, a[-1])
+            logits, cache, _, riders = lm.prefill_window(
+                params, None, *a[:6], (), a[6:8], args, a[8])
+        return logits, cache, riders
 
-    def decode(*a):
+    def decode(params, *a):
+        """(params, tokens, bt, pos, live, cache, cos, sin, record) ->
+        (logits, cache, what rides)"""
         with qm.fused_dispatch(fused, interpret=True):
-            return lm.decode_step(*a[:-1], args, a[-1])
+            logits, cache, _, riders = lm.decode_step(
+                params, None, *a[:5], (), a[5:7], args, a[7])
+        return logits, cache, riders
 
     return jax.jit(prefill), jax.jit(decode)
 
@@ -148,7 +156,7 @@ def _through_the_cache(params, args, ids, chunks, record=0, fused=False):
         touched = bt_row[h // PS:][:bucket // PS + 1]
         new = np.zeros(P, np.int32)
         new[:len(touched)] = touched
-        logits, cache, *_ = prefill(
+        logits, cache, _ = prefill(
             params, jnp.asarray(window), jnp.int32(h), jnp.int32(c - 1),
             jnp.asarray(bt_row), jnp.asarray(new), cache, cos, sin,
             jnp.int32(0))
@@ -157,13 +165,13 @@ def _through_the_cache(params, args, ids, chunks, record=0, fused=False):
     bt = np.zeros((2, P), np.int32)
     bt[0] = bt_row
     for t in range(h, len(ids)):
-        logits, cache, _, _, *sel = decode(
+        logits, cache, riders = decode(
             params, jnp.asarray([ids[t], 0]), jnp.asarray(bt),
             jnp.asarray([t, 0], jnp.int32), jnp.asarray([True, False]),
             cache, cos, sin, jnp.int32(record))
         got[t] = np.asarray(logits[0])
-        if sel:
-            picked[t] = _positions(sel[0])
+        if riders.selection is not None:
+            picked[t] = _positions(riders.selection)
     return got, picked
 
 
@@ -232,7 +240,7 @@ def test_the_recorded_selection_is_the_references_top_k(fam, params, args):
     bt_row[:6] = 1 + np.arange(6)
     new = np.zeros(P, np.int32)
     new[:5] = bt_row[:5]
-    *_, sel = _programs(args, False)[0](
+    *_, (_, _, sel) = _programs(args, False)[0](
         params, jnp.asarray(ids[:32]), jnp.int32(0), jnp.int32(31),
         jnp.asarray(bt_row), jnp.asarray(new), _cache(args), cos, sin,
         jnp.int32(9))
@@ -409,7 +417,7 @@ def _served(fam, params, req, arch=ARCH):
 @pytest.mark.parametrize("chunk", [None, 16])
 def test_engine_serves_the_references_tokens(fam, params, args, chunk):
     eng = PagedEngine(params, args, **dict(ENGINE, prefill_chunk=chunk))
-    latent, index = eng.path.pool
+    latent, index = eng.path.pools
     assert latent.shape == (3 * 80, PS, 128) and index.shape == (3 * 80, PS,
                                                                   16)
     reqs = [Request(_ids(n, n), 6) for n in (20, 37, 9, 50)]
@@ -462,7 +470,7 @@ def test_a_prefix_hit_that_ends_mid_page_copies_both_pools(params, args):
     cold_eng.serve([cold])
     assert list(warm.token_ids) == list(cold.token_ids)
     # the copied page holds the shared positions' rows of BOTH pools
-    for pools in (eng.path.pool, cold_eng.path.pool):
+    for pools in (eng.path.pools, cold_eng.path.pools):
         assert all(float(jnp.abs(p).sum()) > 0 for p in pools)
     for t, sel in warm.routing.selections(49):
         if t >= 12:
@@ -480,7 +488,9 @@ def test_preempt_and_resume_carry_the_pages_of_both_pools(params, args):
     slot = next(s for s in eng.slots.active_slots
                 if eng.slots.owner(s) is req)
     state = eng.preempt(slot)
-    assert state["path_state"] is None and state["pages"]
+    # nothing beside its pages leaves with it: an empty state tree
+    assert not jax.tree_util.tree_leaves(state["path_state"])
+    assert state["pages"]
     eng.serve([Request(_ids(15, 9), 4)])    # the slot is used meanwhile
     assert eng.can_resume(state)
     eng.resume(state)
@@ -498,7 +508,7 @@ def test_a_reset_engine_serves_again_with_a_cold_cache(params, args):
     first = Request(_ids(29, 3), 4)
     eng.serve([first])
     eng.reset()
-    assert eng.path._log == [] and eng.path._steps == 0
+    assert eng.path.riders.log == [] and eng.path.riders._steps == 0
     again = Request(_ids(29, 3), 4)
     eng.serve([again])
     assert list(first.token_ids) == list(again.token_ids)
@@ -527,8 +537,8 @@ def test_window_bucket_below_a_page_crosses_the_page(fam, params, args,
     eng = PagedEngine(params, args, min_bucket=4, **kw)
     # a position nobody wrote holds anything: here, what would win every
     # softmax and every selection it entered
-    eng.path.pool = jax.tree.map(lambda a: jnp.full_like(a, 30.0),
-                                 eng.path.pool)
+    eng.path.pools = jax.tree.map(lambda a: jnp.full_like(a, 30.0),
+                                  eng.path.pools)
     reqs = eng.serve([Request(p, n) for p, n in zip(prompts, (2, 6))])
     assert eng.metrics.summary()["counters"]["prefix_tokens_hit"] == 28
     assert [list(r.token_ids) for r in reqs] == want

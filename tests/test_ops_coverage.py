@@ -4,6 +4,8 @@ methodology (`test/legacy_test/op_test.py`) over the TPU build's op surface.
 Also locks the coverage number from tools/op_manifest.py.
 """
 
+import os
+
 import numpy as np
 import pytest
 
@@ -11,6 +13,11 @@ import paddle_tpu as paddle
 import paddle_tpu.nn.functional as F
 
 from op_test import OpTest
+
+# the checkout of the system this repo was modelled on: not on every machine
+needs_reference = pytest.mark.skipif(
+    not os.path.isdir("/root/reference"),
+    reason="reads /root/reference, which is not on this machine")
 
 rng = np.random.default_rng(7)
 
@@ -338,6 +345,7 @@ class TestDistributionPkg(OpTest):
         assert abs(draws.mean() - 0.8) < 0.05
 
 
+@needs_reference
 def test_manifest_coverage_locked():
     """The checked-in coverage report must stay truthful and >= the bar."""
     import importlib.util
@@ -577,6 +585,7 @@ class TestR4AuditOps(OpTest):
         assert abs(t.numpy().mean() - 0.5) < 0.1
 
 
+@needs_reference
 def test_op_schema_spine():
     """The schema registry (tools/op_schema.py — the TPU build's analogue
     of the reference's single-YAML codegen spine, SURVEY §2.3 L4): parses
@@ -909,6 +918,7 @@ def test_r5_review_semantics_fixes():
     np.testing.assert_allclose(data.device_for_op("relu6"), 2.0)
 
 
+@needs_reference
 def test_op_schema_default_conformance():
     """Default-VALUE conformance against ops.yaml (r5: the drift class
     signature-name conformance can't catch — a wrapper silently shipping a

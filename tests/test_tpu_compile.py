@@ -469,8 +469,8 @@ def test_latent_decode_program_copies_no_experts_and_no_pool(one_chip):
 
     def decode(params, tokens, bt, pos, live, pool, cos, sin):
         with qm.fused_dispatch(True):
-            return lm.decode_step(params, tokens, bt, pos, live, pool, cos,
-                                  sin, args)
+            return lm.decode_step(params, None, tokens, bt, pos, live, pool,
+                                  (), (cos, sin), args)
 
     compiled = jax.jit(decode, donate_argnums=(5,)).lower(
         params, sds((b,), jnp.int32), sds((b, P), jnp.int32),
@@ -489,8 +489,8 @@ def test_latent_decode_program_copies_no_experts_and_no_pool(one_chip):
 
     def prefill(params, ids, at, last, bt_row, new_pages, pool, cos, sin):
         with qm.fused_dispatch(True):
-            return lm.prefill_window(params, ids, at, last, bt_row, new_pages,
-                                     pool, cos, sin, args)
+            return lm.prefill_window(params, None, ids, at, last, bt_row,
+                                     new_pages, pool, (), (cos, sin), args)
 
     text = jax.jit(prefill, donate_argnums=(6,)).lower(
         params, sds((512,), jnp.int32), sds((), jnp.int32),
@@ -549,8 +549,8 @@ def test_selector_programs_compile_at_the_glm5_widths(one_chip):
 
     def decode(params, tokens, bt, pos, live, cache, cos, sin, record):
         with qm.fused_dispatch(True):
-            return lm.decode_step(params, tokens, bt, pos, live, cache, cos,
-                                  sin, args, record)
+            return lm.decode_step(params, None, tokens, bt, pos, live,
+                                  cache, (), (cos, sin), args, record)
 
     compiled = jax.jit(decode, donate_argnums=(5,)).lower(
         params, sds((b,), jnp.int32), sds((b, P), jnp.int32),
@@ -566,8 +566,9 @@ def test_selector_programs_compile_at_the_glm5_widths(one_chip):
     def prefill(params, ids, at, last, bt_row, new_pages, cache, cos, sin,
                 record):
         with qm.fused_dispatch(True):
-            return lm.prefill_window(params, ids, at, last, bt_row, new_pages,
-                                     cache, cos, sin, args, record)
+            return lm.prefill_window(params, None, ids, at, last, bt_row,
+                                     new_pages, cache, (), (cos, sin), args,
+                                     record)
 
     compiled = jax.jit(prefill, donate_argnums=(6,)).lower(
         params, sds((2048,), jnp.int32), sds((), jnp.int32),
@@ -646,7 +647,7 @@ def test_delta_step_kernel_compiles_for_v5e(one_chip, name):
 def test_latent_delta_decode_program_at_the_gigachat_widths(one_chip):
     """GigaChat 3.5's decode program at the cell's widths, slots and pools
     (all five layers: delta + dense, latent + experts, 3 x delta + experts),
-    pools and state donated as `HybridPath` donates them: a kernel
+    pools and state donated as `FamilyPath` donates them: a kernel
     `delta_rule_step` a delta layer under `pt.delta_rule`, its `[64, 64,
     128, 128]` float32 state aliased in and out and nothing of that size
     copied or computed; the latent decode kernel once; the routed experts
@@ -660,7 +661,7 @@ def test_latent_delta_decode_program_at_the_gigachat_widths(one_chip):
 
     from benchmarks.harness import weights
     from paddle_tpu.models import latent_delta_functional as ldf
-    from paddle_tpu.serving import hybrid
+    from paddle_tpu.serving import family
     from paddle_tpu.serving.metrics import Metrics
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -691,10 +692,10 @@ def test_latent_delta_decode_program_at_the_gigachat_widths(one_chip):
     assert state[0]["S"].shape == (64, 64, 128, 128)
     with qm.fused_dispatch(True):
         compiled = jax.jit(
-            functools.partial(hybrid._decode_traced, family=ldf, args=args,
+            functools.partial(family._decode_traced, family=ldf, args=args,
                               metrics=Metrics()),
             donate_argnums=(6, 7)).lower(
-            params, sds((5,)), sds((slots + ldf.RIDERS,)),
+            params, sds((5,)), sds((slots + ldf.riders(args)[0],)),
             sds((slots, max_len // ps)), sds((slots,)),
             sds((slots,), jnp.bool_), pools, state, tables,
             sds((), jnp.float32), sds((), jnp.float32), sds(()),
@@ -716,7 +717,7 @@ def test_latent_delta_decode_program_at_the_gigachat_widths(one_chip):
 def test_delta_decode_program_holds_one_copy_of_the_state(one_chip):
     """Olmo-Hybrid's decode program at the cell's widths and slots (one
     period of its layers: 3 linear + 1 full), pools and state donated as
-    `HybridPath` donates them: a kernel `delta_rule_step` a linear layer
+    `FamilyPath` donates them: a kernel `delta_rule_step` a linear layer
     under `pt.delta_rule`, its state aliased in and out, and no operation
     that copies or computes a `[64, 15, 96, 384]` float32 array (the jnp
     step's two fusions a layer were that)."""
@@ -727,7 +728,7 @@ def test_delta_decode_program_holds_one_copy_of_the_state(one_chip):
 
     from benchmarks.harness import weights
     from paddle_tpu.models import gated_delta_functional as gdf
-    from paddle_tpu.serving import hybrid
+    from paddle_tpu.serving import family
     from paddle_tpu.serving.metrics import Metrics
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -758,7 +759,7 @@ def test_delta_decode_program_holds_one_copy_of_the_state(one_chip):
     assert state[0]["S"].shape == (64, 15, 96, 384)
     with qm.fused_dispatch(True):
         compiled = jax.jit(
-            functools.partial(hybrid._decode_traced, family=gdf, args=args,
+            functools.partial(family._decode_traced, family=gdf, args=args,
                               metrics=Metrics()),
             donate_argnums=(6, 7)).lower(
             params, sds((4,)), sds((slots,)), sds((slots, P)), sds((slots,)),
